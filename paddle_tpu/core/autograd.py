@@ -123,14 +123,13 @@ def _needs_grad(t) -> bool:
     return (not t.stop_gradient) and jnp.issubdtype(t.dtype, jnp.inexact)
 
 
-def _x64_off_scope():
-    if jax.config.jax_enable_x64:
-        # jax.enable_x64(False) was removed upstream; the experimental
-        # context manager is the surviving spelling of a scoped x64-off
-        from jax.experimental import disable_x64
-        return disable_x64()
-    import contextlib
-    return contextlib.nullcontext()
+def x64_off_scope():
+    """Scoped x64-off dtype promotion — the one spelling in the repo.
+    paddle_tpu turns ``jax_enable_x64`` on for the whole process (int64 and
+    float64 are real paddle dtypes); Pallas kernels and the SPMD-partitioned
+    scan step must trace with int32 Python scalars instead (Mosaic has no
+    i64 index type, and XLA's partitioner rejects s64/s32 compares)."""
+    return jax.enable_x64(False)
 
 
 def apply(prim: Callable, *inputs, op_name: str = "", n_outputs: int | None = None,
@@ -155,7 +154,7 @@ def apply(prim: Callable, *inputs, op_name: str = "", n_outputs: int | None = No
         inner = fn
 
         def fn(*a):
-            with _x64_off_scope():
+            with x64_off_scope():
                 return inner(*a)
 
     if not record:
@@ -169,7 +168,7 @@ def apply(prim: Callable, *inputs, op_name: str = "", n_outputs: int | None = No
     out, raw_vjp_fn = jax.vjp(fn, *arrays)
     if x64_off:
         def vjp_fn(cts, _raw=raw_vjp_fn):
-            with _x64_off_scope():
+            with x64_off_scope():
                 return _raw(cts)
     else:
         vjp_fn = raw_vjp_fn

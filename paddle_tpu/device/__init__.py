@@ -48,8 +48,8 @@ _current_device = None
 
 
 def _backend_kind() -> str:
-    plat = jax.default_backend()
-    return "cpu" if plat == "cpu" else "tpu"
+    """The platform as JAX reports it ("cpu", "tpu", ...) — never renamed."""
+    return jax.default_backend()
 
 
 def set_device(device):
@@ -100,8 +100,7 @@ def _place_of(arr) -> Place:
     try:
         devs = arr.devices()
         d = next(iter(devs))
-        kind = "cpu" if d.platform == "cpu" else "tpu"
-        return Place(kind, d.id)
+        return Place(d.platform, d.id)
     except Exception:
         return Place(_backend_kind(), 0)
 

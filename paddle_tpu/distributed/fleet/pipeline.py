@@ -26,7 +26,6 @@ import time
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.framework.jax_compat import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.observability import metrics
@@ -271,7 +270,7 @@ def spmd_pipeline_interleaved(stage_fn, n_stages, n_chunks, n_micro,
         # typed keys are rewrapped inside per_rank
         extra = (jax.random.key_data(rng_key),)
         extra_specs = (P(),)
-    f = _shard_map(
+    f = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(tuple(P("pp") for _ in stacked_params), P()) + extra_specs,
         out_specs=P(), axis_names={"pp"},

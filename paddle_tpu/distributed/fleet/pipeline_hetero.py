@@ -59,7 +59,6 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.framework.jax_compat import shard_map as _shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from paddle_tpu.distributed.fleet.pipeline import (
@@ -235,7 +234,7 @@ def spmd_pipeline_hetero(stage_fns, n_stages, n_micro, packed_params,
     if rng_key is not None:
         extra = (jax.random.key_data(rng_key),)
         extra_specs = (P(),)
-    f = _shard_map(
+    f = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(tmap(lambda _: P("pp", None), packed_params),
                   tmap(lambda _: P("pp", None), packed_bufs),

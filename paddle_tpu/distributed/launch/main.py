@@ -6,9 +6,15 @@ Counterpart of `python -m paddle.distributed.launch`
 subprocesses with `PADDLE_TRAINER_*` env and per-rank log files, a rendezvous
 master address, and a watch loop that tears the pod down on failure.
 
-TPU-native differences: one process per HOST (a process owns all its local
-chips via one jax runtime), so ``--nproc_per_node`` defaults to 1 and is only
-raised for CPU-backend simulation/testing; the rendezvous "store" is the JAX
+TPU-native differences: one process per HOST. A chip belongs to one process
+at a time and nothing here limits a child to its own chip, so the way to use
+the four chips of one host is ONE process that owns them all through one jax
+runtime (a mesh over ``jax.devices()`` — what ``chip_smoke.py --four-chips``
+does). ``--nproc_per_node`` therefore defaults to 1 and is refused above 1
+unless the workers run on the CPU platform (``--backend cpu`` or
+``JAX_PLATFORMS=cpu``), where it simulates several hosts on one. The
+launcher itself never initialises a jax backend: a parent that held the
+chip would starve its children. The rendezvous "store" is the JAX
 coordination service that ``init_parallel_env`` joins via
 ``jax.distributed.initialize`` (coordinator = ``PADDLE_MASTER``).
 
@@ -214,6 +220,12 @@ def launch(argv=None):
     extra = argv[split:]
     if not extra:
         parser.error("no training script given")
+    worker_platform = args.backend or os.environ.get("JAX_PLATFORMS", "")
+    if args.nproc_per_node > 1 and worker_platform != "cpu":
+        parser.error(
+            "--nproc_per_node > 1 needs CPU workers (--backend cpu): on an "
+            "accelerator the first child takes every local chip and the "
+            "others fail or hang; one process drives all chips of a host")
     if args.nnodes > 1 and args.master is None:
         parser.error("--master host:port is required when nnodes > 1 "
                      "(every node must rendezvous at the same coordinator)")

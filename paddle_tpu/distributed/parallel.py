@@ -66,11 +66,15 @@ def init_parallel_env():
     if _initialized:
         return ParallelEnv()
     env = ParallelEnv()
+    if "PADDLE_TRAINER_ID" in os.environ:
+        # a worker started by a launcher: ranks and restarts share one
+        # persistent compilation cache
+        from paddle_tpu.framework import compile_cache
+        compile_cache.enable()
     # NOTE: jax.process_count() would initialise the XLA backend, after which
     # jax.distributed.initialize refuses to run — consult the distributed
     # client state instead
-    from paddle_tpu.framework.jax_compat import distributed_is_initialized
-    already_joined = distributed_is_initialized()
+    already_joined = jax.distributed.is_initialized()
     if env.world_size > 1 and not already_joined:
         coordinator = os.environ.get("PADDLE_MASTER") or (
             env.trainer_endpoints[0] if env.trainer_endpoints else None)

@@ -93,12 +93,19 @@ def shard_parameters(model, mesh=None, axis="dp"):
     return model
 
 
+def host_memory_kind(devices):
+    """``"pinned_host"`` where the devices have a distinct host memory tier
+    (TPU), else None: a CPU device reports its ONLY memory as
+    ``unpinned_host``, so there is nothing to offload to and state stays in
+    default memory (numerics unchanged)."""
+    kinds = {m.kind for d in devices for m in d.addressable_memories()}
+    return "pinned_host" if "pinned_host" in kinds else None
+
+
 def _host_device_shardings(shape, mesh, axis):
-    """(host, device) sharding pair for one state array. On backends with no
-    distinct host tier (CPU: only ``unpinned_host``) the host sharding IS the
-    device sharding — offload degrades to a no-op instead of a PJRT error,
-    so the CPU dryrun/gate can still check stage-3 numerics."""
-    from paddle_tpu.framework.jax_compat import host_memory_kind
+    """(host, device) sharding pair for one state array. Without a host
+    tier (:func:`host_memory_kind` is None) the host sharding IS the device
+    sharding, so the CPU tests still check stage-3 numerics."""
     if mesh is not None:
         kind = host_memory_kind(mesh.devices.flat)
         spec = _shard_spec_for(shape, mesh, axis)

@@ -948,15 +948,19 @@ def _fndef(name, names, body):
 _CONVERT_SEQ = 0
 
 
-def _range_is_builtin(fn) -> bool:
-    """Does the bare name ``range`` resolve to the builtin inside ``fn``?
-    Resolution order mirrors the interpreter's: function locals (any local
-    assignment or parameter named ``range`` makes it local for the WHOLE
-    body), closure cells, then globals, then builtins. Anything that cannot
-    be proven to be the builtin counts as shadowed — the rewrite must never
-    apply builtin-range semantics to a user's own ``range``."""
+def _range_is_builtin(fn, fdef) -> bool:
+    """Does the bare name ``range`` resolve to the builtin inside ``fn``
+    (whose parsed def is ``fdef``)? Resolution order mirrors the
+    interpreter's: function locals (any local assignment or parameter named
+    ``range`` makes it local for the WHOLE body), closure cells, then
+    globals, then builtins. Anything that cannot be proven to be the builtin
+    counts as shadowed — the rewrite must never apply builtin-range
+    semantics to a user's own ``range``. Locals are read off the AST, not
+    ``co_varnames``: Python 3.12 inlines comprehensions (PEP 709), so a
+    comprehension target named ``range`` shows up there although it binds
+    nothing in the function's scope."""
     code = fn.__code__
-    if "range" in code.co_varnames or "range" in code.co_cellvars:
+    if _scope_shadows_range(fdef):
         return False                     # local (param or body assignment)
     if "range" in code.co_freevars:
         try:
@@ -982,7 +986,8 @@ def convert_to_static(fn):
     fdef = tree.body[0]
     # drop decorators — we are already below them
     fdef.decorator_list = []
-    _ForToWhileRewriter(rewrite_range=_range_is_builtin(fn)).visit(fdef)
+    _ForToWhileRewriter(
+        rewrite_range=_range_is_builtin(fn, fdef)).visit(fdef)
     esc = _EscapeRewriter()
     esc.visit(fdef)
     if esc.flag_names:

@@ -416,7 +416,7 @@ class StaticFunction:
         """k steps per dispatch: `lax.scan` over the captured step.
 
         Amortizes the fixed per-dispatch cost (measured 5-10 ms/call through
-        the TPU runtime, docs/PERF.md) across k steps: the returned callable
+        the TPU runtime, PERF.md) across k steps: the returned callable
         takes the SAME arguments as the step function but with an extra
         leading axis of size k (one slice per step), runs all k steps inside
         ONE compiled, donated XLA program, and returns outputs stacked along

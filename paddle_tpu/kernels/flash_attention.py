@@ -13,12 +13,13 @@ Layout note: paddle uses [B, S, H, D]; the pallas op uses [B, H, S, D].
 """
 from __future__ import annotations
 
-import contextlib
 import functools
 import math
 
 import jax
 import jax.numpy as jnp
+
+from paddle_tpu.core.autograd import x64_off_scope
 
 _PALLAS_OK = None
 
@@ -26,22 +27,10 @@ _PALLAS_OK = None
 def _try_pallas():
     global _PALLAS_OK, _fa_mod
     if _PALLAS_OK is None:
-        try:
-            from jax.experimental.pallas.ops.tpu import flash_attention as _m
-            _fa_mod = _m
-            _PALLAS_OK = jax.default_backend() == "tpu"
-        except Exception:
-            _PALLAS_OK = False
+        from jax.experimental.pallas.ops.tpu import flash_attention as _m
+        _fa_mod = _m
+        _PALLAS_OK = jax.default_backend() == "tpu"
     return _PALLAS_OK
-
-
-def _x64_off():
-    """Pallas kernels mix int32 iota with weakly-typed python ints, which
-    breaks under jax_enable_x64 (paddle enables x64 globally for int64 tensor
-    semantics) — trace them under x64-disabled promotion rules. Single shared
-    helper lives in autograd (also used by apply(x64_off=True))."""
-    from paddle_tpu.core.autograd import _x64_off_scope
-    return _x64_off_scope()
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
@@ -54,7 +43,7 @@ def _pallas_flash_fwd(q, k, v, causal, sm_scale):
     _try_pallas()
     bs = _fa_mod.BlockSizes.get_default(
         q.shape[0], q.shape[1], q.shape[2], k.shape[2], q.shape[3])
-    with _x64_off():
+    with x64_off_scope():
         o, res = _fa_mod._flash_attention_fwd(
             q, k, v, None, None, False, causal, sm_scale, bs, False)
     return o, res
@@ -65,7 +54,7 @@ def _pallas_flash_bwd(causal, sm_scale, res, do):
     q, k = res[0], res[1]
     bs = _fa_mod.BlockSizes.get_default(
         q.shape[0], q.shape[1], q.shape[2], k.shape[2], q.shape[3])
-    with _x64_off():
+    with x64_off_scope():
         dq, dk, dv, _, _ = _fa_mod._flash_attention_bwd(
             False, causal, sm_scale, bs, False, res, do)
     return dq, dk, dv
@@ -138,7 +127,7 @@ def _splash_kernel(n_heads, S, causal):
     if key not in _SPLASH_CACHE:
         from jax.experimental.pallas.ops.tpu.splash_attention import (
             splash_attention_kernel as sk, splash_attention_mask as sm)
-        with jax.ensure_compile_time_eval(), _x64_off():
+        with jax.ensure_compile_time_eval(), x64_off_scope():
             mask = sm.MultiHeadMask(
                 [sm.CausalMask((S, S)) if causal else sm.FullMask((S, S))
                  for _ in range(n_heads)])
@@ -345,6 +334,7 @@ def flash_attention_fn(causal=False, scale=None):
     """Returns a pure fn(q, k, v) on paddle-layout [B, S, H, D] tensors."""
 
     def fn(q, k, v):
+        from paddle_tpu.distributed.mesh import get_mesh
         from paddle_tpu.framework.flags import flag_value
         from paddle_tpu.kernels import registry
         # -> [B, H, S, D]
@@ -355,6 +345,10 @@ def flash_attention_fn(causal=False, scale=None):
         tileable = (_try_pallas() and S % 128 == 0 and D % 64 == 0
                     and S == kt.shape[2]
                     and qt.dtype in (jnp.float32, jnp.bfloat16))
+        # under an installed multi-device mesh this trace becomes a program
+        # GSPMD partitions, which the Pallas arms cannot join
+        mesh = get_mesh()
+        partitioned = mesh is not None and mesh.size > 1
 
         def winner():
             # measured selection, cached per (backend, shape, dtype,
@@ -367,12 +361,13 @@ def flash_attention_fn(causal=False, scale=None):
                 tuple(qt.shape), tuple(kt.shape), qt.dtype, causal,
                 tileable,
                 lambda i, q_, k_, v_: _impl_call(i, q_, k_, v_, causal,
-                                                 scale, tileable))
+                                                 scale, tileable),
+                partitioned=partitioned)
 
         impl = registry.dispatch(
             "flash_attention", forced=flag_value("tpu_flash_impl"),
             ctx={"tileable": tileable, "shape_q": tuple(qt.shape),
-                 "shape_k": tuple(kt.shape)},
+                 "shape_k": tuple(kt.shape), "partitioned": partitioned},
             winner=winner)
         out = _impl_call(impl, qt, kt, vt, causal, scale, tileable)
         return jnp.swapaxes(out, 1, 2)

@@ -21,13 +21,15 @@ contract (token-identical output, enforced by parity tests):
   HBM traffic and FLOPs scale with the pool's capacity (`pages_per_slot`),
   not the live lengths.
 - **pallas** — the authored ragged paged-attention kernel
-  (`kernels/pallas/paged_attention.py`): grid over (sequence, head),
-  double-buffered page DMA, page loop bounded by ``ceil((pos+1)/page_size)``
-  so traffic scales with each sequence's true length.
+  (`kernels/pallas/paged_attention.py`): grid over sequences,
+  double-buffered whole-page DMA, page loop bounded by
+  ``ceil((pos+1)/page_size)`` so page traffic scales with each sequence's
+  true length (its pool view costs a relayout per call today — see the
+  kernel's "Layout" note).
 
 ``FLAGS_tpu_paged_impl`` picks: ``auto`` (measured winner per signature on
 real TPU via the kernel registry + `kernels/autotune.py`, xla elsewhere —
-backend viability is decided by NAME/probe, `kernels/pallas/_compat.py`),
+backend viability is decided by NAME, `kernels/autotune.py`),
 ``xla``, or ``pallas`` (interpret mode off-TPU: parity tests only). Every
 selection routes through `kernels/registry.py::dispatch` and is counted
 per program build in ``kernel.dispatch.paged_attention.{xla|pallas}``
@@ -185,7 +187,8 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
                                   k_scale=ones, v_scale=ones)
         return paged_winner(q.shape[0], page_table.shape[1],
                             k_pages.shape[1], q.shape[1], q.shape[2],
-                            q.dtype, run, variant=variant)
+                            q.dtype, run, variant=variant,
+                            num_pages=k_pages.shape[0])
 
     impl = registry.dispatch("paged_attention", forced=forced,
                              winner=winner)
@@ -237,14 +240,15 @@ def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,
 
 
 def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
-                 quant=False, parity=True) -> str:
+                 quant=False, parity=True, num_pages=None) -> str:
     """Resolve (and COUNT) the prefill-attention impl for one program
     build — the registry is the only selector (`kernels/registry.py`;
     ``FLAGS_tpu_prefill_impl`` forces, ``auto`` measures via
     `autotune.prefill_winner`). ``parity=False`` marks a call whose XLA
     arm does NOT read the page pool (the one-shot `prefill_step` over a
     narrowing pool dtype), which drops the pallas candidate rather than
-    silently changing numerics."""
+    silently changing numerics. ``num_pages`` is the pool's size, which
+    the measurement reproduces (`autotune.paged_winner`)."""
     from paddle_tpu.kernels import registry
     try:
         from paddle_tpu.framework.flags import flag_value
@@ -265,7 +269,8 @@ def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
                     impl_, q_, kp_.astype(jnp.int8), vp_.astype(jnp.int8),
                     row_, start_, valid_, k_scale=ones, v_scale=ones)
         return prefill_winner(chunk, pages_per_slot, page_size, nh, dh,
-                              dtype, run, variant=variant, parity=parity)
+                              dtype, run, variant=variant, parity=parity,
+                              num_pages=num_pages)
 
     return registry.dispatch("prefill_attention", forced=forced,
                              ctx={"parity": parity}, winner=winner)
@@ -289,7 +294,8 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid,
     """
     impl = prefill_impl(q.shape[1], page_table.shape[0], k_pages.shape[1],
                         q.shape[2], q.shape[3], q.dtype,
-                        quant=k_scale is not None)
+                        quant=k_scale is not None,
+                        num_pages=k_pages.shape[0])
     return _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start,
                               valid, k_scale=k_scale, v_scale=v_scale)
 

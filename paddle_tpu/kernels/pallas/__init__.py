@@ -10,11 +10,11 @@ Kernels:
   (≈ `fused_attention_op.cu` but flash; the reference has NO flash kernel,
   SURVEY §5.7).
 - :mod:`paged_attention` — ragged paged-attention decode step (arxiv
-  2604.15464): grid over (sequence, head), double-buffered page DMA, page
+  2604.15464): grid over sequences, double-buffered whole-page DMA, page
   loop bounded by each sequence's true length. The serving engine's hot
   kernel (`FLAGS_tpu_paged_impl`).
 - :mod:`prefill_attention` — the ragged PREFILL twin (r15): grid over
-  (chunk-row block, head), scalar-prefetched (start, valid), page walk
+  chunk-row blocks, scalar-prefetched (start, valid), page walk
   bounded by the request's true uncached tail — chunked prefill, prefix
   tails, and the PTKS1 prefill-worker stream all ride it
   (`FLAGS_tpu_prefill_impl`, selection in `kernels/registry.py`).
@@ -24,7 +24,7 @@ Kernels:
   (≈ `fused_rope` in newer reference branches).
 
 All kernels run under ``interpret=True`` on CPU for tests; on TPU they compile
-through Mosaic.
+through Mosaic (`tests/test_tpu_compile.py` asks the chip's compiler for each).
 """
 from paddle_tpu.kernels.pallas.flash_attention import flash_attention  # noqa: F401
 from paddle_tpu.kernels.pallas.fused_layernorm import fused_layer_norm  # noqa: F401

@@ -25,12 +25,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.core.autograd import x64_off_scope
+
 NEG_INF = -1e30
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
                 block_q, block_k, seq_q, seq_k):
     # q_ref: [1, block_q, D]; k_ref/v_ref: [1, seq_k, D]; o_ref: [1, block_q, D]
+    # lse_ref: [1, block_q, 1] — row statistics stay COLUMNS end to end (a
+    # [1, block_q] row block is not (8, 128)-tileable on the array [bh, sq])
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32) * sm_scale
 
@@ -70,7 +74,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, sm_scale, causal,
     a0 = jnp.zeros((block_q, q_ref.shape[-1]), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, num_kb, body, (m0, l0, a0))
     o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = (m + jnp.log(jnp.maximum(l, 1e-30)))[:, 0]
+    lse_ref[0] = m + jnp.log(jnp.maximum(l, 1e-30))
 
 
 def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
@@ -89,24 +93,25 @@ def _fwd(q, k, v, sm_scale, causal, block_q, block_k, interpret):
     kern = functools.partial(_fwd_kernel, sm_scale=sm_scale, causal=causal,
                              block_q=block_q, block_k=block_k, seq_q=sq,
                              seq_k=sk)
-    return pl.pallas_call(
-        kern,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(q.shape, q.dtype),
-            jax.ShapeDtypeStruct(q.shape[:2], jnp.float32),
-        ],
-        interpret=interpret,
-    )(q, k, v)
+    with x64_off_scope():
+        return pl.pallas_call(
+            kern,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(q.shape, q.dtype),
+                jax.ShapeDtypeStruct((bh, sq, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )(q, k, v)
 
 
 def _reference(q, k, v, sm_scale, causal):
@@ -122,7 +127,8 @@ def _reference(q, k, v, sm_scale, causal):
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
                sm_scale, causal, block_q, block_k, seq_q, seq_k):
-    # q/do/dq: [1, block_q, D]; k/v: [1, sk_pad, D]; lse/delta: [1, block_q]
+    # q/do/dq: [1, block_q, D]; k/v: [1, sk_pad, D];
+    # lse/delta: [1, block_q, 1]
     qi = pl.program_id(1)
     q = q_ref[0].astype(jnp.float32)
     do = do_ref[0].astype(jnp.float32)
@@ -147,9 +153,10 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
             qpos = qi * block_q + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 0)
             mask &= kpos <= qpos + (seq_k - seq_q)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
         return dq + jnp.dot(ds, k,
                             preferred_element_type=jnp.float32) * sm_scale
 
@@ -160,7 +167,8 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref, *,
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
                 dv_ref, *, sm_scale, causal, block_q, block_k, seq_q, seq_k):
-    # k/v/dk/dv: [1, block_k, D]; q/do: [1, sq_pad, D]; lse/delta: [1, sq_pad]
+    # k/v/dk/dv: [1, block_k, D]; q/do: [1, sq_pad, D];
+    # lse/delta: [1, sq_pad, 1]
     kj = pl.program_id(1)
     k = k_ref[0].astype(jnp.float32)
     v = v_ref[0].astype(jnp.float32)
@@ -176,8 +184,8 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         dk, dv = carry
         q = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
         do = do_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, pl.ds(i * block_q, block_q)]
-        delta = delta_ref[0, pl.ds(i * block_q, block_q)]
+        lse = lse_ref[0, pl.ds(i * block_q, block_q), :]
+        delta = delta_ref[0, pl.ds(i * block_q, block_q), :]
         s = jax.lax.dot_general(q * sm_scale, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32)
         qpos = i * block_q + jax.lax.broadcasted_iota(
@@ -187,13 +195,16 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dk_ref,
         mask = (kpos < seq_k) & (qpos < seq_q)   # ragged q AND k tails
         if causal:
             mask &= kpos <= qpos + (seq_k - seq_q)
-        p = jnp.where(mask, jnp.exp(s - lse[:, None]), 0.0)
-        dv_new = dv + jnp.dot(p.T, do,
-                              preferred_element_type=jnp.float32)
-        dp = jnp.dot(do, v.T, preferred_element_type=jnp.float32)
-        ds = p * (dp - delta[:, None])
-        dk_new = dk + jnp.dot(ds.T, q,
-                              preferred_element_type=jnp.float32) * sm_scale
+        p = jnp.where(mask, jnp.exp(s - lse), 0.0)
+        dv_new = dv + jax.lax.dot_general(
+            p, do, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
+                                 preferred_element_type=jnp.float32)
+        ds = p * (dp - delta)
+        dk_new = dk + jax.lax.dot_general(
+            ds, q, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale
         return dk_new, dv_new
 
     d = k_ref.shape[-1]
@@ -215,53 +226,55 @@ def _bwd(q, k, v, out, lse, do, sm_scale, causal, block_q, block_k,
     vp = jnp.pad(v, ((0, 0), (0, pad_k), (0, 0))) if pad_k else v
     qp = jnp.pad(q, ((0, 0), (0, pad_q), (0, 0))) if pad_q else q
     dop = jnp.pad(do, ((0, 0), (0, pad_q), (0, 0))) if pad_q else do
-    lsep = jnp.pad(lse, ((0, 0), (0, pad_q))) if pad_q else lse
+    col_pad = ((0, 0), (0, pad_q), (0, 0))
+    lsep = jnp.pad(lse, col_pad) if pad_q else lse
     delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1)                                   # [bh, sq]
-    deltap = jnp.pad(delta, ((0, 0), (0, pad_q))) if pad_q else delta
+                    axis=-1, keepdims=True)                    # [bh, sq, 1]
+    deltap = jnp.pad(delta, col_pad) if pad_q else delta
     sk_pad, sq_pad = sk + pad_k, sq + pad_q
 
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_q=sq,
-                          seq_k=sk),
-        grid=(bh, pl.cdiv(sq, block_q)),
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
-            pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-            pl.BlockSpec((1, block_q), lambda b, i: (b, i)),
-        ],
-        out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
-        out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        interpret=interpret,
-    )(q, kp, vp, do, lse, delta)
+    with x64_off_scope():
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, sm_scale=sm_scale, causal=causal,
+                              block_q=block_q, block_k=block_k, seq_q=sq,
+                              seq_k=sk),
+            grid=(bh, pl.cdiv(sq, block_q)),
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, sk_pad, d), lambda b, i: (b, 0, 0)),
+                pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+                pl.BlockSpec((1, block_q, 1), lambda b, i: (b, i, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, block_q, d), lambda b, i: (b, i, 0)),
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
+            interpret=interpret,
+        )(q, kp, vp, do, lse, delta)
 
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
-                          block_q=block_q, block_k=block_k, seq_q=sq,
-                          seq_k=sk),
-        grid=(bh, pl.cdiv(sk, block_k)),
-        in_specs=[
-            pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, sq_pad), lambda b, j: (b, 0)),
-            pl.BlockSpec((1, sq_pad), lambda b, j: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(k.shape, k.dtype),
-            jax.ShapeDtypeStruct(v.shape, v.dtype),
-        ],
-        interpret=interpret,
-    )(qp, k, v, dop, lsep, deltap)
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, sm_scale=sm_scale, causal=causal,
+                              block_q=block_q, block_k=block_k, seq_q=sq,
+                              seq_k=sk),
+            grid=(bh, pl.cdiv(sk, block_k)),
+            in_specs=[
+                pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((1, sq_pad, d), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((1, sq_pad, 1), lambda b, j: (b, 0, 0)),
+                pl.BlockSpec((1, sq_pad, 1), lambda b, j: (b, 0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
+                pl.BlockSpec((1, block_k, d), lambda b, j: (b, j, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(k.shape, k.dtype),
+                jax.ShapeDtypeStruct(v.shape, v.dtype),
+            ],
+            interpret=interpret,
+        )(qp, k, v, dop, lsep, deltap)
     return dq, dk, dv
 
 

@@ -17,6 +17,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.core.autograd import x64_off_scope
+
 
 def _fwd_kernel(x_ref, g_ref, b_ref, y_ref, mu_ref, rs_ref, *, eps):
     x = x_ref[:].astype(jnp.float32)
@@ -48,63 +50,67 @@ def _bwd_kernel(x_ref, g_ref, mu_ref, rs_ref, dy_ref, dx_ref, dg_ref, db_ref,
     c2 = jnp.mean(wdy * xhat, axis=1, keepdims=True)
     dx = (wdy - c1 - xhat * c2) * rstd
     dx_ref[:] = dx.astype(dx_ref.dtype)
-    dg_ref[:] = jnp.sum(dy * xhat, axis=0, keepdims=True)
-    db_ref[:] = jnp.sum(dy, axis=0, keepdims=True)
+    dg_ref[0] = jnp.sum(dy * xhat, axis=0, keepdims=True)
+    db_ref[0] = jnp.sum(dy, axis=0, keepdims=True)
 
 
 def _fwd(x, gamma, beta, eps, block_rows, interpret):
     n, d = x.shape
     block_rows = min(block_rows, n)
     grid = (pl.cdiv(n, block_rows),)
-    return pl.pallas_call(
-        functools.partial(_fwd_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d), x.dtype),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-            jax.ShapeDtypeStruct((n, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, gamma.reshape(1, d), beta.reshape(1, d))
+    with x64_off_scope():
+        return pl.pallas_call(
+            functools.partial(_fwd_kernel, eps=eps),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((1, d), lambda i: (0, 0)),
+                pl.BlockSpec((1, d), lambda i: (0, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n, d), x.dtype),
+                jax.ShapeDtypeStruct((n, 1), jnp.float32),
+                jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            ],
+            interpret=interpret,
+        )(x, gamma.reshape(1, d), beta.reshape(1, d))
 
 
 def _bwd(x, gamma, mu, rstd, dy, block_rows, interpret):
     n, d = x.shape
     block_rows = min(block_rows, n)
     nb = pl.cdiv(n, block_rows)
-    dx, dg_part, db_part = pl.pallas_call(
-        functools.partial(_bwd_kernel, n_rows=n, block_rows=block_rows),
-        grid=(nb,),
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (0, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-            pl.BlockSpec((1, d), lambda i: (i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((n, d), x.dtype),
-            jax.ShapeDtypeStruct((nb, d), jnp.float32),
-            jax.ShapeDtypeStruct((nb, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(x, gamma.reshape(1, d), mu, rstd, dy)
-    return dx, dg_part.sum(0), db_part.sum(0)
+    # per-block dgamma/dbeta partials ride a [nb, 1, d] array: a (1, d)
+    # block of an [nb, d] array is not (8, 128)-tileable
+    with x64_off_scope():
+        dx, dg_part, db_part = pl.pallas_call(
+            functools.partial(_bwd_kernel, n_rows=n, block_rows=block_rows),
+            grid=(nb,),
+            in_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((1, d), lambda i: (0, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, 1), lambda i: (i, 0)),
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
+                pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+                pl.BlockSpec((1, 1, d), lambda i: (i, 0, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((n, d), x.dtype),
+                jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
+                jax.ShapeDtypeStruct((nb, 1, d), jnp.float32),
+            ],
+            interpret=interpret,
+        )(x, gamma.reshape(1, d), mu, rstd, dy)
+    return dx, dg_part.sum((0, 1)), db_part.sum((0, 1))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))

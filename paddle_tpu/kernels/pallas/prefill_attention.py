@@ -10,8 +10,8 @@ chunked prefill (PR 6) made bucketed prefill the dominant non-decode cost
 and the PR 13 prefill-worker tier runs nothing else, this kernel is the
 drop-in the registry routes to:
 
-- **grid over (chunk-row block, head)** — one grid cell owns a
-  ``[block_q, dh]`` slice of the chunk's queries for one head;
+- **grid over chunk-row blocks** — one grid cell owns a ``[block_q, nh *
+  dh]`` slice of the chunk's queries, all heads;
 - **scalar-prefetched per-slot lengths** — ``start`` (absolute position of
   the chunk's first token) and ``valid`` (true token count in this chunk)
   arrive via scalar prefetch with the page-table row, so every bound below
@@ -24,14 +24,15 @@ drop-in the registry routes to:
   counts are a kernel output (``return_visits``) so tests assert the
   scaling;
 - **double-buffered page DMA** — the K/V pools stay in HBM
-  (``memory_space=ANY``); each cell streams one ``[page_size, dh]`` page
-  slice at a time into a two-slot VMEM scratch, next page's DMA in flight
-  while the current page is on the MXU, folding into an f32 online softmax
-  — the same rhythm as the decode kernel;
-- **int8-KV scale slices ride the same operands** — under ``k_scale``/
-  ``v_scale`` the pools are int8 and each visited page's ``[page_size]``
-  f32 scale slice DMAs in the same double-buffered rhythm; the dequant is
-  in-register after the copy lands, so HBM traffic is the int8 bytes.
+  (``memory_space=ANY``); each cell streams one whole ``[page_size, nh *
+  dh]`` page at a time into a two-slot VMEM scratch, next page's DMA in
+  flight while the current page is on the MXU, folding into an f32 online
+  softmax per head — the same rhythm and the same merged-lane pool view as
+  the decode kernel (see its "Layout" note: on a TPU the view costs a
+  relayout of the layer pool per call until the engine stores it merged);
+- **int8 pools** — under ``k_scale``/``v_scale`` the pages are int8 and
+  the sequence's f32 scale window rides a VMEM operand; the dequant is
+  in-register after the copy lands, so page traffic is the int8 bytes.
 
 Numerics match the XLA arm (f32 scores, absolute-position mask, f32
 softmax) to token identity — parity in interpret mode off-TPU is enforced
@@ -46,6 +47,10 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from paddle_tpu.core.autograd import x64_off_scope
+from paddle_tpu.kernels.pallas.paged_attention import (exact_dot, head_segments,
+                                                       scale_window)
 
 NEG_INF = -1e30
 
@@ -66,109 +71,103 @@ def default_block_q(c: int) -> int:
     return min(int(c), 256)
 
 
-def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, o_ref, *rest,
-                    page_size, block_q, scale, quant=False,
+def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
+                    page_size, block_q, nh, scale, quant=False,
                     has_visits=False):
-    # one grid cell per (q block i, head h): q_ref [block_q, 1, dh] in
-    # VMEM, k_hbm/v_hbm the full [num_pages, page_size, nh, dh] pools in
+    # one grid cell per q block i, all heads: q_ref [block_q, nh*dh] in
+    # VMEM, k_hbm/v_hbm the [num_pages, page_size, nh*dh] pool views in
     # HBM, meta (start, valid) + the page-table row scalar-prefetched into
-    # SMEM. Operand unpacking mirrors the decode kernel: under ``quant``
-    # two scale pools ride extra HBM operands + scale VMEM buffers, and
-    # the visits output exists only under ``return_visits`` (static flag,
-    # never inferred from argument counts).
+    # SMEM. Operand order mirrors the decode kernel: inputs (q, k, v[,
+    # k_scale, v_scale windows [maxp*ps, nh]]), outputs (o[, visits]),
+    # scratch (kbuf, vbuf, sem, m, l, acc). The running max and denominator
+    # live lane-broadcast over each head's dh lanes ([block_q, nh*dh]), so
+    # every per-head update is a same-shape slice of the three scratches.
     if quant:
-        ks_hbm, vs_hbm, o_ref, *rest = o_ref, rest[0], rest[1], *rest[2:]
-    else:
-        ks_hbm = vs_hbm = None
+        ks_ref, vs_ref, *rest = rest
+    o_ref, *rest = rest
     if has_visits:
-        visits_ref, rest = rest[0], rest[1:]
-    else:
-        visits_ref = None
-    if quant:
-        kbuf, vbuf, ksbuf, vsbuf, sem = rest
-    else:
-        kbuf, vbuf, sem = rest
-        ksbuf = vsbuf = None
+        visits_ref, *rest = rest
+    kbuf, vbuf, sem, m_scr, l_scr, acc_scr = rest
     i = pl.program_id(0)
-    h = pl.program_id(1)
     start = meta_ref[0]
     valid = meta_ref[1]
     row0 = i * block_q
     nrows = jnp.clip(valid - row0, 0, block_q)     # active rows this block
-    npages = block_visits(start, valid, row0, block_q, page_size)
-    if visits_ref is not None:
-        visits_ref[0, 0] = npages      # the loop bound, exported for tests
+    # never walk past the page-table row: an out-of-range page index is a
+    # wild DMA, which halts the chip (the XLA arm clamps the same way)
+    npages = jnp.minimum(
+        block_visits(start, valid, row0, block_q, page_size),
+        pt_ref.shape[0])
+    if has_visits:
+        # the loop bound, exported for tests (lane-dense row; lane 0 read)
+        visits_ref[...] = jnp.full(visits_ref.shape, npages, jnp.int32)
 
     def dma(slot, j):
-        # page j of this sequence: DMA this head's [page_size, dh] slice
-        # (plus its [page_size] scale slice when the pool is int8)
-        pg = pt_ref[j]
-        copies = [pltpu.make_async_copy(k_hbm.at[pg, :, h, :], kbuf.at[slot],
-                                        sem.at[0, slot]),
-                  pltpu.make_async_copy(v_hbm.at[pg, :, h, :], vbuf.at[slot],
-                                        sem.at[1, slot])]
-        if quant:
-            copies += [pltpu.make_async_copy(ks_hbm.at[pg, :, h],
-                                             ksbuf.at[slot],
-                                             sem.at[2, slot]),
-                       pltpu.make_async_copy(vs_hbm.at[pg, :, h],
-                                             vsbuf.at[slot],
-                                             sem.at[3, slot])]
-        return copies
+        pg = pt_ref[j]                 # page j of this sequence, whole
+        return [pltpu.make_async_copy(k_hbm.at[pg], kbuf.at[slot],
+                                      sem.at[0, slot]),
+                pltpu.make_async_copy(v_hbm.at[pg], vbuf.at[slot],
+                                      sem.at[1, slot])]
 
     @pl.when(npages > 0)
     def _():                           # a fully-padded block DMAs nothing
         for c in dma(0, 0):
             c.start()
 
-    q = q_ref[:, 0, :].astype(jnp.float32) * scale         # [block_q, dh]
+    hd = q_ref.shape[-1]
+    dh = hd // nh
+    _, segt = head_segments(nh, dh)
     rows = jax.lax.broadcasted_iota(jnp.int32, (block_q, 1), 0)
     pos = start + row0 + rows                              # [block_q, 1]
     row_ok = rows < nrows
+    m_scr[...] = jnp.full(m_scr.shape, NEG_INF, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_scr[...] = jnp.zeros(acc_scr.shape, jnp.float32)
 
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, jnp.int32(2))
-        nslot = jax.lax.rem(j + jnp.int32(1), jnp.int32(2))
+    def body(j, _):
+        slot = jax.lax.rem(j, 2)
 
-        @pl.when(j + jnp.int32(1) < npages)
+        @pl.when(j + 1 < npages)
         def _():                       # overlap: next page's DMA in flight
-            for c in dma(nslot, j + jnp.int32(1)):
+            for c in dma(1 - slot, j + 1):
                 c.start()
 
         for c in dma(slot, j):
             c.wait()
-        k = kbuf[slot].astype(jnp.float32)                 # [ps, dh]
+        k = kbuf[slot].astype(jnp.float32)                 # [ps, nh*dh]
         v = vbuf[slot].astype(jnp.float32)
         if quant:
             # dequantize in-register AFTER the page copy: the DMA moved
             # int8 bytes; only the VMEM-resident working tile widens
-            k = k * ksbuf[slot][:, None]
-            v = v * vsbuf[slot][:, None]
-        s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32)
+            prow = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            k = k * exact_dot(ks_ref[prow, :], segt)
+            v = v * exact_dot(vs_ref[prow, :], segt)
         kpos = j * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (1, page_size), 1)
         # absolute-position causality: query at position p sees keys 0..p
         # — within-chunk future tokens mask out exactly like unwritten
         # pages; padded rows (>= valid) contribute nothing
-        s = jnp.where((kpos <= pos) & row_ok, s, NEG_INF)  # [block_q, ps]
-        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
-        acc_new = acc * alpha + jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        return m_new, l_new, acc_new
+        mask = (kpos <= pos) & row_ok                      # [block_q, ps]
+        for h in range(nh):
+            sl = slice(h * dh, (h + 1) * dh)
+            q = q_ref[:, sl].astype(jnp.float32) * scale   # [block_q, dh]
+            s = jax.lax.dot_general(q, k[:, sl], (((1,), (1,)), ((), ())),
+                                    preferred_element_type=jnp.float32)
+            s = jnp.where(mask, s, NEG_INF)
+            m = m_scr[:, sl]
+            m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+            p = jnp.exp(s - m_new[:, :1])
+            alpha = jnp.exp(m - m_new)
+            m_scr[:, sl] = m_new
+            l_scr[:, sl] = l_scr[:, sl] * alpha + jnp.sum(
+                p, axis=1, keepdims=True)
+            acc_scr[:, sl] = acc_scr[:, sl] * alpha + jnp.dot(
+                p, v[:, sl], preferred_element_type=jnp.float32)
 
-    dh = q_ref.shape[-1]
-    m0 = jnp.full((block_q, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q, 1), jnp.float32)
-    a0 = jnp.zeros((block_q, dh), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, npages, body, (m0, l0, a0))
-    out = jnp.where(row_ok, acc / jnp.maximum(l, 1e-30), 0.0)
-    o_ref[:, 0, :] = out.astype(o_ref.dtype)
+    jax.lax.fori_loop(0, npages, body, None)
+    out = jnp.where(row_ok, acc_scr[...] / jnp.maximum(l_scr[...], 1e-30),
+                    0.0)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
@@ -186,63 +185,69 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
     valid      : scalar int32 — true token count in this chunk
     k_scale/v_scale : optional [num_pages, page_size, nh] f32 (int8 pools)
     returns    : [C, nh, dh] in q.dtype; with ``return_visits=True`` also
-                 the per-(q block, head) page-loop trip counts
-                 [ceil(C / block_q), nh] int32 — the ragged-stop proof.
+                 the page-loop trip counts [ceil(C / block_q), nh] int32
+                 (one walk serves every head of a q block, so a row
+                 repeats one count) — the ragged-stop proof.
 
-    ``interpret=None`` auto-selects the Pallas interpreter off-TPU (CPU
-    parity tests); on TPU the kernel compiles through Mosaic.
+    ``interpret=None`` selects the Pallas interpreter off-TPU (CPU parity
+    tests); on TPU the kernel compiles through Mosaic.
     """
     if interpret is None:
         from paddle_tpu.kernels.pallas._compat import default_interpret
         interpret = default_interpret()
     quant = k_scale is not None
     c, nh, dh = q.shape
-    ps = k_pages.shape[1]
+    num_pages, ps = k_pages.shape[:2]
+    hd = nh * dh
     bq = default_block_q(c) if block_q is None else min(int(block_q), c)
     nq = pl.cdiv(c, bq)
     scale = 1.0 / (dh ** 0.5)
     kern = functools.partial(_prefill_kernel, page_size=ps, block_q=bq,
-                             scale=float(scale), quant=quant,
+                             nh=nh, scale=float(scale), quant=quant,
                              has_visits=bool(return_visits))
-    out_specs = [pl.BlockSpec((bq, 1, dh), lambda i, j, *_: (i, j, 0))]
-    out_shape = [jax.ShapeDtypeStruct(q.shape, q.dtype)]
+    rows = pl.BlockSpec((bq, hd), lambda i, *_: (i, 0))
+    out_specs = [rows]
+    out_shape = [jax.ShapeDtypeStruct((c, hd), q.dtype)]
     if return_visits:
-        out_specs.append(pl.BlockSpec((1, 1), lambda i, j, *_: (i, j)))
-        out_shape.append(jax.ShapeDtypeStruct((nq, nh), jnp.int32))
+        out_specs.append(pl.BlockSpec((1, 1, 128), lambda i, *_: (i, 0, 0)))
+        out_shape.append(jax.ShapeDtypeStruct((nq, 1, 128), jnp.int32))
     in_specs = [
-        pl.BlockSpec((bq, 1, dh), lambda i, j, *_: (i, j, 0)),
-        pl.BlockSpec(memory_space=pltpu.ANY),         # K pool stays in HBM
-        pl.BlockSpec(memory_space=pltpu.ANY),         # V pool stays in HBM
+        rows,
+        pl.BlockSpec(memory_space=pl.ANY),            # K pool stays in HBM
+        pl.BlockSpec(memory_space=pl.ANY),            # V pool stays in HBM
     ]
-    scratch = [
-        pltpu.VMEM((2, ps, dh), k_pages.dtype),       # K double buffer
-        pltpu.VMEM((2, ps, dh), v_pages.dtype),       # V double buffer
-    ]
-    operands = [q, k_pages, v_pages]
+    operands = [q.reshape(c, hd), k_pages.reshape(num_pages, ps, hd),
+                v_pages.reshape(num_pages, ps, hd)]
     if quant:
-        in_specs += [pl.BlockSpec(memory_space=pltpu.ANY),   # K scales
-                     pl.BlockSpec(memory_space=pltpu.ANY)]   # V scales
-        scratch += [pltpu.VMEM((2, ps), jnp.float32),
-                    pltpu.VMEM((2, ps), jnp.float32)]
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
-    # semaphore rows: one per in-flight copy kind (k, v[, ks, vs])
-    scratch.append(pltpu.SemaphoreType.DMA((4 if quant else 2, 2)))
+        win = pl.BlockSpec((page_table.shape[0] * ps, nh),
+                           lambda i, *_: (0, 0))
+        in_specs += [win, win]
+        operands += [scale_window(k_scale, page_table),
+                     scale_window(v_scale, page_table)]
     meta = jnp.stack([jnp.asarray(start, jnp.int32),
                       jnp.asarray(valid, jnp.int32)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(nq, nh),
+        grid=(nq,),
         in_specs=in_specs,
         out_specs=out_specs,
-        scratch_shapes=scratch,
+        scratch_shapes=[
+            pltpu.VMEM((2, ps, hd), k_pages.dtype),   # K double buffer
+            pltpu.VMEM((2, ps, hd), v_pages.dtype),   # V double buffer
+            pltpu.SemaphoreType.DMA((2, 2)),          # (k|v, slot)
+            pltpu.VMEM((bq, hd), jnp.float32),        # running max
+            pltpu.VMEM((bq, hd), jnp.float32),        # running denominator
+            pltpu.VMEM((bq, hd), jnp.float32),        # accumulator
+        ],
     )
-    outs = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=bool(interpret),
-    )(meta, page_table.astype(jnp.int32), *operands)
+    with x64_off_scope():
+        outs = pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=out_shape,
+            interpret=bool(interpret),
+        )(meta, page_table.astype(jnp.int32), *operands)
+    out = outs[0].reshape(c, nh, dh)
     if return_visits:
-        return outs[0], outs[1]
-    return outs[0]
+        return out, jnp.broadcast_to(outs[1][:, 0, :1], (nq, nh))
+    return out

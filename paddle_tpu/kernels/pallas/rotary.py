@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from paddle_tpu.core.autograd import x64_off_scope
+
 
 def _rope_kernel(q_ref, k_ref, cos_ref, sin_ref, qo_ref, ko_ref):
     cos = cos_ref[0].astype(jnp.float32)          # [block_s, D/2]
@@ -45,23 +47,24 @@ def apply_rotary_emb(q, k, cos, sin, *, block_s=256, interpret=None):
     qf = q.reshape(b * h, s, d)
     kf = k.reshape(b * h, s, d)
     grid = (b * h, pl.cdiv(s, block_s))
-    qo, ko = pl.pallas_call(
-        _rope_kernel,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_s, d // 2), lambda bh, i: (0, i, 0)),
-            pl.BlockSpec((1, block_s, d // 2), lambda bh, i: (0, i, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
-            pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct(qf.shape, q.dtype),
-            jax.ShapeDtypeStruct(kf.shape, k.dtype),
-        ],
-        interpret=interpret,
-    )(qf, kf, cos[None], sin[None])
+    with x64_off_scope():
+        qo, ko = pl.pallas_call(
+            _rope_kernel,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, block_s, d // 2), lambda bh, i: (0, i, 0)),
+                pl.BlockSpec((1, block_s, d // 2), lambda bh, i: (0, i, 0)),
+            ],
+            out_specs=[
+                pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
+                pl.BlockSpec((1, block_s, d), lambda bh, i: (bh, i, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct(qf.shape, q.dtype),
+                jax.ShapeDtypeStruct(kf.shape, k.dtype),
+            ],
+            interpret=interpret,
+        )(qf, kf, cos[None], sin[None])
     return qo.reshape(b, h, s, d), ko.reshape(b, h, s, d)

@@ -27,7 +27,6 @@ import math
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.framework.jax_compat import shard_map as _shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -93,7 +92,7 @@ def _ring_fwd(q, k, v, causal, scale, mesh, axis):
 
     spec = P(None, None, axis, None)
     spec3 = P(None, None, axis)
-    f = _shard_map(per_rank, mesh=mesh, in_specs=(spec, spec, spec),
+    f = jax.shard_map(per_rank, mesh=mesh, in_specs=(spec, spec, spec),
                       out_specs=(spec, spec3), axis_names={axis},
                       check_vma=True)
     out, lse = f(q, k, v)
@@ -152,7 +151,7 @@ def _ring_bwd(causal, scale, mesh, axis, res, do):
 
     spec = P(None, None, axis, None)
     spec3 = P(None, None, axis)
-    f = _shard_map(
+    f = jax.shard_map(
         per_rank, mesh=mesh,
         in_specs=(spec, spec, spec, spec, spec3, spec),
         out_specs=(spec, spec, spec), axis_names={axis}, check_vma=True)
@@ -187,6 +186,6 @@ def ulysses_attention(q, k, v, causal, scale, mesh, axis="sp"):
         return head2seq(out)
 
     spec = P(None, None, axis, None)
-    f = _shard_map(per_rank, mesh=mesh, in_specs=(spec, spec, spec),
+    f = jax.shard_map(per_rank, mesh=mesh, in_specs=(spec, spec, spec),
                       out_specs=spec, axis_names={axis}, check_vma=True)
     return f(q, k, v)

@@ -1538,6 +1538,8 @@ class Router:
 
 
 def main(argv=None):
+    from paddle_tpu.framework import compile_cache
+    compile_cache.enable()
     ap = argparse.ArgumentParser("paddle_tpu.serving.router")
     ap.add_argument("--registry-dir", default=None,
                     help="shared-filesystem elastic registry to watch for "

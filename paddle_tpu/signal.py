@@ -15,7 +15,6 @@ from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.core.autograd import apply
 from paddle_tpu.ops.common import ensure_tensor
 from paddle_tpu import fft as _fft
-from paddle_tpu.fft import _apply_or_host
 
 __all__ = ["frame", "overlap_add", "stft", "istft"]
 
@@ -119,7 +118,7 @@ def stft(x, n_fft, hop_length=None, win_length=None, window=None, center=True,
             spec = spec / jnp.sqrt(jnp.asarray(float(n_fft), spec.real.dtype))
         return spec
 
-    return _apply_or_host(_stft, x, op_name="stft")
+    return apply(_stft, x, op_name="stft")
 
 
 def istft(x, n_fft, hop_length=None, win_length=None, window=None, center=True,
@@ -162,4 +161,4 @@ def istft(x, n_fft, hop_length=None, win_length=None, window=None, center=True,
             sig = sig[..., :length]
         return sig
 
-    return _apply_or_host(_istft, x, op_name="istft")
+    return apply(_istft, x, op_name="istft")

@@ -38,11 +38,10 @@ Sites currently wired (the catalog lives in docs/ROBUSTNESS.md):
                           the serve loop must abort every waiter)
 ``engine.pool_pressure``  `PageAllocator.alloc` reports exhaustion (forced
                           page-pool pressure without a giant workload)
-``bench.preflight``       bench.py's backend preflight probe fails on its
-                          first-use op (arm with ``exc=``; ``times=1``
-                          lets the CPU re-probe succeed) — drives the
-                          dead-backend-falls-back-to-CPU-rungs regression
-                          test for the BENCH_r05 ``parsed:null`` shape
+``bench.preflight``       bench.py's first executed op fails (arm with
+                          ``exc=``) — plays a backend that initialises
+                          and then dies on first use: bench.py must name
+                          the error and exit non-zero
 ``serve.slow_read``       serve's client loop stalls ``delay_s`` before
                           reading a request body (slow-client simulation)
 ``serve.socket_drop``     serve's client loop drops the connection before

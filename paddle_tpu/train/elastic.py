@@ -476,7 +476,10 @@ def spawn_local_fleet(world, *, root, until_step, log_dir, every=2,
                       deadline_s=10.0, registry_dir=None, batch=4,
                       env_for_rank=None, attempt=0, extra_args=()):
     """Spawn ``world`` local drill ranks (the controller-side half of the
-    CLI above): fresh coordinator port per call, per-rank
+    CLI above). This is a CPU drill: every rank is pinned to the CPU
+    platform with one device, because several processes cannot share one
+    host's chips (a chip belongs to one process at a time; on a TPU host
+    one process drives all of them). Fresh coordinator port per call, per-rank
     ``rank<r>.a<attempt>.log`` files under ``log_dir``, launch-style env
     (``PADDLE_TRAINER_ID``/``PADDLE_TRAINERS_NUM``/``PADDLE_MASTER``).
     ``env_for_rank(rank) -> dict`` merges per-rank extras (e.g. arming

@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec
 
+from paddle_tpu.core.autograd import x64_off_scope
 from paddle_tpu.core.tensor import Tensor
 from paddle_tpu.distributed.mesh import get_mesh
 from paddle_tpu.observability import metrics
@@ -37,27 +38,33 @@ from paddle_tpu.observability.flight_recorder import (Watchdog,
 from paddle_tpu.testing import faults
 
 
-# per-chip peak for MFU denominators — bench.py imports THIS constant so
-# its rung MFU and the `train.mfu` gauge can never disagree on the peak
-V5E_BF16_PEAK = 197e12
+# Dense bf16 peak FLOP/s of ONE chip, keyed by `jax.devices()[0].device_kind`,
+# each with its source. The one peak table in the repo (bench.py imports
+# `peak_flops`, so its MFU and the `train.mfu` gauge cannot disagree). A
+# device that is not in it is an error, not a default.
+PEAK_BF16_FLOPS = {
+    "TPU v5 lite": 197e12,  # Google Cloud documentation, "TPU v5e"
+}
 
 
-def safe_backend() -> str:
-    """`jax.default_backend()` that cannot raise ("cpu" when the platform
-    plugin is wedged): telemetry reads must never take a hot path down
-    (the BENCH_r05 lesson). The one such probe in the repo — bench.py's
-    `_platform()` delegates here."""
+def peak_flops(device_kind=None) -> float:
+    """Per-chip MFU denominator for ``device_kind`` (default: the first
+    device's). Raises for a kind that has no published peak on record."""
+    kind = jax.devices()[0].device_kind if device_kind is None \
+        else device_kind
     try:
-        return jax.default_backend()
-    except Exception:  # noqa: BLE001 — plugin init errors of any type
-        return "cpu"
+        return PEAK_BF16_FLOPS[kind]
+    except KeyError:
+        raise ValueError(
+            f"no peak FLOP/s on record for device kind {kind!r}: add it to "
+            "PEAK_BF16_FLOPS with its source") from None
 
 
-def peak_flops() -> float:
-    """Per-chip MFU denominator: v5e bf16 peak on TPU, a nominal
-    1 TFLOP/s elsewhere. The ONE peak predicate in the repo — bench.py
-    imports this, so its rung MFU and `train.mfu` cannot disagree."""
-    return V5E_BF16_PEAK if safe_backend() == "tpu" else 1e12
+def _tpu_kind():
+    """``device_kind`` of the chip the step runs on, or None off-TPU —
+    a CPU run has no model FLOP/s utilization to report."""
+    dev = jax.devices()[0]
+    return dev.device_kind if dev.platform == "tpu" else None
 
 
 class ScanUnsupported(ValueError):
@@ -488,8 +495,7 @@ class ScanTrainStep:
         flight.record("train.dispatch", step=self.opt._global_step + 1,
                       shape=str(tuple(xs.shape)))
         t0 = time.perf_counter()
-        from jax.experimental import disable_x64
-        with disable_x64():
+        with x64_off_scope():
             if self._grad_reducer is None:
                 loss, ok, self._params, self._opt_state = self._jit(
                     self._params, self._opt_state, xs, ys, ms, lr, t,
@@ -528,7 +534,9 @@ class ScanTrainStep:
         # whole mesh — a per-chip denominator would read ~device_count too
         # high and clamp at 1.0 exactly on multichip deployments
         n_dev = self.mesh.size if self.mesh is not None else 1
-        mfu = min(1.0, flops / (max(dt, 1e-9) * peak_flops() * n_dev))
+        kind = _tpu_kind()
+        mfu = None if kind is None else min(
+            1.0, flops / (max(dt, 1e-9) * peak_flops(kind) * n_dev))
         if compiled:
             self._compiles += 1
             metrics.counter("train.compile_count").inc()
@@ -542,7 +550,8 @@ class ScanTrainStep:
             # only, like step_ms: a compile step's dt would read as a
             # collapsed mfu and fake the exact alarm the gauge exists to
             # raise (mfu down while step_ms holds = the batch shrank)
-            metrics.gauge("train.mfu").set(mfu)
+            if mfu is not None:
+                metrics.gauge("train.mfu").set(mfu)
             metrics.gauge("train.goodput_tokens_per_s").set(
                 tokens / max(dt, 1e-9))
         metrics.counter("train.steps").inc()
@@ -565,7 +574,8 @@ class ScanTrainStep:
         metrics.counter("train.tokens").inc(tokens)
         flight.record("train.step", step=self.opt._global_step + 1,
                       loss=lossf, ms=round(dt * 1e3, 3),
-                      mfu=round(mfu, 5), compiled=bool(compiled))
+                      mfu=None if mfu is None else round(mfu, 5),
+                      compiled=bool(compiled))
         self.opt._global_step += 1
         self.opt._sync_lr_tensor(self.opt.get_lr())
         self._dirty = True

@@ -118,8 +118,8 @@ class TestShardingStages:
         assert offl, "no state was registered for offload"
         # residence is only checkable where the backend HAS a host tier;
         # CPU's sole memory is unpinned_host and offload is a no-op there
-        from paddle_tpu.framework.jax_compat import host_memory_kind
-        want = host_memory_kind()
+        from paddle_tpu.distributed.sharding import host_memory_kind
+        want = host_memory_kind(jax.devices())
         if want is not None:
             resident = [t._data.sharding.memory_kind for t in offl]
             assert all(k == want for k in resident), resident
@@ -177,8 +177,8 @@ class TestShardingStages:
             opt.clear_grad()
         assert np.isfinite(float(loss))
         assert opt._offloaded_states
-        from paddle_tpu.framework.jax_compat import host_memory_kind
-        want = host_memory_kind()
+        from paddle_tpu.distributed.sharding import host_memory_kind
+        want = host_memory_kind(jax.devices())
         if want is not None:  # CPU has no host tier; offload is a no-op there
             kinds = [t._data.sharding.memory_kind
                      for t in opt._offloaded_states]

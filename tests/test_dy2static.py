@@ -807,8 +807,11 @@ class TestShadowedRange:
                "    return acc\n")
         ns = {}
         exec(compile(src, "<test_global_shadow>", "exec"), glb, ns)
+        import ast
+        import inspect
+        import textwrap
         f = ns["f"]
-        assert not _range_is_builtin(f)
+        assert not _range_is_builtin(f, ast.parse(src).body[0])
         # source for exec'd fns is unavailable; assert the resolver alone
         # (convert_to_static needs inspect.getsource) — plus the builtin
         # direction on a real function:
@@ -819,7 +822,8 @@ class TestShadowedRange:
                 acc = acc + i
             return acc
 
-        assert _range_is_builtin(g)
+        assert _range_is_builtin(
+            g, ast.parse(textwrap.dedent(inspect.getsource(g))).body[0])
         out = convert_to_static(g)(_t([1.0]))
         np.testing.assert_allclose(np.asarray(out._data), [3.0])
 

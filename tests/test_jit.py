@@ -191,7 +191,7 @@ def test_arg_with_grad_through_capture():
 
 class TestMultiSteps:
     """multi_steps(k): one dispatch per k steps (lax.scan over the captured
-    step) — amortizes the per-dispatch overhead docs/PERF.md measures at
+    step) — amortizes the per-dispatch overhead PERF.md measures at
     ~5 ms through the TPU runtime."""
 
     def _build(self):

@@ -366,8 +366,13 @@ class TestTierEngine:
         assert _counter("engine.prefill_tokens") - tok0 == 1
         assert _counter("engine.kvtier.reuploads_host") == up0 + 4
         assert h2.first_token == h1.first_token
-        np.testing.assert_array_equal(h2.k_pages, h1.k_pages)
-        np.testing.assert_array_equal(h2.v_pages, h1.v_pages)
+        # the four re-uploaded pages are bit-equal; the tail token's K/V is
+        # recomputed by the 1-token chunk program where the cold export ran
+        # the one-shot program, and two XLA programs may differ in the
+        # last ulp
+        for new, old in ((h2.k_pages, h1.k_pages), (h2.v_pages, h1.v_pages)):
+            np.testing.assert_array_equal(new[:, :4], old[:, :4])
+            np.testing.assert_allclose(new, old, rtol=1e-5, atol=1e-7)
         _assert_pool_clean(eng)
 
     @pytest.mark.slow

@@ -94,6 +94,18 @@ class TestLengthAwareStop:
         # a 1-token sequence touches ONE page of its 16-page slot
         assert visits[0, 0] == 1 and visits[0, 0] < maxp
 
+    def test_walk_never_leaves_the_page_table_row(self):
+        """A position past the slot's capacity must not index past the
+        page-table row: on the chip that page id is garbage and the DMA it
+        feeds halts the core (interpret mode clamps and hides it)."""
+        rng = np.random.RandomState(4)
+        b, nh, dh, ps, maxp = 2, 2, 16, 4, 4         # 16-token slots
+        q, kp, vp, pt, posj = _random_case(rng, b, nh, dh, ps, maxp,
+                                           1 + b * maxp, [15, 40])
+        _, visits = ppa.paged_attention(q, kp, vp, pt, posj, interpret=True,
+                                        return_visits=True)
+        np.testing.assert_array_equal(np.asarray(visits)[:, 0], [maxp, maxp])
+
     def test_pages_needed_formula(self):
         assert int(ppa.pages_needed(jnp.int32(0), 4)) == 1
         assert int(ppa.pages_needed(jnp.int32(3), 4)) == 1

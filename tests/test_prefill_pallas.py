@@ -117,6 +117,19 @@ class TestLengthScaling:
             assert v.max() == want, (start, valid, v)
             assert v.max() < maxp       # never the capacity walk
 
+    def test_walk_never_leaves_the_page_table_row(self):
+        """start + valid past the slot's capacity (the shape one autotune
+        measurement had) must not walk past the page-table row: on the chip
+        that is a wild DMA and a halted core."""
+        rng = np.random.RandomState(3)
+        maxp = 4
+        kp, vp, row = _pool(rng, maxp=maxp)          # 16-token slot
+        q = jnp.asarray(rng.randn(1, 16, 2, 8).astype(np.float32))
+        _, visits = pallas_prefill(q[0], kp, vp, row, jnp.int32(4),
+                                   jnp.int32(16), interpret=True,
+                                   return_visits=True)
+        assert np.asarray(visits).max() == maxp
+
     def test_padded_qblocks_visit_zero_pages(self):
         rng = np.random.RandomState(2)
         kp, vp, row = _pool(rng, maxp=16)

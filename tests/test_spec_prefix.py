@@ -328,7 +328,13 @@ class TestPrefixCache:
         assert _counter("engine.prefix_hit") == hits0 + 1
         # 17 tokens, 4 pages cached, tail = 1: only the tail prefilled
         assert _counter("engine.prefill_tokens") - tok0 == 1
-        np.testing.assert_array_equal(h2.k_pages, h1.k_pages)
+        # the four cached pages travel by reference and are bit-equal; the
+        # tail token's K is recomputed by the 1-token CHUNK program where
+        # the first export ran the 32-wide one-shot program, and two XLA
+        # programs may round the same matmul differently in the last ulp
+        np.testing.assert_array_equal(h2.k_pages[:, :4], h1.k_pages[:, :4])
+        np.testing.assert_allclose(h2.k_pages, h1.k_pages, rtol=1e-6,
+                                   atol=1e-7)
         assert h2.first_token == h1.first_token
         r = eng_b.import_request(KVHandoff.unpack(h2.pack()),
                                  max_new_tokens=8)

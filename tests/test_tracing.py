@@ -243,7 +243,8 @@ class TestFlightRecorder:
         wd.stop()
         assert wd.dump_count == 0       # healthy loop: no dump
         train_evs = [e for e in flight.events() if e["kind"] == "train.step"]
-        assert train_evs and train_evs[-1]["mfu"] > 0
+        assert train_evs and train_evs[-1]["ms"] > 0
+        assert train_evs[-1]["mfu"] is None     # no utilization off-TPU
 
 
 # ------------------------------------------------------- train.mfu / analytic
@@ -257,7 +258,7 @@ class TestMFU:
         actual = sum(int(np.prod(p.shape)) for p in m.parameters())
         assert analytic_param_count(m.cfg) == actual
 
-    def test_mfu_gauge_in_unit_interval(self):
+    def _two_steps(self):
         from paddle_tpu.train import ScanTrainStep
         m = _tiny_model()
         opt = paddle.optimizer.AdamW(learning_rate=1e-3,
@@ -268,9 +269,31 @@ class TestMFU:
         x, y = ids[:, :-1].astype(np.int32), ids[:, 1:].astype(np.int64)
         step.step(x, y)                 # compile step (gauges stay steady)
         step.step(x, y)                 # steady step sets them
-        snap = metrics.snapshot()["gauges"]
+        return metrics.snapshot()["gauges"]
+
+    def test_mfu_gauge_in_unit_interval_on_a_known_chip(self, monkeypatch):
+        """The device kind is steered from the test (the CPU has no peak
+        on record): against a v5e's published peak the gauge lands in
+        (0, 1]."""
+        from paddle_tpu.train import scan_step
+        monkeypatch.setattr(scan_step, "_tpu_kind", lambda: "TPU v5 lite")
+        snap = self._two_steps()
         assert 0.0 < snap["train.mfu"] <= 1.0, snap["train.mfu"]
         assert snap["train.goodput_tokens_per_s"] > 0
+
+    def test_no_mfu_gauge_off_tpu(self):
+        metrics.reset()                 # zeroes gauges in place
+        snap = self._two_steps()
+        assert not snap.get("train.mfu")
+        assert snap["train.goodput_tokens_per_s"] > 0
+
+    def test_unknown_device_kind_has_no_peak(self):
+        from paddle_tpu.train.scan_step import PEAK_BF16_FLOPS, peak_flops
+        assert peak_flops("TPU v5 lite") == PEAK_BF16_FLOPS["TPU v5 lite"]
+        with pytest.raises(ValueError, match="no peak FLOP/s on record"):
+            peak_flops("TPU v99")
+        with pytest.raises(ValueError, match="'cpu'"):
+            peak_flops()                # this process's device is a CPU
 
 
 # ------------------------------------------------------- prometheus rendering
