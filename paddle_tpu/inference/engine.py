@@ -78,8 +78,11 @@ Python owns admission/retirement, the device runs fixed-shape steps:
   to ``EngineConfig.inflight`` steps stay in flight before the host blocks
   on the oldest step's token ids (`engine.d2h_transfers`; the ONLY blocking
   readback in the loop). Host admission/retirement bookkeeping runs while
-  the device chews on the just-dispatched step; the `engine.host_ms` /
-  `engine.device_ms` timer pair makes the overlap visible in the snapshot.
+  the device chews on the just-dispatched step; each working step is an
+  `engine.step` span whose children `engine.admit`, `engine.dispatch`,
+  `engine.prefill_launch` and `engine.harvest` (the blocking readback
+  alone) put the overlap on the timeline, beside the device's ops while a
+  `jax.profiler` session runs (docs/OBSERVABILITY.md "Spans").
 
 All compiled programs take the weights as inputs — `refresh_params` swaps
 them without recompiling. The engine is greedy-only by design: batched
@@ -113,7 +116,7 @@ from paddle_tpu.observability import metrics
 from paddle_tpu.observability.flight_recorder import (Watchdog,
                                                       default_deadline,
                                                       flight)
-from paddle_tpu.observability.tracing import RequestTrace
+from paddle_tpu.observability.tracing import RequestTrace, new_span_id
 from paddle_tpu.observability.usage import emit_request as _emit_usage
 from paddle_tpu.testing import faults
 
@@ -916,7 +919,6 @@ class DecodeEngine:
         self._keys_dev = jnp.zeros((B + 1, 2), jnp.uint32) \
             if self._sampling else None
         self._inflight: deque = deque()
-        self._blocked_s = 0.0                 # device-wait within this step
 
         self._queue: deque[GenerateRequest] = deque()
         self._qlock = threading.Lock()
@@ -1008,6 +1010,7 @@ class DecodeEngine:
         # so tests/bench can assert the absence (docs/OBSERVABILITY.md)
         self._m_logits_rb = metrics.counter("engine.logits_readback")
         self._m_chunks = metrics.counter("engine.prefill_chunks")
+        self._m_prefill_launches = metrics.counter("engine.prefill_launches")
         self._m_prefill_tokens = metrics.counter("engine.prefill_tokens")
         self._m_prefix_hit = metrics.counter("engine.prefix_hit")
         self._m_prefix_miss = metrics.counter("engine.prefix_miss")
@@ -1049,8 +1052,6 @@ class DecodeEngine:
         self._h_wait = metrics.histogram("engine.queue_wait_seconds")
         self._h_step = metrics.histogram("engine.step_seconds")
         self._h_prefill = metrics.histogram("engine.prefill_seconds")
-        self._h_host = metrics.histogram("engine.host_ms")
-        self._h_device = metrics.histogram("engine.device_ms")
 
     # ------------------------------------------------------------- programs
 
@@ -1061,13 +1062,11 @@ class DecodeEngine:
         if exe is None:
             self._m_miss.inc()
             flight.record("engine.compile_start", program=str(key))
-            t0 = time.perf_counter()
-            exe = self._programs[key] = build()
+            with metrics.span(f"engine.compile:{key[0]}",
+                              cat="compile") as sp:
+                exe = self._programs[key] = build()
             self._m_compiles.inc()
-            metrics.histogram("engine.compile_seconds").observe(
-                time.perf_counter() - t0)
-            metrics.add_span(f"engine.compile:{key[0]}", t0,
-                             time.perf_counter() - t0, cat="compile")
+            metrics.histogram("engine.compile_seconds").observe(sp.dur)
         else:
             self._m_hit.inc()
         return exe
@@ -2061,15 +2060,16 @@ class DecodeEngine:
         """Drain the queue into free slots while pages allow: assign slot,
         attach the longest cached prefix (prefix cache), allocate fresh
         pages for the rest, prefill the uncached tail, seed the first
-        token."""
+        token. Returns how many requests it placed."""
+        placed = 0
         while True:
             slots = self._free_slots()
             if not slots:
-                return
+                return placed
             with self._qlock:
                 if not self._queue:
                     self._g_queue.set(0)
-                    return
+                    return placed
                 req = self._queue[0]
                 if req.done or req.expired():
                     # cancelled/aborted/expired while queued: skipped
@@ -2119,7 +2119,7 @@ class DecodeEngine:
                                     f"pool has "
                                     f"{self.allocator.num_pages - 1}")
                         continue
-                    return                 # wait for a retirement
+                    return placed          # wait for a retirement
                 if self._prefix_enabled and req.cache:
                     (self._m_prefix_hit if shared
                      else self._m_prefix_miss).inc()
@@ -2136,6 +2136,7 @@ class DecodeEngine:
                 n_up = self._tier_reupload(req.page_hashes,
                                            req.prompt.size, shared, pages)
             self._place(req, slots[0], shared + pages, len(shared) + n_up)
+            placed += 1
 
     def _place(self, req: GenerateRequest, slot: int, pages: list[int],
                n_shared: int = 0):
@@ -2226,21 +2227,78 @@ class DecodeEngine:
             tok = None
             for done in range(start, s0, c):
                 tok = self._run_chunk(ids, done, row, c, slot=slot,
-                                      req=req, final=done + c >= s0)
+                                      req=req, final=done + c >= s0,
+                                      kind="tail")
         else:
-            bucket = self.bucket_for(s0)
-            x = 5 if self._sampling else 0
-            packed = np.zeros(bucket + 1 + maxp + x, np.int32)
-            packed[:s0] = ids
-            packed[bucket] = s0
-            packed[bucket + 1:bucket + 1 + maxp] = row
+            with metrics.span("engine.prefill_launch", cat="engine",
+                              kind="oneshot", tokens=int(s0),
+                              request_id=req and req.request_id):
+                bucket = self.bucket_for(s0)
+                x = 5 if self._sampling else 0
+                packed = np.zeros(bucket + 1 + maxp + x, np.int32)
+                packed[:s0] = ids
+                packed[bucket] = s0
+                packed[bucket + 1:bucket + 1 + maxp] = row
+                if self._sampling:
+                    packed[bucket + 1 + maxp:] = self._sample_tail(slot, req)
+                exe = self._prefill_exe(bucket)
+                self._m_h2d.inc()
+                self._m_prefill_launches.inc()
+                self._m_prefill_tokens.inc(s0)
+                if req is not None:
+                    req.u_prefill_computed += int(s0)
+                if self._sampling:
+                    tok, self._keys_dev = self._adopt_pools(
+                        exe(self._params, self._kc, self._vc,
+                            self._keys_dev, jax.device_put(packed),
+                            *self._scale_args()), n_lead=2)
+                else:
+                    tok = self._adopt_pools(
+                        exe(self._params, self._kc, self._vc,
+                            jax.device_put(packed), *self._scale_args()))
+        return self._read_first_token(tok)
+
+    def _read_first_token(self, tok) -> int:
+        """A prefill's only readback: block on its sampled first token."""
+        with metrics.span("engine.harvest", cat="engine", of="prefill",
+                          tokens=1):
+            first = int(tok)
+        self._m_d2h.inc()
+        return first
+
+    def _run_chunk(self, ids: np.ndarray, done: int, row: np.ndarray,
+                   c: int | None = None, slot=None, req=None,
+                   final: bool = False, kind: str = "chunk"):
+        """Pack and enqueue ONE prefill chunk (``ids[done:done+c]`` against
+        page ``row``) — the single owner of the packed chunk layout for
+        the interleaved (`_advance_prefill`), back-to-back
+        (`_run_prefill`), and prefix-tail paths. Returns the chunk
+        program's on-device sampled token (meaningful only for the final
+        chunk; no readback here). On a sampling engine the FINAL chunk
+        samples through the fused sampler and seeds ``slot``'s key chain.
+        ``kind`` names the launch on its `engine.prefill_launch` span:
+        ``chunk`` (one a step, interleaved with decode, or a stream's) or
+        ``tail`` (run to the end inside admission by `_run_prefill`)."""
+        c = int(self.ecfg.prefill_chunk_tokens) if c is None else int(c)
+        chunk = ids[done:done + c]
+        with metrics.span("engine.prefill_launch", cat="engine", kind=kind,
+                          tokens=int(chunk.size),
+                          request_id=req and req.request_id):
+            x = 6 if self._sampling else 0
+            packed = np.zeros(c + 2 + self.pages_per_slot + x, np.int32)
+            packed[:chunk.size] = chunk
+            packed[c] = done
+            packed[c + 1] = chunk.size
+            packed[c + 2:c + 2 + self.pages_per_slot] = row
             if self._sampling:
-                packed[bucket + 1 + maxp:] = self._sample_tail(slot, req)
-            exe = self._prefill_exe(bucket)
+                packed[c + 2 + self.pages_per_slot:] = \
+                    self._sample_tail(slot, req, final=final)
+            exe = self._prefill_chunk_exe(c)
             self._m_h2d.inc()
-            self._m_prefill_tokens.inc(s0)
+            self._m_prefill_launches.inc()
+            self._m_prefill_tokens.inc(int(chunk.size))
             if req is not None:
-                req.u_prefill_computed += int(s0)
+                req.u_prefill_computed += int(chunk.size)
             if self._sampling:
                 tok, self._keys_dev = self._adopt_pools(
                     exe(self._params, self._kc, self._vc, self._keys_dev,
@@ -2250,46 +2308,6 @@ class DecodeEngine:
                 tok = self._adopt_pools(
                     exe(self._params, self._kc, self._vc,
                         jax.device_put(packed), *self._scale_args()))
-        tb = time.perf_counter()
-        first = int(tok)                     # sampled-token readback
-        self._blocked_s += time.perf_counter() - tb
-        self._m_d2h.inc()
-        return first
-
-    def _run_chunk(self, ids: np.ndarray, done: int, row: np.ndarray,
-                   c: int | None = None, slot=None, req=None,
-                   final: bool = False):
-        """Pack and enqueue ONE prefill chunk (``ids[done:done+c]`` against
-        page ``row``) — the single owner of the packed chunk layout for
-        the interleaved (`_advance_prefill`), back-to-back
-        (`_run_prefill`), and prefix-tail paths. Returns the chunk
-        program's on-device sampled token (meaningful only for the final
-        chunk; no readback here). On a sampling engine the FINAL chunk
-        samples through the fused sampler and seeds ``slot``'s key chain."""
-        c = int(self.ecfg.prefill_chunk_tokens) if c is None else int(c)
-        chunk = ids[done:done + c]
-        x = 6 if self._sampling else 0
-        packed = np.zeros(c + 2 + self.pages_per_slot + x, np.int32)
-        packed[:chunk.size] = chunk
-        packed[c] = done
-        packed[c + 1] = chunk.size
-        packed[c + 2:c + 2 + self.pages_per_slot] = row
-        if self._sampling:
-            packed[c + 2 + self.pages_per_slot:] = \
-                self._sample_tail(slot, req, final=final)
-        exe = self._prefill_chunk_exe(c)
-        self._m_h2d.inc()
-        self._m_prefill_tokens.inc(int(chunk.size))
-        if req is not None:
-            req.u_prefill_computed += int(chunk.size)
-        if self._sampling:
-            tok, self._keys_dev = self._adopt_pools(
-                exe(self._params, self._kc, self._vc, self._keys_dev,
-                    jax.device_put(packed), *self._scale_args()), n_lead=2)
-        else:
-            tok = self._adopt_pools(
-                exe(self._params, self._kc, self._vc,
-                    jax.device_put(packed), *self._scale_args()))
         self._m_chunks.inc()
         return tok
 
@@ -2344,10 +2362,7 @@ class DecodeEngine:
         st["done"] = min(done + c, req.prompt.size)
         if st["done"] >= req.prompt.size:
             del self._prefilling[slot]
-            tb = time.perf_counter()
-            first = int(tok)         # the prefill's ONLY readback: the
-            self._blocked_s += time.perf_counter() - tb  # final chunk's token
-            self._m_d2h.inc()
+            first = self._read_first_token(tok)   # the final chunk's token
             self._h_prefill.observe(time.perf_counter() - st["t0"])
             self._seed_first_token(slot, req, first)
         return True
@@ -2407,32 +2422,33 @@ class DecodeEngine:
         """Enqueue ONE fixed-shape decode step: one fused host->device
         upload, no readback — tokens (and, on a sampling engine, the
         per-slot PRNG key chains) stay on device for the next step."""
-        exe = self._decode_exe()
-        self._m_h2d.inc()
-        state = jax.device_put(self._packed_state())
-        t0 = time.perf_counter()
-        if self._sampling:
-            self._tok_dev, self._keys_dev = self._adopt_pools(
-                exe(self._params, self._kc, self._vc, self._tok_dev,
-                    self._keys_dev, state, *self._scale_args()), n_lead=2)
-        else:
-            self._tok_dev = self._adopt_pools(
-                exe(self._params, self._kc, self._vc, self._tok_dev, state,
-                    *self._scale_args()))
-        snapshot = [(int(i), self._slot_req[i])
-                    for i in np.flatnonzero(self._active)]
-        self._inflight.append((self._tok_dev, snapshot, t0))
-        self._g_inflight.set(len(self._inflight))
-        # host bookkeeping for the step just enqueued: each active slot
-        # advances one position; a slot at its token budget stops being
-        # dispatched but stays occupied until its tokens are harvested
-        self._lengths[self._active] += 1
-        self._budget[self._active] -= 1
-        self._fresh[:] = False
-        self._active &= self._budget > 0
-        self._m_steps.inc()
-        metrics.add_span("engine.dispatch", t0,
-                         time.perf_counter() - t0, cat="engine")
+        with metrics.span("engine.dispatch", cat="engine",
+                          active=int(np.count_nonzero(self._active))):
+            exe = self._decode_exe()
+            self._m_h2d.inc()
+            state = jax.device_put(self._packed_state())
+            t0 = time.perf_counter()
+            if self._sampling:
+                self._tok_dev, self._keys_dev = self._adopt_pools(
+                    exe(self._params, self._kc, self._vc, self._tok_dev,
+                        self._keys_dev, state, *self._scale_args()),
+                    n_lead=2)
+            else:
+                self._tok_dev = self._adopt_pools(
+                    exe(self._params, self._kc, self._vc, self._tok_dev,
+                        state, *self._scale_args()))
+            snapshot = [(int(i), self._slot_req[i])
+                        for i in np.flatnonzero(self._active)]
+            self._inflight.append((self._tok_dev, snapshot, t0))
+            self._g_inflight.set(len(self._inflight))
+            # host bookkeeping for the step just enqueued: each active slot
+            # advances one position; a slot at its token budget stops being
+            # dispatched but stays occupied until its tokens are harvested
+            self._lengths[self._active] += 1
+            self._budget[self._active] -= 1
+            self._fresh[:] = False
+            self._active &= self._budget > 0
+            self._m_steps.inc()
 
     # ----------------------------------------------------- speculative step
 
@@ -2477,27 +2493,28 @@ class DecodeEngine:
             if n > 0:                          # most recent occurrence
                 drafts[slot, :n] = d[:n]
                 draft_lens[slot] = n
-        exe = self._verify_exe()
-        self._m_h2d.inc()
-        state = jax.device_put(self._packed_spec_state(drafts, draft_lens))
-        t0 = time.perf_counter()
-        if self._sampling:
-            (emitted_dev, n_emit_dev, self._tok_dev,
-             self._keys_dev) = self._adopt_pools(
-                exe(self._params, self._kc, self._vc, self._tok_dev,
-                    self._keys_dev, state, *self._scale_args()), n_lead=4)
-        else:
-            emitted_dev, n_emit_dev, self._tok_dev = self._adopt_pools(
-                exe(self._params, self._kc, self._vc, self._tok_dev, state,
-                    *self._scale_args()), n_lead=3)
-        snapshot = [(int(i), self._slot_req[i])
-                    for i in np.flatnonzero(self._active)]
-        self._fresh[:] = False
-        self._m_steps.inc()
-        self._m_spec_steps.inc()
-        self._m_spec_drafted.inc(int(draft_lens.sum()))
-        metrics.add_span("engine.dispatch", t0,
-                         time.perf_counter() - t0, cat="engine")
+        with metrics.span("engine.dispatch", cat="engine",
+                          active=int(np.count_nonzero(self._active))):
+            exe = self._verify_exe()
+            self._m_h2d.inc()
+            state = jax.device_put(
+                self._packed_spec_state(drafts, draft_lens))
+            if self._sampling:
+                (emitted_dev, n_emit_dev, self._tok_dev,
+                 self._keys_dev) = self._adopt_pools(
+                    exe(self._params, self._kc, self._vc, self._tok_dev,
+                        self._keys_dev, state, *self._scale_args()),
+                    n_lead=4)
+            else:
+                emitted_dev, n_emit_dev, self._tok_dev = self._adopt_pools(
+                    exe(self._params, self._kc, self._vc, self._tok_dev,
+                        state, *self._scale_args()), n_lead=3)
+            snapshot = [(int(i), self._slot_req[i])
+                        for i in np.flatnonzero(self._active)]
+            self._fresh[:] = False
+            self._m_steps.inc()
+            self._m_spec_steps.inc()
+            self._m_spec_drafted.inc(int(draft_lens.sum()))
         return emitted_dev, n_emit_dev, snapshot
 
     def _harvest_spec(self, emitted_dev, n_emit_dev, snapshot) -> int:
@@ -2507,10 +2524,11 @@ class DecodeEngine:
         page-granular 'rollback' of rejected tokens is just NOT advancing
         past them; their stale KV sits beyond every live position and is
         rewritten before any later query can attend it."""
-        tb = time.perf_counter()
-        emitted = np.asarray(emitted_dev)
-        n_emit = np.asarray(n_emit_dev)
-        self._blocked_s += time.perf_counter() - tb
+        with metrics.span("engine.harvest", cat="engine",
+                          of="decode") as sp:
+            emitted = np.asarray(emitted_dev)
+            n_emit = np.asarray(n_emit_dev)
+            sp.args["tokens"] = int(sum(n_emit[slot] for slot, _ in snapshot))
         self._m_d2h.inc()
         harvested = accepted = 0
         for slot, req in snapshot:
@@ -2557,9 +2575,9 @@ class DecodeEngine:
         snapshot request, retire slots that hit max_new_tokens or EOS."""
         toks_dev, snapshot, t0 = self._inflight.popleft()
         self._g_inflight.set(len(self._inflight))
-        tb = time.perf_counter()
-        toks_np = np.asarray(toks_dev)
-        self._blocked_s += time.perf_counter() - tb
+        with metrics.span("engine.harvest", cat="engine", of="decode",
+                          tokens=len(snapshot)):
+            toks_np = np.asarray(toks_dev)
         self._m_d2h.inc()
         n = 0
         for slot, req in snapshot:
@@ -2587,70 +2605,82 @@ class DecodeEngine:
         """Admit waiting requests, enqueue ONE batched decode step plus at
         most one prefill chunk, harvest steps past the in-flight window.
         Returns False when fully idle."""
-        t_step = time.perf_counter()
         self.step_seq += 1
-        self._blocked_s = 0.0
-        if faults.ENABLED:
-            faults.fire("engine.step_delay")   # armed: sleeps delay_s
-            faults.fire("engine.crash")        # armed with exc=: raises —
-            #                                    serve_loop aborts waiters
-        self._reap()
-        if self._migrate_requested:
-            self._do_migrate_out()
-        self._apply_imports()
-        self._apply_prefill_jobs()
-        self._apply_degradation()
-        self._admit()
-        # capacity tripwire: a token at pos >= slot_capacity would spill to
-        # the trash page on device (kernels/paged_attention.py); the engine
-        # retires the sequence with an error instead of scheduling it
-        for slot in np.flatnonzero(self._active &
-                                   (self._lengths >= self.slot_capacity)):
-            self._retire(int(slot), error=(
-                f"sequence hit slot capacity {self.slot_capacity} "
-                f"(pages_per_slot * page_size); token at position "
-                f"{int(self._lengths[slot])} cannot be cached"))
-        n_active = int(self._active.sum())
-        self._g_occupancy.set(n_active)
-        if n_active or self._inflight or self._prefilling:
-            # idle polls stay out of the ring: an hour of idle serve_loop
-            # must not evict the events around the last real work
-            flight.record("engine.step", step_seq=self.step_seq,
-                          occupancy=n_active, inflight=len(self._inflight))
-        harvested = 0
-        spec_pending = None
-        if n_active:
-            if self._spec:
-                spec_pending = self._dispatch_spec()
-            else:
-                self._dispatch()
-        # decode-priority: the chunk enqueues AFTER the decode step, so the
-        # in-flight decodes' cadence bounds how much a long prompt can add
-        # per step (one chunk), never the whole prefill wall
-        chunked = self._advance_prefill()
-        if spec_pending is not None:
-            # synchronous harvest (after the chunk enqueued, so chunked
-            # prefill keeps its decode-priority slot in the device queue):
-            # the host needs the accepted tokens to draft the next step
-            harvested += self._harvest_spec(*spec_pending)
-        elif n_active:
-            while len(self._inflight) >= max(1, self.ecfg.inflight):
+        with metrics.span("engine.step", cat="engine",
+                          step_seq=self.step_seq) as sp:
+            if faults.ENABLED:
+                faults.fire("engine.step_delay")   # armed: sleeps delay_s
+                faults.fire("engine.crash")    # armed with exc=: raises —
+                #                                serve_loop aborts waiters
+            with metrics.span("engine.admit", cat="engine") as adm:
+                self._reap()
+                if self._migrate_requested:
+                    self._do_migrate_out()
+                self._apply_imports()
+                streamed = self._apply_prefill_jobs()
+                self._apply_degradation()
+                admitted = self._admit()
+                # capacity tripwire: a token at pos >= slot_capacity would
+                # spill to the trash page on device
+                # (kernels/paged_attention.py); the engine retires the
+                # sequence with an error instead of scheduling it
+                for slot in np.flatnonzero(
+                        self._active & (self._lengths >= self.slot_capacity)):
+                    self._retire(int(slot), error=(
+                        f"sequence hit slot capacity {self.slot_capacity} "
+                        f"(pages_per_slot * page_size); token at position "
+                        f"{int(self._lengths[slot])} cannot be cached"))
+                n_active = int(self._active.sum())
+                working = bool(n_active or self._inflight
+                               or self._prefilling)
+                # an idle poll stays off the span ring (serve_loop polls
+                # twenty times a second for as long as nobody calls)
+                idle = not (working or admitted or streamed)
+                if idle:
+                    adm.discard()
+                adm.args.update(admitted=admitted, queued=len(self._queue))
+            sp.args.update(active=n_active, inflight=len(self._inflight))
+            self._g_occupancy.set(n_active)
+            if working:
+                # idle polls stay out of the ring: an hour of idle
+                # serve_loop must not evict the events around the last
+                # real work
+                flight.record("engine.step", step_seq=self.step_seq,
+                              occupancy=n_active,
+                              inflight=len(self._inflight))
+            harvested = 0
+            spec_pending = None
+            if n_active:
+                if self._spec:
+                    spec_pending = self._dispatch_spec()
+                else:
+                    self._dispatch()
+            # decode-priority: the chunk enqueues AFTER the decode step, so
+            # the in-flight decodes' cadence bounds how much a long prompt
+            # can add per step (one chunk), never the whole prefill wall
+            self._advance_prefill()
+            if spec_pending is not None:
+                # synchronous harvest (after the chunk enqueued, so chunked
+                # prefill keeps its decode-priority slot in the device
+                # queue): the host needs the accepted tokens to draft the
+                # next step
+                harvested += self._harvest_spec(*spec_pending)
+            elif n_active:
+                while len(self._inflight) >= max(1, self.ecfg.inflight):
+                    harvested += self._harvest_one()
+            elif self._inflight:
+                # nothing dispatchable: drain the fifo so budget-spent
+                # slots retire (freeing pages/slots for the next admission)
                 harvested += self._harvest_one()
-        elif self._inflight:
-            # nothing dispatchable: drain the fifo so budget-spent slots
-            # retire (freeing pages/slots for the next admission)
-            harvested += self._harvest_one()
-        elif not chunked:
-            with self._qlock:
-                return bool(self._queue) or bool(self._imports) \
-                    or bool(self._prefill_jobs)
-        dt = time.perf_counter() - t_step
+            elif idle:
+                sp.discard()           # nor in the step histogram
+                with self._qlock:
+                    return bool(self._queue) or bool(self._imports) \
+                        or bool(self._prefill_jobs)
+        dt = sp.dur
         self._h_step.observe(dt)
-        self._h_host.observe((dt - self._blocked_s) * 1e3)
-        self._h_device.observe(self._blocked_s * 1e3)
         if harvested:
             self._g_tps.set(harvested / dt if dt > 0 else 0.0)
-        metrics.add_span("engine.step", t_step, dt, cat="engine")
         return self._has_work()
 
     def run_until_idle(self, max_steps: int | None = None):
@@ -2795,9 +2825,13 @@ class DecodeEngine:
                     break
                 ids, cache, trace_ctx, sink = self._prefill_jobs.popleft()
             ran = True
+            fleet = (*trace_ctx, new_span_id()) if trace_ctx else None
             try:
-                self._run_prefill_stream(ids, cache, sink,
-                                         trace_ctx=trace_ctx)
+                with metrics.span("engine.prefill_stream", cat="engine",
+                                  fleet=fleet,
+                                  prompt_len=int(ids.size)) as sp:
+                    sp.args["records"] = self._run_prefill_stream(
+                        ids, cache, sink, trace_ctx=trace_ctx)
                 sink.put(("done", None))
             except Exception as e:  # noqa: BLE001 — surface to the sender
                 sink.put(("err", f"{type(e).__name__}: {e}"))
@@ -2810,9 +2844,7 @@ class DecodeEngine:
         Pages are borrowed from the pool for the duration and freed
         before returning (the freshly prefilled ones stay indexed in the
         prefix store, like `prefill_export`). A ``trace_ctx`` rides the
-        PTKS1 header and records the job's wall as a span in this
-        process's trace ring (zero extra work when None)."""
-        t0_trace = time.perf_counter() if trace_ctx else None
+        PTKS1 header. Returns the number of records streamed."""
         from paddle_tpu.kernels.paged_attention import export_pages
         from paddle_tpu.serving.disagg import (pack_stream_final,
                                                pack_stream_header,
@@ -2896,10 +2928,7 @@ class DecodeEngine:
                     sink.put(("rec",
                               pack_stream_pages(seq, p0, *_blobs(p0, n))))
                     seq += 1
-            tb = time.perf_counter()
-            first = int(tok)          # the stream's only token readback
-            self._blocked_s += time.perf_counter() - tb
-            self._m_d2h.inc()
+            first = self._read_first_token(tok)
             sink.put(("rec", pack_stream_final(
                 seq, first, cursor, *_blobs(cursor, n_src - cursor))))
             if self._prefix_enabled and cache:
@@ -2910,14 +2939,7 @@ class DecodeEngine:
         flight.record("engine.prefill_stream", prompt_len=s0,
                       records=n_records, cached_pages=len(shared),
                       reuploaded_pages=n_up)
-        if trace_ctx:
-            from paddle_tpu.observability.tracing import new_span_id
-            tid, parent = trace_ctx
-            metrics.add_span(
-                "engine.prefill_stream", t0_trace,
-                time.perf_counter() - t0_trace, cat="engine",
-                args={"prompt_len": s0, "records": n_records},
-                trace_id=tid, parent=parent, span_id=new_span_id())
+        return n_records
 
     def import_request(self, handoff: KVHandoff, max_new_tokens=32,
                        trace=None, cache=True,

@@ -941,69 +941,80 @@ class InferenceServer:
                     conn.sendall(struct.pack("<III", MAGIC, 0, 0))
                     self._stop.set()
                     return
-                t0 = time.perf_counter()
                 # the request's SLO clock starts HERE, at wire accept —
                 # body receive, queue wait, prefill and decode all count
                 trace = RequestTrace() \
                     if op in (OP_GENERATE, OP_MIGRATE, OP_KV_STREAM) \
                     else None
                 try:
-                    if faults.ENABLED:
-                        faults.fire("serve.slow_read")   # slow client
-                        if faults.fire("serve.socket_drop"):
-                            return      # network drop: close, no response
-                    arrays = recv_arrays(conn, n)
-                    metrics.counter("serve.request_bytes").inc(
-                        sum(a.nbytes for a in arrays))
-                    if op == OP_PREFILL:
-                        # streaming response: the body sends its own
-                        # header + one array per PTKS1 record AS THE
-                        # ENGINE PRODUCES THEM (the whole point — the
-                        # wire transfer overlaps the prefill compute).
-                        # False = the stream died after the header went
-                        # out (fault drill or engine failure): the
-                        # response is unfinishable, drop the connection
-                        # — the router falls back to symmetric prefill
-                        if not self._prefill_stream(arrays, conn):
-                            return
-                        continue
-                    if op == OP_GENERATE:
-                        outs = [self._generate(arrays, trace, conn)]
-                        if faults.ENABLED and faults.fire("serve.ack_drop"):
-                            # the ACCEPTED-BUT-UNANSWERED window: the
-                            # generation ran to completion, the answer is
-                            # about to ship, and the connection dies —
-                            # the ambiguous failure exactly-once exists
-                            # for. The client's resubmit (same request
-                            # key) replays the cached answer instead of
-                            # re-burning the generation
-                            # (docs/ROBUSTNESS.md "Control-plane HA")
-                            return
-                    elif op == OP_MIGRATE:
-                        outs = [self._migrate_in(arrays, trace, conn)]
-                    elif op == OP_KV_STREAM:
-                        outs = [self._kv_stream_in(arrays, trace, conn)]
-                    elif op == OP_CANCEL:
-                        outs = [self._cancel_op(arrays)]
-                    else:
-                        if self._predictor is None:
-                            raise RuntimeError(
-                                "engine-only server: no model artifact "
-                                "loaded, only GENERATE/PING/STATS served")
-                        with self._lock:
-                            self._predictor.run(arrays)
-                            outs = [
-                                self._predictor.get_output_handle(nm)
-                                .copy_to_cpu()
-                                for nm in self._predictor.get_output_names()]
-                    conn.sendall(struct.pack("<III", MAGIC, 0, len(outs)))
-                    send_arrays(conn, outs)
-                    metrics.counter("serve.requests").inc()
-                    metrics.counter("serve.response_bytes").inc(
-                        sum(a.nbytes for a in outs))
-                    dt = time.perf_counter() - t0
-                    metrics.histogram("serve.request_seconds").observe(dt)
-                    metrics.add_span("serve.request", t0, dt, cat="serve")
+                    with metrics.span("serve.request", cat="serve",
+                                      op=int(op)) as sp:
+                        if faults.ENABLED:
+                            faults.fire("serve.slow_read")   # slow client
+                            if faults.fire("serve.socket_drop"):
+                                return  # network drop: close, no response
+                        arrays = recv_arrays(conn, n)
+                        metrics.counter("serve.request_bytes").inc(
+                            sum(a.nbytes for a in arrays))
+                        if op == OP_PREFILL:
+                            # streaming response: the body sends its own
+                            # header + one array per PTKS1 record AS THE
+                            # ENGINE PRODUCES THEM (the whole point — the
+                            # wire transfer overlaps the prefill compute).
+                            # False = the stream died after the header
+                            # went out (fault drill or engine failure): the
+                            # response is unfinishable, drop the connection
+                            # — the router falls back to symmetric prefill
+                            if not self._prefill_stream(arrays, conn):
+                                return
+                            continue
+                        if op == OP_GENERATE:
+                            outs = [self._generate(arrays, trace, conn)]
+                            if faults.ENABLED \
+                                    and faults.fire("serve.ack_drop"):
+                                # the ACCEPTED-BUT-UNANSWERED window: the
+                                # generation ran to completion, the answer
+                                # is about to ship, and the connection dies —
+                                # the ambiguous failure exactly-once exists
+                                # for. The client's resubmit (same request
+                                # key) replays the cached answer instead of
+                                # re-burning the generation
+                                # (docs/ROBUSTNESS.md "Control-plane HA")
+                                return
+                        elif op == OP_MIGRATE:
+                            outs = [self._migrate_in(arrays, trace, conn)]
+                        elif op == OP_KV_STREAM:
+                            outs = [self._kv_stream_in(arrays, trace, conn)]
+                        elif op == OP_CANCEL:
+                            outs = [self._cancel_op(arrays)]
+                        else:
+                            if self._predictor is None:
+                                raise RuntimeError(
+                                    "engine-only server: no model artifact "
+                                    "loaded, only GENERATE/PING/STATS served")
+                            with self._lock:
+                                self._predictor.run(arrays)
+                                names = self._predictor.get_output_names()
+                                outs = [
+                                    self._predictor.get_output_handle(nm)
+                                    .copy_to_cpu() for nm in names]
+                        conn.sendall(struct.pack("<III", MAGIC, 0, len(outs)))
+                        send_arrays(conn, outs)
+                        metrics.counter("serve.requests").inc()
+                        nbytes = sum(a.nbytes for a in outs)
+                        metrics.counter("serve.response_bytes").inc(nbytes)
+                        if trace is not None and trace.t_done is not None:
+                            # retirement to the reply's last byte written:
+                            # what this thread and the wire add after the
+                            # engine is done with the request
+                            metrics.add_span(
+                                "serve.reply", trace.t_done,
+                                time.perf_counter() - trace.t_done,
+                                cat="serve", under=sp,
+                                args={"request_id": trace.request_id,
+                                      "bytes": nbytes})
+                    metrics.histogram("serve.request_seconds").observe(
+                        sp.dur)
                 except Exception as e:  # noqa: BLE001 — wire back to client
                     metrics.counter("serve.errors").inc()
                     if trace is not None and not trace.done:
@@ -1589,9 +1600,21 @@ class RemotePredictor:
             # SAME context rides every resubmit so a failover's attempts
             # stitch into one trace
             trace_ctx = (str(trace_id), parent_span or new_span_id())
+        if trace_ctx is None:
+            return self._generate_retrying(ids, max_new_tokens, cache,
+                                           speculate, deadline_s, tag, key)
+        with metrics.span("client.generate", cat="client",
+                          fleet=(trace_ctx[0], None, trace_ctx[1])):
+            return self._generate_retrying(ids, max_new_tokens, cache,
+                                           speculate, deadline_s, tag, key,
+                                           trace_ctx)
+
+    def _generate_retrying(self, ids, max_new_tokens, cache, speculate,
+                           deadline_s, tag, key, trace_ctx=None):
+        """`generate` past its argument handling: GENERATE exchanges until
+        one endpoint answers, failing over between them on wire death."""
         t_deadline = None if deadline_s is None \
             else time.monotonic() + float(deadline_s)
-        t0 = time.perf_counter()
         # one attempt per endpoint plus one (the single-endpoint replay
         # case: the same server answers the resubmit from its dedup
         # table after e.g. an ack-window drop)
@@ -1605,15 +1628,9 @@ class RemotePredictor:
                         f"request deadline ({deadline_s}s) exhausted "
                         f"before an endpoint answered")
             try:
-                out = self._generate_once(ids, max_new_tokens, cache,
-                                          speculate, remaining, tag, key,
-                                          trace_ctx)
-                if trace_ctx is not None:
-                    metrics.add_span(
-                        "client.generate", t0, time.perf_counter() - t0,
-                        cat="client", trace_id=trace_ctx[0],
-                        span_id=trace_ctx[1])
-                return out
+                return self._generate_once(ids, max_new_tokens, cache,
+                                           speculate, remaining, tag, key,
+                                           trace_ctx)
             except (ConnectionError, socket.timeout, OSError):
                 # wire death mid-request. Without a key this is the
                 # legacy contract: surface it (a blind resubmit could

@@ -278,13 +278,25 @@ class StaticFunction:
 
     # ------------------------------------------------------------------ capture
 
-    def _capture(self, key, args, kwargs, _converted=False):
+    def _capture(self, key, args, kwargs):
+        """Probe, trace and register one signature's program, as one
+        `jit.capture:<fn>` span. Its wall time covers the abstract probe +
+        pure-fn construction; XLA's own compile lands inside the first
+        dispatch (jit.dispatch_seconds max vs p50 separates compile from
+        steady-state)."""
+        with metrics.span(f"jit.capture:{getattr(self._fn, '__name__', '?')}",
+                          cat="compile") as sp:
+            compiled = self._probe_and_trace(key, args, kwargs)
+        _M_COMPILES.inc()
+        _M_COMPILE_S.observe(sp.dur)
+        return compiled
+
+    def _probe_and_trace(self, key, args, kwargs, _converted=False):
         if not _converted and getattr(self, "_fn_dy2static", None) is not None:
             # a previous signature already needed conversion — start from
             # the converted fn instead of re-probing the original
             _converted = True
         fn = self._fn if not _converted else self._fn_dy2static
-        _t0 = time.perf_counter()
         cap = _CaptureSet(tensor_mod.current_stamp())
         arg_tensors, _, _ = _tree_flatten_tensors((args, kwargs))
         arg_ids = {id(t) for t in arg_tensors}
@@ -340,7 +352,7 @@ class StaticFunction:
             # dy2static conversion, `program_translator.py:283`)
             from paddle_tpu.jit.dy2static import convert_to_static
             self._fn_dy2static = convert_to_static(self._fn)
-            return self._capture(key, args, kwargs, _converted=True)
+            return self._probe_and_trace(key, args, kwargs, _converted=True)
         result = result_box[0]
 
         state_tensors = [cap.reads[k] for k in cap.order]
@@ -403,13 +415,6 @@ class StaticFunction:
                              len(out_tensors), out_stop_grads, grad_mask,
                              pure=pure)
         self._cache.setdefault(key, []).append(compiled)
-        # capture wall time covers the abstract probe + pure-fn construction;
-        # XLA's own compile lands inside the first dispatch (jit.dispatch_
-        # seconds max vs p50 separates compile from steady-state)
-        _M_COMPILES.inc()
-        _M_COMPILE_S.observe(time.perf_counter() - _t0)
-        metrics.add_span(f"jit.capture:{getattr(self._fn, '__name__', '?')}",
-                         _t0, time.perf_counter() - _t0, cat="compile")
         return compiled
 
     def multi_steps(self, k: int) -> "MultiStepFunction":

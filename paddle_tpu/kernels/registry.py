@@ -183,17 +183,44 @@ def select(op: str, key: tuple, candidates: list, measure,
     the table entry in place of its time, so whoever reads
     :func:`table` sees which arm the device refused; when every
     candidate raises, so does this. The winner is cached in memory and,
-    when ``PADDLE_AUTOTUNE_CACHE`` names a table, persisted on disk."""
+    when ``PADDLE_AUTOTUNE_CACHE`` names a table, persisted on disk.
+    Each resolution is one ``kernel.select:<op>`` span whose args name
+    the pick, where it came from and the candidates' timings: what the
+    selection cost inside the program, beside what it chose."""
+    with metrics.span(f"kernel.select:{op}", cat="kernel") as sp:
+        winner, source, timings = _resolve(op, key, candidates, measure)
+        sp.args.update(pick=winner, source=source, timings_ms={
+            k: round(v * 1e3, 4) if isinstance(v, float) else v
+            for k, v in timings.items()})
+    if source == "measured":
+        try:
+            from paddle_tpu.framework.flags import flag_value
+            verbose = flag_value("autotune_verbose")
+        except Exception:  # noqa: BLE001 — flags registry unavailable
+            verbose = False
+        if verbose:
+            _LOG.warning("autotune %s %s -> %s (%s)", verbose_tag or op,
+                         key, winner,
+                         {k: f"{v * 1e3:.2f}ms" for k, v in timings.items()
+                          if isinstance(v, float)})
+    return winner
+
+
+def _resolve(op, key, candidates, measure):
+    """``(winner, source, {impl: seconds | error})`` for :func:`select`;
+    ``source`` says where the answer came from: ``memory`` (this process
+    already decided), ``single`` (one viable candidate: pinned), ``disk``
+    (the persisted table) or ``measured`` (every candidate timed now)."""
     hit = _TABLE.get(key)
     if hit is not None:
-        return hit[0]
+        return hit[0], "memory", hit[1]
     if len(candidates) == 1:
         _TABLE[key] = (candidates[0], {})
-        return candidates[0]
+        return candidates[0], "single", {}
     disk = _disk_lookup(key, candidates)
     if disk is not None:
         _TABLE[key] = (disk, {})
-        return disk
+        return disk, "disk", {}
     import jax
     timings, errors = {}, {}
     for impl in candidates:
@@ -212,18 +239,9 @@ def select(op: str, key: tuple, candidates: list, measure,
         raise RuntimeError(
             f"registry: every candidate of {op} failed for {key}: {errors}")
     winner = min(timings, key=timings.get)
-    try:
-        from paddle_tpu.framework.flags import flag_value
-        verbose = flag_value("autotune_verbose")
-    except Exception:  # noqa: BLE001 — flags registry unavailable
-        verbose = False
-    if verbose:
-        _LOG.warning("autotune %s %s -> %s (%s)", verbose_tag or op, key,
-                     winner,
-                     {k: f"{v * 1e3:.2f}ms" for k, v in timings.items()})
     _TABLE[key] = (winner, {**timings, **errors})
     _disk_store(key, winner)
-    return winner
+    return winner, "measured", _TABLE[key][1]
 
 
 # ------------------------------------------------------------ persistence

@@ -11,8 +11,15 @@ Design:
 - **Counter** — monotonically increasing float/int (`inc`).
 - **Gauge** — last-write-wins scalar (`set`).
 - **Histogram** — count/sum/min/max plus a bounded reservoir of recent
-  observations for p50/p99; `time()` returns a context manager that
-  observes the elapsed seconds AND records a span for Chrome-trace export.
+  observations for p50/p99.
+- **Span** — ``metrics.span(name, cat=..., **args)``: the ONE way a host
+  range is recorded. A context manager that lands on the registry's span
+  ring (start, duration, thread, args, its own id and the id of the span
+  open on the same thread when it began) AND, while a profiler session
+  runs, is a ``jax.profiler.TraceAnnotation`` of the same name for its whole
+  life, so the session shows it on the device trace's clock. ``timer()`` is
+  a span that also observes a histogram; ``add_span()`` records a range
+  whose start lies in the past (ring only); ``spans()`` is the public read.
 - Metrics are keyed by ``(name, sorted(labels))``; the flat snapshot key is
   ``name{k=v,...}`` (Prometheus-style).
 - ``snapshot()`` → plain dict (JSON-ready); ``to_json()`` serializes it;
@@ -22,6 +29,9 @@ Design:
 
 Everything here is stdlib-only ON PURPOSE: instrumented modules import this
 at module scope, so it must never create an import cycle or pull in jax.
+(A span looks jax's annotation class up in ``sys.modules`` once somebody
+else has imported jax; before that, and where jax is absent, it is ring
+only.)
 
 Semantics note for in-graph instrumentation: counters incremented inside a
 jax trace (e.g. `collective.bytes` for the lax.psum path) count **trace-time
@@ -32,16 +42,20 @@ the full metric inventory.
 from __future__ import annotations
 
 import collections
+import itertools
 import json
 import os
+import sys
 import threading
 import time
+from typing import NamedTuple
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "metrics",
     "counter", "gauge", "histogram", "timer", "snapshot", "reset",
     "chrome_trace", "export_chrome_trace", "to_prometheus",
     "set_node_identity", "node_identity", "spans_for_trace",
+    "Span", "SpanRecord", "span", "spans", "add_span",
 ]
 
 # perf_counter origin for span timestamps — one epoch per process so spans
@@ -53,7 +67,11 @@ _EPOCH = time.perf_counter()
 _EPOCH_UNIX_US = time.time() * 1e6
 
 _RESERVOIR = 512       # recent observations kept per histogram (percentiles)
-_MAX_SPANS = 20000     # bounded span ring: old spans drop, process never grows
+# bounded span ring: old spans drop (counted: metrics.spans_dropped), the
+# process never grows. Sized for a benchmark window on top of set-up: 150
+# engine steps a second x 6 spans a step x 45 s (ramp + window) = 40,500,
+# plus six spans a request and set-up's few hundred; ~15 MB when full.
+_MAX_SPANS = 65536
 _MAX_TRACES = 64       # per-trace span rings kept (LRU; fleet TRACE_EXPORT)
 _MAX_TRACE_SPANS = 256  # spans kept per traced request
 _MAX_LABELED_SERIES = 256  # LRU cap on LABELED series (membership churn)
@@ -176,26 +194,97 @@ class Histogram:
         return out
 
 
-class _Timer:
-    """Context manager: observes elapsed seconds into a histogram and records
-    a span on the registry's Chrome-trace timeline."""
+class SpanRecord(NamedTuple):
+    """One recorded span as :meth:`MetricsRegistry.spans` returns it:
+    ``t0`` and ``dur`` in ``time.perf_counter`` seconds, ``id`` unique in
+    the process, ``parent`` the id of the enclosing span (None at a root)."""
+    name: str
+    cat: str
+    t0: float
+    dur: float
+    tid: int
+    args: dict | None
+    id: int
+    parent: int | None
 
-    __slots__ = ("_reg", "_hist", "_name", "_t0")
 
-    def __init__(self, reg, hist, name):
+_span_ids = itertools.count(1)    # next() is atomic under the GIL
+_open = threading.local()         # .stack: ids of this thread's open spans
+_TRACE_ANNOTATION = None          # jax.profiler.TraceAnnotation, once found
+
+
+def _find_annotation():
+    """jax's host annotation class, or None while nobody has imported jax
+    (or where it is absent): a span is then ring only. Never imports jax
+    itself: a process that does no device work pays nothing for it."""
+    global _TRACE_ANNOTATION
+    jax = sys.modules.get("jax")
+    try:
+        _TRACE_ANNOTATION = jax.profiler.TraceAnnotation
+    except AttributeError:      # not imported, or only half way
+        return None
+    return _TRACE_ANNOTATION
+
+
+class Span:
+    """Context manager for one host range (``metrics.span``): on exit it
+    lands on the registry's ring with its id and its parent's, and while a
+    profiler session runs it is, for its whole life, a ``TraceAnnotation``
+    of the same name (with no session the annotation is not built: 0.06 us
+    to ask against 0.75 us to build, enter and leave one). ``args`` may be
+    filled in while it is open (``sp.args["admitted"] = 3``); ``discard()``
+    keeps it off the ring (an idle poll). ``t0``/``dur`` are readable after
+    exit. ``fleet`` is a traced request's ``(trace_id, parent, span_id)``
+    hex context: the span then lands in that trace's ring too."""
+
+    __slots__ = ("_reg", "name", "cat", "args", "id", "parent", "t0", "dur",
+                 "_hist", "_fleet", "_ann", "_keep")
+
+    def __init__(self, reg, name, cat="host", args=None, hist=None,
+                 fleet=None):
         self._reg = reg
+        self.name = name
+        self.cat = cat
+        self.args = args
         self._hist = hist
-        self._name = name
-        self._t0 = None
+        self._fleet = fleet
+        self._ann = None
+        self._keep = True
+
+    def discard(self):
+        self._keep = False
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
+        try:
+            stack = _open.stack
+        except AttributeError:
+            stack = _open.stack = []
+        self.parent = stack[-1] if stack else None
+        self.id = next(_span_ids)
+        stack.append(self.id)
+        ann = _TRACE_ANNOTATION or _find_annotation()
+        if ann is not None and ann.is_enabled():
+            self._ann = ann(self.name)
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
         return self
 
-    def __exit__(self, *a):
-        dt = time.perf_counter() - self._t0
-        self._hist.observe(dt)
-        self._reg.add_span(self._name, self._t0, dt)
+    def __exit__(self, *exc):
+        self.dur = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+            self._ann = None
+        stack = getattr(_open, "stack", ())
+        if stack and stack[-1] == self.id:
+            stack.pop()
+        elif self.id in stack:      # ended out of order (RecordEvent.end)
+            stack.remove(self.id)
+        if self._hist is not None:
+            self._hist.observe(self.dur)
+        if self._keep:
+            self._reg._record(self.name, self.cat, self.t0, self.dur,
+                              self.args or None, self.id, self.parent,
+                              self._fleet)
         return False
 
 
@@ -222,6 +311,10 @@ class MetricsRegistry:
         self._series_evictions = Counter()
         self._counters[("metrics.series_evictions", ())] = \
             self._series_evictions
+        # spans the ring evicted to make room: a reader of an interval
+        # that lost spans must say so, not report a smaller number
+        self.spans_dropped = Counter()
+        self._counters[("metrics.spans_dropped", ())] = self.spans_dropped
         # who this process is in the fleet (role + registry-lease id);
         # stamped by serve/router startup, exported with every trace pull
         self._node = {"role": None, "node_id": None}
@@ -277,30 +370,36 @@ class MetricsRegistry:
     def histogram(self, name, **labels) -> Histogram:
         return self._get(self._histograms, "h", name, labels, Histogram)
 
-    def timer(self, name, **labels) -> _Timer:
-        return _Timer(self, self.histogram(name, **labels),
-                      _flatname(name, _labelkey(labels)))
+    def timer(self, name, **labels) -> Span:
+        """A span that also observes its seconds into the histogram
+        ``name{labels}``."""
+        return Span(self, _flatname(name, _labelkey(labels)),
+                    hist=self.histogram(name, **labels))
 
     # ----------------------------------------------------------------- spans
 
-    def add_span(self, name, t0_perf, dur_s, cat="host", args=None,
-                 trace_id=None, parent=None, span_id=None):
-        """Record one completed host-side range for Chrome-trace export.
-        ``t0_perf`` is a time.perf_counter() value; timestamps are stored in
-        microseconds relative to the process epoch. ``args`` (a small dict,
-        e.g. ``{"request_id": "req-7"}``) lands on the Chrome-trace event's
-        ``args`` field so Perfetto can group/filter spans by request.
+    def span(self, name, cat="host", fleet=None, **args) -> Span:
+        """``with metrics.span("engine.step", cat="engine", step_seq=7):``
+        — see :class:`Span`. A few microseconds an enter and exit with no
+        profiler session running (PERF.md, PR 24): once an engine step or
+        once a request, never once a token."""
+        return Span(self, name, cat, args, fleet=fleet)
 
-        When ``trace_id`` (hex string) is given the span ALSO lands in that
-        trace's bounded ring for the fleet collector (TRACE_EXPORT);
-        ``parent``/``span_id`` are the upstream hop's span id and this
-        process's own (hex). Untraced spans take the exact pre-fleet path —
-        no ring lookup, no allocation beyond the one tuple."""
-        entry = (name, cat, (t0_perf - _EPOCH) * 1e6,
-                 dur_s * 1e6, threading.get_ident(), args)
+    def _record(self, name, cat, t0_perf, dur_s, args, span_id, parent_id,
+                fleet=None):
+        """The one append to the ring. Entries begin ``(name, cat, ts_us,
+        dur_us, tid, args)`` (timestamps in microseconds from the process
+        epoch) and end with the span's id and its parent's. ``fleet`` is a
+        traced request's ``(trace_id, parent, span_id)`` hex context: its
+        copy in that trace's ring ends with the two hex ids instead."""
+        entry = (name, cat, (t0_perf - _EPOCH) * 1e6, dur_s * 1e6,
+                 threading.get_ident(), args, span_id, parent_id)
         with self._span_lock:
+            if len(self._spans) == self._spans.maxlen:
+                self.spans_dropped.inc()
             self._spans.append(entry)
-            if trace_id is not None:
+            if fleet is not None:
+                trace_id = fleet[0]
                 ring = self._trace_spans.get(trace_id)
                 if ring is None:
                     ring = self._trace_spans[trace_id] = \
@@ -309,7 +408,51 @@ class MetricsRegistry:
                         self._trace_spans.popitem(last=False)
                 else:
                     self._trace_spans.move_to_end(trace_id)
-                ring.append(entry + (parent, span_id))
+                ring.append(entry[:6] + tuple(fleet[1:]))
+
+    def add_span(self, name, t0_perf, dur_s, cat="host", args=None,
+                 trace_id=None, parent=None, span_id=None, under=None):
+        """Record one completed host-side range whose START LIES IN THE
+        PAST (a phase closed by a mark, a reply measured from a stamp):
+        ring only, since no annotation can begin in the past. A range that
+        a ``with`` can bracket uses :meth:`span`. ``t0_perf`` is a
+        time.perf_counter() value. ``args`` (a small dict, e.g.
+        ``{"request_id": "req-7"}``) lands on the Chrome-trace event's
+        ``args`` field so Perfetto can group/filter spans by request.
+        ``under`` is the open :class:`Span` this range belongs to (its
+        parent on the ring).
+
+        When ``trace_id`` (hex string) is given the span ALSO lands in that
+        trace's bounded ring for the fleet collector (TRACE_EXPORT);
+        ``parent``/``span_id`` are the upstream hop's span id and this
+        process's own (hex)."""
+        self._record(name, cat, t0_perf, dur_s, args, next(_span_ids),
+                     under.id if under is not None else None,
+                     None if trace_id is None
+                     else (trace_id, parent, span_id))
+
+    def spans(self, name=None, prefix=None, since=None, until=None) -> list:
+        """The public read of the ring: :class:`SpanRecord` tuples, oldest
+        first, of the spans that BEGAN in ``[since, until)``
+        (``time.perf_counter`` seconds; None leaves that side open), named
+        ``name`` or starting with ``prefix`` (a string or a tuple of
+        them). Whether the ring still holds all of an interval:
+        ``metrics.spans_dropped``."""
+        with self._span_lock:
+            raw = list(self._spans)
+        out = []
+        for n, cat, ts, dur, tid, args, sid, pid in raw:
+            if name is not None and n != name:
+                continue
+            if prefix is not None and not n.startswith(prefix):
+                continue
+            t0 = _EPOCH + ts * 1e-6
+            if (since is not None and t0 < since) or \
+                    (until is not None and t0 >= until):
+                continue
+            out.append(SpanRecord(n, cat, t0, dur * 1e-6, tid, args, sid,
+                                  pid))
+        return out
 
     def spans_for_trace(self, trace_id) -> list:
         """Chrome-trace events recorded under ``trace_id`` (hex string) by
@@ -360,9 +503,12 @@ class MetricsRegistry:
         with self._span_lock:
             spans = list(self._spans)
         events = []
-        for name, cat, ts, dur, tid, args in spans:
+        for name, cat, ts, dur, tid, args, sid, pid in spans:
             ev = {"name": name, "cat": cat, "ph": "X", "pid": os.getpid(),
-                  "tid": tid, "ts": round(ts, 3), "dur": round(dur, 3)}
+                  "tid": tid, "ts": round(ts, 3), "dur": round(dur, 3),
+                  "span_id": sid}
+            if pid is not None:
+                ev["parent_id"] = pid
             if args:
                 ev["args"] = dict(args)
             events.append(ev)
@@ -419,3 +565,6 @@ to_prometheus = metrics.to_prometheus
 set_node_identity = metrics.set_node_identity
 node_identity = metrics.node_identity
 spans_for_trace = metrics.spans_for_trace
+span = metrics.span
+spans = metrics.spans
+add_span = metrics.add_span

@@ -83,8 +83,7 @@ class RecordEvent:
 
     def __init__(self, name, event_type=None):
         self.name = name
-        self._ctx = None
-        self._t0 = None
+        self._span = None
 
     def __enter__(self):
         self.begin()
@@ -94,21 +93,16 @@ class RecordEvent:
         self.end()
 
     def begin(self):
-        self._t0 = time.perf_counter()
-        self._ctx = jax.profiler.TraceAnnotation(self.name)
-        self._ctx.__enter__()
+        # the registry's span: on its ring (Profiler.export(path) /
+        # observability.chrome_trace() see it) and a TraceAnnotation
+        self._span = _metrics.span(self.name, cat="host")
+        self._span.__enter__()
 
     def end(self):
-        if self._ctx is not None:
-            self._ctx.__exit__(None, None, None)
-            self._ctx = None
-        if self._t0 is not None:
-            dt = time.perf_counter() - self._t0
-            _record_host_event(self.name, dt)
-            # every host range also lands on the registry's span ring, so
-            # Profiler.export(path) / observability.chrome_trace() see it
-            _metrics.add_span(self.name, self._t0, dt, cat="host")
-            self._t0 = None
+        if self._span is not None:
+            self._span.__exit__(None, None, None)
+            _record_host_event(self.name, self._span.dur)
+            self._span = None
 
 
 class Profiler:

@@ -319,21 +319,29 @@ class TestDesyncStepLoop:
         np.testing.assert_array_equal(req.result(timeout=30),
                                       _fast_ref(m, prompt, 10))
 
-    def test_host_device_timer_pair_visible_in_snapshot(self):
+    def test_harvest_spans_carry_every_blocking_readback(self):
+        """`engine.harvest` is the engine thread waiting for the device:
+        one span a blocking readback, prefill's and decode's told apart,
+        each inside the `engine.step` that made it."""
+        import time
         from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
         m = _tiny_model()
         eng = DecodeEngine(m, EngineConfig(page_size=4, max_slots=1,
                                            min_bucket=8))
-        host = metrics.histogram("engine.host_ms")
-        dev = metrics.histogram("engine.device_ms")
-        base = (host.count, dev.count)
+        d2h = metrics.counter("engine.d2h_transfers")
+        base, t0 = d2h.value, time.perf_counter()
         req = eng.submit(np.random.RandomState(12).randint(0, 97, 4)
                          .astype(np.int32), 4)
         eng.run_until_idle(max_steps=30)
         assert req.done
-        assert host.count > base[0] and dev.count > base[1]
-        snap = metrics.snapshot()["histograms"]
-        assert "engine.host_ms" in snap and "engine.device_ms" in snap
+        harvests = metrics.spans(name="engine.harvest", since=t0)
+        assert len(harvests) == d2h.value - base
+        assert [h.args["of"] for h in harvests] == ["prefill"] + ["decode"] * 3
+        assert all(h.args["tokens"] == 1 and h.dur > 0 for h in harvests)
+        steps = {s.id: s for s in metrics.spans(name="engine.step", since=t0)}
+        for h in harvests[1:]:
+            step = steps[h.parent]
+            assert step.t0 <= h.t0 and h.t0 + h.dur <= step.t0 + step.dur
 
     def test_capacity_guard_retires_instead_of_corrupting(self):
         """Regression (overflow satellite): a sequence about to write past
