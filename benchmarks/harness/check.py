@@ -1,7 +1,9 @@
 """The comparison that decides ``correct``: each number beside its limit.
 
 Every number compared is printed in every run as one JSON line
-``{"check": name, "value": v, "limit": l, "ok": bool}``. Limits live in the
+``{"check": name, "value": v, "limit": l, "ok": bool}``, once more under
+``checks``, the last key of the result's line, and as the run's last lines
+on standard error. Limits live in the
 configuration file under ``limits`` (set from readings on the chip, which
 PERF.md lists), one per number; ``correct`` is true when every number is
 within its limit and nothing failed.
@@ -52,6 +54,12 @@ class Checks:
                "note": why}
         self.rows.append(row)
         print(json.dumps(row), flush=True)
+
+    def summary(self) -> list:
+        """Each number compared beside its limit, for the result's line and
+        the run's last lines on standard error."""
+        return [{"name": r["check"], "value": r["value"], "limit": r["limit"],
+                 "ok": r["ok"]} for r in self.rows]
 
     @property
     def correct(self) -> bool:

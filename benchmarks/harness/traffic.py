@@ -16,7 +16,9 @@ from the file alone:
 So every ``--seed`` offers the same multiset of (class, prompt length,
 answer length), the same hits and misses in every block, and the same due
 times. The seed decides only token ids, which shared prefix a request
-carries, and the order inside a block.
+carries, and the order inside a block. ``request_stream`` cycles the table
+without end, so a closed loop can ask for as many requests as its engine
+answers; ``requests`` is the stream's first ``count`` items.
 
 Distributions: ``{"dist": "lognormal", "min", "max", "median"}`` is a
 lognormal with that median whose 99.5th percentile sits at ``max``, cut to
@@ -25,6 +27,7 @@ lognormal with that median whose 99.5th percentile sits at ``max``, cut to
 """
 from __future__ import annotations
 
+import itertools
 import math
 import random
 from statistics import NormalDist
@@ -103,11 +106,11 @@ def due_times(schedule: dict, horizon_s: float) -> list[float]:
     return due
 
 
-def requests(traffic: dict, seed: int, count: int, vocab: int) -> list[dict]:
-    """The first ``count`` requests for ``seed``: the table cycled as often
-    as needed, each block's order shuffled by the seed, with token ids.
+def request_stream(traffic: dict, seed: int, vocab: int):
+    """The requests for ``seed``, without end: the table cycled, each
+    block's order shuffled by the seed, every cycle with fresh token ids.
     ``{"index", "cls", "prompt_ids", "answer", "prefix", "prefix_id"}``;
-    ``prompt_ids`` is a list of ints. Shared prefixes are ``n_prefixes``
+    ``prompt_ids`` is an int32 array. Shared prefixes are ``n_prefixes``
     seeded sequences; each sharing request draws one."""
     import numpy as np
     table = request_table(traffic)
@@ -118,11 +121,10 @@ def requests(traffic: dict, seed: int, count: int, vocab: int) -> list[dict]:
     pre_len = max((r["prefix"] for r in table), default=0)
     prefixes = [rng.randint(0, vocab, pre_len).astype(np.int32)
                 for _ in range(n_pre)]
-    out = []
-    b = 0
-    while len(out) < count:
-        rows = list(table[(b * block) % len(table):
-                          (b * block) % len(table) + block])
+    index = 0
+    for b in itertools.count():
+        at = (b * block) % len(table)
+        rows = list(table[at:at + block])
         order_rng.shuffle(rows)
         for r in rows:
             pid = int(rng.randint(0, n_pre)) if r["prefix"] else -1
@@ -130,8 +132,12 @@ def requests(traffic: dict, seed: int, count: int, vocab: int) -> list[dict]:
                 .astype(np.int32)
             ids = np.concatenate([prefixes[pid][:r["prefix"]], user]) \
                 if r["prefix"] else user
-            out.append({"index": len(out), "cls": r["cls"],
-                        "prompt_ids": ids, "answer": r["answer"],
-                        "prefix": r["prefix"], "prefix_id": pid})
-        b += 1
-    return out[:count]
+            yield {"index": index, "cls": r["cls"],
+                   "prompt_ids": ids, "answer": r["answer"],
+                   "prefix": r["prefix"], "prefix_id": pid}
+            index += 1
+
+
+def requests(traffic: dict, seed: int, count: int, vocab: int) -> list[dict]:
+    """The first ``count`` items of ``request_stream``."""
+    return list(itertools.islice(request_stream(traffic, seed, vocab), count))

@@ -92,8 +92,16 @@ def run_cell(workload, seed, seconds, trace, rehearsal=None, control=None,
         path = trace_mod.find_xplane(str(trace_dir))
         red = None
         if path:
-            red = trace_mod.reduce(trace_mod.load_xplane(path),
-                                   window_s=tracer.traced_s)
+            # a trace grows with the step rate: what reading it cost
+            t_red = time.perf_counter()
+            loaded = trace_mod.load_xplane(path)
+            red = trace_mod.reduce(loaded, window_s=tracer.traced_s)
+            print(json.dumps({
+                "note": "trace_reduction",
+                "seconds": time.perf_counter() - t_red,
+                "events": sum(len(ln["events"]) for pl in loaded["planes"]
+                              for ln in pl["lines"]),
+                "traced_s": tracer.traced_s}), flush=True)
         obs["trace"] = red
         if red:
             dev["busy_s"], dev["window_s"] = red["busy_s"], red["window_s"]
@@ -117,6 +125,10 @@ def run_cell(workload, seed, seconds, trace, rehearsal=None, control=None,
             if v is not None:
                 result["metrics"][m["name"]] = {"value": float(v),
                                                 "unit": m["unit"]}
+    if obs.get("notes"):
+        # what readers computed on their way (readers/decode_roofline.py)
+        print(json.dumps({"note": "readers", "values": obs["notes"]}),
+              flush=True)
     result["device"] = dev
     if not on_chip:
         # a rehearsal: nothing here is a device metric, so only the names
@@ -127,6 +139,8 @@ def run_cell(workload, seed, seconds, trace, rehearsal=None, control=None,
         result.pop("breakdown", None)
     if "control_out" in ctx:
         result["control"] = ctx["control_out"]
+    # last, so that the end of a cut line still holds them
+    result["checks"] = obs["checks"].summary()
     return result
 
 
@@ -140,6 +154,9 @@ def main(argv=None):
     result = run_cell(args.workload, args.seed, args.seconds, bool(args.trace))
     sys.stdout.flush()
     print(json.dumps(result), flush=True)
+    for c in result["checks"]:
+        print(f'{c["name"]}: {c["value"]} (limit {c["limit"]}) '
+              f'{"ok" if c["ok"] else "NOT OK"}', file=sys.stderr, flush=True)
 
 
 if __name__ == "__main__":

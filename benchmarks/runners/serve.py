@@ -106,35 +106,31 @@ class _Clients:
         return rec
 
 
-def _closed_loop(clients, reqs, n_clients, stop, ramp_answer):
-    """``n_clients`` threads; each sends its next request when the last
-    returned. A client's FIRST request has its answer cut to the fraction
-    (c + 1) / n of ``ramp_answer`` (the table's median answer), so that
-    finishes spread evenly over a typical request's life and the ramp lasts
-    one median request, not the longest. Returns (threads, events set once
-    each client finished one)."""
-    nxt = iter(range(n_clients, len(reqs)))
+def _closed_loop(clients, stream, n_clients, stop, ramp_answer):
+    """``n_clients`` threads; each sends its next request of ``stream`` (a
+    generator without end) when the last returned. A client's FIRST request
+    has its answer cut to the fraction (c + 1) / n of ``ramp_answer`` (the
+    table's median answer), so that finishes spread evenly over a typical
+    request's life and the ramp lasts one median request, not the longest.
+    Returns (threads, events set once each client finished one)."""
+    first = [next(stream) for _ in range(n_clients)]
     lock = threading.Lock()
     first_done = [threading.Event() for _ in range(n_clients)]
 
     def take():
         with lock:
-            return next(nxt, None)
+            return next(stream)
 
     def client(c):
         cli = clients.connect()
         try:
-            r = reqs[c]
+            r = first[c]
             cut = min(r["answer"],
                       max(2, -(-ramp_answer * (c + 1) // n_clients)))
             clients.send(cli, r, answer=cut)
             first_done[c].set()
             while not stop.is_set():
-                i = take()
-                if i is None:
-                    clients.errors.append("request table ran out")
-                    return
-                clients.send(cli, reqs[i])
+                clients.send(cli, take())
         finally:
             first_done[c].set()
             cli.close()
@@ -231,12 +227,10 @@ def run(ctx):
     try:
         if loop == "closed":
             n_clients = int(traffic["clients"])
-            count = n_clients + int(seconds * traffic["max_requests_per_s"]) \
-                + 4 * n_clients
-            reqs = T.requests(traffic, seed, count, cfg["vocab_size"])
             answers = sorted(r["answer"] for r in table)
             threads, first_done = _closed_loop(
-                clients, reqs, n_clients, stop, answers[len(answers) // 2])
+                clients, T.request_stream(traffic, seed, cfg["vocab_size"]),
+                n_clients, stop, answers[len(answers) // 2])
             for ev in first_done:
                 ev.wait()
         else:
@@ -343,6 +337,7 @@ def run(ctx):
     failed = sum(1 for r in records if r["error"] or not r["ok_shape"])
     failed += len(alive)
     steps = _engine_steps(metrics, t_open, t_close)
+    print(json.dumps(_stalls(steps, t_open)), flush=True)
 
     # free the program before the reference runs
     del model, eng, srv, captured, by_key
@@ -373,7 +368,8 @@ def run(ctx):
     return {
         "e2e": e2e, "t_open": t_open, "t_close": t_close,
         "counters_open": snap0, "counters_close": snap1,
-        "records": records, "engine_steps": steps, "loop": loop,
+        "records": records, "engine_steps": [d for _, d in steps],
+        "loop": loop,
         "attempted": len(records), "failed": failed, "checks": checks,
         "memory": mem, "max_slots": sv["max_slots"],
         "program_temp_bytes": temps[temp_of], "program_temp_of": temp_of,
@@ -383,8 +379,8 @@ def run(ctx):
 
 
 def _engine_steps(metrics, t_open, t_close):
-    """Durations (s) of the program's own ``engine.step`` spans that began
-    inside the window, from its span ring."""
+    """(start, seconds) of the program's own ``engine.step`` spans that
+    began inside the window, in order, from its span ring."""
     from paddle_tpu.observability import _EPOCH
     out = []
     with metrics._span_lock:
@@ -393,8 +389,24 @@ def _engine_steps(metrics, t_open, t_close):
         if name == "engine.step":
             t = _EPOCH + ts_us * 1e-6
             if t_open <= t < t_close:
-                out.append(dur_us * 1e-6)
-    return out
+                out.append((t, dur_us * 1e-6))
+    return sorted(out)
+
+
+def _stalls(steps, t_open):
+    """Where a stall in the window would show: the longest step and the
+    longest time between two steps (no work, or the engine's thread held
+    up), each with its offset into the window. A run whose rate reads far
+    off is told from these."""
+    if not steps:
+        return {"note": "steps", "count": 0}
+    t, d = max(steps, key=lambda s: s[1])
+    gaps = [(b[0] - a[0] - a[1], a[0] + a[1])
+            for a, b in zip(steps, steps[1:])] or [(0.0, t_open)]
+    g, tg = max(gaps)
+    return {"note": "steps", "count": len(steps),
+            "longest_ms": 1e3 * d, "longest_at_s": t - t_open,
+            "longest_gap_ms": 1e3 * g, "longest_gap_at_s": tg - t_open}
 
 
 def _sample(finished, seed, k):

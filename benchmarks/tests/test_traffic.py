@@ -2,6 +2,8 @@
 (class, prompt length, answer length), the same hits and misses in every
 block, the same due times; only ids, the prefix drawn and the order inside a
 block differ."""
+import hashlib
+import itertools
 import json
 from collections import Counter
 from pathlib import Path
@@ -81,3 +83,51 @@ def test_stratified_quantiles_keep_range_and_median():
     v = T.stratified(d, 96)
     assert min(v) >= 32 and max(v) <= 384 and v == sorted(v)
     assert abs(np.median(v) - 96) <= 2 and max(v) > 300
+
+
+def digest(reqs):
+    h = hashlib.sha256()
+    for r in reqs:
+        h.update(np.asarray(r["prompt_ids"], np.int32).tobytes())
+        for k in ("answer", "index", "prefix_id"):
+            h.update(int(r[k]).to_bytes(4, "little", signed=True))
+    return h.hexdigest()[:16]
+
+
+# what ``requests()`` gave before it became the first items of a stream
+# without end (the parent of the PR that took the closed loop's list away):
+# 440 was that list at 40 s, 220 the open loop's 45 s of schedule + prefixes
+PARENT = {
+    ("decode-closed-24-v2", 7, 440): "a8f9eedec67180e9",
+    ("decode-closed-24-v2", 3_000_000_019, 440): "0717da1b3363900f",
+    ("prefill-open-sysprompt-v2", 7, 220): "2ad15903d1dadeb2",
+    ("prefill-open-sysprompt-v2", 3_000_000_019, 220): "38139131c705ce98",
+}
+
+
+@pytest.mark.parametrize("name,seed,n", sorted(PARENT))
+def test_the_stream_starts_with_the_requests_the_list_held(name, seed, n):
+    tr = load(name)
+    first = list(itertools.islice(T.request_stream(tr, seed, 50257), n))
+    assert digest(first) == PARENT[name, seed, n]
+    assert [r["index"] for r in first] == list(range(n))
+    # requests(..., n) is the stream's first n
+    assert digest(T.requests(tr, seed, n, 50257)) == PARENT[name, seed, n]
+    assert digest(T.requests(tr, seed, 50, 50257)) == digest(first[:50])
+
+
+@pytest.mark.parametrize("seed", [7, 3_000_000_019])
+@pytest.mark.parametrize("name", SERVE)
+def test_an_unshared_prompt_never_comes_twice(name, seed):
+    """Ten tables' worth of the stream: a repeat would hit the prefix cache
+    in a class that says it shares nothing, and the runner joins what a
+    client saw to the program's marks by the prompt's bytes."""
+    tr = load(name)
+    stream = T.request_stream(tr, seed, 50257)
+    unshared = [r["prompt_ids"].tobytes()
+                for r in itertools.islice(stream, 10 * tr["table_size"])
+                if not r["prefix"]]
+    assert len(unshared) >= 10 * tr["table_size"] // 4
+    assert len(set(unshared)) == len(unshared)
+    # and goes on past any list: the table cycled, indices counting on
+    assert next(stream)["index"] == 10 * tr["table_size"]

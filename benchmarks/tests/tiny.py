@@ -24,7 +24,7 @@ TINY = {
         "traffic": {"batch": 4, "seq": 64}},
     "gpt2m-serve-decode": {
         "config": dict(MODEL, **SERVE),
-        "traffic": {"clients": 4, "table_size": 16, "max_requests_per_s": 200,
+        "traffic": {"clients": 4, "table_size": 16,
                     "classes": [{"name": "unshared", "per_block": 8,
                                  "prompt": _UN(8, 48), "answer": _UN(4, 16)}]}},
     "gpt2m-serve-prefill": {
