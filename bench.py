@@ -576,8 +576,9 @@ def bench_paged_kernel():
     num_pages = 1 + B * maxp
     rng = np.random.RandomState(0)
     q = jnp.asarray(rng.randn(B, nh, dh).astype(np.float32))
-    kp = jnp.asarray(rng.randn(num_pages, ps, nh, dh).astype(np.float32))
-    vp = jnp.asarray(rng.randn(num_pages, ps, nh, dh).astype(np.float32))
+    # the engine's stored layout: a stack of one layer, heads merged
+    kp = jnp.asarray(rng.randn(1, num_pages, ps, nh * dh).astype(np.float32))
+    vp = jnp.asarray(rng.randn(1, num_pages, ps, nh * dh).astype(np.float32))
     pt = jnp.asarray(1 + np.arange(B * maxp, dtype=np.int32)
                      .reshape(B, maxp))
     pos = jnp.asarray(((np.arange(B) % 4) + 1) * 4 * ps - 1, dtype=jnp.int32)
@@ -586,7 +587,7 @@ def bench_paged_kernel():
     impls = ["xla", "pallas"] if _platform() == "tpu" else ["xla"]
     for impl in impls:
         step = jax.jit(lambda q_, k_, v_, _i=impl: pa._impl_call(
-            _i, q_, k_, v_, pt, pos))
+            _i, q_, k_, v_, pt, pos, 0))
         times[impl] = _measure(step, (q, kp, vp))
     return times
 
@@ -608,8 +609,9 @@ def bench_prefill_kernel():
     nh, dh, ps, maxp, c = 12, 64, 16, 16, 64
     num_pages = 1 + maxp
     rng = np.random.RandomState(0)
-    kp = jnp.asarray(rng.randn(num_pages, ps, nh, dh).astype(np.float32))
-    vp = jnp.asarray(rng.randn(num_pages, ps, nh, dh).astype(np.float32))
+    # the engine's stored layout: a stack of one layer, heads merged
+    kp = jnp.asarray(rng.randn(1, num_pages, ps, nh * dh).astype(np.float32))
+    vp = jnp.asarray(rng.randn(1, num_pages, ps, nh * dh).astype(np.float32))
     row = jnp.asarray(1 + np.arange(maxp, dtype=np.int32))
     # ragged mix: the chunk lands after 0, 1, 2, 3 pages of prior context
     # (the prefix-cache / chunked-prefill shapes)
@@ -624,7 +626,7 @@ def bench_prefill_kernel():
         for q, start in zip(qs, starts):
             step = jax.jit(
                 lambda q_, k_, v_, _i=impl, _s=start: pa._prefill_impl_call(
-                    _i, q_, k_, v_, row, jnp.int32(_s), jnp.int32(c)))
+                    _i, q_, k_, v_, row, jnp.int32(_s), jnp.int32(c), 0))
             total += _measure(step, (q, kp, vp))
         times[impl] = total / len(starts)
     return times
@@ -921,17 +923,17 @@ def _int8_kv_prefill_parity(model, cfg, prompt, pps, page_size):
     from paddle_tpu.quantization.serving import margin_gated_parity
 
     params = {k: t._data for k, t in model.state_dict().items()}
-    nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
-    nl = cfg.num_layers
+    nh, nl = cfg.num_heads, cfg.num_layers
     s0 = int(prompt.size)
     need = -(-s0 // page_size)
     npg = 1 + need
     row = jnp.pad(jnp.arange(1, npg, dtype=jnp.int32), (0, pps - need))
     ids = jnp.asarray(np.asarray(prompt, np.int32))
-    zf = jnp.zeros((nl, npg, page_size, nh, dh), jnp.float32)
+    # pools in the engine's stored layout, heads merged
+    zf = jnp.zeros((nl, npg, page_size, cfg.hidden_size), jnp.float32)
     lg_f, _, _ = gpt_mod.prefill_step(params, ids, jnp.int32(s0), row,
                                       zf, zf, cfg=cfg)
-    zq = jnp.zeros((nl, npg, page_size, nh, dh), jnp.int8)
+    zq = jnp.zeros((nl, npg, page_size, cfg.hidden_size), jnp.int8)
     zs = jnp.zeros((nl, npg, page_size, nh), jnp.float32)
     lg_q, _, _, _, _ = gpt_mod.prefill_step(params, ids, jnp.int32(s0),
                                             row, zq, zq, cfg=cfg,

@@ -487,20 +487,29 @@ def phase_kernels(sz, seed, dev):
              lambda: jax.grad(sq(flash_attention_fn(causal=True)),
                               argnums=(0, 1, 2)), (q, k, v), tol=3e-2)
 
-    # paged decode and ragged prefill over a float and an int8 pool
+    # paged decode and ragged prefill over a float and an int8 pool, in
+    # the engine's stored layout [nl, P, ps, h*d], read at the last layer
     ps, maxp, nb = sz.page_size, sz.pages_per_slot, sz.kernel_batch
+    nl = 2
+
+    def int8_pool(pool, h):
+        vals, scales = pa.quantize_kv(pool.reshape(*pool.shape[:3], h, -1))
+        return vals.reshape(pool.shape), scales
+
     for h, d in sz.kernel_widths:
         pool = 1 + nb * maxp
-        kf, vf = rand(pool, ps, h, d), rand(pool, ps, h, d)
-        kq, ks = pa.quantize_kv(kf)
-        vq, vs = pa.quantize_kv(vf)
+        kf, vf = rand(nl, pool, ps, h * d), rand(nl, pool, ps, h * d)
+        kq, ks = int8_pool(kf, h)
+        vq, vs = int8_pool(vf, h)
         table = jnp.asarray(1 + rng.permutation(nb * maxp).astype(np.int32)
                             .reshape(nb, maxp))
         pos = jnp.asarray(rng.randint(0, maxp * ps, nb).astype(np.int32))
         qd = rand(nb, h, d)
         # (the int8 pools' scales are the two trailing positional operands)
-        paged = lambda: lambda *a: pa.paged_attention(*a)      # noqa: E731
-        prefill = lambda: lambda *a: pa.prefill_attention(*a)  # noqa: E731
+        paged = lambda: lambda *a: pa.paged_attention(         # noqa: E731
+            *a, layer=nl - 1)
+        prefill = lambda: lambda *a: pa.prefill_attention(     # noqa: E731
+            *a, layer=nl - 1)
         pair(f"paged h{h}", "tpu_paged_impl", "pallas", paged,
              (qd, kf, vf, table, pos))
         pair(f"paged_int8 h{h}", "tpu_paged_impl", "pallas", paged,

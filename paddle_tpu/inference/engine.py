@@ -870,7 +870,10 @@ class DecodeEngine:
             self._donate = bool(ecfg.donate)
 
         B, maxp = ecfg.max_slots, self.pages_per_slot
-        self._kc = jnp.zeros((self._nl, num_pages, ps, nh, self._dh),
+        # the pool is stored as the attention kernels read it: stacked over
+        # layers, heads merged into the lane axis (kernels/paged_attention
+        # .py) — no program slices, reshapes or relays it
+        self._kc = jnp.zeros((self._nl, num_pages, ps, nh * self._dh),
                              self._cdtype)
         self._vc = jnp.zeros_like(self._kc)
         # int8 pool: per-token-slot per-head f32 scales ride the cache
@@ -1152,6 +1155,17 @@ class DecodeEngine:
 
     def _scale_args(self):
         return [self._ks, self._vs] if self._quant_kv else []
+
+    def _export_pages(self, pages):
+        """The listed pages' contents off the device, as numpy ``(k, v,
+        k_scales, v_scales)``: values ``[nl, n, page_size, nh, dh]`` (the
+        shape every wire format states), scales ``[nl, n, page_size, nh]``
+        or None off an int8 pool. ONE batched gather per pool."""
+        from paddle_tpu.kernels.paged_attention import export_pages
+        out = [np.asarray(b) for b in export_pages(
+            self._kc, self._vc, pages, self._nh,
+            k_scales=self._ks, v_scales=self._vs)]
+        return tuple(out) if self._quant_kv else (*out, None, None)
 
     def _adopt_pools(self, out, n_lead=1):
         """Unpack one step/prefill program's outputs — ``n_lead`` leading
@@ -1536,16 +1550,7 @@ class DecodeEngine:
             if faults.ENABLED and faults.fire("kvtier.spill_fail"):
                 raise faults.FaultInjected(
                     "injected spill failure (kvtier.spill_fail)")
-            from paddle_tpu.kernels.paged_attention import export_pages
-            ksb = vsb = None
-            if self._quant_kv:
-                kb, vb, ksb, vsb = export_pages(
-                    self._kc, self._vc, pages,
-                    k_scales=self._ks, v_scales=self._vs)
-                ksb, vsb = np.asarray(ksb), np.asarray(vsb)
-            else:
-                kb, vb = export_pages(self._kc, self._vc, pages)
-            kb, vb = np.asarray(kb), np.asarray(vb)
+            kb, vb, ksb, vsb = self._export_pages(pages)
             for i, h in enumerate(hashes):
                 self._tiers.put(h, kb[:, i], vb[:, i],
                                 None if ksb is None else ksb[:, i],
@@ -2744,19 +2749,11 @@ class DecodeEngine:
             first = self._run_prefill(
                 ids, row,
                 start=(len(shared) + n_up) * self.ecfg.page_size)
-            from paddle_tpu.kernels.paged_attention import export_pages
-            ks_np = vs_np = None
+            t0 = time.perf_counter()
+            k_np, v_np, ks_np, vs_np = self._export_pages(all_pages)
             if self._quant_kv:
-                t0 = time.perf_counter()
-                k_blob, v_blob, ks_blob, vs_blob = export_pages(
-                    self._kc, self._vc, all_pages,
-                    k_scales=self._ks, v_scales=self._vs)
-                ks_np, vs_np = np.asarray(ks_blob), np.asarray(vs_blob)
                 metrics.histogram("engine.quant_dequant_ms").observe(
                     (time.perf_counter() - t0) * 1e3)
-            else:
-                k_blob, v_blob = export_pages(self._kc, self._vc, all_pages)
-            k_np, v_np = np.asarray(k_blob), np.asarray(v_blob)
             if self._prefix_enabled:
                 # the freshly prefilled pages are cache-eligible: register
                 # BEFORE freeing so the retain hook keeps them resident —
@@ -2845,7 +2842,6 @@ class DecodeEngine:
         before returning (the freshly prefilled ones stay indexed in the
         prefix store, like `prefill_export`). A ``trace_ctx`` rides the
         PTKS1 header. Returns the number of records streamed."""
-        from paddle_tpu.kernels.paged_attention import export_pages
         from paddle_tpu.serving.disagg import (pack_stream_final,
                                                pack_stream_header,
                                                pack_stream_pages)
@@ -2899,15 +2895,7 @@ class DecodeEngine:
         sink.put(("count", n_records))
 
         def _blobs(p0, n):
-            page_ids = all_pages[p0:p0 + n]
-            if self._quant_kv:
-                kb, vb, ksb, vsb = export_pages(
-                    self._kc, self._vc, page_ids,
-                    k_scales=self._ks, v_scales=self._vs)
-                return (np.asarray(kb), np.asarray(vb),
-                        np.asarray(ksb), np.asarray(vsb))
-            kb, vb = export_pages(self._kc, self._vc, page_ids)
-            return np.asarray(kb), np.asarray(vb), None, None
+            return self._export_pages(all_pages[p0:p0 + n])
 
         try:
             seq = 0
@@ -3250,21 +3238,13 @@ class DecodeEngine:
                 # which will now run on the peer)
                 ctx = int(self._lengths[slot])
                 n_src = -(-ctx // self.ecfg.page_size)
-                from paddle_tpu.kernels.paged_attention import export_pages
-                ks_np = vs_np = None
-                if self._quant_kv:
-                    k_b, v_b, ks_b, vs_b = export_pages(
-                        self._kc, self._vc, self._slot_pages[slot][:n_src],
-                        k_scales=self._ks, v_scales=self._vs)
-                    ks_np, vs_np = np.asarray(ks_b), np.asarray(vs_b)
-                else:
-                    k_b, v_b = export_pages(
-                        self._kc, self._vc, self._slot_pages[slot][:n_src])
+                k_np, v_np, ks_np, vs_np = self._export_pages(
+                    self._slot_pages[slot][:n_src])
                 context = np.concatenate(
                     [req.prompt, np.asarray(req.generated[:-1], np.int32)])
                 handoff = KVHandoff(
                     prompt=context, first_token=int(req.generated[-1]),
-                    k_pages=np.asarray(k_b), v_pages=np.asarray(v_b),
+                    k_pages=k_np, v_pages=v_np,
                     page_size=int(self.ecfg.page_size),
                     cache_dtype=np.dtype(self._cdtype).name,
                     k_scales=ks_np, v_scales=vs_np)

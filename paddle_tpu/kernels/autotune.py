@@ -154,11 +154,13 @@ def _pool_key(num_pages):
 
 
 def _synthetic_pools(num_pages, page_size, nh, dh, dtype):
-    """Seeded K and V pools [num_pages, page_size, nh, dh], made on the
+    """Seeded K and V pools in the engine's stored layout, a stack of one
+    layer ``[1, num_pages, page_size, nh * dh]`` (read with ``layer=0``: no
+    arm's cost depends on how many layers the stack holds), made on the
     device (a real pool is GBs: no host round trip)."""
     import jax
     kk, kv = jax.random.split(jax.random.PRNGKey(0))
-    shape = (num_pages, page_size, nh, dh)
+    shape = (1, num_pages, page_size, nh * dh)
     return (jax.random.normal(kk, shape, "float32").astype(dtype),
             jax.random.normal(kv, shape, "float32").astype(dtype))
 
@@ -169,17 +171,18 @@ def paged_winner(b, pages_per_slot, page_size, nh, dh, dtype, run_impl,
     signature — (backend, B, pages_per_slot, page_size, nh, dh, dtype[,
     variant]).
 
-    run_impl(impl, q, k_pages, v_pages, page_table, pos) must execute the
-    named implementation and return [B, nh, dh]. ``dtype`` must be a REAL
+    run_impl(impl, q, k_pages, v_pages, page_table, pos, layer) must execute
+    the named implementation over the stored pool (:func:`_synthetic_pools`)
+    at ``layer`` and return [B, nh, dh]. ``dtype`` must be a REAL
     dtype (the synthetic test arrays are built with it); ``variant`` is a
     free-form key suffix for callers whose execution differs beyond the
     q dtype (e.g. "kv-int8": the dequant changes each candidate's
     arithmetic intensity, so it must not share the float pools' winner).
     ``num_pages`` is the caller's REAL pool size: the synthetic pool is
-    built that large (and the winner keyed by it), because an arm's cost
-    may depend on the pool's capacity and not only on the pages it reads
-    (the pallas arm relayouts the whole pool today). None keeps the
-    smallest pool that holds every slot.
+    built that large (and the winner keyed by it), so that an arm whose
+    cost depends on the pool's capacity and not only on the pages it reads
+    is timed as the step programs run it. None keeps the smallest pool that
+    holds every slot.
     """
     backend = _backend_kind()
     key = ("paged", backend, int(b), int(pages_per_slot), int(page_size),
@@ -208,7 +211,7 @@ def paged_winner(b, pages_per_slot, page_size, nh, dh, dtype, run_impl,
             state["pt"], state["pos"] = pt, pos
         pt, pos = state["pt"], state["pos"]
         step = jax.jit(
-            lambda q_, k_, v_, _i=impl: run_impl(_i, q_, k_, v_, pt, pos))
+            lambda q_, k_, v_, _i=impl: run_impl(_i, q_, k_, v_, pt, pos, 0))
         return _measure(step, state["args"])
 
     return registry.select("paged_attention", key, cands, measure,
@@ -231,9 +234,9 @@ def prefill_winner(chunk, pages_per_slot, page_size, nh, dh, dtype,
     and the winner is cached under a DISTINCT key so a parity-gated
     signature can't adopt an ungated one's pallas win.
 
-    run_impl(impl, q, k_pages, v_pages, row, start, valid) must execute
-    the named implementation on a [1, chunk, nh, dh] query block and
-    return the same shape.
+    run_impl(impl, q, k_pages, v_pages, row, start, valid, layer) must
+    execute the named implementation on a [1, chunk, nh, dh] query block
+    over the stored pool at ``layer`` and return the same shape.
     """
     backend = _backend_kind()
     key = ("prefill", backend, int(chunk), int(pages_per_slot),
@@ -266,7 +269,7 @@ def prefill_winner(chunk, pages_per_slot, page_size, nh, dh, dtype,
         valid = jnp.int32(chunk)
         step = jax.jit(
             lambda q_, k_, v_, _i=impl: run_impl(_i, q_, k_, v_, row,
-                                                 start, valid))
+                                                 start, valid, 0))
         return _measure(step, state["args"])
 
     return registry.select("prefill_attention", key, cands, measure,

@@ -4,14 +4,22 @@ Dense decode caches ([B, L, nh, dh] per layer, one slab per sequence) waste
 HBM on short sequences and force one compiled program per (B, L) shape. The
 paged layout stores tokens in fixed-size PAGES:
 
-    k_pages, v_pages : [num_layers, num_pages, page_size, num_heads, head_dim]
+    k_pages, v_pages : [num_layers, num_pages, page_size, num_heads * head_dim]
 
-and each sequence owns an ordered list of page indices (the host-side page
-table, padded to ``pages_per_slot``). Token position ``t`` of a sequence
-lives at ``(page_table[t // page_size], t % page_size)``. Pages are
-allocated/freed by the engine's host-side allocator as sequences join and
-retire, so B live sequences of wildly different lengths share one fixed-shape
-pool — the decode program never changes shape and never recompiles.
+— stacked over layers, heads MERGED into the lane axis: the layout the
+attention kernels read (768 / 1024 / 1280 lanes for the GPT-2 sizes; a
+head_dim of 64 alone is half a TPU lane tile, and a pool stored
+``[..., nh, dh]`` is relaid whole by every program that touches it).
+Writers scatter lane-dense rows (``pool.at[layer, page, off].set(k.reshape(
+..., nh * dh))``); readers take the stacked pool and a ``layer`` index and
+never slice a layer out (on the chip ``pool[i]`` as a kernel operand is a
+copy of the layer's pool). Each sequence owns an ordered list of page
+indices (the host-side page table, padded to ``pages_per_slot``). Token
+position ``t`` of a sequence lives at ``(page_table[t // page_size], t %
+page_size)``. Pages are allocated/freed by the engine's host-side allocator
+as sequences join and retire, so B live sequences of wildly different
+lengths share one fixed-shape pool — the decode program never changes shape
+and never recompiles.
 
 `paged_attention` is a DISPATCH SWITCH over two implementations with one
 contract (token-identical output, enforced by parity tests):
@@ -24,8 +32,16 @@ contract (token-identical output, enforced by parity tests):
   (`kernels/pallas/paged_attention.py`): grid over sequences,
   double-buffered whole-page DMA, page loop bounded by
   ``ceil((pos+1)/page_size)`` so page traffic scales with each sequence's
-  true length (its pool view costs a relayout per call today — see the
-  kernel's "Layout" note).
+  true length, each DMA reading ``pool[layer, page]`` of the stored pool
+  (the kernel's "Layout" note).
+
+Both ops take the stored pool with ``layer=``. Without the keyword they
+accept ONE layer's pool, ``[num_pages, page_size, nh, dh]`` or merged rank
+3 — the form of callers that own no stack (the benchmark's selection
+probe, tests). Rank cannot tell the two forms apart (both are rank 4), so
+the keyword decides. A per-layer call pays a copy of that pool on the chip
+and is counted at trace time in ``kernel.pool_relayout.{op}``; the engine's
+programs count none (docs/OBSERVABILITY.md).
 
 ``FLAGS_tpu_paged_impl`` picks: ``auto`` (measured winner per signature on
 real TPU via the kernel registry + `kernels/autotune.py`, xla elsewhere —
@@ -57,7 +73,8 @@ TRASH_PAGE = 0
 # written by the same scatters that write the pages (docs/QUANTIZATION.md).
 KV_DTYPES = {"f32": jnp.float32, "bf16": jnp.bfloat16, "int8": jnp.int8}
 
-__all__ = ["TRASH_PAGE", "KV_DTYPES", "gather_kv", "quantize_kv",
+__all__ = ["TRASH_PAGE", "KV_DTYPES", "stored_pools", "kv_rows", "gather_kv",
+           "quantize_kv",
            "dequantize_window", "gather_scales", "paged_attention",
            "prefill_attention", "prefill_impl", "token_page_coords",
            "prompt_page_coords", "chunk_page_coords", "verify_page_coords",
@@ -88,39 +105,79 @@ def dequantize_window(win, scales):
     return win.astype(jnp.float32) * scales[..., None]
 
 
-def gather_kv(pages, page_table):
-    """Materialize one layer's paged K (or V) into per-sequence windows.
+def stored_pools(op, k_pages, v_pages, k_scale=None, v_scale=None,
+                 layer=None):
+    """One attention call's pools in the STORED layout — stacked merged
+    ``[nl, num_pages, page_size, nh * dh]`` values, ``[nl, num_pages,
+    page_size, nh]`` scales — and the layer to read:
+    ``(k_pages, v_pages, k_scale, v_scale, layer)``.
 
-    pages      : [num_pages, page_size, nh, dh]
+    With ``layer`` the operands already are that and pass through. Without
+    it they are ONE layer's pools (``[num_pages, page_size, nh, dh]``, or
+    merged rank 3) and are viewed as a stack of one. On a TPU that view is
+    a copy of the whole pool (and a caller that sliced the layer out of a
+    stack has paid another), so each such call counts in the trace-time
+    ``kernel.pool_relayout.{op}``: a program that still relays the pool
+    shows without a chip."""
+    if layer is not None:
+        if isinstance(layer, int) and not 0 <= layer < k_pages.shape[0]:
+            # on the chip a layer past the stack is a wild DMA, which
+            # halts the core: refuse it while tracing
+            raise IndexError(f"layer {layer} of a pool of "
+                             f"{k_pages.shape[0]} layers")
+        return k_pages, v_pages, k_scale, v_scale, layer
+    from paddle_tpu.kernels import registry
+    registry.count_relayout(op)
+
+    def stack(pool):               # [P, ps, ...] -> [1, P, ps, merged]
+        return None if pool is None else pool.reshape(1, *pool.shape[:2], -1)
+    return (stack(k_pages), stack(v_pages), stack(k_scale), stack(v_scale),
+            0)
+
+
+def kv_rows(x, pool):
+    """``[..., nh, dh]`` K or V values as the pool's lane-dense merged rows
+    ``[..., nh * dh]`` in its dtype — what every writer scatters."""
+    return x.reshape(*x.shape[:-2], x.shape[-2] * x.shape[-1]) \
+        .astype(pool.dtype)
+
+
+def gather_kv(pages, page_table, layer, nh):
+    """Materialize one layer's paged K (or V) into per-sequence windows, in
+    ONE gather over the stored pool (the layer is an index of the gather,
+    never a slice taken first).
+
+    pages      : [nl, num_pages, page_size, nh * dh]
     page_table : [B, pages_per_slot] int32 page indices
     returns    : [B, pages_per_slot * page_size, nh, dh]
     """
-    _, ps, nh, dh = pages.shape
+    ps = pages.shape[2]
     b, maxp = page_table.shape
-    return pages[page_table].reshape(b, maxp * ps, nh, dh)
+    return pages[layer, page_table].reshape(b, maxp * ps, nh, -1)
 
 
-def gather_scales(scales, page_table):
-    """[num_pages, page_size, nh] scales -> [B, Lmax, nh] per-slot windows
-    (the scale-side twin of :func:`gather_kv`)."""
-    _, ps, nh = scales.shape
+def gather_scales(scales, page_table, layer):
+    """[nl, num_pages, page_size, nh] scales -> [B, Lmax, nh] per-slot
+    windows of one layer (the scale-side twin of :func:`gather_kv`)."""
+    ps, nh = scales.shape[2:]
     b, maxp = page_table.shape
-    return scales[page_table].reshape(b, maxp * ps, nh)
+    return scales[layer, page_table].reshape(b, maxp * ps, nh)
 
 
-def _xla_paged_attention(q, k_pages, v_pages, page_table, pos,
+def _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
                          k_scale=None, v_scale=None):
-    """The gather + masked f32-softmax reference implementation. With
-    ``k_scale``/``v_scale`` ([num_pages, page_size, nh] f32) the pages are
-    int8 and dequantize in-register right after the gather — the same f32
-    score/softmax math runs on the dequantized values."""
-    dh = q.shape[-1]
+    """The gather + masked f32-softmax reference implementation over the
+    stored pool at ``layer``. With ``k_scale``/``v_scale`` ([nl, num_pages,
+    page_size, nh] f32) the pages are int8 and dequantize in-register right
+    after the gather — the same f32 score/softmax math runs on the
+    dequantized values."""
+    nh, dh = q.shape[-2:]
     scale = 1.0 / (dh ** 0.5)
-    k = gather_kv(k_pages, page_table).astype(jnp.float32)  # [B, Lmax, nh, dh]
-    v = gather_kv(v_pages, page_table).astype(jnp.float32)
-    if k_scale is not None:
-        k = k * gather_scales(k_scale, page_table)[..., None]
-        v = v * gather_scales(v_scale, page_table)[..., None]
+    k = gather_kv(k_pages, page_table, layer, nh).astype(jnp.float32)
+    v = gather_kv(v_pages, page_table, layer, nh).astype(jnp.float32)
+    if k_scale is not None:                             # [B, Lmax, nh, dh]
+        k = k * gather_scales(k_scale, page_table, layer)[..., None]
+        v = v * gather_scales(v_scale, page_table, layer)[..., None]
     lmax = k.shape[1]
     sc = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32) * scale, k)
     mask = jnp.arange(lmax)[None, :] <= pos[:, None]         # [B, Lmax]
@@ -130,28 +187,34 @@ def _xla_paged_attention(q, k_pages, v_pages, page_table, pos,
     return att.astype(q.dtype)
 
 
-def _impl_call(impl, q, k_pages, v_pages, page_table, pos,
+def _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
                k_scale=None, v_scale=None):
-    """Execute one named implementation (also the autotuner's run_impl)."""
+    """Execute one named implementation over the stored pool at ``layer``
+    (also the autotuner's run_impl)."""
     if impl == "pallas":
         from paddle_tpu.kernels.pallas.paged_attention import (
             paged_attention as pallas_paged)
         return pallas_paged(q, k_pages, v_pages, page_table, pos,
-                            k_scale=k_scale, v_scale=v_scale)
-    return _xla_paged_attention(q, k_pages, v_pages, page_table, pos,
+                            layer=layer, k_scale=k_scale, v_scale=v_scale)
+    return _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
                                 k_scale=k_scale, v_scale=v_scale)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos,
-                    k_scale=None, v_scale=None):
+                    k_scale=None, v_scale=None, *, layer=None):
     """One decode step of attention over paged K/V for B sequences.
 
     q          : [B, nh, dh] query for the CURRENT token of each sequence
-    k_pages    : [num_pages, page_size, nh, dh] (one layer)
-    v_pages    : [num_pages, page_size, nh, dh]
+    k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
+                 read at ``layer``. Without ``layer``: one layer's
+                 [num_pages, page_size, nh, dh] (or merged rank 3), which
+                 costs a copy of it on the chip (:func:`stored_pools`)
+    v_pages    : as k_pages
     page_table : [B, pages_per_slot] int32
     pos        : [B] int32 — position of the current token (already written
                  to the cache); attends over positions 0..pos inclusive
+    k_scale/v_scale : optional f32 scales of an int8 pool, [nl, num_pages,
+                 page_size, nh] (per-layer form: without the leading nl)
     returns    : [B, nh, dh] in q.dtype
 
     Same numerics as the dense path (f32 scores, -1e30 mask, f32 softmax):
@@ -162,6 +225,8 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
     layer per trace), not steps.
     """
     from paddle_tpu.kernels import registry
+    k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
+        "paged_attention", k_pages, v_pages, k_scale, v_scale, layer)
     try:
         from paddle_tpu.framework.flags import flag_value
         forced = flag_value("tpu_paged_impl")
@@ -180,24 +245,24 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
             # REAL dtype (paged_winner builds arrays with it)
             variant = "kv-int8"
 
-            def run(impl_, q_, kp_, vp_, pt_, pos_):
-                ones = jnp.ones(kp_.shape[:3], jnp.float32)
+            def run(impl_, q_, kp_, vp_, pt_, pos_, layer_):
+                ones = jnp.ones(kp_.shape[:3] + (q.shape[1],), jnp.float32)
                 return _impl_call(impl_, q_, kp_.astype(jnp.int8),
-                                  vp_.astype(jnp.int8), pt_, pos_,
+                                  vp_.astype(jnp.int8), pt_, pos_, layer_,
                                   k_scale=ones, v_scale=ones)
         return paged_winner(q.shape[0], page_table.shape[1],
-                            k_pages.shape[1], q.shape[1], q.shape[2],
+                            k_pages.shape[2], q.shape[1], q.shape[2],
                             q.dtype, run, variant=variant,
-                            num_pages=k_pages.shape[0])
+                            num_pages=k_pages.shape[1])
 
     impl = registry.dispatch("paged_attention", forced=forced,
                              winner=winner)
-    return _impl_call(impl, q, k_pages, v_pages, page_table, pos,
+    return _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
                       k_scale=k_scale, v_scale=v_scale)
 
 
 def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
-                           k_scale=None, v_scale=None):
+                           layer, k_scale=None, v_scale=None):
     """The gather + absolute-position-masked f32-softmax PREFILL reference
     — exactly the math `models/gpt.py::prefill_chunk_step` always ran: the
     chunk's queries attend over ALL cached positions (previous chunks AND
@@ -210,14 +275,14 @@ def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
     ``valid`` only matters to the Pallas arm's row masking — padded rows
     here compute like the real ones (their output is never consumed).
     """
-    dh = q.shape[-1]
-    c = q.shape[1]
+    _, c, nh, dh = q.shape
     scale = 1.0 / (dh ** 0.5)
-    kk = gather_kv(k_pages, page_table[None]).astype(jnp.float32)
-    vv = gather_kv(v_pages, page_table[None]).astype(jnp.float32)
+    row = page_table[None]
+    kk = gather_kv(k_pages, row, layer, nh).astype(jnp.float32)
+    vv = gather_kv(v_pages, row, layer, nh).astype(jnp.float32)
     if k_scale is not None:
-        kk = kk * gather_scales(k_scale, page_table[None])[..., None]
-        vv = vv * gather_scales(v_scale, page_table[None])[..., None]
+        kk = kk * gather_scales(k_scale, row, layer)[..., None]
+        vv = vv * gather_scales(v_scale, row, layer)[..., None]
     lmax = kk.shape[1]
     pos = start + jnp.arange(c)
     sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kk)
@@ -228,15 +293,18 @@ def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
 
 
 def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,
-                       k_scale=None, v_scale=None):
-    """Execute one named prefill impl (also the autotuner's run_impl)."""
+                       layer, k_scale=None, v_scale=None):
+    """Execute one named prefill impl over the stored pool at ``layer``
+    (also the autotuner's run_impl)."""
     if impl == "pallas":
         from paddle_tpu.kernels.pallas.prefill_attention import (
             prefill_attention as pallas_prefill)
         return pallas_prefill(q[0], k_pages, v_pages, page_table, start,
-                              valid, k_scale=k_scale, v_scale=v_scale)[None]
+                              valid, layer=layer, k_scale=k_scale,
+                              v_scale=v_scale)[None]
     return _xla_prefill_attention(q, k_pages, v_pages, page_table, start,
-                                  valid, k_scale=k_scale, v_scale=v_scale)
+                                  valid, layer, k_scale=k_scale,
+                                  v_scale=v_scale)
 
 
 def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
@@ -263,11 +331,12 @@ def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
         if quant:
             variant = "kv-int8"
 
-            def run(impl_, q_, kp_, vp_, row_, start_, valid_):
-                ones = jnp.ones(kp_.shape[:3], jnp.float32)
+            def run(impl_, q_, kp_, vp_, row_, start_, valid_, layer_):
+                ones = jnp.ones(kp_.shape[:3] + (nh,), jnp.float32)
                 return _prefill_impl_call(
                     impl_, q_, kp_.astype(jnp.int8), vp_.astype(jnp.int8),
-                    row_, start_, valid_, k_scale=ones, v_scale=ones)
+                    row_, start_, valid_, layer_, k_scale=ones,
+                    v_scale=ones)
         return prefill_winner(chunk, pages_per_slot, page_size, nh, dh,
                               dtype, run, variant=variant, parity=parity,
                               num_pages=num_pages)
@@ -277,7 +346,7 @@ def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
 
 
 def prefill_attention(q, k_pages, v_pages, page_table, start, valid,
-                      k_scale=None, v_scale=None):
+                      k_scale=None, v_scale=None, *, layer=None):
     """One CHUNK of ragged prefill attention for ONE sequence, over pages
     the chunk's K/V were just written to — the dispatch switch the
     registry routes (`prefill_step` / `prefill_chunk_step` / the PTKS1
@@ -285,19 +354,23 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid,
 
     q          : [1, C, nh, dh] chunk queries (leading batch of 1 — the
                  step programs' native layout)
-    k_pages    : [num_pages, page_size, nh, dh] (one layer)
+    k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
+                 read at ``layer`` (without ``layer``: one layer's pool,
+                 as :func:`paged_attention`)
     page_table : [pages_per_slot] int32 — this sequence's page row
     start      : scalar int32 absolute position of the chunk's first token
     valid      : scalar int32 true token count in this chunk
     returns    : [1, C, nh, dh] in q.dtype — token-identical between arms
                  (rows < valid; parity-tested in interpret mode off-TPU)
     """
-    impl = prefill_impl(q.shape[1], page_table.shape[0], k_pages.shape[1],
+    k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
+        "prefill_attention", k_pages, v_pages, k_scale, v_scale, layer)
+    impl = prefill_impl(q.shape[1], page_table.shape[0], k_pages.shape[2],
                         q.shape[2], q.shape[3], q.dtype,
                         quant=k_scale is not None,
-                        num_pages=k_pages.shape[0])
+                        num_pages=k_pages.shape[1])
     return _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start,
-                              valid, k_scale=k_scale, v_scale=v_scale)
+                              valid, layer, k_scale=k_scale, v_scale=v_scale)
 
 
 def token_page_coords(page_table, pos, active, page_size):
@@ -373,7 +446,8 @@ def verify_page_coords(page_table, pos, valid, page_size):
     return page, pos % page_size
 
 
-def export_pages(k_pages, v_pages, page_list, k_scales=None, v_scales=None):
+def export_pages(k_pages, v_pages, page_list, num_heads, k_scales=None,
+                 v_scales=None):
     """Gather the listed pages' contents out of the pool — the send side of
     the page-granular KV handoff (a prefill finished on one replica resumes
     decode on another; docs/SERVING.md). The page table makes the transfer a
@@ -391,9 +465,14 @@ def export_pages(k_pages, v_pages, page_list, k_scales=None, v_scales=None):
     pages and scales are immutable once full, so the round trip is
     bit-identical.
 
-    k_pages/v_pages : [num_layers, num_pages, page_size, nh, dh]
+    k_pages/v_pages : [num_layers, num_pages, page_size, nh * dh]
     page_list       : [n] int page indices (a sequence's allocation,
                       in token order)
+    num_heads       : nh — a blob leaves the pool as ``[..., nh, dh]``, the
+                      shape every wire format states (``PTKV1``'s
+                      ``pages_shape``, ``PTKT1`` frames); ``[nh, dh]`` and
+                      ``nh * dh`` are the same row-major bytes, so the
+                      blobs are byte-for-byte what an unmerged pool gave
     k_scales/v_scales : optional [num_layers, num_pages, page_size, nh] f32
                       (int8 pools); the listed pages' scales travel with
                       their values so the handoff stays bit-exact
@@ -401,10 +480,14 @@ def export_pages(k_pages, v_pages, page_list, k_scales=None, v_scales=None):
                       — plus (k_s_blob, v_s_blob) when scales were given
     """
     idx = jnp.asarray(page_list, jnp.int32)
+
+    def blob(pool):
+        got = pool[:, idx]             # (n may be 0: no -1 in the shape)
+        return got.reshape(*got.shape[:3], num_heads,
+                           got.shape[3] // num_heads)
     if k_scales is None:
-        return k_pages[:, idx], v_pages[:, idx]
-    return (k_pages[:, idx], v_pages[:, idx],
-            k_scales[:, idx], v_scales[:, idx])
+        return blob(k_pages), blob(v_pages)
+    return (blob(k_pages), blob(v_pages), k_scales[:, idx], v_scales[:, idx])
 
 
 def import_pages(k_pages, v_pages, k_blob, v_blob, page_list,
@@ -415,14 +498,15 @@ def import_pages(k_pages, v_pages, k_blob, v_blob, page_list,
     land bit-identical, so decode on the importing replica matches decode
     where the prefill ran.
 
+    k_pages/v_pages : [num_layers, num_pages, page_size, nh * dh]
     k_blob/v_blob : [num_layers, n, page_size, nh, dh] from `export_pages`
     page_list     : [n] destination page indices in THIS pool
     returns       : (k_pages, v_pages) updated — plus (k_scales, v_scales)
                     when the scale pools/blobs were given
     """
     idx = jnp.asarray(page_list, jnp.int32)
-    kp = k_pages.at[:, idx].set(k_blob.astype(k_pages.dtype))
-    vp = v_pages.at[:, idx].set(v_blob.astype(v_pages.dtype))
+    kp = k_pages.at[:, idx].set(kv_rows(k_blob, k_pages))
+    vp = v_pages.at[:, idx].set(kv_rows(v_blob, v_pages))
     if k_scales is None:
         return kp, vp
     return (kp, vp,
@@ -430,20 +514,22 @@ def import_pages(k_pages, v_pages, k_blob, v_blob, page_list,
             v_scales.at[:, idx].set(jnp.asarray(v_s_blob, v_scales.dtype)))
 
 
-def write_token_kv(k_pages, v_pages, k, v, page_table, pos, active):
-    """Scatter one new K/V token per sequence into its page.
+def write_token_kv(k_pages, v_pages, k, v, page_table, pos, active, layer):
+    """Scatter one new K/V token per sequence into its page of ``layer``.
 
+    k_pages/v_pages : [nl, num_pages, page_size, nh * dh]
     k, v       : [B, nh, dh] — the current token's key/value (one layer)
     page_table : [B, pages_per_slot] int32
     pos        : [B] int32 token position being written
     active     : [B] bool — inactive slots write to TRASH_PAGE
     returns    : (k_pages, v_pages) updated
     """
-    page, off = token_page_coords(page_table, pos, active, k_pages.shape[1])
-    return k_pages.at[page, off].set(k), v_pages.at[page, off].set(v)
+    page, off = token_page_coords(page_table, pos, active, k_pages.shape[2])
+    return (k_pages.at[layer, page, off].set(kv_rows(k, k_pages)),
+            v_pages.at[layer, page, off].set(kv_rows(v, v_pages)))
 
 
-def write_prompt_kv(k_pages, v_pages, k, v, page_table, length):
+def write_prompt_kv(k_pages, v_pages, k, v, page_table, length, layer):
     """Scatter a whole prompt's K/V (one sequence, one layer) into its pages.
 
     k, v       : [S, nh, dh] — S is the PADDED bucket length; positions
@@ -453,5 +539,6 @@ def write_prompt_kv(k_pages, v_pages, k, v, page_table, length):
     returns    : (k_pages, v_pages) updated
     """
     page, off = prompt_page_coords(page_table, length, k.shape[0],
-                                   k_pages.shape[1])
-    return k_pages.at[page, off].set(k), v_pages.at[page, off].set(v)
+                                   k_pages.shape[2])
+    return (k_pages.at[layer, page, off].set(kv_rows(k, k_pages)),
+            v_pages.at[layer, page, off].set(kv_rows(v, v_pages)))

@@ -21,13 +21,19 @@ shaped for:
 
 **Layout.** The chip's compiler only slices a page out of a pool whose last
 two dims are tile-aligned, and ``(nh, dh) = (12, 64)`` or ``(16, 64)`` is
-not (dh < 128 lanes). The kernel therefore works on the pool VIEWED as
-``[num_pages, page_size, nh * dh]`` and never splits the lane axis: per-head
-sums and broadcasts are small matmuls against a 0/1 head-segment matrix.
-The engine still stores ``[..., nh, dh]``, so on a TPU the view is a
-relayout copy of the whole layer pool per call — capacity-proportional
-traffic the kernel exists to avoid. Storing the pool merged is the fix and
-is not made here (PERF.md, "Chip bring-up").
+not (dh < 128 lanes). The engine therefore STORES the pool merged and
+stacked over layers, ``[num_layers, num_pages, page_size, nh * dh]`` (768,
+1024 or 1280 lanes; a bf16 page of 16 rows is whole ``(16, 128)`` tiles),
+and this kernel takes that array as it is: it stays in HBM, the layer index
+arrives with the scalar-prefetched operands (one kernel body for every
+layer of a program), and each DMA reads ``pool[layer, page]``. Nothing of
+the pool is sliced, reshaped or copied on the way in. The lane axis is never
+split: per-head sums and broadcasts are small matmuls against a 0/1
+head-segment matrix. A caller that holds ONE layer's pool
+(``[num_pages, page_size, nh, dh]``, or merged rank 3) may leave ``layer``
+out: the pool is then viewed as a stack of one, which on a TPU is a copy of
+that whole pool per call (`kernels.paged_attention.stored_pools` counts
+such calls); no step program of the engine does that.
 
 Numerics match the reference: f32 scores, f32 online softmax, masked tail
 positions excluded — parity with the XLA path is enforced by
@@ -75,11 +81,13 @@ def exact_dot(a, b):
                    precision=jax.lax.Precision.HIGHEST)
 
 
-def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
+def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                    page_size, nh, scale, quant=False, has_visits=False):
     # one grid cell per sequence b, all heads at once: q_ref [1, 1, nh*dh]
-    # in VMEM, k_hbm/v_hbm the [num_pages, page_size, nh*dh] pool views in
-    # HBM, pos/page_table scalar-prefetched into SMEM. Operand order is
+    # in VMEM, k_hbm/v_hbm the stacked [nl, num_pages, page_size, nh*dh]
+    # pools in HBM, pos/page_table/layer scalar-prefetched into SMEM (the
+    # layer is an operand, not a constant: every layer of a program runs
+    # this one kernel). Operand order is
     # inputs (q, k, v[, k_scale, v_scale]), outputs (o[, visits]), scratch
     # (kbuf, vbuf, sem); ``quant`` and ``has_visits`` are static flags,
     # never inferred from argument counts. Under ``quant`` the pools are
@@ -96,6 +104,7 @@ def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
     kbuf, vbuf, sem = rest
     b = pl.program_id(0)
     pos = pos_ref[b]
+    lyr = layer_ref[0]
     # never walk past the page-table row: an out-of-range page index is a
     # wild DMA, which halts the chip (the XLA arm clamps the same way)
     npages = jnp.minimum(pages_needed(pos, page_size), pt_ref.shape[1])
@@ -107,9 +116,9 @@ def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
         # page j of sequence b: the whole page from HBM into the double
         # buffer
         pg = pt_ref[b, j]
-        return [pltpu.make_async_copy(k_hbm.at[pg], kbuf.at[slot],
+        return [pltpu.make_async_copy(k_hbm.at[lyr, pg], kbuf.at[slot],
                                       sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[pg], vbuf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[lyr, pg], vbuf.at[slot],
                                       sem.at[1, slot])]
 
     for c in dma(0, 0):
@@ -156,26 +165,31 @@ def _decode_kernel(pos_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
     o_ref[0] = (acc / exact_dot(jnp.maximum(l, 1e-30), segt)).astype(o_ref.dtype)
 
 
-def scale_window(scales, page_table):
+def scale_window(scales, page_table, layer):
     """[..., pages_per_slot] page rows -> the rows' ``[..., pages_per_slot *
-    page_size, nh]`` f32 scale window of an int8 pool."""
-    win = scales.astype(jnp.float32)[page_table]
+    page_size, nh]`` f32 scale window of one layer of an int8 pool's
+    stacked ``[nl, num_pages, page_size, nh]`` scales (one gather)."""
+    win = scales[layer, page_table].astype(jnp.float32)
     return win.reshape(*page_table.shape[:-1], -1, scales.shape[-1])
 
 
-def paged_attention(q, k_pages, v_pages, page_table, pos, *, interpret=None,
-                    return_visits=False, k_scale=None, v_scale=None):
+def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
+                    interpret=None, return_visits=False, k_scale=None,
+                    v_scale=None):
     """One decode step of ragged paged attention. Same contract as the XLA
     reference `kernels.paged_attention.paged_attention`:
 
     q          : [B, nh, dh] current-token query
-    k_pages    : [num_pages, page_size, nh, dh] (one layer)
-    v_pages    : [num_pages, page_size, nh, dh]
+    k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
+                 read at ``layer`` (without ``layer``: one layer's pool,
+                 see "Layout")
+    v_pages    : as k_pages
     page_table : [B, pages_per_slot] int32
     pos        : [B] int32 — attends positions 0..pos inclusive
-    k_scale/v_scale : optional [num_pages, page_size, nh] f32 — int8 pools:
-                 the dequant runs in-register after each page copy, so the
-                 kernel's page traffic is the int8 bytes (~1/4 of f32)
+    k_scale/v_scale : optional [nl, num_pages, page_size, nh] f32 — int8
+                 pools: the dequant runs in-register after each page copy,
+                 so the kernel's page traffic is the int8 bytes (~1/4 of
+                 f32)
     returns    : [B, nh, dh] in q.dtype; with ``return_visits=True`` also
                  the page-loop trip counts [B, nh] int32 (one walk serves
                  every head of a sequence, so a row repeats one count) —
@@ -187,9 +201,12 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, interpret=None,
     if interpret is None:
         from paddle_tpu.kernels.pallas._compat import default_interpret
         interpret = default_interpret()
+    from paddle_tpu.kernels.paged_attention import stored_pools
+    k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
+        "paged_attention", k_pages, v_pages, k_scale, v_scale, layer)
     quant = k_scale is not None
     b, nh, dh = q.shape
-    num_pages, ps = k_pages.shape[:2]
+    ps = k_pages.shape[2]
     hd = nh * dh
     scale = 1.0 / (dh ** 0.5)
     kern = functools.partial(_decode_kernel, page_size=ps, nh=nh,
@@ -206,16 +223,15 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, interpret=None,
         pl.BlockSpec(memory_space=pl.ANY),            # K pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),            # V pool stays in HBM
     ]
-    operands = [q.reshape(b, 1, hd), k_pages.reshape(num_pages, ps, hd),
-                v_pages.reshape(num_pages, ps, hd)]
+    operands = [q.reshape(b, 1, hd), k_pages, v_pages]
     if quant:
         win = pl.BlockSpec((1, page_table.shape[1] * ps, nh),
                            lambda i, *_: (i, 0, 0))
         in_specs += [win, win]
-        operands += [scale_window(k_scale, page_table),
-                     scale_window(v_scale, page_table)]
+        operands += [scale_window(k_scale, page_table, layer),
+                     scale_window(v_scale, page_table, layer)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
@@ -231,7 +247,8 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, interpret=None,
             grid_spec=grid_spec,
             out_shape=out_shape,
             interpret=bool(interpret),
-        )(pos.astype(jnp.int32), page_table.astype(jnp.int32), *operands)
+        )(pos.astype(jnp.int32), page_table.astype(jnp.int32),
+          jnp.asarray(layer, jnp.int32).reshape(1), *operands)
     out = outs[0].reshape(b, nh, dh)
     if return_visits:
         return out, jnp.broadcast_to(outs[1][:, 0, :1], (b, nh))

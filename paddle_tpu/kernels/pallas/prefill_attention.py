@@ -27,9 +27,11 @@ drop-in the registry routes to:
   (``memory_space=ANY``); each cell streams one whole ``[page_size, nh *
   dh]`` page at a time into a two-slot VMEM scratch, next page's DMA in
   flight while the current page is on the MXU, folding into an f32 online
-  softmax per head — the same rhythm and the same merged-lane pool view as
-  the decode kernel (see its "Layout" note: on a TPU the view costs a
-  relayout of the layer pool per call until the engine stores it merged);
+  softmax per head — the same rhythm and the same operand as the decode
+  kernel (see its "Layout" note): the pool as the engine stores it,
+  stacked and merged ``[nl, num_pages, page_size, nh * dh]``, read at
+  ``pool[layer, page]`` with the layer index among the scalar-prefetched
+  operands, so nothing of the pool is sliced or copied on the way in;
 - **int8 pools** — under ``k_scale``/``v_scale`` the pages are int8 and
   the sequence's f32 scale window rides a VMEM operand; the dequant is
   in-register after the copy lands, so page traffic is the int8 bytes.
@@ -75,13 +77,14 @@ def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
                     page_size, block_q, nh, scale, quant=False,
                     has_visits=False):
     # one grid cell per q block i, all heads: q_ref [block_q, nh*dh] in
-    # VMEM, k_hbm/v_hbm the [num_pages, page_size, nh*dh] pool views in
-    # HBM, meta (start, valid) + the page-table row scalar-prefetched into
-    # SMEM. Operand order mirrors the decode kernel: inputs (q, k, v[,
-    # k_scale, v_scale windows [maxp*ps, nh]]), outputs (o[, visits]),
-    # scratch (kbuf, vbuf, sem, m, l, acc). The running max and denominator
-    # live lane-broadcast over each head's dh lanes ([block_q, nh*dh]), so
-    # every per-head update is a same-shape slice of the three scratches.
+    # VMEM, k_hbm/v_hbm the stacked [nl, num_pages, page_size, nh*dh]
+    # pools in HBM, meta (start, valid, layer) + the page-table row
+    # scalar-prefetched into SMEM. Operand order mirrors the decode
+    # kernel: inputs (q, k, v[, k_scale, v_scale windows [maxp*ps, nh]]),
+    # outputs (o[, visits]), scratch (kbuf, vbuf, sem, m, l, acc). The
+    # running max and denominator live lane-broadcast over each head's dh
+    # lanes ([block_q, nh*dh]), so every per-head update is a same-shape
+    # slice of the three scratches.
     if quant:
         ks_ref, vs_ref, *rest = rest
     o_ref, *rest = rest
@@ -91,6 +94,7 @@ def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
     i = pl.program_id(0)
     start = meta_ref[0]
     valid = meta_ref[1]
+    lyr = meta_ref[2]
     row0 = i * block_q
     nrows = jnp.clip(valid - row0, 0, block_q)     # active rows this block
     # never walk past the page-table row: an out-of-range page index is a
@@ -104,9 +108,9 @@ def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def dma(slot, j):
         pg = pt_ref[j]                 # page j of this sequence, whole
-        return [pltpu.make_async_copy(k_hbm.at[pg], kbuf.at[slot],
+        return [pltpu.make_async_copy(k_hbm.at[lyr, pg], kbuf.at[slot],
                                       sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[pg], vbuf.at[slot],
+                pltpu.make_async_copy(v_hbm.at[lyr, pg], vbuf.at[slot],
                                       sem.at[1, slot])]
 
     @pl.when(npages > 0)
@@ -171,19 +175,22 @@ def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
 
 
 def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
-                      interpret=None, return_visits=False, block_q=None,
-                      k_scale=None, v_scale=None):
+                      layer=None, interpret=None, return_visits=False,
+                      block_q=None, k_scale=None, v_scale=None):
     """One CHUNK of ragged prefill attention for ONE sequence over paged
     K/V (the chunk's own K/V already written to its pages):
 
     q          : [C, nh, dh] — the chunk's queries (rows >= valid are
                  bucket padding; their output is zeroed)
-    k_pages    : [num_pages, page_size, nh, dh] (one layer)
-    v_pages    : [num_pages, page_size, nh, dh]
+    k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
+                 read at ``layer`` (without ``layer``: one layer's pool,
+                 the decode kernel's "Layout" note)
+    v_pages    : as k_pages
     page_table : [pages_per_slot] int32 — THIS sequence's page row
     start      : scalar int32 — absolute position of q[0]
     valid      : scalar int32 — true token count in this chunk
-    k_scale/v_scale : optional [num_pages, page_size, nh] f32 (int8 pools)
+    k_scale/v_scale : optional [nl, num_pages, page_size, nh] f32 (int8
+                 pools)
     returns    : [C, nh, dh] in q.dtype; with ``return_visits=True`` also
                  the page-loop trip counts [ceil(C / block_q), nh] int32
                  (one walk serves every head of a q block, so a row
@@ -195,9 +202,12 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
     if interpret is None:
         from paddle_tpu.kernels.pallas._compat import default_interpret
         interpret = default_interpret()
+    from paddle_tpu.kernels.paged_attention import stored_pools
+    k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
+        "prefill_attention", k_pages, v_pages, k_scale, v_scale, layer)
     quant = k_scale is not None
     c, nh, dh = q.shape
-    num_pages, ps = k_pages.shape[:2]
+    ps = k_pages.shape[2]
     hd = nh * dh
     bq = default_block_q(c) if block_q is None else min(int(block_q), c)
     nq = pl.cdiv(c, bq)
@@ -216,16 +226,16 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
         pl.BlockSpec(memory_space=pl.ANY),            # K pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),            # V pool stays in HBM
     ]
-    operands = [q.reshape(c, hd), k_pages.reshape(num_pages, ps, hd),
-                v_pages.reshape(num_pages, ps, hd)]
+    operands = [q.reshape(c, hd), k_pages, v_pages]
     if quant:
         win = pl.BlockSpec((page_table.shape[0] * ps, nh),
                            lambda i, *_: (0, 0))
         in_specs += [win, win]
-        operands += [scale_window(k_scale, page_table),
-                     scale_window(v_scale, page_table)]
+        operands += [scale_window(k_scale, page_table, layer),
+                     scale_window(v_scale, page_table, layer)]
     meta = jnp.stack([jnp.asarray(start, jnp.int32),
-                      jnp.asarray(valid, jnp.int32)])
+                      jnp.asarray(valid, jnp.int32),
+                      jnp.asarray(layer, jnp.int32)])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nq,),

@@ -54,7 +54,8 @@ from paddle_tpu.observability import metrics
 
 _LOG = logging.getLogger("paddle_tpu.kernels.registry")
 
-__all__ = ["KernelOp", "register_op", "ops", "dispatch", "count", "select",
+__all__ = ["KernelOp", "register_op", "ops", "dispatch", "count",
+           "count_relayout", "select",
            "table", "clear"]
 
 
@@ -121,6 +122,15 @@ def count(op: str, impl: str):
     o = _OPS.get(op)
     if o is not None and o.alias_counter:
         metrics.counter(f"{o.alias_counter}.{impl}").inc()
+
+
+def count_relayout(op: str):
+    """The trace-time twin of :func:`count` for the KV pool's layout: one
+    per attention call whose pools arrive per layer and not as the stored
+    stack (`kernels/paged_attention.py::stored_pools`), so a program that
+    still copies a layer pool on its way into ``op`` reads non-zero in
+    ``kernel.pool_relayout.{op}`` — without a chip."""
+    metrics.counter(f"kernel.pool_relayout.{op}").inc()
 
 
 def dispatch(op: str, *, forced=None, ctx=None, winner=None,

@@ -172,7 +172,12 @@ def decode_step(params, ids, cache, slot_mask, *, cfg):
     params    : state_dict arrays (the `fast_generate` weight layout)
     ids       : [B] int32 — current token per slot
     cache     : dict with
-                  k_pages/v_pages : [nl, num_pages, page_size, nh, dh]
+                  k_pages/v_pages : [nl, num_pages, page_size, nh * dh]
+                                    — heads merged into the lane axis, the
+                                    layout the attention kernels read
+                                    (kernels/paged_attention.py); a layer
+                                    is addressed by index and never
+                                    sliced out of the stack
                   page_table      : [B, pages_per_slot] int32
                   lengths         : [B] int32 tokens already cached
                   k_scale/v_scale : OPTIONAL [nl, num_pages, page_size, nh]
@@ -203,12 +208,10 @@ def decode_step(params, ids, cache, slot_mask, *, cfg):
             v, sv = pa.quantize_kv(v)
             ks = ks.at[i, page, off].set(sk)
             vs = vs.at[i, page, off].set(sv)
-        kc = kc.at[i, page, off].set(k.astype(kc.dtype))
-        vc = vc.at[i, page, off].set(v.astype(vc.dtype))
-        return pa.paged_attention(
-            q, kc[i], vc[i], page_table, pos,
-            k_scale=None if ks is None else ks[i],
-            v_scale=None if vs is None else vs[i])
+        kc = kc.at[i, page, off].set(pa.kv_rows(k, kc))
+        vc = vc.at[i, page, off].set(pa.kv_rows(v, vc))
+        return pa.paged_attention(q, kc, vc, page_table, pos,
+                                  k_scale=ks, v_scale=vs, layer=i)
 
     x = _block_stack(params, x, nl, nh, dh, attend)
     logits = _final_logits(params, x)
@@ -268,26 +271,21 @@ def prefill_step(params, ids, length, page_table, k_pages, v_pages, *, cfg,
         if k_scale is not None:
             qk, sk = pa.quantize_kv(k[0])
             qv, sv = pa.quantize_kv(v[0])
-            k_pages = k_pages.at[i, page, off].set(qk)
-            v_pages = v_pages.at[i, page, off].set(qv)
+            k_pages = k_pages.at[i, page, off].set(pa.kv_rows(qk, k_pages))
+            v_pages = v_pages.at[i, page, off].set(pa.kv_rows(qv, v_pages))
             k_scale = k_scale.at[i, page, off].set(sk)
             v_scale = v_scale.at[i, page, off].set(sv)
             k = pa.dequantize_window(qk, sk)[None].astype(x.dtype)
             v = pa.dequantize_window(qv, sv)[None].astype(x.dtype)
         else:
-            k_pages = k_pages.at[i, page, off].set(
-                k[0].astype(k_pages.dtype))
-            v_pages = v_pages.at[i, page, off].set(
-                v[0].astype(v_pages.dtype))
+            k_pages = k_pages.at[i, page, off].set(pa.kv_rows(k[0], k_pages))
+            v_pages = v_pages.at[i, page, off].set(pa.kv_rows(v[0], v_pages))
         if impl == "pallas":
             # length-aware: the page walk stops at ceil(length/page_size),
             # not at the pow-2 bucket the queries are padded to
             return pa._prefill_impl_call(
-                "pallas", q, k_pages[i], v_pages[i], page_table,
-                jnp.int32(0), length,
-                k_scale=None if k_scale is None else k_scale[i],
-                v_scale=None if v_scale is None else v_scale[i]) \
-                .astype(x.dtype)
+                "pallas", q, k_pages, v_pages, page_table, jnp.int32(0),
+                length, i, k_scale=k_scale, v_scale=v_scale).astype(x.dtype)
         return causal(i, q, k, v)
 
     x = _block_stack(params, x, nl, nh, dh, attend)
@@ -339,18 +337,17 @@ def prefill_chunk_step(params, ids, start, valid, page_table, k_pages,
             k_scale = k_scale.at[i, page, off].set(sk)
             v_scale = v_scale.at[i, page, off].set(sv)
         else:
-            k, v = k[0].astype(k_pages.dtype), v[0].astype(v_pages.dtype)
-        k_pages = k_pages.at[i, page, off].set(k)
-        v_pages = v_pages.at[i, page, off].set(v)
+            k, v = k[0], v[0]
+        k_pages = k_pages.at[i, page, off].set(pa.kv_rows(k, k_pages))
+        v_pages = v_pages.at[i, page, off].set(pa.kv_rows(v, v_pages))
         # ragged prefill attention over the paged cache — previous chunks
         # AND the current one, absolute-position masked. Registry-routed
         # (kernels/registry.py): xla gathers the full window, pallas
         # streams only ceil((start+valid)/page_size) pages per (q block,
         # head) cell
         return pa.prefill_attention(
-            q, k_pages[i], v_pages[i], page_table, start, valid,
-            k_scale=None if k_scale is None else k_scale[i],
-            v_scale=None if v_scale is None else v_scale[i]).astype(x.dtype)
+            q, k_pages, v_pages, page_table, start, valid,
+            k_scale=k_scale, v_scale=v_scale, layer=i).astype(x.dtype)
 
     x = _block_stack(params, x, nl, nh, dh, attend)
     last = x[0, jnp.clip(valid - 1, 0, c - 1)]
@@ -431,13 +428,13 @@ def verify_step(params, tok_seq, draft_len, cache, slot_mask, *, cfg,
             v, sv = pa.quantize_kv(v)
             ks = ks.at[i, page, off].set(sk)
             vs = vs.at[i, page, off].set(sv)
-        kc = kc.at[i, page, off].set(k.astype(kc.dtype))
-        vc = vc.at[i, page, off].set(v.astype(vc.dtype))
-        kk = pa.gather_kv(kc[i], page_table).astype(jnp.float32)  # [B,Lmax,.]
-        vv = pa.gather_kv(vc[i], page_table).astype(jnp.float32)
-        if ks is not None:
-            kk = kk * pa.gather_scales(ks[i], page_table)[..., None]
-            vv = vv * pa.gather_scales(vs[i], page_table)[..., None]
+        kc = kc.at[i, page, off].set(pa.kv_rows(k, kc))
+        vc = vc.at[i, page, off].set(pa.kv_rows(v, vc))
+        kk = pa.gather_kv(kc, page_table, i, nh).astype(jnp.float32)
+        vv = pa.gather_kv(vc, page_table, i, nh).astype(jnp.float32)
+        if ks is not None:                                 # [B, Lmax, nh, dh]
+            kk = kk * pa.gather_scales(ks, page_table, i)[..., None]
+            vv = vv * pa.gather_scales(vs, page_table, i)[..., None]
         lmax = kk.shape[1]
         sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kk)
         # absolute-position causality: query at position p sees keys 0..p —

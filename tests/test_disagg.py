@@ -554,3 +554,46 @@ class TestDisaggObservability:
                 "engine.prefix_exported_hashes", 0) >= 1
         finally:
             srv._stop.set()
+
+
+# PTKS1 page records of conftest's seeded pages [5, 2, 7] (seed 2600),
+# packed by the commit before the pool was stored merged: blake2b-128
+PTKS1_DIGESTS = {"f32": "cf510ec4d2f9c62620b8120155697540",
+                 "int8": "5df4f7a2e383dd67794ebbd4c74bf7f4"}
+
+
+@pytest.mark.parametrize("kv", sorted(PTKS1_DIGESTS))
+def test_ptks1_record_is_byte_identical_to_the_unmerged_pools(
+        kv, seeded_kv_pages):
+    """A stream record cut from the merged pool is the record the
+    ``[..., nh, dh]`` pool gave, byte for byte, and
+    ``import_pages(export_pages(...))`` into another engine's pool at
+    other page ids lands every page bit-identical."""
+    import hashlib
+    import jax.numpy as jnp
+    from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
+    from paddle_tpu.kernels.paged_attention import import_pages
+    from paddle_tpu.serving.disagg import pack_stream_pages
+    ecfg = EngineConfig(page_size=4, max_slots=2, min_bucket=8, kv_dtype=kv)
+    src = DecodeEngine(_tiny_model(), ecfg)
+    pages = [5, 2, 7]
+    seeded_kv_pages(src, pages, 2600)
+    blobs = src._export_pages(pages)
+    assert hashlib.blake2b(pack_stream_pages(1, 0, *blobs),
+                           digest_size=16).hexdigest() == PTKS1_DIGESTS[kv]
+    dst = DecodeEngine(_tiny_model(), ecfg)
+    there = [1, 8, 3]
+    out = import_pages(
+        dst._kc, dst._vc, jnp.asarray(blobs[0]), jnp.asarray(blobs[1]), there,
+        **({} if blobs[2] is None else dict(
+            k_scales=dst._ks, v_scales=dst._vs, k_s_blob=blobs[2],
+            v_s_blob=blobs[3])))
+    dst._kc, dst._vc = out[:2]
+    if blobs[2] is not None:
+        dst._ks, dst._vs = out[2:]
+    for a, b in zip(blobs, dst._export_pages(there)):
+        if a is None:
+            assert b is None
+        else:
+            np.testing.assert_array_equal(a, b)
+

@@ -568,3 +568,43 @@ class TestTierDirectory:
         rid, depth = d.lookup(list(r.page_hashes))
         assert (rid, depth) == ("prefill:x", 4)
         assert d.is_spilled(bytes(r.page_hashes[0]), "prefill:x")
+
+
+# PTKT1 frames of conftest's seeded pages [5, 2, 7] (seed 2600) under a
+# zeroed store salt, spilled by the commit before the pool was stored
+# merged: blake2b-128 of each page's frame
+PTKT1_DIGESTS = {
+    "f32": ["5468f58cba65da999a09fdd00c30f831",
+            "f921813da69094ef7e391303109c14c9",
+            "86a7e948ed9c7d288040441027f95cfe"],
+    "int8": ["d0057238106a659891d4f53b106a1331",
+             "7b66fa671e49dee858e5deea87669181",
+             "be80fcb307e2b620f90033d4f96dcae8"]}
+
+
+@pytest.mark.parametrize("kv", sorted(PTKT1_DIGESTS))
+def test_ptkt1_frames_are_byte_identical_to_the_unmerged_pools(
+        kv, seeded_kv_pages):
+    """A spill out of the merged pool frames each page as the
+    ``[..., nh, dh]`` pool did, byte for byte, and the tier's entries are
+    the page contents that went in."""
+    from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
+    eng = DecodeEngine(_tiny_model(), EngineConfig(
+        page_size=4, max_slots=2, min_bucket=8, kv_dtype=kv,
+        kv_host_tier_bytes=1 << 20))
+    pages = [5, 2, 7]
+    k, v, ks, vs = seeded_kv_pages(eng, pages, 2600)
+    eng._tiers._salt = "0" * 16
+    hashes = [hashlib.blake2b(b"page-%d" % p, digest_size=16).digest()
+              for p in pages]
+    assert eng._spill_pages(pages, hashes) == 3
+    assert [hashlib.blake2b(eng._tiers._host[h], digest_size=16).hexdigest()
+            for h in hashes] == PTKT1_DIGESTS[kv]
+    for i, h in enumerate(hashes):
+        e = eng._tiers.get(h)
+        np.testing.assert_array_equal(e.k, k[:, i])
+        np.testing.assert_array_equal(e.v, v[:, i])
+        if ks is not None:
+            np.testing.assert_array_equal(e.ks, ks[:, i])
+            np.testing.assert_array_equal(e.vs, vs[:, i])
+

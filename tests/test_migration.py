@@ -686,3 +686,40 @@ class TestWireMigration:
         _assert_pool_baseline(a_eng)
         router.stop()
         b.drain(deadline_s=10.0)
+
+
+# PTKV1 blobs of conftest's seeded pages [5, 2, 7] (seed 2600), packed by
+# the commit before the pool was stored merged: blake2b-128 of the blob
+PTKV1_DIGESTS = {"f32": "b9ff500902fc28c5b711b4bf51cab0ae",
+                 "int8": "3d7a4b1c07ddf111fa150d06c06bade3"}
+
+
+@pytest.mark.parametrize("kv", sorted(PTKV1_DIGESTS))
+def test_ptkv1_blob_is_byte_identical_to_the_unmerged_pools(
+        kv, seeded_kv_pages):
+    """The pool is stored ``[nl, P, ps, nh*dh]``; what leaves it is still
+    ``[nl, n, ps, nh, dh]`` and the same row-major bytes: pages scattered
+    in come back bit-identical, and the handoff blob they pack to has the
+    digest the ``[..., nh, dh]`` pool gave (header's ``pages_shape``
+    included)."""
+    import hashlib
+    from paddle_tpu.inference.engine import KVHandoff
+    eng = _engine(_tiny_model(), kv_dtype=kv)
+    pages = [5, 2, 7]
+    want = seeded_kv_pages(eng, pages, 2600)
+    assert eng._kc.shape[2:] == (4, eng._nh * eng._dh)
+    got = eng._export_pages(pages)
+    assert got[0].shape == (eng._nl, 3, 4, eng._nh, eng._dh)
+    for a, b in zip(want, got):
+        if a is None:
+            assert b is None
+        else:
+            np.testing.assert_array_equal(a, b)
+    blob = KVHandoff(prompt=np.arange(10, dtype=np.int32), first_token=7,
+                     k_pages=got[0], v_pages=got[1], page_size=4,
+                     cache_dtype=np.dtype(eng._cdtype).name,
+                     k_scales=got[2], v_scales=got[3]).pack()
+    assert hashlib.blake2b(blob, digest_size=16).hexdigest() \
+        == PTKV1_DIGESTS[kv]
+    assert KVHandoff.unpack(blob).k_pages.shape == got[0].shape
+

@@ -6,7 +6,8 @@ coordinate fix.
 The load-bearing contracts:
 - pallas(interpret) == xla reference on every ragged shape (same f32 masked
   softmax, so the engine's token-identical guarantee survives the kernel
-  swap);
+  swap), on the STORED pool ``[nl, P, ps, nh*dh]`` read at its first and
+  its last layer, and bit-identical to the per-layer form of that layer;
 - the kernel's page-loop trip count is ``ceil((pos+1)/page_size)`` — it
   scales with each sequence's TRUE length, never with ``pages_per_slot``;
 - positions past a slot's capacity route to TRASH_PAGE instead of silently
@@ -23,57 +24,117 @@ from paddle_tpu.kernels.pallas import paged_attention as ppa
 from paddle_tpu.observability import metrics
 
 
+NL = 3                  # layers of the test pools; every layer is noise
+
+
 def _random_case(rng, b, nh, dh, ps, maxp, num_pages, pos):
-    """Distinct non-trash pages per (slot, page) so any wrong page read
-    shows up as a numeric mismatch, not a coincidence."""
+    """Pools in the stored layout [NL, num_pages, ps, nh*dh]. Distinct
+    non-trash pages per (slot, page), and other values in every layer, so
+    any wrong page or layer read shows up as a numeric mismatch, not a
+    coincidence."""
     q = jnp.asarray(rng.randn(b, nh, dh).astype(np.float32))
-    kp = jnp.asarray(rng.randn(num_pages, ps, nh, dh).astype(np.float32))
-    vp = jnp.asarray(rng.randn(num_pages, ps, nh, dh).astype(np.float32))
+    shape = (NL, num_pages, ps, nh * dh)
+    kp = jnp.asarray(rng.randn(*shape).astype(np.float32))
+    vp = jnp.asarray(rng.randn(*shape).astype(np.float32))
     perm = 1 + rng.permutation(num_pages - 1)[:b * maxp]
     pt = jnp.asarray(perm.reshape(b, maxp).astype(np.int32))
     return q, kp, vp, pt, jnp.asarray(np.asarray(pos, np.int32))
 
 
+@pytest.mark.parametrize("layer", [0, NL - 1])
 class TestPallasParity:
-    """pallas(interpret) vs the XLA reference, elementwise."""
+    """pallas(interpret) on the stored pool at ``layer`` vs the XLA
+    reference, elementwise, and vs both per-layer forms, bit for bit."""
 
-    def _check(self, b, nh, dh, ps, maxp, pos, seed=0):
+    def _check(self, layer, b, nh, dh, ps, maxp, pos, seed=0):
         rng = np.random.RandomState(seed)
         num_pages = 1 + b * maxp
         q, kp, vp, pt, pos = _random_case(rng, b, nh, dh, ps, maxp,
                                           num_pages, pos)
-        want = pa._xla_paged_attention(q, kp, vp, pt, pos)
-        got, visits = ppa.paged_attention(q, kp, vp, pt, pos,
+        want = pa._xla_paged_attention(q, kp, vp, pt, pos, layer)
+        got, visits = ppa.paged_attention(q, kp, vp, pt, pos, layer=layer,
                                           interpret=True, return_visits=True)
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
+        # the forms without ``layer=``: that layer's pool alone, merged
+        # rank 3 or [P, ps, nh, dh] as the benchmark's probe passes it
+        for shape in [(num_pages, ps, nh * dh), (num_pages, ps, nh, dh)]:
+            one = ppa.paged_attention(q, kp[layer].reshape(shape),
+                                      vp[layer].reshape(shape), pt, pos,
+                                      interpret=True)
+            np.testing.assert_array_equal(np.asarray(one), np.asarray(got))
         return np.asarray(visits)
 
-    def test_ragged_length_mix(self):
+    def test_ragged_length_mix(self, layer):
         # lengths spanning 1 token .. full capacity across the batch
-        self._check(b=4, nh=2, dh=16, ps=4, maxp=5, pos=[0, 6, 13, 19])
+        self._check(layer, b=4, nh=2, dh=16, ps=4, maxp=5,
+                    pos=[0, 6, 13, 19])
 
-    def test_page_boundary_crossings(self):
+    def test_page_boundary_crossings(self, layer):
         # pos exactly at the last slot of a page and first of the next
-        self._check(b=4, nh=2, dh=16, ps=4, maxp=4, pos=[3, 4, 7, 8])
+        self._check(layer, b=4, nh=2, dh=16, ps=4, maxp=4, pos=[3, 4, 7, 8])
 
-    def test_single_token_batch(self):
-        self._check(b=3, nh=2, dh=8, ps=8, maxp=6, pos=[0, 0, 0])
+    def test_single_token_batch(self, layer):
+        self._check(layer, b=3, nh=2, dh=8, ps=8, maxp=6, pos=[0, 0, 0])
 
-    def test_full_pool_batch(self):
+    def test_full_pool_batch(self, layer):
         # every sequence at capacity: the stop equals pages_per_slot
-        v = self._check(b=3, nh=2, dh=16, ps=4, maxp=3, pos=[11, 11, 11])
+        v = self._check(layer, b=3, nh=2, dh=16, ps=4, maxp=3,
+                        pos=[11, 11, 11])
         assert (v == 3).all()
 
-    def test_jit_composes(self):
-        # the engine calls the kernel from inside a jitted decode step
+    def test_jit_composes(self, layer):
+        # the engine calls the kernel from inside a jitted decode step,
+        # with the layer a constant of the trace
         rng = np.random.RandomState(3)
         q, kp, vp, pt, pos = _random_case(rng, 2, 2, 16, 4, 3, 7, [2, 9])
-        f = jax.jit(lambda *a: ppa.paged_attention(*a, interpret=True))
+        f = jax.jit(lambda *a: ppa.paged_attention(*a, layer=layer,
+                                                   interpret=True))
         np.testing.assert_allclose(
             np.asarray(f(q, kp, vp, pt, pos)),
-            np.asarray(pa._xla_paged_attention(q, kp, vp, pt, pos)),
+            np.asarray(pa._xla_paged_attention(q, kp, vp, pt, pos, layer)),
             rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("layer", [0, NL - 1])
+@pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
+def test_stored_and_per_layer_forms_agree_on_both_arms(kv, layer):
+    """The public op on every pool dtype the engine stores: within an arm
+    the stored pool read with ``layer=`` and that layer's pool passed alone
+    (rank 4, the probe's form) give the same bits; across arms the same
+    attention to rounding."""
+    from paddle_tpu.framework.flags import set_flags
+    rng = np.random.RandomState(11)
+    b, nh, dh, ps, maxp = 3, 2, 16, 4, 4
+    q, kp, vp, pt, pos = _random_case(rng, b, nh, dh, ps, maxp,
+                                      1 + b * maxp, [0, 6, 15])
+    scales = {}
+    if kv == "int8":
+        def quantized(pool):
+            vals, s = pa.quantize_kv(pool.reshape(*pool.shape[:3], nh, dh))
+            return vals.reshape(pool.shape), s
+        (kp, ks), (vp, vs) = quantized(kp), quantized(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    elif kv == "bf16":
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+    one = {k: v[layer] for k, v in scales.items()}
+    outs = {}
+    try:
+        for impl in ("xla", "pallas"):
+            set_flags({"tpu_paged_impl": impl})
+            stored = pa.paged_attention(q, kp, vp, pt, pos, layer=layer,
+                                        **scales)
+            alone = pa.paged_attention(
+                q, kp[layer].reshape(-1, ps, nh, dh),
+                vp[layer].reshape(-1, ps, nh, dh), pt, pos, **one)
+            np.testing.assert_array_equal(np.asarray(stored, np.float32),
+                                          np.asarray(alone, np.float32))
+            outs[impl] = np.asarray(stored, np.float32)
+    finally:
+        set_flags({"tpu_paged_impl": "auto"})
+    tol = 2e-2 if kv == "bf16" else 1e-5
+    np.testing.assert_allclose(outs["pallas"], outs["xla"], rtol=tol,
+                               atol=tol)
 
 
 class TestLengthAwareStop:
@@ -85,8 +146,8 @@ class TestLengthAwareStop:
         pos = [0, 5, 17, 63]
         q, kp, vp, pt, posj = _random_case(rng, b, nh, dh, ps, maxp,
                                            1 + b * maxp, pos)
-        _, visits = ppa.paged_attention(q, kp, vp, pt, posj, interpret=True,
-                                        return_visits=True)
+        _, visits = ppa.paged_attention(q, kp, vp, pt, posj, layer=1,
+                                        interpret=True, return_visits=True)
         visits = np.asarray(visits)
         want = np.array([(p + ps) // ps for p in pos])   # ceil((pos+1)/ps)
         for h in range(nh):
@@ -102,8 +163,8 @@ class TestLengthAwareStop:
         b, nh, dh, ps, maxp = 2, 2, 16, 4, 4         # 16-token slots
         q, kp, vp, pt, posj = _random_case(rng, b, nh, dh, ps, maxp,
                                            1 + b * maxp, [15, 40])
-        _, visits = ppa.paged_attention(q, kp, vp, pt, posj, interpret=True,
-                                        return_visits=True)
+        _, visits = ppa.paged_attention(q, kp, vp, pt, posj, layer=1,
+                                        interpret=True, return_visits=True)
         np.testing.assert_array_equal(np.asarray(visits)[:, 0], [maxp, maxp])
 
     def test_pages_needed_formula(self):
@@ -130,22 +191,28 @@ class TestDispatchSwitch:
         from paddle_tpu.framework.flags import set_flags
         q, kp, vp, pt, pos = self._case()
         set_flags({"tpu_paged_impl": "xla"})
-        a = pa.paged_attention(q, kp, vp, pt, pos)
+        a = pa.paged_attention(q, kp, vp, pt, pos, layer=1)
         set_flags({"tpu_paged_impl": "pallas"})
-        b = pa.paged_attention(q, kp, vp, pt, pos)
+        b = pa.paged_attention(q, kp, vp, pt, pos, layer=1)
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                    rtol=1e-5, atol=1e-5)
+
+    def test_layer_past_the_stack_is_refused_while_tracing(self):
+        # on the chip it would be a wild DMA (a halted core), not an error
+        q, kp, vp, pt, pos = self._case()
+        with pytest.raises(IndexError, match=f"layer {NL} of a pool"):
+            pa.paged_attention(q, kp, vp, pt, pos, layer=NL)
 
     def test_impl_counter_counts_dispatches(self):
         from paddle_tpu.framework.flags import set_flags
         q, kp, vp, pt, pos = self._case()
         set_flags({"tpu_paged_impl": "xla"})
         before = metrics.counter("paged_attention.impl.xla").value
-        pa.paged_attention(q, kp, vp, pt, pos)
+        pa.paged_attention(q, kp, vp, pt, pos, layer=1)
         assert metrics.counter("paged_attention.impl.xla").value == before + 1
         set_flags({"tpu_paged_impl": "pallas"})
         before_p = metrics.counter("paged_attention.impl.pallas").value
-        pa.paged_attention(q, kp, vp, pt, pos)
+        pa.paged_attention(q, kp, vp, pt, pos, layer=1)
         assert metrics.counter(
             "paged_attention.impl.pallas").value == before_p + 1
 
@@ -156,7 +223,7 @@ class TestDispatchSwitch:
         set_flags({"tpu_paged_impl": "auto"})
         q, kp, vp, pt, pos = self._case()
         before = metrics.counter("paged_attention.impl.xla").value
-        pa.paged_attention(q, kp, vp, pt, pos)
+        pa.paged_attention(q, kp, vp, pt, pos, layer=1)
         assert metrics.counter("paged_attention.impl.xla").value == before + 1
         key = [k for k in autotune.cache_table() if k[0] == "paged"]
         assert key and autotune.cache_table()[key[0]][0] == "xla"
@@ -214,15 +281,15 @@ class TestOverflowToTrash:
 
     def test_token_write_overflow_leaves_last_page_intact(self):
         ps, maxp = 2, 2
-        kp = jnp.zeros((4, ps, 1, 4))
+        kp = jnp.zeros((1, 4, ps, 4))                 # [nl, P, ps, nh*dh]
         vp = jnp.zeros_like(kp)
         k = jnp.ones((1, 1, 4))
         pt = jnp.asarray([[1, 2]], jnp.int32)
         kp2, _ = pa.write_token_kv(kp, vp, k, k, pt,
                                    jnp.asarray([4], jnp.int32),   # capacity!
-                                   jnp.asarray([True]))
-        assert np.asarray(kp2)[pa.TRASH_PAGE].sum() == 4
-        assert np.asarray(kp2)[1:].sum() == 0         # page 2 NOT corrupted
+                                   jnp.asarray([True]), 0)
+        assert np.asarray(kp2)[0, pa.TRASH_PAGE].sum() == 4
+        assert np.asarray(kp2)[0, 1:].sum() == 0      # page 2 NOT corrupted
 
     def test_prompt_coords_overflow_routes_to_trash(self):
         ps = 2

@@ -21,15 +21,30 @@ def _restore_flag():
     set_flags({"tpu_prefill_impl": "auto"})
 
 
+NL = 3                  # layers of the test pools; every layer is noise
+
+
 def _pool(rng, nh=2, dh=8, ps=4, maxp=6):
-    npages = 1 + maxp
-    kp = jnp.asarray(rng.randn(npages, ps, nh, dh).astype(np.float32))
-    vp = jnp.asarray(rng.randn(npages, ps, nh, dh).astype(np.float32))
+    """K and V in the stored layout [NL, num_pages, ps, nh*dh]."""
+    shape = (NL, 1 + maxp, ps, nh * dh)
+    kp = jnp.asarray(rng.randn(*shape).astype(np.float32))
+    vp = jnp.asarray(rng.randn(*shape).astype(np.float32))
     row = jnp.asarray(np.arange(1, maxp + 1, dtype=np.int32))
     return kp, vp, row
 
 
+def _quantized(pool, nh=2):
+    """An int8 stored pool and its [NL, P, ps, nh] scales."""
+    q, s = pa.quantize_kv(pool.reshape(*pool.shape[:3], nh, -1))
+    return q.reshape(pool.shape), s
+
+
+@pytest.mark.parametrize("layer", [0, NL - 1])
 class TestKernelParity:
+    """The stored pool read at its first and its last layer: pallas
+    (interpret) against the XLA arm, and bit for bit against the
+    per-layer form of that layer's pool."""
+
     @pytest.mark.parametrize("start,valid,c", [
         (0, 7, 8),       # fresh prompt, padded tail
         (8, 5, 8),       # chunk after 2 pages of context
@@ -37,47 +52,60 @@ class TestKernelParity:
         (0, 1, 4),       # single real token
         (12, 3, 4),      # deep context, short tail
     ])
-    def test_matches_xla_arm(self, start, valid, c):
+    def test_matches_xla_arm(self, layer, start, valid, c):
         rng = np.random.RandomState(start * 17 + valid)
         kp, vp, row = _pool(rng)
         q = jnp.asarray(rng.randn(1, c, 2, 8).astype(np.float32))
         ref = pa._xla_prefill_attention(q, kp, vp, row, jnp.int32(start),
-                                        jnp.int32(valid))
+                                        jnp.int32(valid), layer)
         out = pallas_prefill(q[0], kp, vp, row, jnp.int32(start),
-                             jnp.int32(valid), interpret=True)
+                             jnp.int32(valid), layer=layer, interpret=True)
         np.testing.assert_allclose(np.asarray(ref)[0, :valid],
                                    np.asarray(out)[:valid],
                                    rtol=1e-5, atol=1e-5)
+        # without ``layer=``: that layer's pool alone, merged rank 3 or
+        # [P, ps, nh, dh] as the benchmark's probe passes it
+        for shape in [kp.shape[1:], kp.shape[1:3] + (2, 8)]:
+            one = pallas_prefill(q[0], kp[layer].reshape(shape),
+                                 vp[layer].reshape(shape), row,
+                                 jnp.int32(start), jnp.int32(valid),
+                                 interpret=True)
+            np.testing.assert_array_equal(np.asarray(one), np.asarray(out))
 
-    def test_multi_qblock_grid(self):
+    def test_multi_qblock_grid(self, layer):
         rng = np.random.RandomState(3)
         kp, vp, row = _pool(rng, maxp=16)
         q = jnp.asarray(rng.randn(1, 16, 2, 8).astype(np.float32))
         ref = pa._xla_prefill_attention(q, kp, vp, row, jnp.int32(8),
-                                        jnp.int32(10))
+                                        jnp.int32(10), layer)
         out = pallas_prefill(q[0], kp, vp, row, jnp.int32(8),
-                             jnp.int32(10), interpret=True, block_q=4)
+                             jnp.int32(10), layer=layer, interpret=True,
+                             block_q=4)
         np.testing.assert_allclose(np.asarray(ref)[0, :10],
                                    np.asarray(out)[:10],
                                    rtol=1e-5, atol=1e-5)
 
-    def test_int8_scales_ride_the_same_operands(self):
+    def test_int8_scales_ride_the_same_operands(self, layer):
         rng = np.random.RandomState(7)
         kp, vp, row = _pool(rng)
-        kq, ks = pa.quantize_kv(kp)
-        vq, vs = pa.quantize_kv(vp)
+        kq, ks = _quantized(kp)
+        vq, vs = _quantized(vp)
         q = jnp.asarray(rng.randn(1, 8, 2, 8).astype(np.float32))
         ref = pa._xla_prefill_attention(q, kq, vq, row, jnp.int32(4),
-                                        jnp.int32(6), k_scale=ks,
+                                        jnp.int32(6), layer, k_scale=ks,
                                         v_scale=vs)
         out = pallas_prefill(q[0], kq, vq, row, jnp.int32(4),
-                             jnp.int32(6), interpret=True,
+                             jnp.int32(6), layer=layer, interpret=True,
                              k_scale=ks, v_scale=vs)
         np.testing.assert_allclose(np.asarray(ref)[0, :6],
                                    np.asarray(out)[:6],
                                    rtol=1e-5, atol=1e-5)
+        one = pallas_prefill(q[0], kq[layer], vq[layer], row, jnp.int32(4),
+                             jnp.int32(6), interpret=True,
+                             k_scale=ks[layer], v_scale=vs[layer])
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(out))
 
-    def test_jit_composes(self):
+    def test_jit_composes(self, layer):
         import jax
         rng = np.random.RandomState(9)
         kp, vp, row = _pool(rng)
@@ -86,11 +114,11 @@ class TestKernelParity:
         @jax.jit
         def f(q_, kp_, vp_, start, valid):
             return pallas_prefill(q_[0], kp_, vp_, row, start, valid,
-                                  interpret=True)
+                                  layer=layer, interpret=True)
 
         out = f(q, kp, vp, jnp.int32(4), jnp.int32(5))
         ref = pa._xla_prefill_attention(q, kp, vp, row, jnp.int32(4),
-                                        jnp.int32(5))
+                                        jnp.int32(5), layer)
         np.testing.assert_allclose(np.asarray(ref)[0, :5],
                                    np.asarray(out)[:5],
                                    rtol=1e-5, atol=1e-5)
@@ -111,7 +139,7 @@ class TestLengthScaling:
             q = jnp.asarray(rng.randn(1, c, 2, 8).astype(np.float32))
             _, visits = pallas_prefill(
                 q[0], kp, vp, row, jnp.int32(start), jnp.int32(valid),
-                interpret=True, return_visits=True)
+                layer=1, interpret=True, return_visits=True)
             v = np.asarray(visits)
             want = -(-(start + valid) // ps)
             assert v.max() == want, (start, valid, v)
@@ -126,7 +154,7 @@ class TestLengthScaling:
         kp, vp, row = _pool(rng, maxp=maxp)          # 16-token slot
         q = jnp.asarray(rng.randn(1, 16, 2, 8).astype(np.float32))
         _, visits = pallas_prefill(q[0], kp, vp, row, jnp.int32(4),
-                                   jnp.int32(16), interpret=True,
+                                   jnp.int32(16), layer=1, interpret=True,
                                    return_visits=True)
         assert np.asarray(visits).max() == maxp
 
@@ -135,7 +163,7 @@ class TestLengthScaling:
         kp, vp, row = _pool(rng, maxp=16)
         q = jnp.asarray(rng.randn(1, 16, 2, 8).astype(np.float32))
         _, visits = pallas_prefill(q[0], kp, vp, row, jnp.int32(0),
-                                   jnp.int32(5), interpret=True,
+                                   jnp.int32(5), layer=1, interpret=True,
                                    return_visits=True, block_q=4)
         v = np.asarray(visits)[:, 0]    # per q block, head 0
         assert v[0] > 0 and v[1] > 0    # rows 0..7 hold the 5 real tokens
@@ -245,13 +273,15 @@ class TestEngineTokenIdentity:
         set_flags({"tpu_prefill_impl": "xla"})
         before = metrics.counter(
             "kernel.dispatch.prefill_attention.xla").value
-        a = pa.prefill_attention(q, kp, vp, row, jnp.int32(0), jnp.int32(4))
+        a = pa.prefill_attention(q, kp, vp, row, jnp.int32(0), jnp.int32(4),
+                                 layer=1)
         assert metrics.counter(
             "kernel.dispatch.prefill_attention.xla").value == before + 1
         set_flags({"tpu_prefill_impl": "pallas"})
         pbefore = metrics.counter(
             "kernel.dispatch.prefill_attention.pallas").value
-        b = pa.prefill_attention(q, kp, vp, row, jnp.int32(0), jnp.int32(4))
+        b = pa.prefill_attention(q, kp, vp, row, jnp.int32(0), jnp.int32(4),
+                                 layer=1)
         assert metrics.counter(
             "kernel.dispatch.prefill_attention.pallas").value == pbefore + 1
         np.testing.assert_allclose(np.asarray(a)[0], np.asarray(b)[0],

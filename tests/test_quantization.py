@@ -69,14 +69,24 @@ def _margin_gated_match(lg_f, lg_q):
 # ---------------------------------------------------------------- int8 KV
 
 
+def _int8_pool(rng, nl, npages, ps, nh, dh):
+    """A random int8 pool in the stored layout [nl, P, ps, nh*dh] and its
+    [nl, P, ps, nh] scales."""
+    from paddle_tpu.kernels import paged_attention as pa
+    q, s = pa.quantize_kv(jnp.asarray(
+        rng.randn(nl, npages, ps, nh, dh).astype(np.float32)))
+    return q.reshape(nl, npages, ps, nh * dh), s
+
+
 class TestInt8KV:
     def _pools(self, cfg, npg, ps, quant):
-        nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
+        # the stored layout: [nl, P, ps, nh*dh] values, [nl, P, ps, nh] scales
+        shape = (cfg.num_layers, npg, ps, cfg.hidden_size)
         if quant:
-            z = jnp.zeros((cfg.num_layers, npg, ps, nh, dh), jnp.int8)
-            s = jnp.zeros((cfg.num_layers, npg, ps, nh), jnp.float32)
+            z = jnp.zeros(shape, jnp.int8)
+            s = jnp.zeros(shape[:3] + (cfg.num_heads,), jnp.float32)
             return z, jnp.zeros_like(z), s, jnp.zeros_like(s)
-        z = jnp.zeros((cfg.num_layers, npg, ps, nh, dh), jnp.float32)
+        z = jnp.zeros(shape, jnp.float32)
         return z, jnp.zeros_like(z), None, None
 
     def test_prefill_and_decode_logits_within_bound(self):
@@ -118,6 +128,8 @@ class TestInt8KV:
                                             cfg=cfg)
         _margin_gated_match(dl_f, dl_q)
         assert cache_q["k_pages"].dtype == jnp.int8
+        assert cache_q["k_pages"].shape == (cfg.num_layers, npg, ps,
+                                            cfg.hidden_size)
         assert cache_q["k_scale"].shape == (cfg.num_layers, npg, ps,
                                             cfg.num_heads)
 
@@ -244,20 +256,18 @@ class TestInt8KV:
         b, nh, dh, ps, maxp = 2, 1, 8, 4, 3   # unique geometry: fresh key
         npages = 1 + b * maxp
         q = jnp.asarray(rng.randn(b, nh, dh).astype(np.float32))
-        kq, ks = pa.quantize_kv(jnp.asarray(
-            rng.randn(npages, ps, nh, dh).astype(np.float32)))
-        vq, vs = pa.quantize_kv(jnp.asarray(
-            rng.randn(npages, ps, nh, dh).astype(np.float32)))
+        kq, ks = _int8_pool(rng, 2, npages, ps, nh, dh)
+        vq, vs = _int8_pool(rng, 2, npages, ps, nh, dh)
         pt = jnp.asarray(np.arange(1, npages).reshape(b, maxp)
                          .astype(np.int32))
         pos = jnp.asarray(np.array([2, 9], np.int32))
         set_flags({"tpu_paged_impl": "auto"})
         try:
             out = pa.paged_attention(q, kq, vq, pt, pos,
-                                     k_scale=ks, v_scale=vs)
+                                     k_scale=ks, v_scale=vs, layer=1)
         finally:
             set_flags({"tpu_paged_impl": "auto"})
-        ref = pa._xla_paged_attention(q, kq, vq, pt, pos,
+        ref = pa._xla_paged_attention(q, kq, vq, pt, pos, 1,
                                       k_scale=ks, v_scale=vs)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    atol=1e-5)
@@ -265,10 +275,13 @@ class TestInt8KV:
         assert any(k[0] == "paged" and str(k[-1]).endswith("/kv-int8")
                    for k in autotune._CACHE), autotune._CACHE.keys()
 
-    def test_pallas_int8_parity(self):
+    @pytest.mark.parametrize("layer", [0, 2])
+    def test_pallas_int8_parity(self, layer):
         """The Pallas kernel's in-register dequant matches the XLA gather
-        path bit-for-f32-bit on the same int8 pages (interpret mode), and
-        the ragged length-aware stop still holds."""
+        path bit-for-f32-bit on the same int8 pages (interpret mode) of
+        the stored pool's first and last layer, the per-layer form of that
+        layer gives the same bits, and the ragged length-aware stop still
+        holds."""
         from paddle_tpu.kernels import paged_attention as pa
         from paddle_tpu.kernels.pallas.paged_attention import (
             paged_attention as pallas_paged)
@@ -276,20 +289,23 @@ class TestInt8KV:
         B, nh, dh, ps, maxp = 3, 2, 8, 4, 4
         npages = 1 + B * maxp
         q = jnp.asarray(rng.randn(B, nh, dh).astype(np.float32))
-        kq, ks = pa.quantize_kv(jnp.asarray(
-            rng.randn(npages, ps, nh, dh).astype(np.float32)))
-        vq, vs = pa.quantize_kv(jnp.asarray(
-            rng.randn(npages, ps, nh, dh).astype(np.float32)))
+        kq, ks = _int8_pool(rng, 3, npages, ps, nh, dh)
+        vq, vs = _int8_pool(rng, 3, npages, ps, nh, dh)
         pt = jnp.asarray(rng.permutation(np.arange(1, npages))
                          .reshape(B, maxp).astype(np.int32))
         pos = jnp.asarray(np.array([2, 7, 13], np.int32))
-        ref = pa._xla_paged_attention(q, kq, vq, pt, pos,
+        ref = pa._xla_paged_attention(q, kq, vq, pt, pos, layer,
                                       k_scale=ks, v_scale=vs)
-        out, visits = pallas_paged(q, kq, vq, pt, pos, k_scale=ks,
-                                   v_scale=vs, interpret=True,
+        out, visits = pallas_paged(q, kq, vq, pt, pos, layer=layer,
+                                   k_scale=ks, v_scale=vs, interpret=True,
                                    return_visits=True)
         np.testing.assert_allclose(np.asarray(ref), np.asarray(out),
                                    atol=1e-5)
+        one = pallas_paged(
+            q, kq[layer].reshape(npages, ps, nh, dh),
+            vq[layer].reshape(npages, ps, nh, dh), pt, pos,
+            k_scale=ks[layer], v_scale=vs[layer], interpret=True)
+        np.testing.assert_array_equal(np.asarray(one), np.asarray(out))
         np.testing.assert_array_equal(
             np.asarray(visits)[:, 0], (np.asarray(pos) + ps) // ps)
 
@@ -368,8 +384,7 @@ class TestWeightInt8:
         ids = jnp.asarray(np.random.RandomState(1)
                           .randint(0, 64, 6).astype(np.int32))
         row = jnp.pad(jnp.arange(1, 3, dtype=jnp.int32), (0, 14))
-        z = jnp.zeros((cfg.num_layers, 3, 4, cfg.num_heads,
-                       cfg.hidden_size // cfg.num_heads), jnp.float32)
+        z = jnp.zeros((cfg.num_layers, 3, 4, cfg.hidden_size), jnp.float32)
         lg_f, _, _ = gpt_mod.prefill_step(params, ids, jnp.int32(6),
                                           row[:2], z, jnp.zeros_like(z),
                                           cfg=cfg)

@@ -482,7 +482,7 @@ class TestVerifyStepSampled:
         ref = _fast_ref(m, prompt, N, temperature=temperature, top_k=top_k,
                         seed=seed)
 
-        kc = jnp.zeros((cfg.num_layers, 1 + maxp, ps, 2, 16), jnp.float32)
+        kc = jnp.zeros((cfg.num_layers, 1 + maxp, ps, 2 * 16), jnp.float32)
         vc = jnp.zeros_like(kc)
         row = np.full(maxp, TRASH_PAGE, np.int32)
         row[:maxp - 1] = np.arange(1, maxp)
@@ -532,7 +532,7 @@ class TestVerifyStepSampled:
         cfg = m.cfg
         params = {k: t._data for k, t in m.state_dict().items()}
         ps, maxp, K = 4, 4, 2
-        kc = jnp.zeros((cfg.num_layers, 1 + 2 * maxp, ps, 2, 16),
+        kc = jnp.zeros((cfg.num_layers, 1 + 2 * maxp, ps, 2 * 16),
                        jnp.float32)
         vc = jnp.zeros_like(kc)
         table = np.arange(1, 1 + 2 * maxp, dtype=np.int32).reshape(2, maxp)
@@ -549,6 +549,67 @@ class TestVerifyStepSampled:
                                       np.asarray(keys[1]))
         # the ACTIVE slot's chain did advance by its one split
         assert not np.array_equal(np.asarray(nk[0]), np.asarray(keys[0]))
+
+
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step",
+                                     "verify_step"])
+def test_xla_arms_gather_from_the_stack_without_slicing_a_layer(program, kv):
+    """The XLA arms index ``pool[layer, page_table]`` in ONE gather: no
+    equation of the traced step yields a layer's pool (``[P, ps, nh*dh]``
+    with or without a leading 1), values or scales — on the chip such a
+    slice is a copy of 1/nl of the pool, per layer, per program."""
+    import jax
+    import jax.numpy as jnp
+    from paddle_tpu.framework.flags import set_flags
+    from paddle_tpu.models import gpt
+    m = _tiny_model()
+    cfg = m.cfg
+    params = {k: t._data for k, t in m.state_dict().items()}
+    nl, nh, hd, npages, ps, maxp, b = 2, 2, 32, 9, 4, 4, 2
+    quant = kv == "int8"
+    pool = jnp.zeros((nl, npages, ps, hd), jnp.int8 if quant
+                     else jnp.float32)
+    sc = dict(k_scale=jnp.zeros((nl, npages, ps, nh), jnp.float32),
+              v_scale=jnp.zeros((nl, npages, ps, nh), jnp.float32)) \
+        if quant else {}
+    table = jnp.arange(1, 1 + b * maxp, dtype=jnp.int32).reshape(b, maxp)
+    cache = dict(k_pages=pool, v_pages=pool, page_table=table,
+                 lengths=jnp.asarray([3, 5], jnp.int32), **sc)
+    live = jnp.asarray([True, True])
+    if program == "decode_step":
+        def fn():
+            return gpt.decode_step(params, jnp.zeros(b, jnp.int32), cache,
+                                   live, cfg=cfg)
+    elif program == "verify_step":
+        def fn():
+            return gpt.verify_step(params, jnp.zeros((b, 3), jnp.int32),
+                                   jnp.asarray([2, 1], jnp.int32), cache,
+                                   live, cfg=cfg)
+    else:
+        def fn():
+            return gpt.prefill_chunk_step(
+                params, jnp.zeros(8, jnp.int32), jnp.int32(4), jnp.int32(6),
+                table[0], pool, pool, cfg=cfg, **sc)
+    set_flags({"tpu_paged_impl": "xla", "tpu_prefill_impl": "xla"})
+    try:
+        jaxpr = jax.make_jaxpr(fn)()
+    finally:
+        set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
+    layer_shapes = {(npages, ps, w) for w in (hd, nh)}
+
+    def walk(jp):
+        for eqn in jp.eqns:
+            for v in eqn.outvars:
+                shape = tuple(getattr(v.aval, "shape", ()))
+                yield eqn.primitive.name, shape[1:] if shape[:1] == (1,) \
+                    else shape
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from walk(sub)
+    seen = list(walk(jaxpr.jaxpr))
+    assert [e for e in seen if e[1] in layer_shapes] == []
+    # and the windows do come out of gathers over the whole stack
+    assert sum(name == "gather" for name, _ in seen) >= 2 * nl
 
 
 class TestDraftIndex:
@@ -606,8 +667,8 @@ class TestAutotuneDiskCache:
 
         monkeypatch.setattr(autotune, "_measure", fake_measure)
 
-        def run_impl(impl, q, k, v, pt, pos):
-            return _impl_call("xla", q, k, v, pt, pos)
+        def run_impl(impl, q, k, v, pt, pos, layer):
+            return _impl_call("xla", q, k, v, pt, pos, layer)
 
         win = autotune.paged_winner(1, 2, 2, 1, 2, "float32", run_impl)
         return win, len(calls), path
@@ -671,8 +732,8 @@ class TestAutotuneDiskCache:
         from paddle_tpu.kernels.paged_attention import _impl_call
         autotune.paged_winner(
             1, 2, 2, 1, 2, "float32",
-            lambda impl, q, k, v, pt, pos: _impl_call("xla", q, k, v,
-                                                      pt, pos))
+            lambda impl, q, k, v, pt, pos, layer: _impl_call(
+                "xla", q, k, v, pt, pos, layer))
         assert not list(tmp_path.iterdir())
         autotune.clear_cache()
 

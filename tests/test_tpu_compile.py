@@ -6,6 +6,8 @@
   cannot see what these see: a block shape that is not (8, 128)-tileable,
   an i64 index from a Python int under global x64, a DMA slice below one
   tile, too much VMEM. A compile that passes is not a chip run.
+- The engine's two hot step programs whole, at GPT-2 medium's serving
+  shapes: no copy of the KV pool is left in them.
 - ``chip_smoke.py``'s phases end to end at a toy size on the CPU, with the
   platform assertion steered from here.
 - The compile-cache placement rule and the "no backend at import" rule the
@@ -75,45 +77,205 @@ def test_flash_attention_compiles_for_v5e(chip, width, bwd):
         jax.grad(_sq(fn), argnums=(0, 1, 2)) if bwd else fn, q, q, q)
 
 
-def _pool(nh, dh, dtype, chip):
+LAYERS = 12             # of the stored pool the kernels are handed
+
+
+def _pool(nh, dh, kv, chip):
+    """Shapes of one pool and its scales, and the ``layer=`` to call with:
+    the stored stack ``[nl, P, page, nh*dh]`` read at its last layer, or
+    (``per-layer``) one layer's ``[P, page, nh, dh]`` with no layer, the
+    form the benchmark's selection probe passes."""
     pages = 1 + SLOTS * PAGES_PER_SLOT
-    return (jax.ShapeDtypeStruct((pages, PAGE, nh, dh), dtype, sharding=chip),
-            jax.ShapeDtypeStruct((pages, PAGE, nh), jnp.float32,
-                                 sharding=chip))
+    dtype = jnp.int8 if kv.startswith("int8") else BF16
+    if kv.endswith("per-layer"):
+        shape, lead, layer = (pages, PAGE, nh, dh), (), None
+    else:
+        shape, lead, layer = (LAYERS, pages, PAGE, nh * dh), (LAYERS,), \
+            LAYERS - 1
+    return (jax.ShapeDtypeStruct(shape, dtype, sharding=chip),
+            jax.ShapeDtypeStruct(lead + (pages, PAGE, nh), jnp.float32,
+                                 sharding=chip), layer)
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
+POOL_FORMS = ["bf16", "int8", "bf16-per-layer"]
+
+
+@pytest.mark.parametrize("kv", POOL_FORMS)
 @pytest.mark.parametrize("width", WIDTHS)
 def test_paged_decode_attention_compiles_for_v5e(chip, width, kv):
     from paddle_tpu.kernels.pallas.paged_attention import paged_attention
     nh, dh, _ = WIDTHS[width]
-    pool, scales = _pool(nh, dh, BF16 if kv == "bf16" else jnp.int8, chip)
+    pool, scales, layer = _pool(nh, dh, kv, chip)
     q = jax.ShapeDtypeStruct((SLOTS, nh, dh), BF16, sharding=chip)
     table = jax.ShapeDtypeStruct((SLOTS, PAGES_PER_SLOT), jnp.int32,
                                  sharding=chip)
     pos = jax.ShapeDtypeStruct((SLOTS,), jnp.int32, sharding=chip)
     _compiles_to_a_kernel(
         lambda q_, k_, v_, t_, p_, *s: paged_attention(
-            q_, k_, v_, t_, p_, interpret=False,
+            q_, k_, v_, t_, p_, layer=layer, interpret=False,
             **dict(zip(("k_scale", "v_scale"), s))),
         q, pool, pool, table, pos, *((scales, scales) if kv == "int8" else ()))
 
 
-@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("kv", POOL_FORMS)
 @pytest.mark.parametrize("width", WIDTHS)
 def test_ragged_prefill_attention_compiles_for_v5e(chip, width, kv):
     from paddle_tpu.kernels.pallas.prefill_attention import prefill_attention
     nh, dh, _ = WIDTHS[width]
-    pool, scales = _pool(nh, dh, BF16 if kv == "bf16" else jnp.int8, chip)
+    pool, scales, layer = _pool(nh, dh, kv, chip)
     q = jax.ShapeDtypeStruct((CHUNK, nh, dh), BF16, sharding=chip)
     row = jax.ShapeDtypeStruct((PAGES_PER_SLOT,), jnp.int32, sharding=chip)
     scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=chip)
     _compiles_to_a_kernel(
         lambda q_, k_, v_, t_, s_, n_, *sc: prefill_attention(
-            q_, k_, v_, t_, s_, n_, interpret=False,
+            q_, k_, v_, t_, s_, n_, layer=layer, interpret=False,
             **dict(zip(("k_scale", "v_scale"), sc))),
         q, pool, pool, row, scalar, scalar,
         *((scales, scales) if kv == "int8" else ()))
+
+
+# ------------------------- whole step programs: no copy of the pool left
+
+# gpt2-medium as benchmarks/configs/gpt2-medium.json serves it
+MEDIUM = dict(layers=24, heads=16, hidden=1024, vocab=50304, positions=1024,
+              slots=24, pages=1537, page=16, per_slot=64, chunk=256)
+
+
+def _medium_params(chip):
+    """Shapes of gpt2-medium's state_dict in bf16 (no array is made)."""
+    h, v = MEDIUM["hidden"], MEDIUM["vocab"]
+
+    def leaf(*shape):
+        return jax.ShapeDtypeStruct(shape, BF16, sharding=chip)
+    params = {"gpt.wte.weight": leaf(v, h),
+              "gpt.wpe.weight": leaf(MEDIUM["positions"], h),
+              "gpt.ln_f.weight": leaf(h), "gpt.ln_f.bias": leaf(h)}
+    for i in range(MEDIUM["layers"]):
+        for name, shape in [
+                ("ln_1", (h,)), ("ln_2", (h,)), ("attn.qkv_proj", (h, 3 * h)),
+                ("attn.out_proj", (h, h)), ("mlp.fc_in", (h, 4 * h)),
+                ("mlp.fc_out", (4 * h, h))]:
+            params[f"gpt.h.{i}.{name}.weight"] = leaf(*shape)
+            params[f"gpt.h.{i}.{name}.bias"] = leaf(shape[-1])
+    return params
+
+
+def pool_sized_ops(hlo_text, pool_elems):
+    """``[(opcode, name, shape)]`` of every materialised ``copy``,
+    ``slice``, ``transpose`` or ``fusion`` of the optimized HLO whose
+    output holds ``pool_elems`` elements or more — a layer pool relaid,
+    sliced out or viewed — other than the pool's in-place update (a fusion
+    whose root is the scatter). Instructions inside a fusion's body
+    materialise nothing and are not counted."""
+    import re
+    bodies, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None and " = " in line:
+            bodies[name].append(line)
+    fused = {m.group(1) for lines in bodies.values() for ln in lines
+             for m in [re.search(r" fusion\(.*calls=%([\w.\-]+)", ln)] if m}
+    found = []
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for ln in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.+?) "
+                         r"([a-z][a-z\-]*)\(", ln)
+            if not m or m.group(3) not in ("copy", "slice", "transpose",
+                                           "fusion"):
+                continue
+            sizes = [int(np.prod([int(d) for d in dims.split(",") if d]))
+                     for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2))]
+            if not sizes or max(sizes) < pool_elems:
+                continue
+            if m.group(3) == "fusion":
+                body = bodies[re.search(r"calls=%([\w.\-]+)", ln).group(1)]
+                if any(re.match(r"\s*ROOT %\S+ = .+? scatter\(", b)
+                       for b in body):
+                    continue                  # the in-place pool update
+            found.append((m.group(3), m.group(1), m.group(2)))
+    return found
+
+
+def test_pool_sized_ops_sees_a_relayout():
+    """The reader the next test rests on, on four lines of HLO: a copy and
+    a slice of a pool count, the scatter fusion and a fusion's inside do
+    not, nor does anything smaller than a layer pool."""
+    text = """\
+%fused_scatter (p: bf16[2,8,4,16]) -> bf16[2,8,4,16] {
+  %c = bf16[2,8,4,16]{3,2,1,0} copy(%p)
+  ROOT %s = bf16[2,8,4,16]{3,2,1,0:T(8,128)(2,1)} scatter(%c, %i, %u), to_apply=%r
+}
+ENTRY %main (a: bf16[2,8,4,16]) -> bf16[2,8,4,16] {
+  %copy.1 = bf16[2,8,4,2,8]{4,3,2,1,0:T(8,128)(2,1)} copy(%a)
+  %slice.2 = bf16[1,8,4,16]{3,2,1,0} slice(%a), slice={[0:1], [0:8]}
+  %copy.3 = bf16[8,16]{1,0} copy(%q)
+  ROOT %fusion.4 = bf16[2,8,4,16]{3,2,1,0} fusion(%a), kind=kLoop, calls=%fused_scatter
+}
+"""
+    assert [(op, name) for op, name, _ in pool_sized_ops(text, 8 * 4 * 16)] \
+        == [("copy", "copy.1"), ("slice", "slice.2")]
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
+                                                  monkeypatch):
+    """The engine's decode step and one prefill chunk, whole, at
+    gpt2-medium's serving shapes with the Pallas arms pinned, compiled for
+    the described chip with the pools donated: the optimized HLO holds no
+    copy, slice, transpose or fusion of a layer pool's size (1537 x 16 x
+    1024 elements) but the in-place update, and the compiler's temporaries
+    stay under one layer's pool (they were 7.3 GB: PERF.md, PR 26)."""
+    from paddle_tpu.framework.flags import set_flags
+    from paddle_tpu.kernels.pallas import _compat
+    from paddle_tpu.models import gpt
+    # the kernels ask the default backend, which is the CPU here
+    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
+    m = MEDIUM
+    cfg = gpt.GPTConfig(vocab_size=m["vocab"], hidden_size=m["hidden"],
+                        num_layers=m["layers"], num_heads=m["heads"],
+                        max_position_embeddings=m["positions"])
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
+    pool = jax.ShapeDtypeStruct(
+        (m["layers"], m["pages"], m["page"], m["hidden"]), BF16,
+        sharding=chip)
+    if program == "decode_step":
+        def step(params, kc, vc, ids, table, lengths, active):
+            cache = dict(k_pages=kc, v_pages=vc, page_table=table,
+                         lengths=lengths)
+            logits, cache = gpt.decode_step(params, ids, cache, active,
+                                            cfg=cfg)
+            return (jnp.argmax(logits, -1), cache["k_pages"],
+                    cache["v_pages"])
+        args = (ints(m["slots"]), ints(m["slots"], m["per_slot"]),
+                ints(m["slots"]),
+                jax.ShapeDtypeStruct((m["slots"],), jnp.bool_,
+                                     sharding=chip))
+    else:
+        def step(params, kc, vc, ids, start, valid, row):
+            logits, kc, vc = gpt.prefill_chunk_step(
+                params, ids, start, valid, row, kc, vc, cfg=cfg)
+            return jnp.argmax(logits, -1), kc, vc
+        args = (ints(m["chunk"]), ints(), ints(), ints(m["per_slot"]))
+    set_flags({"tpu_paged_impl": "pallas", "tpu_prefill_impl": "pallas"})
+    try:
+        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
+            _medium_params(chip), pool, pool, *args).compile()
+    finally:
+        set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") >= m["layers"]
+    layer_pool = m["pages"] * m["page"] * m["hidden"]
+    assert pool_sized_ops(text, layer_pool) == []
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 2 * layer_pool          # bf16 bytes
+    assert mem.alias_size_in_bytes >= 2 * 2 * m["layers"] * layer_pool
 
 
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "bwd"])
