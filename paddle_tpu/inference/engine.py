@@ -10,7 +10,19 @@ Python owns admission/retirement, the device runs fixed-shape steps:
 - **Paged KV cache** (arxiv 2604.15464): one fixed pool of token pages
   (`kernels/paged_attention.py`) shared by all slots; a host-side allocator
   hands pages to sequences at admission and reclaims them at retirement.
-- **Fixed-shape decode step**: every step runs `models.gpt.decode_step` on
+- **The model seam** (`inference/family.py`): the engine imports no model.
+  `model.engine_family()` supplies the step functions the programs trace
+  (`models/gpt.py`, `models/phi4flash.py`) and the kinds and shapes of
+  state a sequence keeps. Three kinds live in the cache manager: PAGED K/V
+  that grows (the pool below), WINDOW K/V (a ring of `window / page + 1`
+  pages a slot, constant in sequence length) and RECURRENT state (fixed
+  size a slot; read as zero by the chunk that starts a sequence, carried
+  from chunk to chunk and into decode). The last two ride every step
+  program after the pools, donated like them. For a model that keeps
+  them, whatever would restore a sequence from pages alone — prefix
+  reuse, speculation, hand-off, migration, tier spill — refuses by typed
+  error (docs/SERVING.md "The model seam").
+- **Fixed-shape decode step**: every step runs the family's `decode_step` on
   all `max_slots` slots — active or not — in ONE device call. Slot churn
   only changes the *contents* of the page table / active mask, never a
   shape, so after warmup there are ZERO recompiles (continuous batching;
@@ -110,7 +122,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.inference.errors import (Cancelled, DeadlineExceeded,
                                          HandoffCorrupt, Overloaded,
+                                         RecurrentStateUnsupported,
                                          from_wire)
+from paddle_tpu.inference.family import family_of
 from paddle_tpu.kernels.paged_attention import TRASH_PAGE
 from paddle_tpu.observability import metrics
 from paddle_tpu.observability.flight_recorder import (Watchdog,
@@ -820,7 +834,8 @@ def unpack_migration(buf: bytes) -> MigrationItem:
 
 
 class DecodeEngine:
-    """Continuous-batching decode over a paged KV cache for one GPT model.
+    """Continuous-batching decode over a paged KV cache for one model of a
+    family that declares itself through ``model.engine_family()``.
 
     >>> eng = DecodeEngine(model)                    # snapshots the weights
     >>> reqs = [eng.submit(ids, max_new_tokens=32) for ids in prompts]
@@ -832,19 +847,23 @@ class DecodeEngine:
         ecfg = engine_config or EngineConfig()
         self.cfg = model.cfg
         self.ecfg = ecfg
-        state = model.state_dict()
-        self._params = {k: t._data for k, t in state.items()}
-        self._cdtype = self._params["gpt.wte.weight"].dtype
-        nh = self.cfg.num_heads
-        self._nh, self._dh = nh, self.cfg.hidden_size // nh
-        self._nl = self.cfg.num_layers
+        # the model seam (inference/family.py): the model object says which
+        # step functions the programs trace and what a sequence keeps
+        fam = self._fam = family_of(model)
+        self._steps = fam.steps
+        self._stateful = fam.state is not None
+        self._params = fam.params(model)
+        self._cdtype = self._served_dtype = \
+            self._params[fam.table_key].dtype
+        nh = fam.kv_heads
+        self._nh, self._dh = nh, fam.head_dim
+        self._nl = fam.kv_layers
+        self._refuse_stateful_config(ecfg)
         if ecfg.weight_dtype not in ("native", None):
             # matmul leaves -> int8 + per-channel scales, dequantized at
             # use inside the same AOT programs (quantization/serving.py);
             # the conversion wall lands in engine.quant_dequant_ms
-            from paddle_tpu.quantization.serving import quantize_gpt_params
-            self._params = quantize_gpt_params(self._params,
-                                               ecfg.weight_dtype)
+            self._params = self._quantized(self._params)
         kvd = ecfg.kv_dtype
         if kvd not in ("native", None):
             from paddle_tpu.kernels.paged_attention import KV_DTYPES
@@ -856,8 +875,8 @@ class DecodeEngine:
         self._quant_kv = kvd == "int8"
 
         ps = ecfg.page_size
-        max_seq = ecfg.max_seq_len or self.cfg.max_position_embeddings
-        max_seq = min(max_seq, self.cfg.max_position_embeddings)
+        max_seq = ecfg.max_seq_len or fam.max_positions
+        max_seq = min(max_seq, fam.max_positions)
         self.max_seq_len = max_seq
         self.pages_per_slot = -(-max_seq // ps)           # ceil
         self.slot_capacity = self.pages_per_slot * ps     # tokens per slot
@@ -891,6 +910,30 @@ class DecodeEngine:
             + (nh * 4 if self._quant_kv else 0))
         metrics.gauge("engine.kv_bytes_per_token").set(
             self.kv_bytes_per_token)
+        # state beside the page pool (a family that keeps any): window K/V
+        # rings and recurrent state, per slot, threaded through every step
+        # program after the pools and donated like them
+        self._state: tuple = ()
+        self._state_kinds: tuple = ()
+        if self._stateful:
+            specs = fam.state(B, ps, self._served_dtype)
+            self._state = tuple(jnp.zeros(shape, dtype)
+                                for _, _, shape, dtype in specs)
+            self._state_kinds = tuple(kind for _, kind, _, _ in specs)
+        self._window_pages = -(-fam.window_tokens // ps) + 1 \
+            if fam.window_tokens else 0
+
+        def nbytes(kind):
+            return sum(int(a.nbytes) for a, k in zip(self._state,
+                                                     self._state_kinds)
+                       if k == kind)
+        metrics.gauge("engine.cache_bytes.paged").set(
+            int(self._kc.nbytes) * 2 + (int(self._ks.nbytes) * 2
+                                        if self._quant_kv else 0))
+        metrics.gauge("engine.cache_bytes.window").set(nbytes("window"))
+        metrics.gauge("engine.cache_bytes.state").set(nbytes("recurrent"))
+        metrics.gauge("engine.state_bytes_per_slot").set(
+            nbytes("recurrent") // B)
         # published for the router's fleet prefix directory: affinity
         # hashing needs the fleet's page size (docs/SERVING.md
         # "Disaggregated serving")
@@ -1015,6 +1058,10 @@ class DecodeEngine:
         self._m_chunks = metrics.counter("engine.prefill_chunks")
         self._m_prefill_launches = metrics.counter("engine.prefill_launches")
         self._m_prefill_tokens = metrics.counter("engine.prefill_tokens")
+        self._m_win_recycled = metrics.counter(
+            "engine.window_pages_recycled")
+        self._m_state_resets = metrics.counter("engine.state_resets")
+        self._m_state_carries = metrics.counter("engine.state_carries")
         self._m_prefix_hit = metrics.counter("engine.prefix_hit")
         self._m_prefix_miss = metrics.counter("engine.prefix_miss")
         self._m_prefix_reused = metrics.counter("engine.prefix_pages_reused")
@@ -1056,6 +1103,44 @@ class DecodeEngine:
         self._h_step = metrics.histogram("engine.step_seconds")
         self._h_prefill = metrics.histogram("engine.prefill_seconds")
 
+    # ------------------------------------------------------- the model seam
+
+    def _quantized(self, params):
+        if self._fam.quantize is None:
+            raise ValueError(
+                f"weight_dtype={self.ecfg.weight_dtype!r}: the "
+                f"{self._fam.name} family supplies no weight quantizer")
+        return self._fam.quantize(params, self.ecfg.weight_dtype)
+
+    def _refuse_stateful(self, what: str):
+        """A model that keeps window or recurrent state beside the page
+        pool cannot be restored from pages alone: whatever would rebuild a
+        sequence from them refuses, by name, instead of serving from half
+        of its state (docs/SERVING.md "What refuses")."""
+        if self._stateful:
+            raise RecurrentStateUnsupported(
+                f"{what}: the {self._fam.name} family keeps window and "
+                "recurrent state per sequence beside the page pool; pages "
+                "alone cannot restore a sequence")
+
+    def _refuse_stateful_config(self, ecfg: EngineConfig):
+        if not self._stateful:
+            return
+        if ecfg.prefix_cache:
+            self._refuse_stateful("EngineConfig.prefix_cache=True (prefix "
+                                  "reuse; pass prefix_cache=False)")
+        if ecfg.speculate_k is not None:
+            self._refuse_stateful("EngineConfig.speculate_k (speculation "
+                                  "rolls rejected tokens back by length "
+                                  "alone)")
+        if ecfg.kv_host_tier_bytes or ecfg.kv_disk_tier_bytes:
+            self._refuse_stateful("EngineConfig.kv_*_tier_bytes (tier "
+                                  "spill)")
+        if ecfg.kv_dtype == "int8":
+            raise ValueError(
+                f"kv_dtype='int8': the {self._fam.name} family's step "
+                "functions take no scale pools")
+
     # ------------------------------------------------------------- programs
 
     def _compiled(self, key, build):
@@ -1075,9 +1160,9 @@ class DecodeEngine:
         return exe
 
     def _decode_exe(self):
-        from paddle_tpu.models import gpt as gpt_mod
         from paddle_tpu.framework.flags import flag_value
-        cfg = self.cfg
+        cfg, steps = self.cfg, self._steps
+        n_scales = 2 if self._quant_kv else 0
         B, maxp = self.ecfg.max_slots, self.pages_per_slot
         # the paged-attention impl is baked into the traced program, so the
         # flag is part of the cache key — flipping it compiles a new decode
@@ -1098,10 +1183,11 @@ class DecodeEngine:
             # buffer rides between `tokens` and the upload — tokens AND
             # keys stay on device step to step.
             if sampling:
-                keys, slot_state, *scales = rest
+                keys, slot_state, *tail = rest
             else:
                 keys = None
-                slot_state, *scales = rest
+                slot_state, *tail = rest
+            scales, state = tail[:n_scales], tail[n_scales:]
             flags = slot_state[:, _COL_FLAGS]
             active = (flags & _FLAG_ACTIVE) != 0
             fresh = (flags & _FLAG_FRESH) != 0
@@ -1113,8 +1199,10 @@ class DecodeEngine:
                          lengths=slot_state[:, _COL_LENGTH])
             if scales:
                 cache.update(k_scale=scales[0], v_scale=scales[1])
-            logits, cache = gpt_mod.decode_step(params, toks, cache,
-                                                active, cfg=cfg)
+            if state:
+                cache.update(state=tuple(state))
+            logits, cache = steps.decode_step(params, toks, cache,
+                                              active, cfg=cfg)
             if sampling:
                 from paddle_tpu.kernels.sampling import fused_sample
                 temps = jax.lax.bitcast_convert_type(
@@ -1132,21 +1220,21 @@ class DecodeEngine:
                 out = (nxt, cache["k_pages"], cache["v_pages"])
             if scales:
                 out += (cache["k_scale"], cache["v_scale"])
+            if state:
+                out += tuple(cache["state"])
             return out
 
         def build():
             if sampling:
-                donate = ((1, 2, 4) + ((6, 7) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc,
                         jnp.zeros(B, jnp.int32), self._keys_dev,
                         jnp.zeros((B, _STATE_COLS + maxp + 2), jnp.int32)]
+                donate = self._donated(args, 1, 2, 4)
             else:
-                donate = ((1, 2) + ((5, 6) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc,
                         jnp.zeros(B, jnp.int32),
                         jnp.zeros((B, _STATE_COLS + maxp), jnp.int32)]
+                donate = self._donated(args, 1, 2)
             args += self._scale_args()
             return jax.jit(step_fn, donate_argnums=donate).lower(
                 *args).compile()
@@ -1154,7 +1242,20 @@ class DecodeEngine:
         return self._compiled(("decode", impl_flag), build)
 
     def _scale_args(self):
-        return [self._ks, self._vs] if self._quant_kv else []
+        """What every step program takes after its upload: the int8 pool's
+        scale pools, then the family's state arrays (window rings,
+        recurrent state) — all of them returned updated and donated."""
+        return ([self._ks, self._vs] if self._quant_kv else []) \
+            + list(self._state)
+
+    def _donated(self, lead_args, *lead):
+        """donate_argnums of a step program: the ``lead`` positions (pools,
+        on-device token/key chains) and every `_scale_args` position, which
+        follow ``lead_args``."""
+        if not self._donate:
+            return ()
+        n = len(lead_args)
+        return tuple(lead) + tuple(range(n, n + len(self._scale_args())))
 
     def _export_pages(self, pages):
         """The listed pages' contents off the device, as numpy ``(k, v,
@@ -1173,17 +1274,20 @@ class DecodeEngine:
         adopting the pools in place. The ONE place the output pytree's
         pool tail is interpreted: a future pool (fp8, paged metadata)
         extends this and every invocation site follows."""
+        pools = out[n_lead:]
         if self._quant_kv:
-            self._kc, self._vc, self._ks, self._vs = out[n_lead:]
+            self._kc, self._vc, self._ks, self._vs = pools[:4]
         else:
-            self._kc, self._vc = out[n_lead:]
+            self._kc, self._vc = pools[:2]
+        self._state = tuple(pools[4 if self._quant_kv else 2:])
         return out[0] if n_lead == 1 else out[:n_lead]
 
     def _prefill_exe(self, bucket: int):
-        from paddle_tpu.models import gpt as gpt_mod
         from paddle_tpu.framework.flags import flag_value
-        cfg = self.cfg
+        cfg, steps = self.cfg, self._steps
         maxp = self.pages_per_slot
+        n_scales = 2 if self._quant_kv else 0
+        x_slot = 1 if self._stateful else 0    # trailing slot index
         # the prefill-attention impl is baked into the traced program
         # (kernels/registry.py) — the flag keys the cache like
         # tpu_paged_impl keys the decode program
@@ -1201,20 +1305,23 @@ class DecodeEngine:
             # slotless export/stream prefills) — no key readback, the
             # decode step picks the chain up where prefill left it.
             if sampling:
-                keys, packed, *scales = rest
+                keys, packed, *tail = rest
             else:
                 keys = None
-                packed, *scales = rest
+                packed, *tail = rest
+            scales, state = tail[:n_scales], tail[n_scales:]
             ids = packed[:bucket]
             length = packed[bucket]
             row = packed[bucket + 1:bucket + 1 + maxp]
+            kw = {}
             if scales:
-                logits, kc, vc, ks, vs = gpt_mod.prefill_step(
-                    params, ids, length, row, kc, vc, cfg=cfg,
-                    k_scale=scales[0], v_scale=scales[1])
-            else:
-                logits, kc, vc = gpt_mod.prefill_step(
-                    params, ids, length, row, kc, vc, cfg=cfg)
+                kw.update(k_scale=scales[0], v_scale=scales[1])
+            if state:
+                # a family with state: the packed upload's LAST int is the
+                # slot whose rings and recurrent state this prompt fills
+                kw.update(state=tuple(state), slot=packed[-1])
+            logits, kc, vc, *more = steps.prefill_step(
+                params, ids, length, row, kc, vc, cfg=cfg, **kw)
             if sampling:
                 from paddle_tpu.kernels.sampling import sample_one
                 tail = packed[bucket + 1 + maxp:]
@@ -1227,21 +1334,18 @@ class DecodeEngine:
             else:
                 tok = jnp.argmax(logits, axis=-1).astype(ids.dtype)
                 out = (tok, kc, vc)
-            if scales:
-                out += (ks, vs)
-            return out
+            return out + tuple(more)
 
         def build():
             if sampling:
-                donate = ((1, 2, 3) + ((5, 6) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc, self._keys_dev,
-                        jnp.zeros(bucket + 1 + maxp + 5, jnp.int32)]
+                        jnp.zeros(bucket + 1 + maxp + 5 + x_slot,
+                                  jnp.int32)]
+                donate = self._donated(args, 1, 2, 3)
             else:
-                donate = ((1, 2) + ((4, 5) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc,
-                        jnp.zeros(bucket + 1 + maxp, jnp.int32)]
+                        jnp.zeros(bucket + 1 + maxp + x_slot, jnp.int32)]
+                donate = self._donated(args, 1, 2)
             args += self._scale_args()
             return jax.jit(prefill_fn, donate_argnums=donate).lower(
                 *args).compile()
@@ -1254,10 +1358,11 @@ class DecodeEngine:
         prefix-cache TAIL prefill (c = the tail's pow-2 bucket) — both are
         'prefill a window starting at an absolute position', which is
         exactly `prefill_chunk_step`'s contract."""
-        from paddle_tpu.models import gpt as gpt_mod
         from paddle_tpu.framework.flags import flag_value
-        cfg = self.cfg
+        cfg, steps = self.cfg, self._steps
         maxp = self.pages_per_slot
+        n_scales = 2 if self._quant_kv else 0
+        x_slot = 1 if self._stateful else 0    # trailing slot index
         c = int(self.ecfg.prefill_chunk_tokens) if c is None else int(c)
         impl_flag = flag_value("tpu_prefill_impl")   # keys the cache (see
         #                                              _prefill_exe)
@@ -1273,21 +1378,22 @@ class DecodeEngine:
             # chunks leave tok at the argmax arm and the chain untouched,
             # so the chain advances exactly once per emitted token.
             if sampling:
-                keys, packed, *scales = rest
+                keys, packed, *tail = rest
             else:
                 keys = None
-                packed, *scales = rest
+                packed, *tail = rest
+            scales, state = tail[:n_scales], tail[n_scales:]
             ids = packed[:c]
             start = packed[c]
             valid = packed[c + 1]
             row = packed[c + 2:c + 2 + maxp]
+            kw = {}
             if scales:
-                logits, kc, vc, ks, vs = gpt_mod.prefill_chunk_step(
-                    params, ids, start, valid, row, kc, vc, cfg=cfg,
-                    k_scale=scales[0], v_scale=scales[1])
-            else:
-                logits, kc, vc = gpt_mod.prefill_chunk_step(
-                    params, ids, start, valid, row, kc, vc, cfg=cfg)
+                kw.update(k_scale=scales[0], v_scale=scales[1])
+            if state:
+                kw.update(state=tuple(state), slot=packed[-1])
+            logits, kc, vc, *more = steps.prefill_chunk_step(
+                params, ids, start, valid, row, kc, vc, cfg=cfg, **kw)
             if sampling:
                 from paddle_tpu.kernels.sampling import sample_one
                 tail = packed[c + 2 + maxp:]
@@ -1305,21 +1411,17 @@ class DecodeEngine:
             else:
                 tok = jnp.argmax(logits, axis=-1).astype(ids.dtype)
                 out = (tok, kc, vc)
-            if scales:
-                out += (ks, vs)
-            return out
+            return out + tuple(more)
 
         def build():
             if sampling:
-                donate = ((1, 2, 3) + ((5, 6) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc, self._keys_dev,
-                        jnp.zeros(c + 2 + maxp + 6, jnp.int32)]
+                        jnp.zeros(c + 2 + maxp + 6 + x_slot, jnp.int32)]
+                donate = self._donated(args, 1, 2, 3)
             else:
-                donate = ((1, 2) + ((4, 5) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc,
-                        jnp.zeros(c + 2 + maxp, jnp.int32)]
+                        jnp.zeros(c + 2 + maxp + x_slot, jnp.int32)]
+                donate = self._donated(args, 1, 2)
             args += self._scale_args()
             return jax.jit(chunk_fn, donate_argnums=donate).lower(
                 *args).compile()
@@ -1330,8 +1432,7 @@ class DecodeEngine:
         """The speculative k-token verify step: ONE AOT program regardless
         of which slots drafted how much — draft contents and draft_len ride
         the packed upload, never a shape (tests/test_no_retrace.py)."""
-        from paddle_tpu.models import gpt as gpt_mod
-        cfg = self.cfg
+        cfg, steps = self.cfg, self._steps
         B, maxp = self.ecfg.max_slots, self.pages_per_slot
         K = self._spec_k
 
@@ -1367,12 +1468,12 @@ class DecodeEngine:
                 temps = jax.lax.bitcast_convert_type(
                     slot_state[:, _SPEC_COLS + K + maxp], jnp.float32)
                 topks = slot_state[:, _SPEC_COLS + K + maxp + 1]
-                emitted, n_emitted, cache, new_keys = gpt_mod.verify_step(
+                emitted, n_emitted, cache, new_keys = steps.verify_step(
                     params, tok_seq, draft_len, cache, active, cfg=cfg,
                     sample_state=(keys[:B], temps, topks))
                 keys = keys.at[:B].set(new_keys)
             else:
-                emitted, n_emitted, cache = gpt_mod.verify_step(
+                emitted, n_emitted, cache = steps.verify_step(
                     params, tok_seq, draft_len, cache, active, cfg=cfg)
             nxt = jnp.take_along_axis(
                 emitted, jnp.maximum(n_emitted - 1, 0)[:, None], axis=1)[:, 0]
@@ -1386,18 +1487,16 @@ class DecodeEngine:
 
         def build():
             if sampling:
-                donate = ((1, 2, 4) + ((6, 7) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc,
                         jnp.zeros(B, jnp.int32), self._keys_dev,
                         jnp.zeros((B, _SPEC_COLS + K + maxp + 2),
                                   jnp.int32)]
+                donate = self._donated(args, 1, 2, 4)
             else:
-                donate = ((1, 2) + ((5, 6) if self._quant_kv else ())) \
-                    if self._donate else ()
                 args = [self._params, self._kc, self._vc,
                         jnp.zeros(B, jnp.int32),
                         jnp.zeros((B, _SPEC_COLS + K + maxp), jnp.int32)]
+                donate = self._donated(args, 1, 2)
             args += self._scale_args()
             return jax.jit(step_fn, donate_argnums=donate).lower(
                 *args).compile()
@@ -1412,7 +1511,7 @@ class DecodeEngine:
         """Next power-of-two >= prompt_len (floor min_bucket, capped at the
         position table so wpe[:bucket] stays in range)."""
         b = max(self.ecfg.min_bucket, 1 << max(0, prompt_len - 1).bit_length())
-        return min(b, self.cfg.max_position_embeddings)
+        return min(b, self._fam.max_positions)
 
     def warmup(self, prompt_lens=(1,), tail_lens=()):
         """Compile the decode/verify step + the prefill programs (buckets
@@ -1445,14 +1544,12 @@ class DecodeEngine:
         spill tiers included: cached OR spilled pages hold KV computed
         under the old weights, and a hit (or tier re-upload) after the
         swap would silently condition new-weights decode on stale KV."""
-        self._params = {k: t._data for k, t in model.state_dict().items()}
+        self._params = self._fam.params(model)
         if self.ecfg.weight_dtype not in ("native", None):
             # re-quantize: a QuantizedLeaf is part of the traced pytree
             # STRUCTURE, so the swapped-in params must keep it or the next
             # warm call would be a structure mismatch, not a hot swap
-            from paddle_tpu.quantization.serving import quantize_gpt_params
-            self._params = quantize_gpt_params(self._params,
-                                               self.ecfg.weight_dtype)
+            self._params = self._quantized(self._params)
         self._flush_prefix()
 
     # --------------------------------------------------------- prefix cache
@@ -2167,6 +2264,10 @@ class DecodeEngine:
         if self._sampling:
             self._temps[slot] = req.temperature
             self._topks[slot] = req.top_k
+        if self._stateful:
+            # the slot's rings and recurrent state are another sequence's:
+            # the chunk that starts at 0 reads them as empty
+            self._m_state_resets.inc()
         if self._use_chunked(req.prompt.size - cached):
             # decode-priority chunked prefill: the slot holds its pages but
             # stays decode-inactive; step() runs ONE chunk per step after
@@ -2240,12 +2341,17 @@ class DecodeEngine:
                               request_id=req and req.request_id):
                 bucket = self.bucket_for(s0)
                 x = 5 if self._sampling else 0
-                packed = np.zeros(bucket + 1 + maxp + x, np.int32)
+                packed = np.zeros(bucket + 1 + maxp + x
+                                  + (1 if self._stateful else 0), np.int32)
                 packed[:s0] = ids
                 packed[bucket] = s0
                 packed[bucket + 1:bucket + 1 + maxp] = row
                 if self._sampling:
-                    packed[bucket + 1 + maxp:] = self._sample_tail(slot, req)
+                    packed[bucket + 1 + maxp:bucket + 1 + maxp + x] = \
+                        self._sample_tail(slot, req)
+                if self._stateful:
+                    packed[-1] = slot
+                    self._count_window_pages(0, s0)
                 exe = self._prefill_exe(bucket)
                 self._m_h2d.inc()
                 self._m_prefill_launches.inc()
@@ -2262,6 +2368,16 @@ class DecodeEngine:
                         exe(self._params, self._kc, self._vc,
                             jax.device_put(packed), *self._scale_args()))
         return self._read_first_token(tok)
+
+    def _count_window_pages(self, lo: int, hi: int):
+        """`engine.window_pages_recycled` for positions ``lo .. hi - 1`` of
+        one slot: each page opened past the ring's capacity reuses a page
+        the window slid out of."""
+        if not self._window_pages:
+            return
+        ps = self.ecfg.page_size
+        first = max(-(-lo // ps), self._window_pages)
+        self._m_win_recycled.inc(max(0, -(-hi // ps) - first))
 
     def _read_first_token(self, tok) -> int:
         """A prefill's only readback: block on its sampled first token."""
@@ -2286,18 +2402,25 @@ class DecodeEngine:
         ``tail`` (run to the end inside admission by `_run_prefill`)."""
         c = int(self.ecfg.prefill_chunk_tokens) if c is None else int(c)
         chunk = ids[done:done + c]
+        carried = {"state_carried": done > 0} if self._stateful else {}
         with metrics.span("engine.prefill_launch", cat="engine", kind=kind,
                           tokens=int(chunk.size),
-                          request_id=req and req.request_id):
+                          request_id=req and req.request_id, **carried):
             x = 6 if self._sampling else 0
-            packed = np.zeros(c + 2 + self.pages_per_slot + x, np.int32)
+            n = c + 2 + self.pages_per_slot
+            packed = np.zeros(n + x + (1 if self._stateful else 0),
+                              np.int32)
             packed[:chunk.size] = chunk
             packed[c] = done
             packed[c + 1] = chunk.size
-            packed[c + 2:c + 2 + self.pages_per_slot] = row
+            packed[c + 2:n] = row
             if self._sampling:
-                packed[c + 2 + self.pages_per_slot:] = \
-                    self._sample_tail(slot, req, final=final)
+                packed[n:n + x] = self._sample_tail(slot, req, final=final)
+            if self._stateful:
+                packed[-1] = slot
+                if done > 0:
+                    self._m_state_carries.inc()
+                self._count_window_pages(done, done + int(chunk.size))
             exe = self._prefill_chunk_exe(c)
             self._m_h2d.inc()
             self._m_prefill_launches.inc()
@@ -2449,6 +2572,15 @@ class DecodeEngine:
             # host bookkeeping for the step just enqueued: each active slot
             # advances one position; a slot at its token budget stops being
             # dispatched but stays occupied until its tokens are harvested
+            if self._window_pages:
+                # a ring wraps onto a page it already used: one recycled
+                # for each active slot whose new token opens a page past
+                # the ring's capacity
+                self._m_win_recycled.inc(int(np.count_nonzero(
+                    self._active
+                    & (self._lengths % self.ecfg.page_size == 0)
+                    & (self._lengths >= self._window_pages
+                       * self.ecfg.page_size))))
             self._lengths[self._active] += 1
             self._budget[self._active] -= 1
             self._fresh[:] = False
@@ -2706,6 +2838,7 @@ class DecodeEngine:
         — the prefill half of prefill/decode disaggregation. Pages are
         borrowed from the pool for the duration of the call and freed
         before returning. Driver-thread only (runs device programs)."""
+        self._refuse_stateful("prefill_export (KV hand-off)")
         ids = np.asarray(
             prompt_ids._data if hasattr(prompt_ids, "_data") else prompt_ids)
         ids = np.ascontiguousarray(ids).reshape(-1).astype(np.int32)
@@ -2792,6 +2925,7 @@ class DecodeEngine:
         (docs/OBSERVABILITY.md "Fleet tracing"): it rides the PTKS1
         header so the decode side joins the same stitched trace, and the
         prefill wall lands as a span in this process's trace ring."""
+        self._refuse_stateful("submit_prefill_stream (KV hand-off)")
         ids = np.asarray(
             prompt_ids._data if hasattr(prompt_ids, "_data") else prompt_ids)
         ids = np.ascontiguousarray(ids).reshape(-1).astype(np.int32)
@@ -2942,6 +3076,7 @@ class DecodeEngine:
         queueing. Pass the ORIGINATING request's ``trace`` to keep SLO
         accounting honest across the transfer — with the default fresh
         trace, TTFT on this engine measures only the import itself."""
+        self._refuse_stateful("import_request (KV hand-off)")
         req = self._build_import_request(handoff, max_new_tokens,
                                          trace=trace, cache=cache,
                                          speculate=speculate)
@@ -3109,6 +3244,7 @@ class DecodeEngine:
         (tests/test_no_retrace.py). Unlike `import_request`, a full
         engine DEFERS the placement to a later step instead of raising;
         an engine that could never fit it answers a typed error."""
+        self._refuse_stateful("submit_import (migration)")
         # double-checked like submit(): fail a draining/dead engine fast,
         # BEFORE the O(context) blake2b pass in _build_import_request —
         # the drain fallback chain probes peers exactly when that pass
@@ -3395,6 +3531,8 @@ class DecodeEngine:
         layer, which ships them to a peer and answers the original
         futures. Scale-down then costs one step + the transfer, not the
         longest running generation."""
+        if migrate:
+            self._refuse_stateful("drain(migrate=True) (migration)")
         with self._work:
             self._draining = True
             if migrate:

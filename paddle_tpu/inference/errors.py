@@ -16,7 +16,7 @@ the classes and the two conversions:
   ``f"{type(e).__name__}: {e}"`` round-trips: the client's `from_wire`
   reconstructs the same type.
 
-All three subclass `RuntimeError`, so pre-existing ``except RuntimeError``
+All of them subclass `RuntimeError`, so pre-existing ``except RuntimeError``
 / ``pytest.raises(RuntimeError)`` call sites keep working unchanged.
 
 The router classifies these by name (`serving/router.py`):
@@ -28,7 +28,7 @@ doing — another replica would change neither).
 from __future__ import annotations
 
 __all__ = ["DeadlineExceeded", "Cancelled", "Overloaded", "HandoffCorrupt",
-           "from_wire"]
+           "RecurrentStateUnsupported", "from_wire"]
 
 
 class DeadlineExceeded(RuntimeError):
@@ -58,8 +58,18 @@ class HandoffCorrupt(RuntimeError):
     to re-ship from the source — nothing about the request is wrong."""
 
 
+class RecurrentStateUnsupported(RuntimeError):
+    """The served model keeps per-sequence state beside the page pool
+    (recurrent state, window K/V), and the operation would rebuild or move
+    a sequence from pages alone: prefix reuse, speculation, KV hand-off,
+    live migration, tier spill. Refused at configuration or call time —
+    never served from half of a sequence's state (docs/SERVING.md "What
+    refuses"). Retrying elsewhere does not help: it is the model's."""
+
+
 _BY_NAME = {c.__name__: c for c in (DeadlineExceeded, Cancelled,
-                                    Overloaded, HandoffCorrupt)}
+                                    Overloaded, HandoffCorrupt,
+                                    RecurrentStateUnsupported)}
 
 
 def from_wire(msg: str) -> Exception:

@@ -1,0 +1,69 @@
+"""The engine's model seam: what `DecodeEngine` takes from a model family.
+
+`DecodeEngine` is a scheduler and a cache manager; which model it steps is
+not its business. A model object hands it one :class:`ModelFamily` through
+``model.engine_family()``: the pure step functions the AOT programs trace,
+where the parameters are, and the kinds and shapes of state a sequence
+keeps. There is no flag and no `EngineConfig` field that picks a model —
+the model object decides. `models/gpt.py` and `models/phi4flash.py` each
+supply one; the GPT family describes exactly what the engine used to
+import, so its programs trace as before.
+
+The step functions' contracts (``steps`` is any namespace that has them):
+
+- ``decode_step(params, ids, cache, slot_mask, *, cfg)`` -> ``(logits
+  [B, V] f32, cache)``; ``cache`` holds ``k_pages``, ``v_pages``,
+  ``page_table``, ``lengths``, ``k_scale`` / ``v_scale`` on an int8 pool,
+  and ``state`` (a tuple, in ``state(...)``'s order) for a family that
+  keeps any;
+- ``prefill_step(params, ids, length, row, k_pages, v_pages, *, cfg, ...)``
+  and ``prefill_chunk_step(params, ids, start, valid, row, k_pages,
+  v_pages, *, cfg, ...)`` -> ``(logits [V] f32, k_pages, v_pages, ...)``;
+  a family with state also takes ``state=`` and ``slot=`` and returns the
+  state arrays after the pools;
+- ``verify_step`` (optional): the speculative k-token step. A family
+  without one cannot speculate, and the engine refuses ``speculate_k``.
+
+State beside the page pool is described by ``state(slots, page_size,
+dtype)`` -> ``((name, kind, shape, dtype), ...)`` with ``kind`` one of
+``"window"`` (K and V of a bounded window: constant in sequence length) or
+``"recurrent"`` (fixed-size state, read as zero by the chunk that starts a
+sequence and carried from chunk to chunk). The engine allocates the
+arrays, threads them through every step program donated like the pools,
+and — because pages alone then cannot restore a sequence — refuses prefix
+reuse, speculation, hand-off, migration and tier spill by typed error
+(`errors.RecurrentStateUnsupported`; docs/SERVING.md "The model seam").
+"""
+from __future__ import annotations
+
+from dataclasses import dataclass
+from typing import Any, Callable
+
+__all__ = ["ModelFamily", "family_of"]
+
+
+@dataclass(frozen=True)
+class ModelFamily:
+    name: str
+    steps: Any                      # namespace of the step functions
+    params: Callable[[Any], dict]   # model -> {leaf name: device array}
+    table_key: str                  # the leaf whose dtype is the served one
+    kv_layers: int                  # layers that own rows of the page pool
+    kv_heads: int
+    head_dim: int
+    max_positions: int              # longest sequence the model can see
+    state: Callable | None = None   # (slots, page, dtype) -> specs, or None
+    window_tokens: int = 0          # window of the ``window`` state, if any
+    quantize: Callable | None = None  # (params, weight_dtype) -> params
+
+
+def family_of(model) -> ModelFamily:
+    """The family ``model`` declares; a model that declares none cannot be
+    served by `DecodeEngine`."""
+    get = getattr(model, "engine_family", None)
+    if get is None:
+        raise TypeError(
+            f"{type(model).__name__} has no engine_family(): DecodeEngine "
+            "serves models that supply their step functions and state "
+            "description (inference/family.py)")
+    return get()

@@ -924,6 +924,27 @@ class GPTForCausalLM(nn.Layer):
         self.cfg = cfg
         self.gpt = GPTModel(cfg)
 
+    def engine_family(self):
+        """What `DecodeEngine` takes from this model (inference/family.py):
+        this module's step functions over the state_dict's arrays, one
+        page-pool row per layer, and no state beside the pool."""
+        import sys
+        from paddle_tpu.inference.family import ModelFamily
+        cfg = self.cfg
+
+        def quantize(params, weight_dtype):
+            from paddle_tpu.quantization.serving import quantize_gpt_params
+            return quantize_gpt_params(params, weight_dtype)
+        return ModelFamily(
+            name="gpt", steps=sys.modules[__name__],
+            params=lambda m: {k: t._data
+                              for k, t in m.state_dict().items()},
+            table_key="gpt.wte.weight", kv_layers=cfg.num_layers,
+            kv_heads=cfg.num_heads,
+            head_dim=cfg.hidden_size // cfg.num_heads,
+            max_positions=cfg.max_position_embeddings,
+            quantize=quantize)
+
     def forward(self, input_ids, labels=None, loss_mask=None):
         h = self.gpt(input_ids)
         # tied lm head: logits = h @ wte^T (vocab-sharded over mp like the

@@ -7,7 +7,9 @@
   an i64 index from a Python int under global x64, a DMA slice below one
   tile, too much VMEM. A compile that passes is not a chip run.
 - The engine's two hot step programs whole, at GPT-2 medium's serving
-  shapes: no copy of the KV pool is left in them.
+  shapes: no copy of the KV pool is left in them. The same for
+  Phi-4-mini-flash's decode step and prefill chunk at its published widths
+  and full depth: no copy of a pool or of a state stack.
 - ``chip_smoke.py``'s phases end to end at a toy size on the CPU, with the
   platform assertion steered from here.
 - The compile-cache placement rule and the "no backend at import" rule the
@@ -160,13 +162,14 @@ def _medium_params(chip):
     return params
 
 
-def pool_sized_ops(hlo_text, pool_elems):
+def pool_sized_ops(hlo_text, pool_elems, in_place=("scatter",)):
     """``[(opcode, name, shape)]`` of every materialised ``copy``,
     ``slice``, ``transpose`` or ``fusion`` of the optimized HLO whose
     output holds ``pool_elems`` elements or more — a layer pool relaid,
     sliced out or viewed — other than the pool's in-place update (a fusion
-    whose root is the scatter). Instructions inside a fusion's body
-    materialise nothing and are not counted."""
+    whose root is one of ``in_place``: the scatter; for a state stack
+    updated a layer at a time also ``dynamic-update-slice``). Instructions
+    inside a fusion's body materialise nothing and are not counted."""
     import re
     bodies, name = {}, None
     for line in hlo_text.splitlines():
@@ -194,7 +197,8 @@ def pool_sized_ops(hlo_text, pool_elems):
                 continue
             if m.group(3) == "fusion":
                 body = bodies[re.search(r"calls=%([\w.\-]+)", ln).group(1)]
-                if any(re.match(r"\s*ROOT %\S+ = .+? scatter\(", b)
+                if any(re.match(r"\s*ROOT %\S+ = .+? (?:"
+                                + "|".join(in_place) + r")\(", b)
                        for b in body):
                     continue                  # the in-place pool update
             found.append((m.group(3), m.group(1), m.group(2)))
@@ -276,6 +280,87 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * layer_pool          # bf16 bytes
     assert mem.alias_size_in_bytes >= 2 * 2 * m["layers"] * layer_pool
+
+
+# Phi-4-mini-flash as benchmarks/configs/phi-4-mini-flash.json serves it
+FLASH = dict(slots=64, page=16, per_slot=128, chunk=256)
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+def test_hybrid_step_program_copies_no_pool_or_state_on_v5e(chip, program):
+    """Phi-4-mini-flash's decode step and one prefill chunk, whole, at the
+    published widths and all 32 layers, compiled for the described chip
+    with pool, rings and state donated. The optimized HLO holds no copy,
+    slice, transpose or fusion of the size of a state stack (SSM: 9 x 64 x
+    16 x 5120; convolution: 9 x 64 x 15360), of the page pool or of all
+    the window rings but their in-place updates; everything donated is
+    aliased; and the program fits the chip beside its 10 GB of arguments.
+    What the plain-XLA arm still materialises is named, not hidden: the
+    decode step gathers the shared cache through the page table once (K
+    and V, ``[slots * pages_per_slot, page, 1280]``), for the eight layers
+    that read it; a Pallas arm that walks the pages in place is ROADMAP
+    Reach A5's. (A chunk's attention scores over its slot's gathered row,
+    ``[.., 256, 2048]`` float32, are larger than a state stack and are no
+    copy of anything.)"""
+    from paddle_tpu.models import phi4flash as phi
+    cfg = phi.Phi4FlashConfig()
+    f = FLASH
+    slots, per_slot = f["slots"], f["per_slot"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+    params = {k: sds(s, BF16) for k, s in phi.leaf_shapes(cfg).items()}
+    pool = sds((1, 1 + slots * per_slot, f["page"], cfg.kv_width), BF16)
+    state = tuple(sds(s, d) for _, _, s, d in
+                  phi.state_arrays(cfg, slots, f["page"], BF16))
+    if program == "decode_step":
+        def step(params, kc, vc, ids, table, lengths, active, *state):
+            cache = dict(k_pages=kc, v_pages=vc, page_table=table,
+                         lengths=lengths, state=state)
+            logits, cache = phi.decode_step(params, ids, cache, active,
+                                            cfg=cfg)
+            return (jnp.argmax(logits, -1), cache["k_pages"],
+                    cache["v_pages"], *cache["state"])
+        args = (sds((slots,), jnp.int32), sds((slots, per_slot), jnp.int32),
+                sds((slots,), jnp.int32), sds((slots,), jnp.bool_))
+    else:
+        def step(params, kc, vc, ids, start, valid, row, slot, *state):
+            logits, kc, vc, *state = phi.prefill_chunk_step(
+                params, ids, start, valid, row, kc, vc, cfg=cfg,
+                state=state, slot=slot)
+            return (jnp.argmax(logits, -1), kc, vc, *state)
+        args = (sds((f["chunk"],), jnp.int32), sds((), jnp.int32),
+                sds((), jnp.int32), sds((per_slot,), jnp.int32),
+                sds((), jnp.int32))
+    n = 3 + len(args)
+    compiled = jax.jit(step, donate_argnums=(1, 2) + tuple(
+        range(n, n + len(state)))).lower(
+            params, pool, pool, *args, *state).compile()
+    text = compiled.as_text()
+    elems = {name: int(np.prod(s)) for name, _, s, _ in
+             phi.state_arrays(cfg, slots, f["page"], BF16)}
+    pool_elems = int(np.prod(pool.shape))
+    updates = ("scatter", "dynamic-update-slice")
+    # nothing of a state stack's size but the stacks' in-place updates
+    # (and, in the decode step, the two gathers of the shared cache)
+    big = pool_sized_ops(text, min(elems["conv"], elems["ssm"]), updates)
+    gathers = [op for op in big if op[0] == "fusion" and
+               f"[{slots * per_slot},{f['page']},{cfg.kv_width}]" in op[2]]
+    scores = [op for op in big if op[0] == "fusion" and
+              f",{f['chunk']},{per_slot * f['page']}]" in op[2]]
+    rest = [op for op in big if op not in gathers + scores]
+    assert rest == [], rest
+    if program == "decode_step":
+        assert len(gathers) == 2 and scores == []
+    else:
+        assert gathers == []
+    mem = compiled.memory_analysis()
+    donated = 2 * (2 * pool_elems + 2 * cfg.n_front * elems["win_k.0"]
+                   + elems["conv"]) + 4 * elems["ssm"]
+    assert mem.alias_size_in_bytes >= donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13e9
+    assert mem.temp_size_in_bytes < (1.0e9 if program == "decode_step"
+                                     else 0.2e9)
 
 
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "bwd"])
