@@ -29,11 +29,16 @@ contract (token-identical output, enforced by parity tests):
   HBM traffic and FLOPs scale with the pool's capacity (`pages_per_slot`),
   not the live lengths.
 - **pallas** — the authored ragged paged-attention kernel
-  (`kernels/pallas/paged_attention.py`): grid over sequences,
-  double-buffered whole-page DMA, page loop bounded by
-  ``ceil((pos+1)/page_size)`` so page traffic scales with each sequence's
-  true length, each DMA reading ``pool[layer, page]`` of the stored pool
-  (the kernel's "Layout" note).
+  (`kernels/pallas/paged_attention.py`): grid over sequences, a BLOCK of a
+  sequence's pages a loop turn (256 tokens at the GPT-2 widths, read off
+  the pool's shape), the block's page copies started together and those of
+  the next turns, the next sequences' too, in flight while one block is
+  reduced on the MXU in a single online-softmax update; only
+  ``ceil((pos+1)/page_size)`` pages are ever fetched, so page traffic
+  scales with each sequence's true length, each DMA reading ``pool[layer,
+  page]`` of the stored pool (the kernel's "Layout" note). Dot precision
+  follows the pool's dtype: one bf16 pass on exact operands for a bf16
+  pool, f32 ``HIGHEST`` for f32 and dequantised int8.
 
 Both ops take the stored pool with ``layer=``. Without the keyword they
 accept ONE layer's pool, ``[num_pages, page_size, nh, dh]`` or merged rank

@@ -10,9 +10,10 @@ Kernels:
   (≈ `fused_attention_op.cu` but flash; the reference has NO flash kernel,
   SURVEY §5.7).
 - :mod:`paged_attention` — ragged paged-attention decode step (arxiv
-  2604.15464): grid over sequences, double-buffered whole-page DMA, page
-  loop bounded by each sequence's true length. The serving engine's hot
-  kernel (`FLAGS_tpu_paged_impl`).
+  2604.15464): grid over sequences, a block of a sequence's pages a loop
+  turn with the next turns' page copies in flight, only the pages of each
+  sequence's true length fetched. The serving engine's hot kernel
+  (`FLAGS_tpu_paged_impl`).
 - :mod:`prefill_attention` — the ragged PREFILL twin (r15): grid over
   chunk-row blocks, scalar-prefetched (start, valid), page walk
   bounded by the request's true uncached tail — chunked prefill, prefix

@@ -8,16 +8,37 @@ shaped for:
 
 - **grid over sequences** — one grid cell owns one sequence and produces
   the context vectors of all its heads;
-- **pages streamed block-by-block** — the K/V pools stay in HBM
-  (``memory_space=ANY``); each cell DMAs one whole ``[page_size, nh * dh]``
-  page at a time into a double-buffered VMEM scratch (next page's DMA in
-  flight while the current page is being reduced) and folds it into a
-  running online softmax (max, denom, accumulator) per head;
-- **length-aware stop** — the page loop's trip count is
-  ``ceil((pos[b]+1) / page_size)``, read from the scalar-prefetched ``pos``,
-  so compute AND DMA traffic scale with each sequence's true length instead
-  of ``pages_per_slot``. A 1-token sequence in a 4096-token slot touches one
-  page, not 256.
+- **a block of pages a loop turn** — the K/V pools stay in HBM
+  (``memory_space=ANY``). A turn takes ``block_pages`` of the sequence's
+  pages (256 tokens for the GPT-2 widths; read off the pool's shape and
+  dtype, see :func:`block_pages`): all of the block's page copies are
+  started together into one ``[tokens, nh * dh]`` VMEM buffer for K and one
+  for V, and the block is folded into a running online softmax (max, denom,
+  accumulator per head, f32) in ONE update. The buffers are a ring of
+  ``DEPTH + 1`` slots: the copies of the next ``DEPTH`` turns are in flight
+  while a turn is reduced, and because the grid runs in sequence and the
+  ring outlives a cell, those turns run on into the NEXT sequences — a
+  sequence's first block is on its way before its cell begins;
+- **length-aware stop** — only the pages a sequence has,
+  ``ceil((pos[b]+1) / page_size)`` clamped to the page-table row, are ever
+  fetched (``visits``); a block's tail beyond them is not fetched but
+  masked, and a turn reduces no more leading rows of its block than the
+  smallest of three rungs (an eighth, a half, the whole) that holds its
+  live tokens. Compute AND DMA traffic scale with each sequence's true
+  length instead of ``pages_per_slot``: a 1-token sequence in a 4096-token
+  slot copies one page and reduces 32 rows.
+
+**Arithmetic.** Heads sit on sublanes and the lane axis is never split: q
+enters block-diagonal, ``Qd [heads, nh * dh]`` (row h holds q's head h on
+that head's dh lanes), so the block's scores are one MXU product ``Qd x
+K_block^T -> [heads, tokens]`` (lane-dense for the softmax) and its values
+another, ``P [heads, tokens] x V_block -> [heads, nh * dh]``, of which row
+h's own dh lanes are kept. No operand is rounded below what the pool
+holds: against a bf16 pool the left operand goes in as bf16 pieces that sum
+to it exactly (a bf16 q is one piece; the f32 probabilities are three), one
+bf16 pass with f32 accumulation, whose products are exact in f32; an f32
+pool, and an int8 pool dequantised after its copies land, take the f32
+``HIGHEST`` form (:func:`_lossless_dot`).
 
 **Layout.** The chip's compiler only slices a page out of a pool whose last
 two dims are tile-aligned, and ``(nh, dh) = (12, 64)`` or ``(16, 64)`` is
@@ -27,20 +48,20 @@ stacked over layers, ``[num_layers, num_pages, page_size, nh * dh]`` (768,
 and this kernel takes that array as it is: it stays in HBM, the layer index
 arrives with the scalar-prefetched operands (one kernel body for every
 layer of a program), and each DMA reads ``pool[layer, page]``. Nothing of
-the pool is sliced, reshaped or copied on the way in. The lane axis is never
-split: per-head sums and broadcasts are small matmuls against a 0/1
-head-segment matrix. A caller that holds ONE layer's pool
-(``[num_pages, page_size, nh, dh]``, or merged rank 3) may leave ``layer``
-out: the pool is then viewed as a stack of one, which on a TPU is a copy of
-that whole pool per call (`kernels.paged_attention.stored_pools` counts
-such calls); no step program of the engine does that.
+the pool is sliced, reshaped or copied on the way in. A caller that holds
+ONE layer's pool (``[num_pages, page_size, nh, dh]``, or merged rank 3) may
+leave ``layer`` out: the pool is then viewed as a stack of one, which on a
+TPU is a copy of that whole pool per call
+(`kernels.paged_attention.stored_pools` counts such calls); no step program
+of the engine does that.
 
 Numerics match the reference: f32 scores, f32 online softmax, masked tail
 positions excluded — parity with the XLA path is enforced by
 tests/test_paged_pallas.py in interpret mode on CPU; on TPU the kernel
 compiles through Mosaic (tests/test_tpu_compile.py). Selection between the
 two lives in `kernels/paged_attention.py` (``FLAGS_tpu_paged_impl``),
-measured winners in `kernels/autotune.py`.
+measured winners in `kernels/autotune.py`; the block a build chose is
+counted in ``kernel.paged_block.{pages}`` (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations
 
@@ -57,8 +78,8 @@ NEG_INF = -1e30
 
 
 def pages_needed(pos, page_size):
-    """Trip count of the kernel's page loop for position ``pos`` — the
-    length-aware stop: ``ceil((pos + 1) / page_size)``, NOT pages_per_slot."""
+    """Pages the kernel fetches for position ``pos`` — the length-aware
+    stop: ``ceil((pos + 1) / page_size)``, NOT pages_per_slot."""
     return (pos + page_size) // page_size
 
 
@@ -81,88 +102,224 @@ def exact_dot(a, b):
                    precision=jax.lax.Precision.HIGHEST)
 
 
+# Read on the chip at GPT-2 medium's width (24 sequences, bf16): 256
+# tokens a turn beat 128 on every mix (a turn is bound by the latency of
+# its chain of two MXU products, not by their size, so fewer turns win:
+# 170 live tokens are one turn, not two) and 512 gained nothing more;
+# two turns ahead is as good as any deeper ring.
+BLOCK_TOKENS = 256      # tokens a loop turn takes
+DEPTH = 2               # turns whose copies are in flight ahead of a turn
+VMEM_BUDGET = 8 << 20   # bytes the K and V rings may take together
+
+
+def block_pages(page_size, hd, itemsize):
+    """Pages a loop turn of the kernel takes, from the pool's shape:
+    ``BLOCK_TOKENS`` tokens' worth, halved while the ``DEPTH + 1`` K and V
+    buffers of ``[tokens, hd]`` would pass ``VMEM_BUDGET``, and never under
+    one page."""
+    tokens = BLOCK_TOKENS
+    while (tokens > page_size
+           and 2 * (DEPTH + 1) * tokens * hd * itemsize > VMEM_BUDGET):
+        tokens //= 2
+    return max(1, tokens // page_size)
+
+
+def _bf16_pieces(x):
+    """``x`` as bf16 arrays whose sum is ``x`` exactly: itself if it is
+    bf16, else the three 8-bit slices of an f32 mantissa."""
+    if x.dtype == jnp.bfloat16:
+        return [x]
+    x = x.astype(jnp.float32)
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return [hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)]
+
+
+def _lossless_dot(a, b, dims):
+    """``dot_general(a, b)`` over ``[rows, c]`` operands with f32
+    accumulation and no operand rounded. A bf16 ``b`` (a bf16 pool's K or
+    V block) is exact as the MXU takes it, so ``a`` goes in as its bf16
+    pieces stacked over the rows — ONE bf16 pass, whose products are exact
+    in f32 — and the pieces' results are summed. Any other ``b`` takes the
+    f32 ``HIGHEST`` form."""
+    if b.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(a.astype(jnp.float32), b, (dims, ((), ())),
+                                   preferred_element_type=jnp.float32,
+                                   precision=jax.lax.Precision.HIGHEST)
+    pieces = _bf16_pieces(a)
+    n = a.shape[0]
+    out = jax.lax.dot_general(jnp.concatenate(pieces, axis=0), b,
+                              (dims, ((), ())),
+                              preferred_element_type=jnp.float32)
+    return sum(out[i * n:(i + 1) * n] for i in range(len(pieces)))
+
+
 def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
-                   page_size, nh, scale, quant=False, has_visits=False):
+                   page_size, nh, bp, scale, quant=False, has_visits=False):
     # one grid cell per sequence b, all heads at once: q_ref [1, 1, nh*dh]
     # in VMEM, k_hbm/v_hbm the stacked [nl, num_pages, page_size, nh*dh]
     # pools in HBM, pos/page_table/layer scalar-prefetched into SMEM (the
     # layer is an operand, not a constant: every layer of a program runs
     # this one kernel). Operand order is
     # inputs (q, k, v[, k_scale, v_scale]), outputs (o[, visits]), scratch
-    # (kbuf, vbuf, sem); ``quant`` and ``has_visits`` are static flags,
-    # never inferred from argument counts. Under ``quant`` the pools are
-    # int8 and ks_ref/vs_ref hold this sequence's [1, maxp*ps, nh] f32
-    # scale window (a [page_size, nh] page slice of the scale pool is
+    # (kbuf, vbuf, sem, ring); ``quant`` and ``has_visits`` are static
+    # flags, never inferred from argument counts. Under ``quant`` the pools
+    # are int8 and ks_ref/vs_ref hold this sequence's [1, >= maxp*ps, nh]
+    # f32 scale window (a [page_size, nh] page slice of the scale pool is
     # below one tile, so the window is gathered by XLA, 1/dh of the value
-    # bytes); the dequant happens in-register after the page copy lands,
-    # so the page DMA traffic is the int8 bytes.
+    # bytes); the dequant happens in-register after the block's copies
+    # land, so the page DMA traffic is the int8 bytes.
+    #
+    # A loop turn is a BLOCK of ``bp`` pages = ``tokens`` rows: kbuf/vbuf
+    # are rings of [nslots, tokens, nh*dh]; turn t of the whole call (cells
+    # in order, blocks in order) owns slot t % nslots, and a block's page
+    # copies all signal its slot's semaphore (one for K, one for V) and
+    # are waited one by one. ``ring`` (SMEM, it outlives a cell) holds the
+    # turns reduced so far and the cursor (sequence, block) whose copies
+    # start next: the cursor runs nslots - 1 turns ahead, on into the next
+    # cells' sequences.
     if quant:
         ks_ref, vs_ref, *rest = rest
     o_ref, *rest = rest
     if has_visits:
         visits_ref, *rest = rest
-    kbuf, vbuf, sem = rest
+    kbuf, vbuf, sem, ring = rest
     b = pl.program_id(0)
+    nb = pl.num_programs(0)
     pos = pos_ref[b]
     lyr = layer_ref[0]
-    # never walk past the page-table row: an out-of-range page index is a
-    # wild DMA, which halts the chip (the XLA arm clamps the same way)
-    npages = jnp.minimum(pages_needed(pos, page_size), pt_ref.shape[1])
+    nslots, tokens, hd = kbuf.shape
+    dh = hd // nh
+    last_page = k_hbm.shape[1] - 1
+
+    def npages_of(seq):
+        # never walk past the page-table row: an out-of-range page index
+        # is a wild DMA, which halts the chip (the XLA arm clamps the same
+        # way). Entries of the row past this count are never read.
+        return jnp.clip(pages_needed(pos_ref[seq], page_size), 1,
+                        pt_ref.shape[1])
+
+    def nblocks_of(seq):
+        return (npages_of(seq) + bp - 1) // bp
+
+    def copies(seq, j, turn, act):
+        # block j of sequence seq <-> the ring slot of its turn: only the
+        # pages the sequence has; the block's tail beyond them is not
+        # fetched (what an earlier turn left there is masked below)
+        slot = jax.lax.rem(turn, nslots)
+        first = j * bp
+        count = jnp.minimum(bp, npages_of(seq) - first)
+
+        def page(i, _):
+            pg = jnp.clip(pt_ref[seq, first + i], 0, last_page)
+            rows = pl.ds(pl.multiple_of(i * page_size, page_size), page_size)
+            for pool, buf, kv in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                act(pltpu.make_async_copy(pool.at[lyr, pg],
+                                          buf.at[slot, rows],
+                                          sem.at[kv, slot]))
+            return 0
+
+        jax.lax.fori_loop(0, count, page, 0)
+        return slot
+
+    def prefetch(turn):
+        # start the copies of the turn the cursor stands on, and move the
+        # cursor to the turn after it: the next block of its sequence, or
+        # block 0 of the next sequence
+        seq, j = ring[1], ring[2]
+
+        @pl.when(seq < nb)
+        def _():
+            copies(seq, j, turn, lambda c: c.start())
+            more = j + 1 < nblocks_of(seq)
+            ring[1] = jnp.where(more, seq, seq + 1)
+            ring[2] = jnp.where(more, j + 1, 0)
+
+    @pl.when(b == 0)
+    def _():
+        # a masked position's probability is 0, and 0 * NaN is NaN: V's
+        # never-fetched rows must hold numbers, which pool data is and
+        # fresh VMEM need not be
+        vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
+        ring[0] = 0
+        ring[1] = 0
+        ring[2] = 0
+        for turn in range(nslots - 1):
+            prefetch(turn)
+
+    npages = npages_of(b)
+    nblocks = nblocks_of(b)
+    # the last position attended: pos, or the row's last token where pos
+    # lies past the row (rows beyond the fetched pages hold no data)
+    pos = jnp.minimum(pos, npages * page_size - 1)
+    turn0 = ring[0]
+    ring[0] = turn0 + nblocks
     if has_visits:
-        # the loop bound, exported for tests (lane-dense row; lane 0 read)
+        # the pages fetched, exported for tests (lane-dense row; lane 0
+        # read)
         visits_ref[...] = jnp.full(visits_ref.shape, npages, jnp.int32)
 
-    def dma(slot, j):
-        # page j of sequence b: the whole page from HBM into the double
-        # buffer
-        pg = pt_ref[b, j]
-        return [pltpu.make_async_copy(k_hbm.at[lyr, pg], kbuf.at[slot],
-                                      sem.at[0, slot]),
-                pltpu.make_async_copy(v_hbm.at[lyr, pg], vbuf.at[slot],
-                                      sem.at[1, slot])]
+    # heads live on SUBLANES here, padded to whole bf16 tiles: row h of
+    # ``own`` marks head h's dh lanes (a padded row marks none, so it
+    # scores 0 everywhere and is dropped by the final reduce); q goes in
+    # block-diagonal, [nhp, nh*dh]
+    nhp = -(-nh // 16) * 16
+    lane = jax.lax.broadcasted_iota(jnp.int32, (nhp, hd), 1)
+    lane0 = jax.lax.broadcasted_iota(jnp.int32, (nhp, hd), 0) * dh
+    own = ((lane >= lane0) & (lane < lane0 + dh)).astype(jnp.float32)
+    qd = (own * q_ref[0].astype(jnp.float32)).astype(q_ref.dtype)
+    nt = ((1,), (1,))                  # [r, c] x [t, c] -> [r, t]
+    nn = ((1,), (0,))                  # [r, t] x [t, c] -> [r, c]
+    # rows of a block a turn may reduce: an eighth, a half (where those
+    # are whole int8 tiles of 32 rows), the whole
+    rungs = [r for r in (tokens // 8, tokens // 2) if r % 32 == 0] + [tokens]
 
-    for c in dma(0, 0):
-        c.start()
-    hd = q_ref.shape[-1]
-    seg, segt = head_segments(nh, hd // nh)
-    q = q_ref[0].astype(jnp.float32) * scale                   # [1, nh*dh]
-
-    def body(j, carry):
-        m, l, acc = carry
-        slot = jax.lax.rem(j, 2)
-
-        @pl.when(j + 1 < npages)
-        def _():                       # overlap: next page's DMA in flight
-            for c in dma(1 - slot, j + 1):
-                c.start()
-
-        for c in dma(slot, j):
-            c.wait()
-        k = kbuf[slot].astype(jnp.float32)                     # [ps, nh*dh]
-        v = vbuf[slot].astype(jnp.float32)
+    def reduce(rows, j, slot, m, l, acc):
+        # fold the block's first ``rows`` rows into the running softmax
+        k = kbuf[slot, :rows]                              # [rows, nh*dh]
+        v = vbuf[slot, :rows]
+        if k.dtype != jnp.bfloat16:
+            k = k.astype(jnp.float32)
+            v = v.astype(jnp.float32)
         if quant:
-            # dequantize in-register AFTER the page copy: the DMA moved
-            # int8 bytes; only the VMEM-resident working tile widens
-            rows = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
-            k = k * exact_dot(ks_ref[0, rows, :], segt)
-            v = v * exact_dot(vs_ref[0, rows, :], segt)
-        s = exact_dot(k * q, seg)                                   # [ps, nh]
-        kpos = j * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (page_size, 1), 0)
-        s = jnp.where(kpos <= pos, s, NEG_INF)  # tail of the last page
-        m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))  # [1, nh]
+            # dequantize in-register AFTER the copies: the DMAs moved int8
+            # bytes; only the VMEM-resident working block widens
+            at = pl.ds(pl.multiple_of(j * tokens, tokens), rows)
+            k = k * exact_dot(ks_ref[0, at, :], own[:nh])
+            v = v * exact_dot(vs_ref[0, at, :], own[:nh])
+        s = _lossless_dot(qd, k, nt) * scale               # [nhp, rows]
+        kpos = j * tokens + jax.lax.broadcasted_iota(
+            jnp.int32, (1, rows), 1)
+        s = jnp.where(kpos <= pos, s, NEG_INF)  # the block's tail past pos
+        m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))  # [nhp,1]
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-        acc_new = acc * exact_dot(alpha, segt) + jnp.sum(
-            exact_dot(p, segt) * v, axis=0, keepdims=True)          # [1, nh*dh]
+        l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        acc_new = acc * alpha + _lossless_dot(p, v, nn)    # [nhp, nh*dh]
         return m_new, l_new, acc_new
 
-    m0 = jnp.full((1, nh), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((1, nh), jnp.float32)
-    a0 = jnp.zeros((1, hd), jnp.float32)
-    m, l, acc = jax.lax.fori_loop(0, npages, body, (m0, l0, a0))
-    o_ref[0] = (acc / exact_dot(jnp.maximum(l, 1e-30), segt)).astype(o_ref.dtype)
+    def body(j, carry):
+        turn = turn0 + j
+        prefetch(turn + nslots - 1)
+        slot = copies(b, j, turn, lambda c: c.wait())
+        # the smallest rung that holds the block's live tokens: a one-page
+        # sequence costs a short turn, not a block
+        live = pos + 1 - j * tokens
+        rung = sum((live > r).astype(jnp.int32) for r in rungs[:-1])
+        return jax.lax.switch(
+            rung, [functools.partial(reduce, r) for r in rungs],
+            j, slot, *carry)
+
+    m0 = jnp.full((nhp, 1), NEG_INF, jnp.float32)
+    l0 = jnp.zeros((nhp, 1), jnp.float32)
+    a0 = jnp.zeros((nhp, hd), jnp.float32)
+    m, l, acc = jax.lax.fori_loop(0, nblocks, body, (m0, l0, a0))
+    # row h of acc holds head h's sum over ALL lanes; keep its own dh (one
+    # nonzero term a lane, so these reduces are exact)
+    o_ref[0] = (jnp.sum(acc * own, axis=0, keepdims=True)
+                / jnp.sum(jnp.maximum(l, 1e-30) * own, axis=0,
+                          keepdims=True)).astype(o_ref.dtype)
 
 
 def scale_window(scales, page_table, layer):
@@ -187,13 +344,13 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
     page_table : [B, pages_per_slot] int32
     pos        : [B] int32 — attends positions 0..pos inclusive
     k_scale/v_scale : optional [nl, num_pages, page_size, nh] f32 — int8
-                 pools: the dequant runs in-register after each page copy,
-                 so the kernel's page traffic is the int8 bytes (~1/4 of
-                 f32)
+                 pools: the dequant runs in-register after a block's
+                 copies, so the kernel's page traffic is the int8 bytes
+                 (~1/4 of f32)
     returns    : [B, nh, dh] in q.dtype; with ``return_visits=True`` also
-                 the page-loop trip counts [B, nh] int32 (one walk serves
-                 every head of a sequence, so a row repeats one count) —
-                 the ragged-stop proof the parity tests assert on.
+                 the pages fetched [B, nh] int32 (one walk serves every
+                 head of a sequence, so a row repeats one count) — the
+                 ragged-stop proof the parity tests assert on.
 
     ``interpret=None`` selects the Pallas interpreter off-TPU (CPU parity
     tests); on TPU the kernel compiles through Mosaic.
@@ -204,14 +361,31 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
     from paddle_tpu.kernels.paged_attention import stored_pools
     k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
         "paged_attention", k_pages, v_pages, k_scale, v_scale, layer)
+    from paddle_tpu.kernels import registry
+    registry.count_paged_block(block_pages(
+        k_pages.shape[2], k_pages.shape[3], k_pages.dtype.itemsize))
+    return _stored_call(q, k_pages, v_pages, page_table, pos,
+                        jnp.asarray(layer, jnp.int32), k_scale, v_scale,
+                        interpret=bool(interpret),
+                        return_visits=bool(return_visits))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "return_visits"))
+def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
+                 v_scale, *, interpret, return_visits):
+    # the kernel over the stored pools at a TRACED layer, as a function of
+    # its own: every layer of a step program is the same call of it, so a
+    # program traces and lowers the kernel once, not once a layer (which
+    # was 4 s of each start for GPT-2 medium's 24)
     quant = k_scale is not None
     b, nh, dh = q.shape
     ps = k_pages.shape[2]
     hd = nh * dh
     scale = 1.0 / (dh ** 0.5)
-    kern = functools.partial(_decode_kernel, page_size=ps, nh=nh,
+    bp = block_pages(ps, hd, k_pages.dtype.itemsize)
+    kern = functools.partial(_decode_kernel, page_size=ps, nh=nh, bp=bp,
                              scale=float(scale), quant=quant,
-                             has_visits=bool(return_visits))
+                             has_visits=return_visits)
     row = pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0))
     out_specs = [row]
     out_shape = [jax.ShapeDtypeStruct((b, 1, hd), q.dtype)]
@@ -225,20 +399,31 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
     ]
     operands = [q.reshape(b, 1, hd), k_pages, v_pages]
     if quant:
-        win = pl.BlockSpec((1, page_table.shape[1] * ps, nh),
-                           lambda i, *_: (i, 0, 0))
+        # whole blocks of scales, so that the last block's rows exist; and
+        # zeros past the pages a sequence has, whose table entries may name
+        # any page: a block's unfetched tail then dequantises to 0
+        maxp = page_table.shape[1]
+        rows = -(-maxp // bp) * bp * ps
+        live = jnp.minimum(pages_needed(pos, ps), maxp) * ps
+        keep = (jnp.arange(rows) < live[:, None])[..., None]
+        win = pl.BlockSpec((1, rows, nh), lambda i, *_: (i, 0, 0))
         in_specs += [win, win]
-        operands += [scale_window(k_scale, page_table, layer),
-                     scale_window(v_scale, page_table, layer)]
+        operands += [jnp.where(keep, jnp.pad(
+            scale_window(s, page_table, layer),
+            ((0, 0), (0, rows - maxp * ps), (0, 0))), 0.0)
+            for s in (k_scale, v_scale)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(b,),
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((2, ps, hd), k_pages.dtype),   # K double buffer
-            pltpu.VMEM((2, ps, hd), v_pages.dtype),   # V double buffer
-            pltpu.SemaphoreType.DMA((2, 2)),          # (k|v, slot)
+            pltpu.VMEM((DEPTH + 1, bp * ps, hd), k_pages.dtype),  # K ring
+            pltpu.VMEM((DEPTH + 1, bp * ps, hd), v_pages.dtype),  # V ring
+            pltpu.SemaphoreType.DMA((2, DEPTH + 1)),        # (k|v, slot)
+            # the ring's state across cells: turns reduced so far, and
+            # the (sequence, block) whose copies start next
+            pltpu.SMEM((3,), jnp.int32),
         ],
     )
     with x64_off_scope():
@@ -246,9 +431,12 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
             kern,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            interpret=bool(interpret),
+            # in sequence: a cell starts the next cell's first copies
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=interpret,
         )(pos.astype(jnp.int32), page_table.astype(jnp.int32),
-          jnp.asarray(layer, jnp.int32).reshape(1), *operands)
+          layer.reshape(1), *operands)
     out = outs[0].reshape(b, nh, dh)
     if return_visits:
         return out, jnp.broadcast_to(outs[1][:, 0, :1], (b, nh))

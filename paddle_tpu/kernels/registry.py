@@ -55,7 +55,7 @@ from paddle_tpu.observability import metrics
 _LOG = logging.getLogger("paddle_tpu.kernels.registry")
 
 __all__ = ["KernelOp", "register_op", "ops", "dispatch", "count",
-           "count_relayout", "select",
+           "count_relayout", "count_paged_block", "select",
            "table", "clear"]
 
 
@@ -131,6 +131,15 @@ def count_relayout(op: str):
     still copies a layer pool on its way into ``op`` reads non-zero in
     ``kernel.pool_relayout.{op}`` — without a chip."""
     metrics.counter(f"kernel.pool_relayout.{op}").inc()
+
+
+def count_paged_block(pages: int):
+    """Trace-time, once per build of the Pallas paged-attention decode
+    kernel: the pages a loop turn of it takes, which the kernel reads off
+    the pool's shape (`kernels/pallas/paged_attention.py::block_pages`) —
+    ``kernel.paged_block.{pages}`` says which shape a program runs,
+    without a chip."""
+    metrics.counter(f"kernel.paged_block.{pages}").inc()
 
 
 def dispatch(op: str, *, forced=None, ctx=None, winner=None,

@@ -68,10 +68,12 @@ _EPOCH_UNIX_US = time.time() * 1e6
 
 _RESERVOIR = 512       # recent observations kept per histogram (percentiles)
 # bounded span ring: old spans drop (counted: metrics.spans_dropped), the
-# process never grows. Sized for a benchmark window on top of set-up: 150
-# engine steps a second x 6 spans a step x 45 s (ramp + window) = 40,500,
-# plus six spans a request and set-up's few hundred; ~15 MB when full.
-_MAX_SPANS = 65536
+# process never grows. Sized for a benchmark window on top of set-up: 550
+# engine steps a second x 6 spans a step x 45 s (ramp + window) = 148,500
+# (the GPT-2 decode cell wrote 47,000 at 135 steps a second and writes
+# 100,000 at the 300 it runs since PR 28), plus six spans a request and
+# set-up's few hundred; about 60 MB when full.
+_MAX_SPANS = 262144
 _MAX_TRACES = 64       # per-trace span rings kept (LRU; fleet TRACE_EXPORT)
 _MAX_TRACE_SPANS = 256  # spans kept per traced request
 _MAX_LABELED_SERIES = 256  # LRU cap on LABELED series (membership churn)

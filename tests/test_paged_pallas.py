@@ -41,6 +41,24 @@ def _random_case(rng, b, nh, dh, ps, maxp, num_pages, pos):
     return q, kp, vp, pt, jnp.asarray(np.asarray(pos, np.int32))
 
 
+def _pool_case(kv, rng, b, nh, dh, ps, maxp, lens):
+    """(q, k_pool, v_pool, table, pos, scales) on a pool of dtype ``kv``;
+    ``scales`` is the keyword dict of an int8 pool, else empty."""
+    q, kp, vp, pt, pos = _random_case(rng, b, nh, dh, ps, maxp,
+                                      2 + b * maxp,
+                                      [n - 1 for n in lens])
+    scales = {}
+    if kv == "int8":
+        def quantized(pool):
+            vals, s = pa.quantize_kv(pool.reshape(*pool.shape[:3], nh, dh))
+            return vals.reshape(pool.shape), s
+        (kp, ks), (vp, vs) = quantized(kp), quantized(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    elif kv == "bf16":
+        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+    return q, kp, vp, pt, pos, scales
+
+
 @pytest.mark.parametrize("layer", [0, NL - 1])
 class TestPallasParity:
     """pallas(interpret) on the stored pool at ``layer`` vs the XLA
@@ -104,19 +122,10 @@ def test_stored_and_per_layer_forms_agree_on_both_arms(kv, layer):
     (rank 4, the probe's form) give the same bits; across arms the same
     attention to rounding."""
     from paddle_tpu.framework.flags import set_flags
-    rng = np.random.RandomState(11)
-    b, nh, dh, ps, maxp = 3, 2, 16, 4, 4
-    q, kp, vp, pt, pos = _random_case(rng, b, nh, dh, ps, maxp,
-                                      1 + b * maxp, [0, 6, 15])
-    scales = {}
-    if kv == "int8":
-        def quantized(pool):
-            vals, s = pa.quantize_kv(pool.reshape(*pool.shape[:3], nh, dh))
-            return vals.reshape(pool.shape), s
-        (kp, ks), (vp, vs) = quantized(kp), quantized(vp)
-        scales = dict(k_scale=ks, v_scale=vs)
-    elif kv == "bf16":
-        q, kp, vp = (x.astype(jnp.bfloat16) for x in (q, kp, vp))
+    nh, dh, ps = 2, 16, 4
+    q, kp, vp, pt, pos, scales = _pool_case(
+        kv, np.random.RandomState(11), b=3, nh=nh, dh=dh, ps=ps, maxp=4,
+        lens=[1, 7, 16])
     one = {k: v[layer] for k, v in scales.items()}
     outs = {}
     try:
@@ -135,6 +144,129 @@ def test_stored_and_per_layer_forms_agree_on_both_arms(kv, layer):
     tol = 2e-2 if kv == "bf16" else 1e-5
     np.testing.assert_allclose(outs["pallas"], outs["xla"], rtol=tol,
                                atol=tol)
+
+
+# A loop turn of the kernel takes block_pages pages: at these shapes 16
+# pages of 16 tokens, so a row of 40 pages is two and a half blocks.
+BLOCKED = dict(b=3, nh=2, dh=16, ps=16, maxp=40)
+# the middle sequence's length; its neighbours (17 and 530 tokens) make the
+# copy ring run on from one grid cell into the next
+LENGTHS = {"inside-a-block": 300, "at-a-block-edge": 256,
+           "first-of-the-next-block": 257, "one-page": 16, "one-token": 1,
+           "full-capacity": 640, "past-capacity": 700}
+
+
+def _fetched(lens, ps, maxp):
+    return np.minimum((np.asarray(lens) + ps - 1) // ps, maxp)
+
+
+@pytest.mark.parametrize("layer", [0, NL - 1])
+@pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
+@pytest.mark.parametrize("case", LENGTHS)
+def test_blocked_walk_matches_the_xla_arm(case, kv, layer):
+    """Sequences that end inside a block, at its edge, after one page and
+    at capacity, on every pool dtype, at the first and last layer: the
+    XLA arm's attention to rounding, and ``visits`` the pages the
+    sequence has, clamped to its row."""
+    c = BLOCKED
+    assert ppa.block_pages(c["ps"], c["nh"] * c["dh"], 2) == 16
+    lens = [17, LENGTHS[case], 530]
+    q, kp, vp, pt, pos, scales = _pool_case(
+        kv, np.random.RandomState(5), lens=lens, **c)
+    want = pa._xla_paged_attention(q, kp, vp, pt, pos, layer, **scales)
+    got, visits = ppa.paged_attention(q, kp, vp, pt, pos, layer=layer,
+                                      interpret=True, return_visits=True,
+                                      **scales)
+    tol = 2e-2 if kv == "bf16" else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+    np.testing.assert_array_equal(np.asarray(visits)[:, 0],
+                                  _fetched(lens, c["ps"], c["maxp"]))
+
+
+@pytest.mark.parametrize("kv", ["bf16", "f32", "int8"])
+def test_unused_table_entries_are_never_dereferenced(kv):
+    """Entries of a table row past the pages its sequence has may hold
+    anything, out-of-range page ids too: on the chip a copy from such a
+    page halts the core. The kernel clamps a page id into the pool, so a
+    dereference here would read the pool's first or last page: both are
+    poisoned, and one fetched row of them would turn the output NaN (a
+    masked position's 0 times NaN). An int8 pool's poison sits in its
+    scales, whose window the op zeroes past the pages a sequence has:
+    there the wild ids show that the window's gather stays sound."""
+    c = BLOCKED
+    lens = [17, 300, 1, 640]
+    b = len(lens)
+    q, kp, vp, pt, pos, scales = _pool_case(
+        kv, np.random.RandomState(6), lens=lens, **{**c, "b": b})
+    last = kp.shape[1] - 1                  # the table names neither
+    pt = jnp.asarray(1 + np.random.RandomState(6).permutation(last - 1)
+                     .reshape(b, c["maxp"]).astype(np.int32))
+    if kv == "int8":
+        scales = {k: v.at[:, jnp.asarray([0, last])].set(jnp.nan)
+                  for k, v in scales.items()}
+    else:
+        kp, vp = (x.at[:, jnp.asarray([0, last])].set(jnp.nan)
+                  for x in (kp, vp))
+    used = np.arange(c["maxp"])[None, :] < _fetched(
+        lens, c["ps"], c["maxp"])[:, None]
+    wild = np.where(used, np.asarray(pt),
+                    np.resize([2 ** 30, -5, last + 1], used.shape))
+    want = pa._xla_paged_attention(
+        q, kp, vp, jnp.asarray(np.where(used, np.asarray(pt), 1)), pos, 1,
+        **scales)
+    got, visits = ppa.paged_attention(
+        q, kp, vp, jnp.asarray(wild, jnp.int32), pos, layer=1,
+        interpret=True, return_visits=True, **scales)
+    assert np.isfinite(np.asarray(got, np.float32)).all()
+    tol = 2e-2 if kv == "bf16" else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), rtol=tol,
+                               atol=tol)
+    np.testing.assert_array_equal(np.asarray(visits)[:, 0],
+                                  _fetched(lens, c["ps"], c["maxp"]))
+
+
+def test_f32_query_on_a_bf16_pool_loses_nothing():
+    """The single-pass form takes the left operand as bf16 pieces that sum
+    to it: an f32 query against a bf16 pool agrees with the f32 reference
+    to f32 rounding, not to bf16's."""
+    c = BLOCKED
+    q, kp, vp, pt, pos, _ = _pool_case(
+        "f32", np.random.RandomState(7), lens=[17, 300, 530], **c)
+    kp, vp = kp.astype(jnp.bfloat16), vp.astype(jnp.bfloat16)
+    want = pa._xla_paged_attention(q, kp, vp, pt, pos, 1)
+    got = ppa.paged_attention(q, kp, vp, pt, pos, layer=1, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-5, atol=1e-5)
+    x = jnp.asarray(np.random.RandomState(8).randn(4, 9).astype(np.float32))
+    pieces = ppa._bf16_pieces(x)
+    assert [p.dtype for p in pieces] == [jnp.bfloat16] * 3
+    np.testing.assert_array_equal(
+        np.asarray(sum(p.astype(jnp.float32) for p in pieces)),
+        np.asarray(x))
+
+
+@pytest.mark.parametrize("shape,pages", [
+    ((16, 1024, 2), 16),      # GPT-2 medium's bf16 pool: 256 tokens a turn
+    ((16, 768, 1), 16),       # int8
+    ((16, 1280, 4), 16),      # GPT-2 large in float32: still inside VMEM
+    ((16, 4096, 4), 4),       # a wide float32 pool: halved to fit
+    ((512, 1024, 2), 1),      # a page larger than a block: one page
+    ((4, 32, 4), 64),
+])
+def test_block_follows_the_pools_shape(shape, pages):
+    assert ppa.block_pages(*shape) == pages
+
+
+def test_every_build_counts_the_block_it_chose():
+    rng = np.random.RandomState(9)
+    q, kp, vp, pt, pos = _random_case(rng, 2, 2, 16, 4, 3, 7, [2, 9])
+    counter = metrics.counter("kernel.paged_block.64")
+    before = counter.value
+    ppa.paged_attention(q, kp, vp, pt, pos, layer=0, interpret=True)
+    assert counter.value == before + 1
 
 
 class TestLengthAwareStop:

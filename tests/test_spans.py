@@ -195,6 +195,25 @@ def test_ring_holds_setup_and_a_window_at_150_steps_a_second():
     assert obs._MAX_SPANS >= 150 * 6 * 45 + 5000
 
 
+def test_ring_holds_a_window_of_100000_spans_on_top_of_setup():
+    """What the decode cell writes at 1.5-2x its old step rate (70,000 to
+    95,000 spans in a window): a registry's own ring keeps set-up's spans
+    and all of such a window, so a span reader that asks for the window
+    (`metrics.spans(since=...)`) is told nothing was lost."""
+    reg = MetricsRegistry()
+    assert reg._spans.maxlen == obs._MAX_SPANS
+    for i in range(5000):
+        reg.add_span("setup", float(i), 0.5)
+    opened = 5000.0
+    for i in range(100_000):
+        reg.add_span("engine.step", opened + i, 0.5)
+    assert reg.spans_dropped.value == 0
+    assert len(reg.spans()) == 105_000
+    assert reg.spans()[0].name == "setup"
+    window = reg.spans(since=opened - 0.25)
+    assert len(window) == 100_000 and window[0].name == "engine.step"
+
+
 def test_discarded_span_leaves_no_entry_but_keeps_its_children_sound():
     reg = MetricsRegistry()
     with reg.span("poll") as sp:

@@ -88,7 +88,7 @@ def _pool(nh, dh, kv, chip):
     (``per-layer``) one layer's ``[P, page, nh, dh]`` with no layer, the
     form the benchmark's selection probe passes."""
     pages = 1 + SLOTS * PAGES_PER_SLOT
-    dtype = jnp.int8 if kv.startswith("int8") else BF16
+    dtype = {"int8": jnp.int8, "f32": jnp.float32}.get(kv.split("-")[0], BF16)
     if kv.endswith("per-layer"):
         shape, lead, layer = (pages, PAGE, nh, dh), (), None
     else:
@@ -102,11 +102,16 @@ def _pool(nh, dh, kv, chip):
 POOL_FORMS = ["bf16", "int8", "bf16-per-layer"]
 
 
-@pytest.mark.parametrize("kv", POOL_FORMS)
-@pytest.mark.parametrize("width", WIDTHS)
+# the decode kernel's block and dot forms follow the pool's width and
+# dtype: GPT-2 large's width and a float32 pool besides
+DECODE_WIDTHS = {**WIDTHS, "large": (20, 64, 1280)}
+
+
+@pytest.mark.parametrize("kv", POOL_FORMS + ["f32"])
+@pytest.mark.parametrize("width", DECODE_WIDTHS)
 def test_paged_decode_attention_compiles_for_v5e(chip, width, kv):
     from paddle_tpu.kernels.pallas.paged_attention import paged_attention
-    nh, dh, _ = WIDTHS[width]
+    nh, dh, _ = DECODE_WIDTHS[width]
     pool, scales, layer = _pool(nh, dh, kv, chip)
     q = jax.ShapeDtypeStruct((SLOTS, nh, dh), BF16, sharding=chip)
     table = jax.ShapeDtypeStruct((SLOTS, PAGES_PER_SLOT), jnp.int32,
