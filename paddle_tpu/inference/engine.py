@@ -17,11 +17,17 @@ Python owns admission/retirement, the device runs fixed-shape steps:
   that grows (the pool below), WINDOW K/V (a ring of `window / page + 1`
   pages a slot, constant in sequence length) and RECURRENT state (fixed
   size a slot; read as zero by the chunk that starts a sequence, carried
-  from chunk to chunk and into decode). The last two ride every step
-  program after the pools, donated like them. For a model that keeps
-  them, whatever would restore a sequence from pages alone — prefix
+  from chunk to chunk and into decode). For a model that keeps the last
+  two, whatever would restore a sequence from pages alone — prefix
   reuse, speculation, hand-off, migration, tier spill — refuses by typed
   error (docs/SERVING.md "The model seam").
+- **The cache seam** (`inference/cache.py`, `inference/programs.py`): all
+  of that state — pools, an int8 pool's scales, rings, recurrent state,
+  the sampler's key chains — is ONE `DeviceCache`. Every step program is
+  ``exe(params, cache, *small) -> (*lead, cache)`` with the cache donated
+  whole; the engine replaces its cache at every call and never names a
+  leaf. The page allocator lives with it; the prefix store and the tiers
+  are still the engine's.
 - **Fixed-shape decode step**: every step runs the family's `decode_step` on
   all `max_slots` slots — active or not — in ONE device call. Slot churn
   only changes the *contents* of the page table / active mask, never a
@@ -120,11 +126,16 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.inference.cache import DeviceCache, PageAllocator
 from paddle_tpu.inference.errors import (Cancelled, DeadlineExceeded,
                                          HandoffCorrupt, Overloaded,
                                          RecurrentStateUnsupported,
                                          from_wire)
 from paddle_tpu.inference.family import family_of
+from paddle_tpu.inference.programs import (FLAG_ACTIVE, FLAG_FRESH,
+                                           decode_program, prefill_program,
+                                           prefill_upload, step_upload,
+                                           verify_program)
 from paddle_tpu.kernels.paged_attention import TRASH_PAGE
 from paddle_tpu.observability import metrics
 from paddle_tpu.observability.flight_recorder import (Watchdog,
@@ -138,15 +149,6 @@ __all__ = ["EngineConfig", "PageAllocator", "GenerateRequest", "DecodeEngine",
            "KVHandoff", "MigrationItem", "DeadlineExceeded", "Cancelled",
            "Overloaded", "HandoffCorrupt", "pack_migration",
            "unpack_migration"]
-
-# packed slot-state upload layout: [B, _STATE_COLS + pages_per_slot] int32,
-# ONE host->device transfer per step (engine.h2d_transfers). The
-# speculative verify step widens it to [B, _SPEC_COLS + K + pages_per_slot]
-# (an extra draft-length column + K drafted-token columns) — still one
-# fused upload per step.
-_COL_TOKEN, _COL_LENGTH, _COL_FLAGS, _STATE_COLS = 0, 1, 2, 3
-_COL_DRAFT, _SPEC_COLS = 3, 4
-_FLAG_ACTIVE, _FLAG_FRESH = 1, 2
 
 
 @dataclass
@@ -284,120 +286,6 @@ class EngineConfig:
     weight_dtype: str = "native"
     sampling: bool = False
     dedup_capacity: int = 1024
-
-
-class PageAllocator:
-    """Host-side REFCOUNTED free-list over the page pool. Page 0
-    (TRASH_PAGE) is never handed out — it is the spill target for masked
-    writes.
-
-    Prefix caching (docs/SERVING.md) shares pages copy-on-write across
-    slots: `share` grows a page's refcount and `free` releases one owner's
-    claim, reclaiming only at refcount 0. A page the engine's prefix store
-    still indexes is RETAINED at refcount 0 (its contents stay valid for
-    future hits) instead of returning to the free list; under pool pressure
-    `alloc` reclaims retained pages through ``evict_hook`` (LRU order, the
-    engine owns the policy), so eviction can never touch a live slot's
-    pages — only refcount-0 ones."""
-
-    def __init__(self, num_pages: int):
-        if num_pages < 2:
-            raise ValueError(f"need >= 2 pages (1 is reserved), got {num_pages}")
-        self.num_pages = num_pages
-        self._free = deque(range(1, num_pages))
-        self._refcnt = [0] * num_pages
-        self._retained: set[int] = set()
-        self.retain_hook = None   # page -> bool: keep this refcount-0 page?
-        self.evict_hook = None    # n -> list[page]: reclaim retained pages
-        self._g_in_use = metrics.gauge("engine.pages_in_use")
-
-    @property
-    def free_pages(self) -> int:
-        """Pages allocatable RIGHT NOW: the free list plus refcount-0
-        cached pages (reclaimable by eviction)."""
-        return len(self._free) + len(self._retained)
-
-    def _update_gauge(self):
-        self._g_in_use.set(self.num_pages - 1 - self.free_pages)
-
-    def refcount(self, page: int) -> int:
-        return self._refcnt[page]
-
-    def alloc(self, n: int) -> list[int] | None:
-        """n pages or None (caller keeps the request queued — admission
-        control is 'wait', never 'partially allocate'). Evicts refcount-0
-        cached pages (LRU via ``evict_hook``) when the free list alone
-        cannot cover the request."""
-        if faults.ENABLED and faults.fire("engine.pool_pressure"):
-            return None        # injected pool pressure (testing/faults.py)
-        if n > self.free_pages:
-            return None
-        if n > len(self._free) and self.evict_hook is not None:
-            for p in self.evict_hook(n - len(self._free)):
-                if p not in self._retained or self._refcnt[p] != 0:
-                    raise RuntimeError(
-                        f"evict hook surrendered live page {p}")
-                self._retained.discard(p)
-                self._free.append(p)
-        if n > len(self._free):
-            return None
-        pages = [self._free.popleft() for _ in range(n)]
-        for p in pages:
-            self._refcnt[p] = 1
-        self._update_gauge()
-        return pages
-
-    def reclaim(self, pages: list[int]):
-        """Return RETAINED (refcount-0 cached) pages to the free list —
-        the prefix store dropping its index outside an alloc-driven
-        eviction (e.g. a weight swap invalidating every cached page)."""
-        for p in pages:
-            if p not in self._retained or self._refcnt[p] != 0:
-                raise ValueError(f"reclaiming non-retained page {p}")
-        for p in pages:
-            self._retained.discard(p)
-            self._free.append(p)
-        self._update_gauge()
-
-    def share(self, pages: list[int]):
-        """Attach cached pages to ONE more owner (a prefix-cache hit):
-        refcount-0 retained pages come back to life, live shared pages just
-        gain a reference."""
-        for p in pages:
-            if not (0 < p < self.num_pages):
-                raise ValueError(f"sharing bogus page {p}")
-            if self._refcnt[p] == 0 and p not in self._retained:
-                raise ValueError(f"sharing unallocated page {p}")
-        for p in pages:
-            self._retained.discard(p)
-            self._refcnt[p] += 1
-        self._update_gauge()
-
-    def free(self, pages: list[int]):
-        """Release one owner's claim on each page. Fails LOUDLY — before
-        mutating anything — on a double-free (refcount already 0), a
-        duplicate page id within the call, an out-of-pool id, or the
-        reserved trash page 0: tolerating any of these would eventually
-        hand the same page to two live sequences."""
-        seen = set()
-        for p in pages:
-            if p == TRASH_PAGE:
-                raise ValueError("freeing reserved trash page 0")
-            if not (0 < p < self.num_pages):
-                raise ValueError(f"freeing bogus page {p}")
-            if p in seen:
-                raise ValueError(f"duplicate page {p} in one free() call")
-            seen.add(p)
-            if self._refcnt[p] <= 0:
-                raise ValueError(f"double free of page {p}")
-        for p in pages:
-            self._refcnt[p] -= 1
-            if self._refcnt[p] == 0:
-                if self.retain_hook is not None and self.retain_hook(p):
-                    self._retained.add(p)
-                else:
-                    self._free.append(p)
-        self._update_gauge()
 
 
 class GenerateRequest:
@@ -853,10 +741,8 @@ class DecodeEngine:
         self._steps = fam.steps
         self._stateful = fam.state is not None
         self._params = fam.params(model)
-        self._cdtype = self._served_dtype = \
-            self._params[fam.table_key].dtype
-        nh = fam.kv_heads
-        self._nh, self._dh = nh, fam.head_dim
+        self._served_dtype = self._params[fam.table_key].dtype
+        self._nh, self._dh = fam.kv_heads, fam.head_dim
         self._nl = fam.kv_layers
         self._refuse_stateful_config(ecfg)
         if ecfg.weight_dtype not in ("native", None):
@@ -864,16 +750,6 @@ class DecodeEngine:
             # use inside the same AOT programs (quantization/serving.py);
             # the conversion wall lands in engine.quant_dequant_ms
             self._params = self._quantized(self._params)
-        kvd = ecfg.kv_dtype
-        if kvd not in ("native", None):
-            from paddle_tpu.kernels.paged_attention import KV_DTYPES
-            if kvd not in KV_DTYPES:
-                raise ValueError(
-                    f"kv_dtype={kvd!r}: expected 'native', "
-                    f"{sorted(KV_DTYPES)}")
-            self._cdtype = jnp.dtype(KV_DTYPES[kvd])
-        self._quant_kv = kvd == "int8"
-
         ps = ecfg.page_size
         max_seq = ecfg.max_seq_len or fam.max_positions
         max_seq = min(max_seq, fam.max_positions)
@@ -889,51 +765,17 @@ class DecodeEngine:
             self._donate = bool(ecfg.donate)
 
         B, maxp = ecfg.max_slots, self.pages_per_slot
-        # the pool is stored as the attention kernels read it: stacked over
-        # layers, heads merged into the lane axis (kernels/paged_attention
-        # .py) — no program slices, reshapes or relays it
-        self._kc = jnp.zeros((self._nl, num_pages, ps, nh * self._dh),
-                             self._cdtype)
-        self._vc = jnp.zeros_like(self._kc)
-        # int8 pool: per-token-slot per-head f32 scales ride the cache
-        # pytree through every step program (written by the same scatters
-        # that write the pages; docs/QUANTIZATION.md)
-        self._ks = self._vs = None
-        if self._quant_kv:
-            self._ks = jnp.zeros((self._nl, num_pages, ps, nh), jnp.float32)
-            self._vs = jnp.zeros_like(self._ks)
-        # bytes each cached token costs across all layers (K+V values plus
-        # scales when quantized) — the capacity yardstick bench_quant's
-        # slots-at-fixed-pool-bytes assertion is computed from
-        self.kv_bytes_per_token = self._nl * 2 * (
-            nh * self._dh * jnp.dtype(self._cdtype).itemsize
-            + (nh * 4 if self._quant_kv else 0))
-        metrics.gauge("engine.kv_bytes_per_token").set(
-            self.kv_bytes_per_token)
-        # state beside the page pool (a family that keeps any): window K/V
-        # rings and recurrent state, per slot, threaded through every step
-        # program after the pools and donated like them
-        self._state: tuple = ()
-        self._state_kinds: tuple = ()
-        if self._stateful:
-            specs = fam.state(B, ps, self._served_dtype)
-            self._state = tuple(jnp.zeros(shape, dtype)
-                                for _, _, shape, dtype in specs)
-            self._state_kinds = tuple(kind for _, kind, _, _ in specs)
+        # everything a step program updates in place: page pools, an int8
+        # pool's scales, the family's state beside the pool, the sampler's
+        # key chains. Every program takes it whole, donated, and returns it
+        # (inference/cache.py); `_kc` ... `_state` below are views of it
+        self._cache = DeviceCache.allocate(fam, ecfg, num_pages,
+                                           self._served_dtype)
+        self._cdtype = self._cache.k.dtype
+        self._quant_kv = self._cache.k_scale is not None
+        self.kv_bytes_per_token = self._cache.bytes_per_token
         self._window_pages = -(-fam.window_tokens // ps) + 1 \
             if fam.window_tokens else 0
-
-        def nbytes(kind):
-            return sum(int(a.nbytes) for a, k in zip(self._state,
-                                                     self._state_kinds)
-                       if k == kind)
-        metrics.gauge("engine.cache_bytes.paged").set(
-            int(self._kc.nbytes) * 2 + (int(self._ks.nbytes) * 2
-                                        if self._quant_kv else 0))
-        metrics.gauge("engine.cache_bytes.window").set(nbytes("window"))
-        metrics.gauge("engine.cache_bytes.state").set(nbytes("recurrent"))
-        metrics.gauge("engine.state_bytes_per_slot").set(
-            nbytes("recurrent") // B)
         # published for the router's fleet prefix directory: affinity
         # hashing needs the fleet's page size (docs/SERVING.md
         # "Disaggregated serving")
@@ -954,16 +796,12 @@ class DecodeEngine:
         # (device tokens, [(slot, request)] snapshot, dispatch t0)
         self._tok_dev = jnp.zeros(B, jnp.int32)
         # fused on-device sampling (EngineConfig.sampling): per-slot
-        # (temperature, top_k) host mirrors ride the packed upload, the
-        # PRNG key chains live ON DEVICE ([B+1, 2] uint32 — row B is the
-        # scratch row slotless prefills write, prefill_export/stream) and
-        # are threaded through every step program exactly like _tok_dev,
-        # so sampled decode reads back TOKENS only
+        # (temperature, top_k) host mirrors ride the packed upload; the
+        # PRNG key chains live in the cache, so sampled decode reads back
+        # TOKENS only
         self._sampling = bool(ecfg.sampling)
         self._temps = np.ones(B, np.float32)
         self._topks = np.zeros(B, np.int32)
-        self._keys_dev = jnp.zeros((B + 1, 2), jnp.uint32) \
-            if self._sampling else None
         self._inflight: deque = deque()
 
         self._queue: deque[GenerateRequest] = deque()
@@ -1015,6 +853,11 @@ class DecodeEngine:
                 f"speculate_k must be >= 1, got {ecfg.speculate_k}")
         self._spec = ecfg.speculate_k is not None
         self._spec_k = int(ecfg.speculate_k) if self._spec else 0
+        # the batched step's packed upload (a verify step's when
+        # speculating): laid out once, written by `_packed_state`, read
+        # by the program (inference/programs.py)
+        self._step_upload = step_upload(B, maxp, self._sampling,
+                                        k=self._spec_k)
         # prefix cache: rolling full-page hash -> resident page, plus the
         # reverse map and the LRU of refcount-0 ("idle") cached pages the
         # allocator retains for us. All mutations happen on the driver
@@ -1037,7 +880,7 @@ class DecodeEngine:
                 host_bytes=ecfg.kv_host_tier_bytes,
                 disk_bytes=ecfg.kv_disk_tier_bytes,
                 disk_dir=ecfg.kv_disk_tier_dir,
-                page_shape=(self._nl, ps, nh, self._dh),
+                page_shape=(self._nl, ps, self._nh, self._dh),
                 dtype=np.dtype(self._cdtype).name,
                 scales=self._quant_kv)
         self.step_seq = 0             # advances once per step(); the
@@ -1103,6 +946,15 @@ class DecodeEngine:
         self._h_step = metrics.histogram("engine.step_seconds")
         self._h_prefill = metrics.histogram("engine.prefill_seconds")
 
+    # read-only views of the cache's leaves under the names they had as
+    # engine attributes: tests read them, and the benchmark frees the
+    # program's buffers by them (benchmarks/runners/serve_hybrid.py)
+    _kc = property(lambda self: self._cache.k)
+    _vc = property(lambda self: self._cache.v)
+    _ks = property(lambda self: self._cache.k_scale)
+    _vs = property(lambda self: self._cache.v_scale)
+    _state = property(lambda self: self._cache.state)
+
     # ------------------------------------------------------- the model seam
 
     def _quantized(self, params):
@@ -1159,349 +1011,51 @@ class DecodeEngine:
             self._m_hit.inc()
         return exe
 
-    def _decode_exe(self):
+    def _build(self, program, *small):
+        """Compile one step program ahead of time against this engine's
+        parameters and cache: ``exe(params, cache, *small) -> (*lead,
+        cache)``, the cache donated whole (inference/programs.py)."""
+        return jax.jit(
+            program, donate_argnums=(1,) if self._donate else ()).lower(
+                self._params, self._cache, *small).compile()
+
+    def _step_exe(self):
+        """The batched step: decode, or ONE verify program when
+        speculating, regardless of which slots drafted how much (tests/
+        test_no_retrace.py). The paged-attention impl is baked into the
+        traced decode program, so the flag is part of the cache key:
+        flipping it compiles a new program instead of being silently
+        ignored (same rule as tpu_flash_impl in the jit ProgramCache)."""
         from paddle_tpu.framework.flags import flag_value
-        cfg, steps = self.cfg, self._steps
-        n_scales = 2 if self._quant_kv else 0
-        B, maxp = self.ecfg.max_slots, self.pages_per_slot
-        # the paged-attention impl is baked into the traced program, so the
-        # flag is part of the cache key — flipping it compiles a new decode
-        # program instead of being silently ignored (same rule as
-        # tpu_flash_impl in the jit ProgramCache)
-        impl_flag = flag_value("tpu_paged_impl")
-
-        sampling = self._sampling
-
-        def step_fn(params, kc, vc, tokens, *rest):
-            # slot_state: the ONE fused upload — [B, 3 + maxp] int32 of
-            # (fresh token id, length, flags, page-table row); `tokens` is
-            # the previous step's on-device output, overridden only for
-            # slots the host admitted since the last dispatch. ``scales``
-            # is (k_scale, v_scale) on an int8-KV engine, else empty. On a
-            # SAMPLING engine the upload carries two more trailing columns
-            # (temperature bits, top_k) and the [B+1, 2] uint32 key-chain
-            # buffer rides between `tokens` and the upload — tokens AND
-            # keys stay on device step to step.
-            if sampling:
-                keys, slot_state, *tail = rest
-            else:
-                keys = None
-                slot_state, *tail = rest
-            scales, state = tail[:n_scales], tail[n_scales:]
-            flags = slot_state[:, _COL_FLAGS]
-            active = (flags & _FLAG_ACTIVE) != 0
-            fresh = (flags & _FLAG_FRESH) != 0
-            toks = jnp.where(fresh, slot_state[:, _COL_TOKEN], tokens)
-            cache = dict(k_pages=kc, v_pages=vc,
-                         page_table=slot_state[:,
-                                               _STATE_COLS:_STATE_COLS
-                                               + maxp],
-                         lengths=slot_state[:, _COL_LENGTH])
-            if scales:
-                cache.update(k_scale=scales[0], v_scale=scales[1])
-            if state:
-                cache.update(state=tuple(state))
-            logits, cache = steps.decode_step(params, toks, cache,
-                                              active, cfg=cfg)
-            if sampling:
-                from paddle_tpu.kernels.sampling import fused_sample
-                temps = jax.lax.bitcast_convert_type(
-                    slot_state[:, _STATE_COLS + maxp], jnp.float32)
-                topks = slot_state[:, _STATE_COLS + maxp + 1]
-                nxt, new_keys = fused_sample(logits, keys[:B], temps,
-                                             topks)
-                nxt = jnp.where(active, nxt.astype(toks.dtype), toks)
-                keys = keys.at[:B].set(
-                    jnp.where(active[:, None], new_keys, keys[:B]))
-                out = (nxt, keys, cache["k_pages"], cache["v_pages"])
-            else:
-                nxt = jnp.argmax(logits, axis=-1).astype(toks.dtype)
-                nxt = jnp.where(active, nxt, toks)
-                out = (nxt, cache["k_pages"], cache["v_pages"])
-            if scales:
-                out += (cache["k_scale"], cache["v_scale"])
-            if state:
-                out += tuple(cache["state"])
-            return out
-
-        def build():
-            if sampling:
-                args = [self._params, self._kc, self._vc,
-                        jnp.zeros(B, jnp.int32), self._keys_dev,
-                        jnp.zeros((B, _STATE_COLS + maxp + 2), jnp.int32)]
-                donate = self._donated(args, 1, 2, 4)
-            else:
-                args = [self._params, self._kc, self._vc,
-                        jnp.zeros(B, jnp.int32),
-                        jnp.zeros((B, _STATE_COLS + maxp), jnp.int32)]
-                donate = self._donated(args, 1, 2)
-            args += self._scale_args()
-            return jax.jit(step_fn, donate_argnums=donate).lower(
-                *args).compile()
-
-        return self._compiled(("decode", impl_flag), build)
-
-    def _scale_args(self):
-        """What every step program takes after its upload: the int8 pool's
-        scale pools, then the family's state arrays (window rings,
-        recurrent state) — all of them returned updated and donated."""
-        return ([self._ks, self._vs] if self._quant_kv else []) \
-            + list(self._state)
-
-    def _donated(self, lead_args, *lead):
-        """donate_argnums of a step program: the ``lead`` positions (pools,
-        on-device token/key chains) and every `_scale_args` position, which
-        follow ``lead_args``."""
-        if not self._donate:
-            return ()
-        n = len(lead_args)
-        return tuple(lead) + tuple(range(n, n + len(self._scale_args())))
-
-    def _export_pages(self, pages):
-        """The listed pages' contents off the device, as numpy ``(k, v,
-        k_scales, v_scales)``: values ``[nl, n, page_size, nh, dh]`` (the
-        shape every wire format states), scales ``[nl, n, page_size, nh]``
-        or None off an int8 pool. ONE batched gather per pool."""
-        from paddle_tpu.kernels.paged_attention import export_pages
-        out = [np.asarray(b) for b in export_pages(
-            self._kc, self._vc, pages, self._nh,
-            k_scales=self._ks, v_scales=self._vs)]
-        return tuple(out) if self._quant_kv else (*out, None, None)
-
-    def _adopt_pools(self, out, n_lead=1):
-        """Unpack one step/prefill program's outputs — ``n_lead`` leading
-        values, then the cache pools (+ scale pools on an int8 engine) —
-        adopting the pools in place. The ONE place the output pytree's
-        pool tail is interpreted: a future pool (fp8, paged metadata)
-        extends this and every invocation site follows."""
-        pools = out[n_lead:]
-        if self._quant_kv:
-            self._kc, self._vc, self._ks, self._vs = pools[:4]
+        if self._spec:
+            make, key = verify_program, ("verify", self._spec_k)
         else:
-            self._kc, self._vc = pools[:2]
-        self._state = tuple(pools[4 if self._quant_kv else 2:])
-        return out[0] if n_lead == 1 else out[:n_lead]
+            make = decode_program
+            key = ("decode", flag_value("tpu_paged_impl"))
+        up = self._step_upload
+        return self._compiled(key, lambda: self._build(
+            make(self._steps, self.cfg, up), self._tok_dev, up.spec()))
 
-    def _prefill_exe(self, bucket: int):
+    def _prefill_upload(self, tokens: int, chunk: bool):
+        return prefill_upload(tokens, self.pages_per_slot, self._sampling,
+                              self._stateful, chunk)
+
+    def _prefill_exe(self, tokens: int, chunk: bool = False):
+        """A one-shot prefill of a ``tokens`` bucket, or (``chunk``) the
+        chunk program, which serves two callers with one shape family:
+        decode-priority chunked prefill (tokens = prefill_chunk_tokens)
+        and the prefix-cache TAIL prefill (tokens = the tail's pow-2
+        bucket). The prefill-attention impl is baked into the traced
+        program (kernels/registry.py), so the flag keys the cache."""
         from paddle_tpu.framework.flags import flag_value
-        cfg, steps = self.cfg, self._steps
-        maxp = self.pages_per_slot
-        n_scales = 2 if self._quant_kv else 0
-        x_slot = 1 if self._stateful else 0    # trailing slot index
-        # the prefill-attention impl is baked into the traced program
-        # (kernels/registry.py) — the flag keys the cache like
-        # tpu_paged_impl keys the decode program
-        impl_flag = flag_value("tpu_prefill_impl")
-
-        sampling = self._sampling
-
-        def prefill_fn(params, kc, vc, *rest):
-            # packed [bucket + 1 + maxp] int32: ids | true length | page
-            # row — one fused upload per admission. A SAMPLING engine
-            # appends [slot, key0, key1, temperature bits, top_k]: the
-            # first token samples through the fused sampler from the
-            # request's seed key and the advanced chain lands in the
-            # on-device key buffer at `slot` (row B = scratch for
-            # slotless export/stream prefills) — no key readback, the
-            # decode step picks the chain up where prefill left it.
-            if sampling:
-                keys, packed, *tail = rest
-            else:
-                keys = None
-                packed, *tail = rest
-            scales, state = tail[:n_scales], tail[n_scales:]
-            ids = packed[:bucket]
-            length = packed[bucket]
-            row = packed[bucket + 1:bucket + 1 + maxp]
-            kw = {}
-            if scales:
-                kw.update(k_scale=scales[0], v_scale=scales[1])
-            if state:
-                # a family with state: the packed upload's LAST int is the
-                # slot whose rings and recurrent state this prompt fills
-                kw.update(state=tuple(state), slot=packed[-1])
-            logits, kc, vc, *more = steps.prefill_step(
-                params, ids, length, row, kc, vc, cfg=cfg, **kw)
-            if sampling:
-                from paddle_tpu.kernels.sampling import sample_one
-                tail = packed[bucket + 1 + maxp:]
-                kseed = jax.lax.bitcast_convert_type(tail[1:3], jnp.uint32)
-                temp = jax.lax.bitcast_convert_type(tail[3], jnp.float32)
-                tok, new_key = sample_one(logits, kseed, temp, tail[4])
-                tok = tok.astype(ids.dtype)
-                keys = keys.at[tail[0]].set(new_key)
-                out = (tok, keys, kc, vc)
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype(ids.dtype)
-                out = (tok, kc, vc)
-            return out + tuple(more)
 
         def build():
-            if sampling:
-                args = [self._params, self._kc, self._vc, self._keys_dev,
-                        jnp.zeros(bucket + 1 + maxp + 5 + x_slot,
-                                  jnp.int32)]
-                donate = self._donated(args, 1, 2, 3)
-            else:
-                args = [self._params, self._kc, self._vc,
-                        jnp.zeros(bucket + 1 + maxp + x_slot, jnp.int32)]
-                donate = self._donated(args, 1, 2)
-            args += self._scale_args()
-            return jax.jit(prefill_fn, donate_argnums=donate).lower(
-                *args).compile()
-
-        return self._compiled(("prefill", bucket, impl_flag), build)
-
-    def _prefill_chunk_exe(self, c: int | None = None):
-        """The chunk program serves two callers with one shape family:
-        decode-priority chunked prefill (c = prefill_chunk_tokens) and the
-        prefix-cache TAIL prefill (c = the tail's pow-2 bucket) — both are
-        'prefill a window starting at an absolute position', which is
-        exactly `prefill_chunk_step`'s contract."""
-        from paddle_tpu.framework.flags import flag_value
-        cfg, steps = self.cfg, self._steps
-        maxp = self.pages_per_slot
-        n_scales = 2 if self._quant_kv else 0
-        x_slot = 1 if self._stateful else 0    # trailing slot index
-        c = int(self.ecfg.prefill_chunk_tokens) if c is None else int(c)
-        impl_flag = flag_value("tpu_prefill_impl")   # keys the cache (see
-        #                                              _prefill_exe)
-
-        sampling = self._sampling
-
-        def chunk_fn(params, kc, vc, *rest):
-            # packed [c + 2 + maxp] int32: chunk ids | start | valid | page
-            # row — one fused upload per chunk, no readback until the final
-            # chunk's sampled token. A SAMPLING engine appends [slot, key0,
-            # key1, temperature bits, top_k, final]: only the FINAL chunk
-            # samples (and advances the chain at `slot`) — intermediate
-            # chunks leave tok at the argmax arm and the chain untouched,
-            # so the chain advances exactly once per emitted token.
-            if sampling:
-                keys, packed, *tail = rest
-            else:
-                keys = None
-                packed, *tail = rest
-            scales, state = tail[:n_scales], tail[n_scales:]
-            ids = packed[:c]
-            start = packed[c]
-            valid = packed[c + 1]
-            row = packed[c + 2:c + 2 + maxp]
-            kw = {}
-            if scales:
-                kw.update(k_scale=scales[0], v_scale=scales[1])
-            if state:
-                kw.update(state=tuple(state), slot=packed[-1])
-            logits, kc, vc, *more = steps.prefill_chunk_step(
-                params, ids, start, valid, row, kc, vc, cfg=cfg, **kw)
-            if sampling:
-                from paddle_tpu.kernels.sampling import sample_one
-                tail = packed[c + 2 + maxp:]
-                kseed = jax.lax.bitcast_convert_type(tail[1:3], jnp.uint32)
-                temp = jax.lax.bitcast_convert_type(tail[3], jnp.float32)
-                tok_s, new_key = sample_one(logits, kseed, temp, tail[4])
-                final = tail[5] != 0
-                tok = jnp.where(final, tok_s.astype(ids.dtype),
-                                jnp.argmax(logits, axis=-1)
-                                .astype(ids.dtype))
-                slot = tail[0]
-                keys = keys.at[slot].set(
-                    jnp.where(final, new_key, keys[slot]))
-                out = (tok, keys, kc, vc)
-            else:
-                tok = jnp.argmax(logits, axis=-1).astype(ids.dtype)
-                out = (tok, kc, vc)
-            return out + tuple(more)
-
-        def build():
-            if sampling:
-                args = [self._params, self._kc, self._vc, self._keys_dev,
-                        jnp.zeros(c + 2 + maxp + 6 + x_slot, jnp.int32)]
-                donate = self._donated(args, 1, 2, 3)
-            else:
-                args = [self._params, self._kc, self._vc,
-                        jnp.zeros(c + 2 + maxp + x_slot, jnp.int32)]
-                donate = self._donated(args, 1, 2)
-            args += self._scale_args()
-            return jax.jit(chunk_fn, donate_argnums=donate).lower(
-                *args).compile()
-
-        return self._compiled(("prefill_chunk", c, impl_flag), build)
-
-    def _verify_exe(self):
-        """The speculative k-token verify step: ONE AOT program regardless
-        of which slots drafted how much — draft contents and draft_len ride
-        the packed upload, never a shape (tests/test_no_retrace.py)."""
-        cfg, steps = self.cfg, self._steps
-        B, maxp = self.ecfg.max_slots, self.pages_per_slot
-        K = self._spec_k
-
-        sampling = self._sampling
-
-        def step_fn(params, kc, vc, tokens, *rest):
-            # slot_state: [B, 4 + K + maxp] int32 — (fresh token, length,
-            # flags, draft_len, K drafted tokens, page-table row); a
-            # SAMPLING engine appends (temperature bits, top_k) columns
-            # and threads the on-device key buffer like _decode_exe —
-            # verify_step's fused sample_state path advances each slot's
-            # chain by exactly its n_emitted splits
-            if sampling:
-                keys, slot_state, *scales = rest
-            else:
-                keys = None
-                slot_state, *scales = rest
-            flags = slot_state[:, _COL_FLAGS]
-            active = (flags & _FLAG_ACTIVE) != 0
-            fresh = (flags & _FLAG_FRESH) != 0
-            tok0 = jnp.where(fresh, slot_state[:, _COL_TOKEN], tokens)
-            draft_len = slot_state[:, _COL_DRAFT]
-            drafts = slot_state[:, _SPEC_COLS:_SPEC_COLS + K]
-            tok_seq = jnp.concatenate([tok0[:, None], drafts], axis=1)
-            cache = dict(k_pages=kc, v_pages=vc,
-                         page_table=slot_state[:,
-                                               _SPEC_COLS + K:
-                                               _SPEC_COLS + K + maxp],
-                         lengths=slot_state[:, _COL_LENGTH])
-            if scales:
-                cache.update(k_scale=scales[0], v_scale=scales[1])
-            if sampling:
-                temps = jax.lax.bitcast_convert_type(
-                    slot_state[:, _SPEC_COLS + K + maxp], jnp.float32)
-                topks = slot_state[:, _SPEC_COLS + K + maxp + 1]
-                emitted, n_emitted, cache, new_keys = steps.verify_step(
-                    params, tok_seq, draft_len, cache, active, cfg=cfg,
-                    sample_state=(keys[:B], temps, topks))
-                keys = keys.at[:B].set(new_keys)
-            else:
-                emitted, n_emitted, cache = steps.verify_step(
-                    params, tok_seq, draft_len, cache, active, cfg=cfg)
-            nxt = jnp.take_along_axis(
-                emitted, jnp.maximum(n_emitted - 1, 0)[:, None], axis=1)[:, 0]
-            nxt = jnp.where(active, nxt, tok0)
-            out = (emitted, n_emitted, nxt) \
-                + ((keys,) if sampling else ()) \
-                + (cache["k_pages"], cache["v_pages"])
-            if scales:
-                out += (cache["k_scale"], cache["v_scale"])
-            return out
-
-        def build():
-            if sampling:
-                args = [self._params, self._kc, self._vc,
-                        jnp.zeros(B, jnp.int32), self._keys_dev,
-                        jnp.zeros((B, _SPEC_COLS + K + maxp + 2),
-                                  jnp.int32)]
-                donate = self._donated(args, 1, 2, 4)
-            else:
-                args = [self._params, self._kc, self._vc,
-                        jnp.zeros(B, jnp.int32),
-                        jnp.zeros((B, _SPEC_COLS + K + maxp), jnp.int32)]
-                donate = self._donated(args, 1, 2)
-            args += self._scale_args()
-            return jax.jit(step_fn, donate_argnums=donate).lower(
-                *args).compile()
-
-        return self._compiled(("verify", K), build)
+            up = self._prefill_upload(tokens, chunk)
+            return self._build(prefill_program(self._steps, self.cfg, up),
+                               up.spec())
+        return self._compiled(
+            ("prefill_chunk" if chunk else "prefill", tokens,
+             flag_value("tpu_prefill_impl")), build)
 
     def _use_chunked(self, prompt_len: int) -> bool:
         c = self.ecfg.prefill_chunk_tokens
@@ -1520,10 +1074,7 @@ class DecodeEngine:
         tail bucket) so a server's first cache hit doesn't pay a compile
         inside a request's TTFT. Optional — programs also compile lazily on
         first use — but lets servers front-load compiles before traffic."""
-        if self._spec:
-            self._verify_exe()
-        else:
-            self._decode_exe()
+        self._step_exe()
         need_chunk = False
         for s in prompt_lens:
             if self._use_chunked(int(s)):
@@ -1534,9 +1085,10 @@ class DecodeEngine:
             if self.ecfg.prefill_chunk_tokens is not None:
                 need_chunk = True
             else:
-                self._prefill_chunk_exe(self.bucket_for(int(t)))
+                self._prefill_exe(self.bucket_for(int(t)), chunk=True)
         if need_chunk:
-            self._prefill_chunk_exe()
+            self._prefill_exe(int(self.ecfg.prefill_chunk_tokens),
+                              chunk=True)
 
     def refresh_params(self, model):
         """Swap in current weights; programs take params as inputs, so this
@@ -1647,7 +1199,7 @@ class DecodeEngine:
             if faults.ENABLED and faults.fire("kvtier.spill_fail"):
                 raise faults.FaultInjected(
                     "injected spill failure (kvtier.spill_fail)")
-            kb, vb, ksb, vsb = self._export_pages(pages)
+            kb, vb, ksb, vsb = self._cache.export_pages(pages)
             for i, h in enumerate(hashes):
                 self._tiers.put(h, kb[:, i], vb[:, i],
                                 None if ksb is None else ksb[:, i],
@@ -1690,20 +1242,11 @@ class DecodeEngine:
             if faults.ENABLED and faults.fire("kvtier.reupload_fail"):
                 raise faults.FaultInjected(
                     "injected re-upload failure (kvtier.reupload_fail)")
-            from paddle_tpu.kernels.paged_attention import import_pages
-            kb = jnp.asarray(np.stack([e.k for e in entries], axis=1))
-            vb = jnp.asarray(np.stack([e.v for e in entries], axis=1))
-            if self._quant_kv:
-                self._kc, self._vc, self._ks, self._vs = import_pages(
-                    self._kc, self._vc, kb, vb, pages[:n],
-                    k_scales=self._ks, v_scales=self._vs,
-                    k_s_blob=jnp.asarray(
-                        np.stack([e.ks for e in entries], axis=1)),
-                    v_s_blob=jnp.asarray(
-                        np.stack([e.vs for e in entries], axis=1)))
-            else:
-                self._kc, self._vc = import_pages(
-                    self._kc, self._vc, kb, vb, pages[:n])
+            def stack(f):
+                return np.stack([getattr(e, f) for e in entries], axis=1)
+            self._cache = self._cache.import_pages(
+                pages[:n], stack("k"), stack("v"),
+                *((stack("ks"), stack("vs")) if self._quant_kv else ()))
         except Exception as e:  # noqa: BLE001 — degrade to cold prefill
             self._m_reupload_fail.inc()
             flight.record("engine.kvtier.reupload_fail", pages=n,
@@ -2284,31 +1827,57 @@ class DecodeEngine:
         self._h_prefill.observe(time.perf_counter() - t0)
         self._seed_first_token(slot, req, first)
 
-    def _sample_tail(self, slot, req, final=None) -> np.ndarray:
-        """The trailing ints a SAMPLING engine's prefill uploads carry:
-        [slot, key0, key1, temperature bits, top_k(, final)]. ``slot``
-        None routes the chain write to the scratch row B (slotless
-        export/stream prefills); ``req`` None (or a greedy request) rides
-        the argmax arm with a frozen zero key. The PRNGKey(seed)
-        materialization (a tiny device round trip) happens once per
-        REQUEST, cached — and only for the upload that consumes it (the
-        one-shot / FINAL chunk): intermediate chunks never sample, so
-        their tails ship zero key words."""
-        tail = np.zeros(5 if final is None else 6, np.int32)
-        tail[0] = self.ecfg.max_slots if slot is None else int(slot)
+    def _put_sampler(self, up, packed, slot, req, final=None):
+        """Write the fields a SAMPLING engine's prefill upload carries
+        (inference/programs.py::prefill_upload). ``slot`` None routes the
+        chain write to the scratch row B (slotless export/stream
+        prefills); ``req`` None (or a greedy request) rides the argmax arm
+        with a frozen zero key. The PRNGKey(seed) materialization (a tiny
+        device round trip) happens once per REQUEST, cached — and only
+        for the upload that consumes it (the one-shot / FINAL chunk):
+        intermediate chunks never sample, so they ship zero key words."""
+        packed[up["key_slot"]] = self.ecfg.max_slots if slot is None \
+            else int(slot)
+        packed[up["temp"]] = np.float32(
+            1.0 if req is None else req.temperature).view(np.int32)
         if req is not None:
             if final is None or final:
                 if req._seed_key is None:
                     req._seed_key = np.asarray(
                         jax.random.PRNGKey(int(req.seed)), np.uint32)
-                tail[1:3] = req._seed_key.view(np.int32)
-            tail[3] = np.float32(req.temperature).view(np.int32)
-            tail[4] = int(req.top_k)
-        else:
-            tail[3] = np.float32(1.0).view(np.int32)
+                packed[up["seed"]] = req._seed_key.view(np.int32)
+            packed[up["top_k"]] = int(req.top_k)
         if final is not None:
-            tail[5] = 1 if final else 0
-        return tail
+            packed[up["final"]] = 1 if final else 0
+
+    def _launch_prefill(self, tokens: int, chunk: bool, ids: np.ndarray,
+                        where: dict, row: np.ndarray, slot, req,
+                        final=None):
+        """Pack ONE prefill upload (``ids`` into a ``tokens``-wide program,
+        ``where`` = its length, or its start and valid count) and enqueue
+        its program: one fused upload, no readback. Returns the on-device
+        sampled token. The single owner of the host side of
+        `prefill_upload` for the one-shot, interleaved, back-to-back and
+        prefix-tail paths."""
+        up = self._prefill_upload(tokens, chunk)
+        packed = np.zeros(up.shape, np.int32)
+        packed[up["ids"]][:ids.size] = ids
+        for name, value in where.items():
+            packed[up[name]] = value
+        packed[up["row"]] = row
+        if self._sampling:
+            self._put_sampler(up, packed, slot, req, final)
+        if self._stateful:
+            packed[up["slot"]] = slot
+        exe = self._prefill_exe(tokens, chunk)
+        self._m_h2d.inc()
+        self._m_prefill_launches.inc()
+        self._m_prefill_tokens.inc(int(ids.size))
+        if req is not None:
+            req.u_prefill_computed += int(ids.size)
+        tok, self._cache = exe(self._params, self._cache,
+                               jax.device_put(packed))
+        return tok
 
     def _run_prefill(self, ids: np.ndarray, row: np.ndarray,
                      start: int = 0, slot=None, req=None) -> int:
@@ -2320,7 +1889,6 @@ class DecodeEngine:
         the slot's key chain) and `prefill_export` (which has no slot to
         interleave around, so its chunks run consecutively)."""
         s0 = ids.size
-        maxp = self.pages_per_slot
         if start or self._use_chunked(s0):
             # chunk-program prefill from ``start`` on: the configured chunk
             # size when chunking is on, else the tail's own pow-2 bucket
@@ -2339,34 +1907,10 @@ class DecodeEngine:
             with metrics.span("engine.prefill_launch", cat="engine",
                               kind="oneshot", tokens=int(s0),
                               request_id=req and req.request_id):
-                bucket = self.bucket_for(s0)
-                x = 5 if self._sampling else 0
-                packed = np.zeros(bucket + 1 + maxp + x
-                                  + (1 if self._stateful else 0), np.int32)
-                packed[:s0] = ids
-                packed[bucket] = s0
-                packed[bucket + 1:bucket + 1 + maxp] = row
-                if self._sampling:
-                    packed[bucket + 1 + maxp:bucket + 1 + maxp + x] = \
-                        self._sample_tail(slot, req)
                 if self._stateful:
-                    packed[-1] = slot
                     self._count_window_pages(0, s0)
-                exe = self._prefill_exe(bucket)
-                self._m_h2d.inc()
-                self._m_prefill_launches.inc()
-                self._m_prefill_tokens.inc(s0)
-                if req is not None:
-                    req.u_prefill_computed += int(s0)
-                if self._sampling:
-                    tok, self._keys_dev = self._adopt_pools(
-                        exe(self._params, self._kc, self._vc,
-                            self._keys_dev, jax.device_put(packed),
-                            *self._scale_args()), n_lead=2)
-                else:
-                    tok = self._adopt_pools(
-                        exe(self._params, self._kc, self._vc,
-                            jax.device_put(packed), *self._scale_args()))
+                tok = self._launch_prefill(self.bucket_for(s0), False, ids,
+                                           dict(length=s0), row, slot, req)
         return self._read_first_token(tok)
 
     def _count_window_pages(self, lo: int, hi: int):
@@ -2390,9 +1934,8 @@ class DecodeEngine:
     def _run_chunk(self, ids: np.ndarray, done: int, row: np.ndarray,
                    c: int | None = None, slot=None, req=None,
                    final: bool = False, kind: str = "chunk"):
-        """Pack and enqueue ONE prefill chunk (``ids[done:done+c]`` against
-        page ``row``) — the single owner of the packed chunk layout for
-        the interleaved (`_advance_prefill`), back-to-back
+        """Enqueue ONE prefill chunk (``ids[done:done+c]`` against page
+        ``row``) for the interleaved (`_advance_prefill`), back-to-back
         (`_run_prefill`), and prefix-tail paths. Returns the chunk
         program's on-device sampled token (meaningful only for the final
         chunk; no readback here). On a sampling engine the FINAL chunk
@@ -2406,36 +1949,13 @@ class DecodeEngine:
         with metrics.span("engine.prefill_launch", cat="engine", kind=kind,
                           tokens=int(chunk.size),
                           request_id=req and req.request_id, **carried):
-            x = 6 if self._sampling else 0
-            n = c + 2 + self.pages_per_slot
-            packed = np.zeros(n + x + (1 if self._stateful else 0),
-                              np.int32)
-            packed[:chunk.size] = chunk
-            packed[c] = done
-            packed[c + 1] = chunk.size
-            packed[c + 2:n] = row
-            if self._sampling:
-                packed[n:n + x] = self._sample_tail(slot, req, final=final)
             if self._stateful:
-                packed[-1] = slot
                 if done > 0:
                     self._m_state_carries.inc()
                 self._count_window_pages(done, done + int(chunk.size))
-            exe = self._prefill_chunk_exe(c)
-            self._m_h2d.inc()
-            self._m_prefill_launches.inc()
-            self._m_prefill_tokens.inc(int(chunk.size))
-            if req is not None:
-                req.u_prefill_computed += int(chunk.size)
-            if self._sampling:
-                tok, self._keys_dev = self._adopt_pools(
-                    exe(self._params, self._kc, self._vc, self._keys_dev,
-                        jax.device_put(packed), *self._scale_args()),
-                    n_lead=2)
-            else:
-                tok = self._adopt_pools(
-                    exe(self._params, self._kc, self._vc,
-                        jax.device_put(packed), *self._scale_args()))
+            tok = self._launch_prefill(
+                c, True, chunk, dict(start=done, valid=chunk.size), row,
+                slot, req, final)
         self._m_chunks.inc()
         return tok
 
@@ -2532,18 +2052,22 @@ class DecodeEngine:
 
     # ----------------------------------------------------------------- step
 
-    def _packed_state(self) -> np.ndarray:
-        B, maxp = self.ecfg.max_slots, self.pages_per_slot
-        x = 2 if self._sampling else 0   # trailing (temp bits, top_k)
-        packed = np.empty((B, _STATE_COLS + maxp + x), np.int32)
-        packed[:, _COL_TOKEN] = self._tokens
-        packed[:, _COL_LENGTH] = self._lengths
-        packed[:, _COL_FLAGS] = (self._active.astype(np.int32) * _FLAG_ACTIVE
-                                 | self._fresh.astype(np.int32) * _FLAG_FRESH)
-        packed[:, _STATE_COLS:_STATE_COLS + maxp] = self._page_table
+    def _packed_state(self, drafts=None, draft_lens=None) -> np.ndarray:
+        """The host mirrors as the batched step's ONE upload (`step_upload`;
+        a verify step's carries its drafts)."""
+        up = self._step_upload
+        packed = np.empty(up.shape, np.int32)
+        packed[:, up["token"]] = self._tokens
+        packed[:, up["length"]] = self._lengths
+        packed[:, up["flags"]] = (self._active.astype(np.int32) * FLAG_ACTIVE
+                                  | self._fresh.astype(np.int32) * FLAG_FRESH)
+        packed[:, up["table"]] = self._page_table
+        if drafts is not None:
+            packed[:, up["draft_len"]] = draft_lens
+            packed[:, up["drafts"]] = drafts
         if self._sampling:
-            packed[:, _STATE_COLS + maxp] = self._temps.view(np.int32)
-            packed[:, _STATE_COLS + maxp + 1] = self._topks
+            packed[:, up["temp"]] = self._temps.view(np.int32)
+            packed[:, up["top_k"]] = self._topks
         return packed
 
     def _dispatch(self):
@@ -2552,19 +2076,12 @@ class DecodeEngine:
         per-slot PRNG key chains) stay on device for the next step."""
         with metrics.span("engine.dispatch", cat="engine",
                           active=int(np.count_nonzero(self._active))):
-            exe = self._decode_exe()
+            exe = self._step_exe()
             self._m_h2d.inc()
             state = jax.device_put(self._packed_state())
             t0 = time.perf_counter()
-            if self._sampling:
-                self._tok_dev, self._keys_dev = self._adopt_pools(
-                    exe(self._params, self._kc, self._vc, self._tok_dev,
-                        self._keys_dev, state, *self._scale_args()),
-                    n_lead=2)
-            else:
-                self._tok_dev = self._adopt_pools(
-                    exe(self._params, self._kc, self._vc, self._tok_dev,
-                        state, *self._scale_args()))
+            self._tok_dev, self._cache = exe(self._params, self._cache,
+                                             self._tok_dev, state)
             snapshot = [(int(i), self._slot_req[i])
                         for i in np.flatnonzero(self._active)]
             self._inflight.append((self._tok_dev, snapshot, t0))
@@ -2588,23 +2105,6 @@ class DecodeEngine:
             self._m_steps.inc()
 
     # ----------------------------------------------------- speculative step
-
-    def _packed_spec_state(self, drafts: np.ndarray,
-                           draft_lens: np.ndarray) -> np.ndarray:
-        B, maxp, K = self.ecfg.max_slots, self.pages_per_slot, self._spec_k
-        x = 2 if self._sampling else 0   # trailing (temp bits, top_k)
-        packed = np.empty((B, _SPEC_COLS + K + maxp + x), np.int32)
-        packed[:, _COL_TOKEN] = self._tokens
-        packed[:, _COL_LENGTH] = self._lengths
-        packed[:, _COL_FLAGS] = (self._active.astype(np.int32) * _FLAG_ACTIVE
-                                 | self._fresh.astype(np.int32) * _FLAG_FRESH)
-        packed[:, _COL_DRAFT] = draft_lens
-        packed[:, _SPEC_COLS:_SPEC_COLS + K] = drafts
-        packed[:, _SPEC_COLS + K:_SPEC_COLS + K + maxp] = self._page_table
-        if self._sampling:
-            packed[:, _SPEC_COLS + K + maxp] = self._temps.view(np.int32)
-            packed[:, _SPEC_COLS + K + maxp + 1] = self._topks
-        return packed
 
     def _dispatch_spec(self):
         """Enqueue ONE speculative verify step: draft on host (n-gram),
@@ -2632,20 +2132,11 @@ class DecodeEngine:
                 draft_lens[slot] = n
         with metrics.span("engine.dispatch", cat="engine",
                           active=int(np.count_nonzero(self._active))):
-            exe = self._verify_exe()
+            exe = self._step_exe()
             self._m_h2d.inc()
-            state = jax.device_put(
-                self._packed_spec_state(drafts, draft_lens))
-            if self._sampling:
-                (emitted_dev, n_emit_dev, self._tok_dev,
-                 self._keys_dev) = self._adopt_pools(
-                    exe(self._params, self._kc, self._vc, self._tok_dev,
-                        self._keys_dev, state, *self._scale_args()),
-                    n_lead=4)
-            else:
-                emitted_dev, n_emit_dev, self._tok_dev = self._adopt_pools(
-                    exe(self._params, self._kc, self._vc, self._tok_dev,
-                        state, *self._scale_args()), n_lead=3)
+            state = jax.device_put(self._packed_state(drafts, draft_lens))
+            emitted_dev, n_emit_dev, self._tok_dev, self._cache = exe(
+                self._params, self._cache, self._tok_dev, state)
             snapshot = [(int(i), self._slot_req[i])
                         for i in np.flatnonzero(self._active)]
             self._fresh[:] = False
@@ -2883,7 +2374,8 @@ class DecodeEngine:
                 ids, row,
                 start=(len(shared) + n_up) * self.ecfg.page_size)
             t0 = time.perf_counter()
-            k_np, v_np, ks_np, vs_np = self._export_pages(all_pages)
+            k_np, v_np, ks_np, vs_np = self._cache.export_pages(
+                all_pages)
             if self._quant_kv:
                 metrics.histogram("engine.quant_dequant_ms").observe(
                     (time.perf_counter() - t0) * 1e3)
@@ -3029,7 +2521,7 @@ class DecodeEngine:
         sink.put(("count", n_records))
 
         def _blobs(p0, n):
-            return self._export_pages(all_pages[p0:p0 + n])
+            return self._cache.export_pages(all_pages[p0:p0 + n])
 
         try:
             seq = 0
@@ -3196,17 +2688,9 @@ class DecodeEngine:
         flight.record("engine.kv_import", request_id=req.request_id,
                       slot=slot, pages=len(pages),
                       prompt_len=int(req.prompt.size))
-        from paddle_tpu.kernels.paged_attention import import_pages
-        if self._quant_kv:
-            self._kc, self._vc, self._ks, self._vs = import_pages(
-                self._kc, self._vc, jnp.asarray(handoff.k_pages),
-                jnp.asarray(handoff.v_pages), pages[:n_src],
-                k_scales=self._ks, v_scales=self._vs,
-                k_s_blob=handoff.k_scales, v_s_blob=handoff.v_scales)
-        else:
-            self._kc, self._vc = import_pages(
-                self._kc, self._vc, jnp.asarray(handoff.k_pages),
-                jnp.asarray(handoff.v_pages), pages[:n_src])
+        self._cache = self._cache.import_pages(
+            pages[:n_src], handoff.k_pages, handoff.v_pages,
+            handoff.k_scales, handoff.v_scales)
         row = np.full(self.pages_per_slot, TRASH_PAGE, np.int32)
         row[:len(pages)] = pages
         self._page_table[slot] = row
@@ -3223,8 +2707,8 @@ class DecodeEngine:
                 # resume the ADVANCED chain exactly where the exporter
                 # left it (host write outside the step loop — imports are
                 # admission-rate events, never per-step)
-                self._keys_dev = self._keys_dev.at[slot].set(
-                    jnp.asarray(handoff.sample["key"], jnp.uint32))
+                self._cache = self._cache.with_key_chain(
+                    slot, handoff.sample["key"])
         metrics.counter("engine.kv_imports").inc()
         self._seed_first_token(slot, req, int(handoff.first_token))
 
@@ -3374,7 +2858,7 @@ class DecodeEngine:
                 # which will now run on the peer)
                 ctx = int(self._lengths[slot])
                 n_src = -(-ctx // self.ecfg.page_size)
-                k_np, v_np, ks_np, vs_np = self._export_pages(
+                k_np, v_np, ks_np, vs_np = self._cache.export_pages(
                     self._slot_pages[slot][:n_src])
                 context = np.concatenate(
                     [req.prompt, np.asarray(req.generated[:-1], np.int32)])
@@ -3390,11 +2874,10 @@ class DecodeEngine:
                     # on the peer continues the bit-identical sampled
                     # sequence (the readback is migration-time only,
                     # never on the step loop)
-                    krow = np.asarray(self._keys_dev)[slot]
                     handoff.sample = {
                         "temperature": float(req.temperature),
                         "top_k": int(req.top_k),
-                        "key": [int(krow[0]), int(krow[1])]}
+                        "key": self._cache.key_chain(slot)}
                 # the seed counts as the peer's first emission, so the
                 # peer budget is remaining + 1 — its full answer is then
                 # exactly the uninterrupted run's sequence

@@ -28,9 +28,10 @@ State beside the page pool is described by ``state(slots, page_size,
 dtype)`` -> ``((name, kind, shape, dtype), ...)`` with ``kind`` one of
 ``"window"`` (K and V of a bounded window: constant in sequence length) or
 ``"recurrent"`` (fixed-size state, read as zero by the chunk that starts a
-sequence and carried from chunk to chunk). The engine allocates the
-arrays, threads them through every step program donated like the pools,
-and — because pages alone then cannot restore a sequence — refuses prefix
+sequence and carried from chunk to chunk). The arrays live in the
+engine's `DeviceCache` (inference/cache.py: allocated there, donated into
+and returned from every step program with the pools), and — because pages
+alone then cannot restore a sequence — the engine refuses prefix
 reuse, speculation, hand-off, migration and tier spill by typed error
 (`errors.RecurrentStateUnsupported`; docs/SERVING.md "The model seam").
 """

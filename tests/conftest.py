@@ -30,26 +30,23 @@ def seeded_kv_pages():
     """``fill(eng, pages, seed) -> (k, v, k_scales, v_scales)``: seeded
     page contents ``[nl, n, page_size, nh, dh]`` (int8 values and f32
     scales on an int8 engine, else f32 and None) scattered into the
-    engine's pool at ``pages`` through `import_pages`. The wire-byte tests
-    export them again (test_migration, test_kv_tiers, test_disagg): their
+    engine's pool at ``pages`` through the cache's `import_pages`. The
+    wire-byte tests export them again (test_migration, test_kv_tiers,
+    test_disagg): their
     pinned digests were taken from the pool stored ``[..., nh, dh]``."""
     def fill(eng, pages, seed):
-        import jax.numpy as jnp
-        from paddle_tpu.kernels.paged_attention import import_pages
         rng = np.random.RandomState(seed)
         shape = (eng._nl, len(pages), eng.ecfg.page_size, eng._nh, eng._dh)
-        if not eng._quant_kv:
+        if eng._quant_kv:
+            k, v = (rng.randint(-127, 128, shape).astype(np.int8)
+                    for _ in range(2))
+            ks, vs = (rng.rand(*shape[:-1]).astype(np.float32)
+                      for _ in range(2))
+        else:
             k, v = (rng.standard_normal(shape).astype(np.float32)
                     for _ in range(2))
-            eng._kc, eng._vc = import_pages(
-                eng._kc, eng._vc, jnp.asarray(k), jnp.asarray(v), pages)
-            return k, v, None, None
-        k, v = (rng.randint(-127, 128, shape).astype(np.int8)
-                for _ in range(2))
-        ks, vs = (rng.rand(*shape[:-1]).astype(np.float32) for _ in range(2))
-        eng._kc, eng._vc, eng._ks, eng._vs = import_pages(
-            eng._kc, eng._vc, jnp.asarray(k), jnp.asarray(v), pages,
-            k_scales=eng._ks, v_scales=eng._vs, k_s_blob=ks, v_s_blob=vs)
+            ks = vs = None
+        eng._cache = eng._cache.import_pages(pages, k, v, ks, vs)
         return k, v, ks, vs
     return fill
 

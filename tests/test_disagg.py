@@ -570,30 +570,25 @@ def test_ptks1_record_is_byte_identical_to_the_unmerged_pools(
     ``import_pages(export_pages(...))`` into another engine's pool at
     other page ids lands every page bit-identical."""
     import hashlib
-    import jax.numpy as jnp
     from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
-    from paddle_tpu.kernels.paged_attention import import_pages
     from paddle_tpu.serving.disagg import pack_stream_pages
     ecfg = EngineConfig(page_size=4, max_slots=2, min_bucket=8, kv_dtype=kv)
     src = DecodeEngine(_tiny_model(), ecfg)
     pages = [5, 2, 7]
     seeded_kv_pages(src, pages, 2600)
-    blobs = src._export_pages(pages)
+    blobs = src._cache.export_pages(pages)
     assert hashlib.blake2b(pack_stream_pages(1, 0, *blobs),
                            digest_size=16).hexdigest() == PTKS1_DIGESTS[kv]
     dst = DecodeEngine(_tiny_model(), ecfg)
     there = [1, 8, 3]
-    out = import_pages(
-        dst._kc, dst._vc, jnp.asarray(blobs[0]), jnp.asarray(blobs[1]), there,
-        **({} if blobs[2] is None else dict(
-            k_scales=dst._ks, v_scales=dst._vs, k_s_blob=blobs[2],
-            v_s_blob=blobs[3])))
-    dst._kc, dst._vc = out[:2]
-    if blobs[2] is not None:
-        dst._ks, dst._vs = out[2:]
-    for a, b in zip(blobs, dst._export_pages(there)):
+    dst._cache = dst._cache.import_pages(there, *blobs)
+    for a, b in zip(blobs, dst._cache.export_pages(there)):
         if a is None:
             assert b is None
         else:
             np.testing.assert_array_equal(a, b)
+    # scales a float pool has no place for, or an int8 pool is not given
+    wrong = (blobs[0][..., 0], blobs[0][..., 0]) if kv == "f32" else ()
+    with pytest.raises(ValueError, match="page import"):
+        dst._cache.import_pages(there, blobs[0], blobs[1], *wrong)
 

@@ -708,7 +708,7 @@ def test_ptkv1_blob_is_byte_identical_to_the_unmerged_pools(
     pages = [5, 2, 7]
     want = seeded_kv_pages(eng, pages, 2600)
     assert eng._kc.shape[2:] == (4, eng._nh * eng._dh)
-    got = eng._export_pages(pages)
+    got = eng._cache.export_pages(pages)
     assert got[0].shape == (eng._nl, 3, 4, eng._nh, eng._dh)
     for a, b in zip(want, got):
         if a is None:

@@ -8,6 +8,7 @@ frozen — a regression that sneaks a host value into a traced shape fails
 here instead of as a silent 100x serving slowdown.
 """
 import numpy as np
+import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu.observability import metrics
@@ -636,3 +637,109 @@ def test_fused_sampler_adds_zero_programs():
     assert r3.done
     assert _compile_counters() == frozen2, (
         "greedy/sampled mix recompiled the verify program")
+
+
+class _OneMoreLeaf:
+    """A model whose family keeps one array more than the real one's: a
+    ``[slots]`` int32 that every step function bumps (decode: every slot;
+    a prefill: its slot). Nothing of the engine or its programs knows."""
+
+    def __init__(self, model):
+        self.cfg, self._model = model.cfg, model
+
+    def engine_family(self):
+        import dataclasses
+        import jax.numpy as jnp
+        fam = self._model.engine_family()
+        real, had = fam.steps, fam.state is not None
+
+        def state(slots, page, dtype):
+            return (*(fam.state(slots, page, dtype) if had else ()),
+                    ("calls", "recurrent", (slots,), jnp.int32))
+
+        def prefill(step):
+            def wrapped(*args, cfg, state, slot, **kw):
+                *rest, calls = state
+                if had:
+                    kw.update(state=tuple(rest), slot=slot)
+                return (*step(*args, cfg=cfg, **kw), calls.at[slot].add(1))
+            return wrapped
+
+        class steps:
+            @staticmethod
+            def decode_step(params, ids, cache, mask, *, cfg):
+                *rest, calls = cache.pop("state")
+                if had:
+                    cache["state"] = tuple(rest)
+                logits, cache = real.decode_step(params, ids, cache, mask,
+                                                 cfg=cfg)
+                cache["state"] = (*cache.get("state", ()), calls + 1)
+                return logits, cache
+            prefill_step = staticmethod(prefill(real.prefill_step))
+            prefill_chunk_step = staticmethod(
+                prefill(real.prefill_chunk_step))
+
+        return dataclasses.replace(
+            fam, steps=steps, state=state,
+            params=lambda m: fam.params(m._model))
+
+
+@pytest.mark.parametrize("kind", ["bf16", "int8", "sampling", "hybrid"])
+def test_every_program_takes_and_returns_the_cache_whole(kind):
+    """The one calling convention (inference/programs.py): every program
+    in ``eng._programs`` is ``exe(params, cache, *small) -> (*lead,
+    cache)``, the cache its one donated argument, every leaf of it
+    aliased to a result. And what is in the cache is the cache module's
+    business alone: an array added through the family's ``state`` reaches
+    every program, donated and returned, with no program edited. (An int8
+    pool refuses a family with state, so it is held to the convention
+    alone: its scale pools are the leaves a float pool has not.)"""
+    import re
+    import jax
+    from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
+    ecfg = dict(page_size=4, max_slots=3, min_bucket=8, max_seq_len=64,
+                prefill_chunk_tokens=8, prefix_cache=False, donate=True)
+    if kind == "hybrid":
+        from paddle_tpu.models import phi4flash as phi
+        cfg = phi.tiny_config()
+        model = phi.Phi4FlashForCausalLM(
+            cfg, phi.init_params(cfg, seed=7, std=0.1))
+    else:
+        model = _tiny_model()
+        ecfg.update({"bf16": dict(kv_dtype="bf16"),
+                     "int8": dict(kv_dtype="int8"),
+                     "sampling": dict(sampling=True)}[kind])
+    extra = kind != "int8"
+    eng = DecodeEngine(_OneMoreLeaf(model) if extra else model,
+                       EngineConfig(**ecfg))
+    state = model.engine_family().state
+    want = {"bf16": 2, "int8": 4, "sampling": 3}.get(kind) \
+        or 2 + len(state(3, 4, np.float32))
+    want += extra
+    leaves = jax.tree_util.tree_leaves
+    assert len(leaves(eng._cache)) == want
+    reqs = [eng.submit(np.arange(1, n + 1, dtype=np.int32), 3)
+            for n in (5, 21)]           # one one-shot prefill, three chunks
+    eng.run_until_idle(max_steps=60)
+    assert all(r.done for r in reqs)
+    assert sorted(k[0] for k in eng._programs) == \
+        ["decode", "prefill", "prefill_chunk"]
+    tree = jax.tree_util.tree_structure(eng._cache)
+    for key, exe in eng._programs.items():
+        args = exe.in_tree.children()[0].children()
+        assert args[1] == tree, key                    # ONE argument
+        assert exe.out_tree.children()[-1] == tree, key  # and ONE result
+        donated = [[i.donated for i in leaves(a)] for a in exe.args_info[0]]
+        assert all(donated[1]) and not any(
+            d for i, ds in enumerate(donated) if i != 1 for d in ds), key
+        head = exe.as_text().split("\n", 1)[0]
+        n_params = len(leaves(exe.args_info[0][0]))
+        aliased = sorted(int(p) for p in re.findall(
+            r"\{\d+\}: \((\d+), \{\}", head))
+        assert aliased == list(range(n_params, n_params + want)), (key, head)
+    if extra:
+        calls = np.asarray(eng._cache.state[-1])
+        # two prefilled slots: 1 one-shot + 3 chunks; every decode step
+        # bumps all three slots
+        steps = int(calls[2])
+        assert steps > 0 and sorted(calls - steps) == [0, 1, 3]

@@ -240,6 +240,10 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
     1024 elements) but the in-place update, and the compiler's temporaries
     stay under one layer's pool (they were 7.3 GB: PERF.md, PR 26)."""
     from paddle_tpu.framework.flags import set_flags
+    from paddle_tpu.inference.cache import DeviceCache
+    from paddle_tpu.inference.programs import (decode_program,
+                                               prefill_program,
+                                               prefill_upload, step_upload)
     from paddle_tpu.kernels.pallas import _compat
     from paddle_tpu.models import gpt
     # the kernels ask the default backend, which is the CPU here
@@ -248,34 +252,27 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
     cfg = gpt.GPTConfig(vocab_size=m["vocab"], hidden_size=m["hidden"],
                         num_layers=m["layers"], num_heads=m["heads"],
                         max_position_embeddings=m["positions"])
-
-    def ints(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=chip)
     pool = jax.ShapeDtypeStruct(
         (m["layers"], m["pages"], m["page"], m["hidden"]), BF16,
         sharding=chip)
+    cache = DeviceCache(k=pool, v=pool, k_scale=None, v_scale=None,
+                        state=(), keys=None, heads=m["heads"])
+    # the engine's own program functions, lowered as the engine lowers
+    # them: (params, cache, *small), the cache donated whole
     if program == "decode_step":
-        def step(params, kc, vc, ids, table, lengths, active):
-            cache = dict(k_pages=kc, v_pages=vc, page_table=table,
-                         lengths=lengths)
-            logits, cache = gpt.decode_step(params, ids, cache, active,
-                                            cfg=cfg)
-            return (jnp.argmax(logits, -1), cache["k_pages"],
-                    cache["v_pages"])
-        args = (ints(m["slots"]), ints(m["slots"], m["per_slot"]),
-                ints(m["slots"]),
-                jax.ShapeDtypeStruct((m["slots"],), jnp.bool_,
-                                     sharding=chip))
+        up = step_upload(m["slots"], m["per_slot"], sampling=False)
+        step = decode_program(gpt, cfg, up)
+        small = (jax.ShapeDtypeStruct((m["slots"],), jnp.int32,
+                                      sharding=chip), up.spec(sharding=chip))
     else:
-        def step(params, kc, vc, ids, start, valid, row):
-            logits, kc, vc = gpt.prefill_chunk_step(
-                params, ids, start, valid, row, kc, vc, cfg=cfg)
-            return jnp.argmax(logits, -1), kc, vc
-        args = (ints(m["chunk"]), ints(), ints(), ints(m["per_slot"]))
+        up = prefill_upload(m["chunk"], m["per_slot"], sampling=False,
+                            stateful=False, chunk=True)
+        step = prefill_program(gpt, cfg, up)
+        small = (up.spec(sharding=chip),)
     set_flags({"tpu_paged_impl": "pallas", "tpu_prefill_impl": "pallas"})
     try:
-        compiled = jax.jit(step, donate_argnums=(1, 2)).lower(
-            _medium_params(chip), pool, pool, *args).compile()
+        compiled = jax.jit(step, donate_argnums=(1,)).lower(
+            _medium_params(chip), cache, *small).compile()
     finally:
         set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
     text = compiled.as_text()
@@ -307,6 +304,10 @@ def test_hybrid_step_program_copies_no_pool_or_state_on_v5e(chip, program):
     Reach A5's. (A chunk's attention scores over its slot's gathered row,
     ``[.., 256, 2048]`` float32, are larger than a state stack and are no
     copy of anything.)"""
+    from paddle_tpu.inference.cache import DeviceCache
+    from paddle_tpu.inference.programs import (decode_program,
+                                               prefill_program,
+                                               prefill_upload, step_upload)
     from paddle_tpu.models import phi4flash as phi
     cfg = phi.Phi4FlashConfig()
     f = FLASH
@@ -318,29 +319,19 @@ def test_hybrid_step_program_copies_no_pool_or_state_on_v5e(chip, program):
     pool = sds((1, 1 + slots * per_slot, f["page"], cfg.kv_width), BF16)
     state = tuple(sds(s, d) for _, _, s, d in
                   phi.state_arrays(cfg, slots, f["page"], BF16))
+    cache = DeviceCache(k=pool, v=pool, k_scale=None, v_scale=None,
+                        state=state, keys=None, heads=cfg.num_kv_heads)
     if program == "decode_step":
-        def step(params, kc, vc, ids, table, lengths, active, *state):
-            cache = dict(k_pages=kc, v_pages=vc, page_table=table,
-                         lengths=lengths, state=state)
-            logits, cache = phi.decode_step(params, ids, cache, active,
-                                            cfg=cfg)
-            return (jnp.argmax(logits, -1), cache["k_pages"],
-                    cache["v_pages"], *cache["state"])
-        args = (sds((slots,), jnp.int32), sds((slots, per_slot), jnp.int32),
-                sds((slots,), jnp.int32), sds((slots,), jnp.bool_))
+        up = step_upload(slots, per_slot, sampling=False)
+        step = decode_program(phi, cfg, up)
+        small = (sds((slots,), jnp.int32), up.spec(sharding=chip))
     else:
-        def step(params, kc, vc, ids, start, valid, row, slot, *state):
-            logits, kc, vc, *state = phi.prefill_chunk_step(
-                params, ids, start, valid, row, kc, vc, cfg=cfg,
-                state=state, slot=slot)
-            return (jnp.argmax(logits, -1), kc, vc, *state)
-        args = (sds((f["chunk"],), jnp.int32), sds((), jnp.int32),
-                sds((), jnp.int32), sds((per_slot,), jnp.int32),
-                sds((), jnp.int32))
-    n = 3 + len(args)
-    compiled = jax.jit(step, donate_argnums=(1, 2) + tuple(
-        range(n, n + len(state)))).lower(
-            params, pool, pool, *args, *state).compile()
+        up = prefill_upload(f["chunk"], per_slot, sampling=False,
+                            stateful=True, chunk=True)
+        step = prefill_program(phi, cfg, up)
+        small = (up.spec(sharding=chip),)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, *small).compile()
     text = compiled.as_text()
     elems = {name: int(np.prod(s)) for name, _, s, _ in
              phi.state_arrays(cfg, slots, f["page"], BF16)}
