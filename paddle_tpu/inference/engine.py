@@ -167,8 +167,9 @@ class EngineConfig:
     donate       : donate cache buffers into the step program (defaults to
                    on for real accelerators, off on CPU where PJRT ignores
                    donation and warns)
-    inflight     : decode steps kept in flight before the host blocks on
-                   the oldest step's sampled tokens (deferred readback; 1
+    inflight     : decode steps (and prefills' first tokens, each an entry
+                   of the same fifo) kept in flight before the host blocks
+                   on the oldest one's sampled tokens (deferred readback; 1
                    restores the synchronous loop). EOS detection lags by up
                    to this many steps — the surplus tokens are discarded at
                    harvest, never delivered
@@ -781,8 +782,9 @@ class DecodeEngine:
         # "Disaggregated serving")
         metrics.gauge("engine.page_size").set(ps)
         # host-side mirrors of the per-slot state, fused into ONE packed
-        # int32 upload per step; sampled tokens live on device and only the
-        # _tokens column is consulted for freshly admitted slots
+        # int32 upload per step; sampled tokens live on device and the
+        # _tokens column is consulted only for slots seeded with a token
+        # the host holds (`_seed_first_token`: imports, speculation)
         self._page_table = np.full((B, maxp), TRASH_PAGE, np.int32)
         self._lengths = np.zeros(B, np.int32)
         self._tokens = np.zeros(B, np.int32)
@@ -793,7 +795,8 @@ class DecodeEngine:
         self._slot_pages: list[list[int]] = [[] for _ in range(B)]
         self._slot_draft: list[_DraftIndex | None] = [None] * B
         # device-resident sampled-token chain + deferred-readback fifo of
-        # (device tokens, [(slot, request)] snapshot, dispatch t0)
+        # (device tokens, [(slot, request)] snapshot, dispatch t0): a
+        # decode step's, or a prefill's with the one slot it admitted
         self._tok_dev = jnp.zeros(B, jnp.int32)
         # fused on-device sampling (EngineConfig.sampling): per-slot
         # (temperature, top_k) host mirrors ride the packed upload; the
@@ -900,6 +903,11 @@ class DecodeEngine:
         self._m_logits_rb = metrics.counter("engine.logits_readback")
         self._m_chunks = metrics.counter("engine.prefill_chunks")
         self._m_prefill_launches = metrics.counter("engine.prefill_launches")
+        # first tokens by the way they reached the host: through the fifo
+        # (`_harvest_one`), or read inside admission (`_first_token`)
+        self._m_first_deferred = metrics.counter(
+            "engine.first_tokens_deferred")
+        self._m_first_sync = metrics.counter("engine.first_tokens_sync")
         self._m_prefill_tokens = metrics.counter("engine.prefill_tokens")
         self._m_win_recycled = metrics.counter(
             "engine.window_pages_recycled")
@@ -1038,7 +1046,7 @@ class DecodeEngine:
 
     def _prefill_upload(self, tokens: int, chunk: bool):
         return prefill_upload(tokens, self.pages_per_slot, self._sampling,
-                              self._stateful, chunk)
+                              chunk)
 
     def _prefill_exe(self, tokens: int, chunk: bool = False):
         """A one-shot prefill of a ``tokens`` bucket, or (``chunk``) the
@@ -1052,7 +1060,7 @@ class DecodeEngine:
         def build():
             up = self._prefill_upload(tokens, chunk)
             return self._build(prefill_program(self._steps, self.cfg, up),
-                               up.spec())
+                               self._tok_dev, up.spec())
         return self._compiled(
             ("prefill_chunk" if chunk else "prefill", tokens,
              flag_value("tpu_prefill_impl")), build)
@@ -1704,8 +1712,9 @@ class DecodeEngine:
     def _admit(self):
         """Drain the queue into free slots while pages allow: assign slot,
         attach the longest cached prefix (prefix cache), allocate fresh
-        pages for the rest, prefill the uncached tail, seed the first
-        token. Returns how many requests it placed."""
+        pages for the rest, launch the uncached tail's prefill (its first
+        token stays on the chip: `_first_token`). Returns how many
+        requests it placed."""
         placed = 0
         while True:
             slots = self._free_slots()
@@ -1822,10 +1831,9 @@ class DecodeEngine:
                                       "t0": time.perf_counter()}
             return
         t0 = time.perf_counter()
-        first = self._run_prefill(req.prompt, row, start=cached,
-                                  slot=slot, req=req)
-        self._h_prefill.observe(time.perf_counter() - t0)
-        self._seed_first_token(slot, req, first)
+        toks = self._run_prefill(req.prompt, row, start=cached,
+                                 slot=slot, req=req)
+        self._first_token(slot, req, toks, t0)
 
     def _put_sampler(self, up, packed, slot, req, final=None):
         """Write the fields a SAMPLING engine's prefill upload carries
@@ -1847,47 +1855,54 @@ class DecodeEngine:
                         jax.random.PRNGKey(int(req.seed)), np.uint32)
                 packed[up["seed"]] = req._seed_key.view(np.int32)
             packed[up["top_k"]] = int(req.top_k)
-        if final is not None:
-            packed[up["final"]] = 1 if final else 0
 
     def _launch_prefill(self, tokens: int, chunk: bool, ids: np.ndarray,
                         where: dict, row: np.ndarray, slot, req,
                         final=None):
         """Pack ONE prefill upload (``ids`` into a ``tokens``-wide program,
         ``where`` = its length, or its start and valid count) and enqueue
-        its program: one fused upload, no readback. Returns the on-device
-        sampled token. The single owner of the host side of
-        `prefill_upload` for the one-shot, interleaved, back-to-back and
-        prefix-tail paths."""
+        its program: one fused upload, no readback. Returns the token
+        chain the program returns: the engine's own from here on, with
+        ``slot``'s entry set to the sampled token (by a one-shot launch or
+        a FINAL chunk). A slotless launch (``slot`` None: export, stream)
+        gets its token in entry 0 of a chain that only its caller reads;
+        the engine's chain is not donated and stays as it was. The single
+        owner of the host side of `prefill_upload` for the one-shot,
+        interleaved, back-to-back and prefix-tail paths."""
         up = self._prefill_upload(tokens, chunk)
         packed = np.zeros(up.shape, np.int32)
         packed[up["ids"]][:ids.size] = ids
         for name, value in where.items():
             packed[up[name]] = value
         packed[up["row"]] = row
+        packed[up["slot"]] = 0 if slot is None else slot
+        if chunk:
+            packed[up["final"]] = bool(final)
         if self._sampling:
             self._put_sampler(up, packed, slot, req, final)
-        if self._stateful:
-            packed[up["slot"]] = slot
         exe = self._prefill_exe(tokens, chunk)
         self._m_h2d.inc()
         self._m_prefill_launches.inc()
         self._m_prefill_tokens.inc(int(ids.size))
         if req is not None:
             req.u_prefill_computed += int(ids.size)
-        tok, self._cache = exe(self._params, self._cache,
-                               jax.device_put(packed))
-        return tok
+        toks, self._cache = exe(self._params, self._cache, self._tok_dev,
+                                jax.device_put(packed))
+        if slot is not None:
+            self._tok_dev = toks
+        return toks
 
     def _run_prefill(self, ids: np.ndarray, row: np.ndarray,
-                     start: int = 0, slot=None, req=None) -> int:
+                     start: int = 0, slot=None, req=None):
         """Fill ``row``'s pages with the prompt's KV from position
         ``start`` on (0 = whole prompt; a prefix-cache hit passes the
         cached token count) — one-shot bucketed, back-to-back chunks, or a
-        bucketed TAIL chunk — and return the sampled first token. Shared by
-        `_place` (which passes ``slot``/``req`` so a sampling engine seeds
-        the slot's key chain) and `prefill_export` (which has no slot to
-        interleave around, so its chunks run consecutively)."""
+        bucketed TAIL chunk — and return the last launch's token chain
+        (`_launch_prefill`), unread. Shared by `_place` (which passes
+        ``slot``/``req``: the slot's chain entry takes the first token, and
+        a sampling engine seeds the slot's key chain) and `prefill_export`
+        (which has no slot to interleave around, so its chunks run
+        consecutively)."""
         s0 = ids.size
         if start or self._use_chunked(s0):
             # chunk-program prefill from ``start`` on: the configured chunk
@@ -1898,20 +1913,20 @@ class DecodeEngine:
             c = int(self.ecfg.prefill_chunk_tokens) \
                 if self.ecfg.prefill_chunk_tokens is not None \
                 else self.bucket_for(s0 - start)
-            tok = None
+            toks = None
             for done in range(start, s0, c):
-                tok = self._run_chunk(ids, done, row, c, slot=slot,
-                                      req=req, final=done + c >= s0,
-                                      kind="tail")
+                toks = self._run_chunk(ids, done, row, c, slot=slot,
+                                       req=req, final=done + c >= s0,
+                                       kind="tail")
         else:
             with metrics.span("engine.prefill_launch", cat="engine",
                               kind="oneshot", tokens=int(s0),
                               request_id=req and req.request_id):
                 if self._stateful:
                     self._count_window_pages(0, s0)
-                tok = self._launch_prefill(self.bucket_for(s0), False, ids,
-                                           dict(length=s0), row, slot, req)
-        return self._read_first_token(tok)
+                toks = self._launch_prefill(self.bucket_for(s0), False, ids,
+                                            dict(length=s0), row, slot, req)
+        return toks
 
     def _count_window_pages(self, lo: int, hi: int):
         """`engine.window_pages_recycled` for positions ``lo .. hi - 1`` of
@@ -1923,11 +1938,14 @@ class DecodeEngine:
         first = max(-(-lo // ps), self._window_pages)
         self._m_win_recycled.inc(max(0, -(-hi // ps) - first))
 
-    def _read_first_token(self, tok) -> int:
-        """A prefill's only readback: block on its sampled first token."""
+    def _read_first_token(self, toks, slot=None) -> int:
+        """Block on a prefill's first token, here and now: entry ``slot``
+        of the chain its launch returned (a slotless launch's: entry 0).
+        For who cannot wait for the fifo: export and stream prefills, whose
+        caller takes the token away, and a speculating engine."""
         with metrics.span("engine.harvest", cat="engine", of="prefill",
                           tokens=1):
-            first = int(tok)
+            first = int(np.asarray(toks)[slot or 0])
         self._m_d2h.inc()
         return first
 
@@ -1936,10 +1954,11 @@ class DecodeEngine:
                    final: bool = False, kind: str = "chunk"):
         """Enqueue ONE prefill chunk (``ids[done:done+c]`` against page
         ``row``) for the interleaved (`_advance_prefill`), back-to-back
-        (`_run_prefill`), and prefix-tail paths. Returns the chunk
-        program's on-device sampled token (meaningful only for the final
-        chunk; no readback here). On a sampling engine the FINAL chunk
-        samples through the fused sampler and seeds ``slot``'s key chain.
+        (`_run_prefill`), and prefix-tail paths. Returns the token chain
+        its program returns (`_launch_prefill`; only the FINAL chunk writes
+        a token into it; no readback here). On a sampling engine the FINAL
+        chunk samples through the fused sampler and seeds ``slot``'s key
+        chain.
         ``kind`` names the launch on its `engine.prefill_launch` span:
         ``chunk`` (one a step, interleaved with decode, or a stream's) or
         ``tail`` (run to the end inside admission by `_run_prefill`)."""
@@ -1953,23 +1972,58 @@ class DecodeEngine:
                 if done > 0:
                     self._m_state_carries.inc()
                 self._count_window_pages(done, done + int(chunk.size))
-            tok = self._launch_prefill(
+            toks = self._launch_prefill(
                 c, True, chunk, dict(start=done, valid=chunk.size), row,
                 slot, req, final)
         self._m_chunks.inc()
-        return tok
+        return toks
+
+    def _first_token(self, slot: int, req: GenerateRequest, toks, t0):
+        """``slot``'s prompt is cached and its first token is entry
+        ``slot`` of ``toks``, the chain the last prefill launch (begun at
+        ``t0``) returned: the slot decodes from the next dispatch on. The
+        token stays on the chip, where that step reads it, and reaches the
+        host through the fifo like every later one (`_harvest_one`), so
+        admission waits for nothing. A speculating engine drafts on the
+        host from the first token and harvests every step where it
+        dispatched it: it reads the token here."""
+        if self._spec:
+            first = self._read_first_token(toks, slot)
+            self._m_first_sync.inc()
+            self._h_prefill.observe(time.perf_counter() - t0)
+            self._seed_first_token(slot, req, first)
+            return
+        self._activate(slot, req)
+        self._inflight.append((toks, [(slot, req)], t0))
+        self._g_inflight.set(len(self._inflight))
+
+    def _activate(self, slot: int, req: GenerateRequest):
+        """The slot's context is resident (prefilled here, or imported):
+        it decodes from the next dispatch on."""
+        self._lengths[slot] = req.prompt.size
+        self._budget[slot] = req.max_new_tokens - 1
+        # a budget of one token is spent by the prefill: the slot stays
+        # occupied and undispatched until its first token retires it
+        self._active[slot] = self._budget[slot] > 0
+        if self._prefix_enabled and req.cache:
+            # the prompt's full pages are resident and correct for every
+            # program launched from here on (the device runs them in
+            # order): index them for future submits (shared leading pages
+            # of a hit are already indexed; chunked and imported pages are
+            # equally cache-eligible since all land here)
+            self._register_prefix(req.page_hashes, self._slot_pages[slot])
 
     def _seed_first_token(self, slot: int, req: GenerateRequest,
                           first: int):
-        """Prefill finished (or a handoff was imported): activate the slot
-        for decode and deliver the first generated token. Prefill-latency
-        accounting stays with the CALLERS that actually ran a prefill — a
-        KV import must not land a ~0 s observation in the histogram."""
-        self._lengths[slot] = req.prompt.size
+        """Activate the slot with a first token the HOST holds (a KV
+        import's, or one a speculating engine read): deliver it now, and
+        hand it to the next step through the upload's fresh flag.
+        Prefill-latency accounting stays with the CALLERS that actually
+        ran a prefill — a KV import must not land a ~0 s observation in
+        the histogram."""
+        self._activate(slot, req)
         self._tokens[slot] = first
-        self._active[slot] = True
         self._fresh[slot] = True
-        self._budget[slot] = req.max_new_tokens - 1
         if self._spec and req.speculate:
             # O(prompt) once at admission, O(1) per token after: the
             # drafter must not rescan the history inside the step loop
@@ -1980,12 +2034,6 @@ class DecodeEngine:
         req.trace.mark_first_token()
         req.u_generated += 1
         self._m_tokens.inc()
-        if self._prefix_enabled and req.cache:
-            # the prompt's full pages are now resident and correct —
-            # index them for future submits (shared leading pages of a
-            # hit are already indexed; chunked and imported pages are
-            # equally cache-eligible since all three land here)
-            self._register_prefix(req.page_hashes, self._slot_pages[slot])
         if req.max_new_tokens == 1 or first == self.ecfg.eos_id:
             self._retire(slot)
 
@@ -2004,15 +2052,13 @@ class DecodeEngine:
         req = st["req"]
         c = int(self.ecfg.prefill_chunk_tokens)
         done = st["done"]
-        tok = self._run_chunk(req.prompt, done, self._page_table[slot],
-                              slot=slot, req=req,
-                              final=done + c >= req.prompt.size)
+        toks = self._run_chunk(req.prompt, done, self._page_table[slot],
+                               slot=slot, req=req,
+                               final=done + c >= req.prompt.size)
         st["done"] = min(done + c, req.prompt.size)
         if st["done"] >= req.prompt.size:
             del self._prefilling[slot]
-            first = self._read_first_token(tok)   # the final chunk's token
-            self._h_prefill.observe(time.perf_counter() - st["t0"])
-            self._seed_first_token(slot, req, first)
+            self._first_token(slot, req, toks, st["t0"])
         return True
 
     def _detach_slot(self, slot: int):
@@ -2198,22 +2244,40 @@ class DecodeEngine:
         return harvested
 
     def _harvest_one(self) -> int:
-        """Block on the OLDEST in-flight step's sampled token ids (the only
-        blocking readback in the loop) and deliver them: append to each
-        snapshot request, retire slots that hit max_new_tokens or EOS."""
+        """Block on the OLDEST in-flight entry's token chain (the only
+        blocking readback in the loop) and deliver its snapshot's tokens:
+        a decode step's, or the first token of the one request a prefill
+        launch admitted (`_first_token`; ``t0`` is then when that prefill
+        began). Append to each snapshot request, retire slots that hit
+        max_new_tokens or EOS. A request's first token is stamped here,
+        when the host holds it."""
         toks_dev, snapshot, t0 = self._inflight.popleft()
         self._g_inflight.set(len(self._inflight))
-        with metrics.span("engine.harvest", cat="engine", of="decode",
+
+        def owed(slot, req):
+            # not EOS-retired earlier in the fifo, cancelled, expired or
+            # aborted since: such a request's tokens are dropped
+            return not req.done and self._slot_req[slot] is req
+        firsts = sum(not req.generated and owed(slot, req)
+                     for slot, req in snapshot)
+        with metrics.span("engine.harvest", cat="engine",
+                          of="prefill" if firsts else "decode",
                           tokens=len(snapshot)):
             toks_np = np.asarray(toks_dev)
         self._m_d2h.inc()
+        if firsts:
+            self._m_first_deferred.inc(firsts)
+            self._h_prefill.observe(time.perf_counter() - t0)
         n = 0
         for slot, req in snapshot:
-            if req.done or self._slot_req[slot] is not req:
-                continue        # EOS-retired earlier in the fifo (or abort)
+            if not owed(slot, req):
+                continue
             tok = int(toks_np[slot])
+            if req.generated:
+                req.trace.mark_tokens(1)
+            else:
+                req.trace.mark_first_token()
             req.generated.append(tok)
-            req.trace.mark_tokens(1)
             req.u_generated += 1
             n += 1
             if len(req.generated) >= req.max_new_tokens \
@@ -2370,9 +2434,9 @@ class DecodeEngine:
         row = np.full(self.pages_per_slot, TRASH_PAGE, np.int32)
         row[:n_src] = all_pages
         try:
-            first = self._run_prefill(
+            first = self._read_first_token(self._run_prefill(
                 ids, row,
-                start=(len(shared) + n_up) * self.ecfg.page_size)
+                start=(len(shared) + n_up) * self.ecfg.page_size))
             t0 = time.perf_counter()
             k_np, v_np, ks_np, vs_np = self._cache.export_pages(
                 all_pages)
@@ -2535,14 +2599,14 @@ class DecodeEngine:
                           pack_stream_pages(seq, 0,
                                             *_blobs(0, n_res))))
                 seq += 1
-            tok = None
+            toks = None
             for a, (p0, n) in zip(chunk_starts, batches):
-                tok = self._run_chunk(ids, a, row, c)
+                toks = self._run_chunk(ids, a, row, c, final=a + c >= s0)
                 if n > 0:
                     sink.put(("rec",
                               pack_stream_pages(seq, p0, *_blobs(p0, n))))
                     seq += 1
-            first = self._read_first_token(tok)
+            first = self._read_first_token(toks)
             sink.put(("rec", pack_stream_final(
                 seq, first, cursor, *_blobs(cursor, n_src - cursor))))
             if self._prefix_enabled and cache:

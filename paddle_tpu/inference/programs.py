@@ -7,8 +7,8 @@ ONE calling convention: every program is
 
 where ``cache`` is the `inference/cache.py::DeviceCache` (the one donated
 argument, returned whole as the last result) and ``small`` is the
-on-device token chain (the batched steps only; never donated) and one
-packed upload. `DecodeEngine` compiles them ahead of time
+on-device token chain (never donated; every program returns the next one
+among ``lead``) and one packed upload. `DecodeEngine` compiles them ahead of time
 (``jax.jit(program, donate_argnums=(1,)).lower(...).compile()``); nothing
 here needs an engine, so a program lowers from `jax.ShapeDtypeStruct`s
 alone (tests/test_tpu_compile.py).
@@ -69,20 +69,21 @@ def step_upload(slots: int, pages_per_slot: int, sampling: bool,
 
 
 def prefill_upload(tokens: int, pages_per_slot: int, sampling: bool,
-                   stateful: bool, chunk: bool) -> Upload:
+                   chunk: bool) -> Upload:
     """One prefill launch: ``tokens`` ids (a bucket, or a chunk), the
-    prompt's true length (one-shot) or the chunk's start and valid count,
-    the slot's page row; on a sampling engine the key-chain row to write
-    (row ``slots`` = scratch, for slotless prefills), the request's seed
-    key, temperature bits and top-k, and for a chunk whether it is the
-    FINAL one (only that one samples, so the chain advances once a
-    token); for a family with state, the slot whose state it fills."""
+    prompt's true length (one-shot) or the chunk's start, valid count and
+    whether it is the FINAL one (only that one's token counts: it alone
+    enters the token chain and advances a key chain), the slot's page row
+    and the slot itself (its entry of the token chain takes the sampled
+    token; a family with state fills that slot's state); on a sampling
+    engine the key-chain row to write (row ``slots`` = scratch, for
+    slotless prefills), the request's seed key, temperature bits and
+    top-k."""
     return Upload(
-        (), ("ids", tokens), *(("start", "valid") if chunk else ("length",)),
-        ("row", pages_per_slot),
-        *(("key_slot", ("seed", 2), "temp", "top_k",
-           *(("final",) if chunk else ())) if sampling else ()),
-        *(("slot",) if stateful else ()))
+        (), ("ids", tokens),
+        *(("start", "valid", "final") if chunk else ("length",)),
+        ("row", pages_per_slot), "slot",
+        *(("key_slot", ("seed", 2), "temp", "top_k") if sampling else ()))
 
 
 def _f32(bits):
@@ -161,41 +162,45 @@ def verify_program(steps, cfg, up: Upload):
 
 
 def prefill_program(steps, cfg, up: Upload):
-    """``(params, cache, packed) -> (first token, cache)``: fill one
-    slot's pages from ``packed``'s ids. A one-shot upload runs the
-    family's `prefill_step` over a whole bucketed prompt; a chunk upload
-    (it has ``start``) runs `prefill_chunk_step` over a window that starts
-    at an absolute position: decode-priority chunks and prefix-cache tails
-    alike. The token is the argmax, or on a sampling engine the fused
-    sampler's from the request's seed key, the advanced chain landing in
-    the cache at ``key_slot`` with no readback."""
+    """``(params, cache, tokens, packed) -> (tokens, cache)``: fill one
+    slot's pages from ``packed``'s ids, and set that slot's entry of the
+    token chain to the first token, so that the next decode step reads it
+    where it reads every other token and no host waits for it. A one-shot
+    upload runs the family's `prefill_step` over a whole bucketed prompt;
+    a chunk upload (it has ``start``) runs `prefill_chunk_step` over a
+    window that starts at an absolute position: decode-priority chunks and
+    prefix-cache tails alike, and a chunk that is not the FINAL one
+    returns the chain as it came. The token is the argmax, or on a
+    sampling engine the fused sampler's from the request's seed key, the
+    advanced chain landing in the cache at ``key_slot`` with no
+    readback."""
     chunk, sampling = "start" in up, "seed" in up
 
-    def program(params, cache, packed):
+    def program(params, cache, tokens, packed):
         ids = packed[up["ids"]]
         where = (packed[up["start"]], packed[up["valid"]]) if chunk \
             else (packed[up["length"]],)
+        slot = packed[up["slot"]]
         kw = cache.extras()
-        if "slot" in up:
-            kw["slot"] = packed[up["slot"]]
+        if cache.state:
+            kw["slot"] = slot
         step = steps.prefill_chunk_step if chunk else steps.prefill_step
         logits, *pools = step(params, ids, *where, packed[up["row"]],
                               cache.k, cache.v, cfg=cfg, **kw)
         cache = cache.after_prefill(*pools)
-        if not sampling:
-            return jnp.argmax(logits, axis=-1).astype(ids.dtype), cache
-        from paddle_tpu.kernels.sampling import sample_one
-        tok, new_key = sample_one(
-            logits, jax.lax.bitcast_convert_type(packed[up["seed"]],
-                                                 jnp.uint32),
-            _f32(packed[up["temp"]]), packed[up["top_k"]])
-        tok = tok.astype(ids.dtype)
-        slot, keys = packed[up["key_slot"]], cache.keys
-        if chunk:
-            final = packed[up["final"]] != 0
-            tok = jnp.where(final, tok,
-                            jnp.argmax(logits, axis=-1).astype(ids.dtype))
-            new_key = jnp.where(final, new_key, keys[slot])
-        return tok, cache.with_keys(keys.at[slot].set(new_key))
+        final = packed[up["final"]] != 0 if chunk else True
+        if sampling:
+            from paddle_tpu.kernels.sampling import sample_one
+            tok, new_key = sample_one(
+                logits, jax.lax.bitcast_convert_type(packed[up["seed"]],
+                                                     jnp.uint32),
+                _f32(packed[up["temp"]]), packed[up["top_k"]])
+            row, keys = packed[up["key_slot"]], cache.keys
+            cache = cache.with_keys(keys.at[row].set(
+                jnp.where(final, new_key, keys[row])))
+        else:
+            tok = jnp.argmax(logits, axis=-1)
+        return tokens.at[slot].set(
+            jnp.where(final, tok.astype(tokens.dtype), tokens[slot])), cache
 
     return program

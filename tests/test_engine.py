@@ -338,7 +338,8 @@ def test_no_engine_program_relays_the_pool(impl, ecfg):
 class TestDesyncStepLoop:
     """The de-synchronized hot path: ONE fused host->device upload per step,
     no blocking readback besides sampled token ids (deferred by the
-    in-flight window), host/device timers populated."""
+    in-flight window, a prefill's first token among them), host/device
+    timers populated."""
 
     def test_one_upload_one_token_readback_per_step(self):
         from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
@@ -358,9 +359,9 @@ class TestDesyncStepLoop:
         n_steps = steps.value - base[2]
         n_prefills = 2
         # exactly one packed slot-state upload per decode step (+ one fused
-        # upload per prefill), and exactly one sampled-token readback per
-        # dispatched step (+ the prefill's first token) — nothing else
-        # crosses the transfer boundary in the loop
+        # upload per prefill), and exactly one token-chain readback per
+        # fifo entry: a dispatched step's, or a prefill's first token —
+        # nothing else crosses the transfer boundary in the loop
         assert h2d.value - base[0] == n_steps + n_prefills
         assert d2h.value - base[1] == n_steps + n_prefills
 
@@ -372,10 +373,16 @@ class TestDesyncStepLoop:
         prompt = np.random.RandomState(11).randint(0, 97, 4).astype(np.int32)
         req = eng.submit(prompt, max_new_tokens=10)
         eng.step()                    # prefill + dispatch #1
-        eng.step()                    # dispatch #2 — still nothing harvested
-        assert len(eng._inflight) == 2
-        assert len(req.generated) == 1          # only the prefill token yet
-        eng.step()                    # window full: oldest step harvested
+        # admission waited for nothing: the first token is the fifo's
+        # oldest entry, still on the chip with the step that read it there
+        assert [[s for s, _ in snap] for _, snap, _ in eng._inflight] \
+            == [[0], [0]]
+        assert req.generated == [] and req.trace.t_first_token is None
+        eng.step()                    # dispatch #2: window full, the
+        assert len(eng._inflight) == 2          # oldest entry harvested:
+        assert len(req.generated) == 1          # the prefill's token
+        assert req.trace.t_first_token is not None
+        eng.step()                    # dispatch #3: step #1 harvested
         assert len(eng._inflight) == 2
         assert len(req.generated) == 2
         eng.run_until_idle(max_steps=30)
@@ -385,7 +392,8 @@ class TestDesyncStepLoop:
     def test_harvest_spans_carry_every_blocking_readback(self):
         """`engine.harvest` is the engine thread waiting for the device:
         one span a blocking readback, prefill's and decode's told apart,
-        each inside the `engine.step` that made it."""
+        each directly under the `engine.step` that made it (none under
+        `engine.admit`, which waits for nothing)."""
         import time
         from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
         m = _tiny_model()
@@ -402,7 +410,7 @@ class TestDesyncStepLoop:
         assert [h.args["of"] for h in harvests] == ["prefill"] + ["decode"] * 3
         assert all(h.args["tokens"] == 1 and h.dur > 0 for h in harvests)
         steps = {s.id: s for s in metrics.spans(name="engine.step", since=t0)}
-        for h in harvests[1:]:
+        for h in harvests:
             step = steps[h.parent]
             assert step.t0 <= h.t0 and h.t0 + h.dur <= step.t0 + step.dur
 

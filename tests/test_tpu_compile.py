@@ -230,10 +230,14 @@ ENTRY %main (a: bf16[2,8,4,16]) -> bf16[2,8,4,16] {
         == [("copy", "copy.1"), ("slice", "slice.2")]
 
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+PROGRAMS = ["decode_step", "prefill_chunk_step", "prefill_step"]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
                                                   monkeypatch):
-    """The engine's decode step and one prefill chunk, whole, at
+    """The engine's decode step, one prefill chunk and a one-shot prefill
+    (each takes the token chain and returns the next one), whole, at
     gpt2-medium's serving shapes with the Pallas arms pinned, compiled for
     the described chip with the pools donated: the optimized HLO holds no
     copy, slice, transpose or fusion of a layer pool's size (1537 x 16 x
@@ -262,13 +266,12 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
     if program == "decode_step":
         up = step_upload(m["slots"], m["per_slot"], sampling=False)
         step = decode_program(gpt, cfg, up)
-        small = (jax.ShapeDtypeStruct((m["slots"],), jnp.int32,
-                                      sharding=chip), up.spec(sharding=chip))
     else:
         up = prefill_upload(m["chunk"], m["per_slot"], sampling=False,
-                            stateful=False, chunk=True)
+                            chunk=program == "prefill_chunk_step")
         step = prefill_program(gpt, cfg, up)
-        small = (up.spec(sharding=chip),)
+    small = (jax.ShapeDtypeStruct((m["slots"],), jnp.int32, sharding=chip),
+             up.spec(sharding=chip))
     set_flags({"tpu_paged_impl": "pallas", "tpu_prefill_impl": "pallas"})
     try:
         compiled = jax.jit(step, donate_argnums=(1,)).lower(
@@ -276,6 +279,8 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
     finally:
         set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
     text = compiled.as_text()
+    chain, _ = compiled.out_info
+    assert chain.shape == (m["slots"],) and chain.dtype == jnp.int32
     assert text.count("tpu_custom_call") >= m["layers"]
     layer_pool = m["pages"] * m["page"] * m["hidden"]
     assert pool_sized_ops(text, layer_pool) == []
@@ -288,11 +293,12 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
 FLASH = dict(slots=64, page=16, per_slot=128, chunk=256)
 
 
-@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+@pytest.mark.parametrize("program", PROGRAMS)
 def test_hybrid_step_program_copies_no_pool_or_state_on_v5e(chip, program):
-    """Phi-4-mini-flash's decode step and one prefill chunk, whole, at the
-    published widths and all 32 layers, compiled for the described chip
-    with pool, rings and state donated. The optimized HLO holds no copy,
+    """Phi-4-mini-flash's decode step, one prefill chunk and a one-shot
+    prefill of a chunk's length (each takes the token chain and returns
+    the next one), whole, at the published widths and all 32 layers,
+    compiled for the described chip with pool, rings and state donated. The optimized HLO holds no copy,
     slice, transpose or fusion of the size of a state stack (SSM: 9 x 64 x
     16 x 5120; convolution: 9 x 64 x 15360), of the page pool or of all
     the window rings but their in-place updates; everything donated is
@@ -324,15 +330,16 @@ def test_hybrid_step_program_copies_no_pool_or_state_on_v5e(chip, program):
     if program == "decode_step":
         up = step_upload(slots, per_slot, sampling=False)
         step = decode_program(phi, cfg, up)
-        small = (sds((slots,), jnp.int32), up.spec(sharding=chip))
     else:
         up = prefill_upload(f["chunk"], per_slot, sampling=False,
-                            stateful=True, chunk=True)
+                            chunk=program == "prefill_chunk_step")
         step = prefill_program(phi, cfg, up)
-        small = (up.spec(sharding=chip),)
+    small = (sds((slots,), jnp.int32), up.spec(sharding=chip))
     compiled = jax.jit(step, donate_argnums=(1,)).lower(
         params, cache, *small).compile()
     text = compiled.as_text()
+    chain, _ = compiled.out_info
+    assert chain.shape == (slots,) and chain.dtype == jnp.int32
     elems = {name: int(np.prod(s)) for name, _, s, _ in
              phi.state_arrays(cfg, slots, f["page"], BF16)}
     pool_elems = int(np.prod(pool.shape))
