@@ -796,8 +796,13 @@ class DecodeEngine:
         self._slot_draft: list[_DraftIndex | None] = [None] * B
         # device-resident sampled-token chain + deferred-readback fifo of
         # (device tokens, [(slot, request)] snapshot, dispatch t0): a
-        # decode step's, or a prefill's with the one slot it admitted
-        self._tok_dev = jnp.zeros(B, jnp.int32)
+        # decode step's, or a prefill's with the one slot it admitted.
+        # Behind the slots' tokens the chain carries the family's running
+        # step counts, if it hands any back (inference/family.py): they
+        # reach the host on the tokens' readback (`_harvest_one`)
+        self._n_counts = int(fam.step_counts)
+        self._tok_dev = jnp.zeros(B + self._n_counts, jnp.int32)
+        self._counts_seen = np.zeros(self._n_counts, np.uint32)
         # fused on-device sampling (EngineConfig.sampling): per-slot
         # (temperature, top_k) host mirrors ride the packed upload; the
         # PRNG key chains live in the cache, so sampled decode reads back
@@ -1042,7 +1047,8 @@ class DecodeEngine:
             key = ("decode", flag_value("tpu_paged_impl"))
         up = self._step_upload
         return self._compiled(key, lambda: self._build(
-            make(self._steps, self.cfg, up), self._tok_dev, up.spec()))
+            make(self._steps, self.cfg, up, self._n_counts), self._tok_dev,
+            up.spec()))
 
     def _prefill_upload(self, tokens: int, chunk: bool):
         return prefill_upload(tokens, self.pages_per_slot, self._sampling,
@@ -1059,8 +1065,9 @@ class DecodeEngine:
 
         def build():
             up = self._prefill_upload(tokens, chunk)
-            return self._build(prefill_program(self._steps, self.cfg, up),
-                               self._tok_dev, up.spec())
+            return self._build(
+                prefill_program(self._steps, self.cfg, up, self._n_counts),
+                self._tok_dev, up.spec())
         return self._compiled(
             ("prefill_chunk" if chunk else "prefill", tokens,
              flag_value("tpu_prefill_impl")), build)
@@ -2265,6 +2272,14 @@ class DecodeEngine:
                           tokens=len(snapshot)):
             toks_np = np.asarray(toks_dev)
         self._m_d2h.inc()
+        if self._n_counts:
+            # the family's running totals rode the same array: what they
+            # grew by since the last entry (modulo 2**32) is this one's
+            seen = toks_np[-self._n_counts:].astype(np.uint32)
+            grown = (seen - self._counts_seen).astype(np.int64)
+            self._counts_seen = seen
+            if self._fam.on_counts is not None:
+                self._fam.on_counts(grown)
         if firsts:
             self._m_first_deferred.inc(firsts)
             self._h_prefill.observe(time.perf_counter() - t0)

@@ -5,9 +5,9 @@ not its business. A model object hands it one :class:`ModelFamily` through
 ``model.engine_family()``: the pure step functions the AOT programs trace,
 where the parameters are, and the kinds and shapes of state a sequence
 keeps. There is no flag and no `EngineConfig` field that picks a model —
-the model object decides. `models/gpt.py` and `models/phi4flash.py` each
-supply one; the GPT family describes exactly what the engine used to
-import, so its programs trace as before.
+the model object decides. `models/gpt.py`, `models/phi4flash.py` and
+`models/granitemoehybrid.py` each supply one; the GPT family describes
+exactly what the engine used to import, so its programs trace as before.
 
 The step functions' contracts (``steps`` is any namespace that has them):
 
@@ -34,6 +34,18 @@ and returned from every step program with the pools), and — because pages
 alone then cannot restore a sequence — the engine refuses prefix
 reuse, speculation, hand-off, migration and tier spill by typed error
 (`errors.RecurrentStateUnsupported`; docs/SERVING.md "The model seam").
+
+A step may hand back COUNTS with its tokens. A family with ``step_counts``
+= n > 0 (sparse experts: which held expert took how many tokens) has its
+token chain lengthened by n int32 entries: every program gives the tail to
+the step function (``cache["counts"]`` of `decode_step`, ``counts=`` of the
+prefill steps), which returns it with this step's counts ADDED
+(``cache["counts"]``; after the state arrays), and the program puts it back
+behind the tokens. So the tail is a running total that reaches the host on
+the one readback a step's tokens already make; `DecodeEngine` hands what it
+grew by since the last readback to ``on_counts`` (a numpy int64 vector),
+where the family turns it into counters. A family without counts pays
+nothing: its chain and its programs are as they were.
 """
 from __future__ import annotations
 
@@ -56,6 +68,8 @@ class ModelFamily:
     state: Callable | None = None   # (slots, page, dtype) -> specs, or None
     window_tokens: int = 0          # window of the ``window`` state, if any
     quantize: Callable | None = None  # (params, weight_dtype) -> params
+    step_counts: int = 0            # int32 entries a step adds to (above)
+    on_counts: Callable | None = None  # (grown: np.ndarray) -> None
 
 
 def family_of(model) -> ModelFamily:

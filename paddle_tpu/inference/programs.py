@@ -8,7 +8,10 @@ ONE calling convention: every program is
 where ``cache`` is the `inference/cache.py::DeviceCache` (the one donated
 argument, returned whole as the last result) and ``small`` is the
 on-device token chain (never donated; every program returns the next one
-among ``lead``) and one packed upload. `DecodeEngine` compiles them ahead of time
+among ``lead``) and one packed upload. For a family whose steps hand back
+counts (``counts`` > 0: inference/family.py) the chain is the slots' tokens
+followed by that many running totals, which every program passes through
+its step function and puts back behind the tokens. `DecodeEngine` compiles them ahead of time
 (``jax.jit(program, donate_argnums=(1,)).lower(...).compile()``); nothing
 here needs an engine, so a program lowers from `jax.ShapeDtypeStruct`s
 alone (tests/test_tpu_compile.py).
@@ -99,44 +102,58 @@ def _slot_tokens(up, slot_state, tokens):
     return active, jnp.where(fresh, slot_state[:, up["token"]], tokens)
 
 
-def decode_program(steps, cfg, up: Upload):
+def _split(chain, counts: int):
+    """(the slots' tokens, the running counts behind them or None)."""
+    return (chain[:-counts], chain[-counts:]) if counts else (chain, None)
+
+
+def _join(tokens, tail):
+    return tokens if tail is None else jnp.concatenate([tokens, tail])
+
+
+def decode_program(steps, cfg, up: Upload, counts: int = 0):
     """``(params, cache, tokens, slot_state) -> (next tokens, cache)``:
     the family's `decode_step` on all slots and the next token a slot
     (argmax, or the fused sampler with each active slot's chain advanced
     once). Tokens and key chains stay on device step to step."""
     sampling = "temp" in up
 
-    def program(params, cache, tokens, slot_state):
+    def program(params, cache, chain, slot_state):
+        tokens, tail = _split(chain, counts)
         active, toks = _slot_tokens(up, slot_state, tokens)
-        logits, view = steps.decode_step(
-            params, toks, cache.step_view(slot_state[:, up["table"]],
-                                          slot_state[:, up["length"]]),
-            active, cfg=cfg)
+        view = cache.step_view(slot_state[:, up["table"]],
+                               slot_state[:, up["length"]])
+        if counts:
+            view["counts"] = tail
+        logits, view = steps.decode_step(params, toks, view, active, cfg=cfg)
         cache = cache.after_step(view)
+        tail = view["counts"] if counts else None
         if not sampling:
             nxt = jnp.argmax(logits, axis=-1).astype(toks.dtype)
-            return jnp.where(active, nxt, toks), cache
+            return _join(jnp.where(active, nxt, toks), tail), cache
         from paddle_tpu.kernels.sampling import fused_sample
         B, keys = tokens.shape[0], cache.keys
         nxt, new_keys = fused_sample(logits, keys[:B],
                                      _f32(slot_state[:, up["temp"]]),
                                      slot_state[:, up["top_k"]])
         nxt = jnp.where(active, nxt.astype(toks.dtype), toks)
-        return nxt, cache.with_keys(keys.at[:B].set(
+        return _join(nxt, tail), cache.with_keys(keys.at[:B].set(
             jnp.where(active[:, None], new_keys, keys[:B])))
 
     return program
 
 
-def verify_program(steps, cfg, up: Upload):
+def verify_program(steps, cfg, up: Upload, counts: int = 0):
     """``(params, cache, tokens, slot_state) -> (emitted [B, k+1],
     n_emitted, next tokens, cache)``: the speculative k-token step. Draft
     contents and lengths ride the upload, never a shape; on a sampling
     engine `verify_step` advances each slot's chain by exactly its
-    ``n_emitted`` splits."""
+    ``n_emitted`` splits. Running counts pass through as they came: a
+    verify step adds none."""
     sampling = "temp" in up
 
-    def program(params, cache, tokens, slot_state):
+    def program(params, cache, chain, slot_state):
+        tokens, tail = _split(chain, counts)
         active, tok0 = _slot_tokens(up, slot_state, tokens)
         draft_len = slot_state[:, up["draft_len"]]
         tok_seq = jnp.concatenate(
@@ -155,13 +172,14 @@ def verify_program(steps, cfg, up: Upload):
                 params, tok_seq, draft_len, view, active, cfg=cfg)
         nxt = jnp.take_along_axis(
             emitted, jnp.maximum(n_emitted - 1, 0)[:, None], axis=1)[:, 0]
-        return (emitted, n_emitted, jnp.where(active, nxt, tok0),
+        return (emitted, n_emitted,
+                _join(jnp.where(active, nxt, tok0), tail),
                 cache.after_step(view))
 
     return program
 
 
-def prefill_program(steps, cfg, up: Upload):
+def prefill_program(steps, cfg, up: Upload, counts: int = 0):
     """``(params, cache, tokens, packed) -> (tokens, cache)``: fill one
     slot's pages from ``packed``'s ids, and set that slot's entry of the
     token chain to the first token, so that the next decode step reads it
@@ -176,7 +194,8 @@ def prefill_program(steps, cfg, up: Upload):
     readback."""
     chunk, sampling = "start" in up, "seed" in up
 
-    def program(params, cache, tokens, packed):
+    def program(params, cache, chain, packed):
+        tokens, tail = _split(chain, counts)
         ids = packed[up["ids"]]
         where = (packed[up["start"]], packed[up["valid"]]) if chunk \
             else (packed[up["length"]],)
@@ -184,9 +203,13 @@ def prefill_program(steps, cfg, up: Upload):
         kw = cache.extras()
         if cache.state:
             kw["slot"] = slot
+        if counts:
+            kw["counts"] = tail
         step = steps.prefill_chunk_step if chunk else steps.prefill_step
         logits, *pools = step(params, ids, *where, packed[up["row"]],
                               cache.k, cache.v, cfg=cfg, **kw)
+        if counts:
+            *pools, tail = pools
         cache = cache.after_prefill(*pools)
         final = packed[up["final"]] != 0 if chunk else True
         if sampling:
@@ -200,7 +223,8 @@ def prefill_program(steps, cfg, up: Upload):
                 jnp.where(final, new_key, keys[row])))
         else:
             tok = jnp.argmax(logits, axis=-1)
-        return tokens.at[slot].set(
-            jnp.where(final, tok.astype(tokens.dtype), tokens[slot])), cache
+        return _join(tokens.at[slot].set(
+            jnp.where(final, tok.astype(tokens.dtype), tokens[slot])),
+            tail), cache
 
     return program

@@ -169,23 +169,45 @@ def gather_scales(scales, page_table, layer):
     return scales[layer, page_table].reshape(b, maxp * ps, nh)
 
 
+def query_groups(q_heads, dh, pool_width):
+    """Query heads that read one K/V head of a pool whose rows are
+    ``pool_width`` wide: 1 for GPT-2's one-to-one heads, ``g`` for grouped
+    queries (query head ``h`` reads K/V head ``h // g``)."""
+    nkv, rest = divmod(pool_width, dh)
+    if rest or nkv == 0 or q_heads % nkv:
+        raise ValueError(f"{q_heads} query heads of width {dh} over a pool "
+                         f"row of {pool_width}")
+    return q_heads // nkv
+
+
 def _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
-                         k_scale=None, v_scale=None):
+                         k_scale=None, v_scale=None, scale=None):
     """The gather + masked f32-softmax reference implementation over the
     stored pool at ``layer``. With ``k_scale``/``v_scale`` ([nl, num_pages,
     page_size, nh] f32) the pages are int8 and dequantize in-register right
     after the gather — the same f32 score/softmax math runs on the
-    dequantized values."""
+    dequantized values. Grouped queries (``q`` has ``g`` x the pool's
+    heads) run the same math with the K/V head shared by its ``g`` query
+    heads; ``scale`` multiplies the scores (None: ``1 / sqrt(dh)``)."""
     nh, dh = q.shape[-2:]
-    scale = 1.0 / (dh ** 0.5)
-    k = gather_kv(k_pages, page_table, layer, nh).astype(jnp.float32)
-    v = gather_kv(v_pages, page_table, layer, nh).astype(jnp.float32)
+    g = query_groups(nh, dh, k_pages.shape[-1])
+    nkv = nh // g
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
+    k = gather_kv(k_pages, page_table, layer, nkv).astype(jnp.float32)
+    v = gather_kv(v_pages, page_table, layer, nkv).astype(jnp.float32)
     if k_scale is not None:                             # [B, Lmax, nh, dh]
         k = k * gather_scales(k_scale, page_table, layer)[..., None]
         v = v * gather_scales(v_scale, page_table, layer)[..., None]
     lmax = k.shape[1]
-    sc = jnp.einsum("bhd,blhd->bhl", q.astype(jnp.float32) * scale, k)
     mask = jnp.arange(lmax)[None, :] <= pos[:, None]         # [B, Lmax]
+    qs = q.astype(jnp.float32) * scale
+    if g > 1:
+        sc = jnp.einsum("bkgd,blkd->bkgl", qs.reshape(-1, nkv, g, dh), k)
+        sc = jnp.where(mask[:, None, None, :], sc, -1e30)
+        pr = jax.nn.softmax(sc, axis=-1)
+        att = jnp.einsum("bkgl,blkd->bkgd", pr, v).reshape(q.shape)
+        return att.astype(q.dtype)
+    sc = jnp.einsum("bhd,blhd->bhl", qs, k)
     sc = jnp.where(mask[:, None, :], sc, -1e30)
     pr = jax.nn.softmax(sc, axis=-1)
     att = jnp.einsum("bhl,blhd->bhd", pr, v)
@@ -193,23 +215,27 @@ def _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
 
 
 def _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
-               k_scale=None, v_scale=None):
+               k_scale=None, v_scale=None, scale=None):
     """Execute one named implementation over the stored pool at ``layer``
     (also the autotuner's run_impl)."""
     if impl == "pallas":
         from paddle_tpu.kernels.pallas.paged_attention import (
             paged_attention as pallas_paged)
         return pallas_paged(q, k_pages, v_pages, page_table, pos,
-                            layer=layer, k_scale=k_scale, v_scale=v_scale)
+                            layer=layer, k_scale=k_scale, v_scale=v_scale,
+                            scale=scale)
     return _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
-                                k_scale=k_scale, v_scale=v_scale)
+                                k_scale=k_scale, v_scale=v_scale,
+                                scale=scale)
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos,
-                    k_scale=None, v_scale=None, *, layer=None):
+                    k_scale=None, v_scale=None, *, layer=None, scale=None):
     """One decode step of attention over paged K/V for B sequences.
 
-    q          : [B, nh, dh] query for the CURRENT token of each sequence
+    q          : [B, nh, dh] query for the CURRENT token of each sequence;
+                 ``nh`` may be ``g`` x the pool's heads (grouped queries:
+                 head ``h`` reads K/V head ``h // g``)
     k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
                  read at ``layer``. Without ``layer``: one layer's
                  [num_pages, page_size, nh, dh] (or merged rank 3), which
@@ -220,6 +246,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
                  to the cache); attends over positions 0..pos inclusive
     k_scale/v_scale : optional f32 scales of an int8 pool, [nl, num_pages,
                  page_size, nh] (per-layer form: without the leading nl)
+    scale      : what multiplies the scores (None: ``1 / sqrt(dh)``)
     returns    : [B, nh, dh] in q.dtype
 
     Same numerics as the dense path (f32 scores, -1e30 mask, f32 softmax):
@@ -260,14 +287,20 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
                             q.dtype, run, variant=variant,
                             num_pages=k_pages.shape[1])
 
+    # a grouped signature is not measured: the probe's pool would be a
+    # second pool of the model's own size (every slot's pages are distinct)
+    # with the xla arm's gathers beside it, at a size where the first fills
+    # the chip. It takes the registry's preference (`registry._paged_cands`)
+    grouped = query_groups(q.shape[1], q.shape[2], k_pages.shape[-1]) > 1
     impl = registry.dispatch("paged_attention", forced=forced,
-                             winner=winner)
+                             ctx={"grouped": grouped},
+                             winner=None if grouped else winner)
     return _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
-                      k_scale=k_scale, v_scale=v_scale)
+                      k_scale=k_scale, v_scale=v_scale, scale=scale)
 
 
 def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
-                           layer, k_scale=None, v_scale=None):
+                           layer, k_scale=None, v_scale=None, scale=None):
     """The gather + absolute-position-masked f32-softmax PREFILL reference
     — exactly the math `models/gpt.py::prefill_chunk_step` always ran: the
     chunk's queries attend over ALL cached positions (previous chunks AND
@@ -279,26 +312,37 @@ def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
     start/valid : the chunk's absolute origin and true token count.
     ``valid`` only matters to the Pallas arm's row masking — padded rows
     here compute like the real ones (their output is never consumed).
+    Grouped queries and ``scale`` as :func:`_xla_paged_attention`.
     """
     _, c, nh, dh = q.shape
-    scale = 1.0 / (dh ** 0.5)
+    g = query_groups(nh, dh, k_pages.shape[-1])
+    nkv = nh // g
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
     row = page_table[None]
-    kk = gather_kv(k_pages, row, layer, nh).astype(jnp.float32)
-    vv = gather_kv(v_pages, row, layer, nh).astype(jnp.float32)
+    kk = gather_kv(k_pages, row, layer, nkv).astype(jnp.float32)
+    vv = gather_kv(v_pages, row, layer, nkv).astype(jnp.float32)
     if k_scale is not None:
         kk = kk * gather_scales(k_scale, row, layer)[..., None]
         vv = vv * gather_scales(v_scale, row, layer)[..., None]
     lmax = kk.shape[1]
     pos = start + jnp.arange(c)
-    sc = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32) * scale, kk)
     mask = jnp.arange(lmax)[None, :] <= pos[:, None]         # [C, Lmax]
+    qs = q.astype(jnp.float32) * scale
+    if g > 1:
+        sc = jnp.einsum("bqkgd,blkd->bkgql", qs.reshape(1, c, nkv, g, dh),
+                        kk)
+        sc = jnp.where(mask[None, None, None], sc, -1e30)
+        pr = jax.nn.softmax(sc, axis=-1)
+        return jnp.einsum("bkgql,blkd->bqkgd", pr, vv).reshape(
+            q.shape).astype(q.dtype)
+    sc = jnp.einsum("bqhd,bkhd->bhqk", qs, kk)
     sc = jnp.where(mask[None, None], sc, -1e30)
     pr = jax.nn.softmax(sc, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", pr, vv).astype(q.dtype)
 
 
 def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,
-                       layer, k_scale=None, v_scale=None):
+                       layer, k_scale=None, v_scale=None, scale=None):
     """Execute one named prefill impl over the stored pool at ``layer``
     (also the autotuner's run_impl)."""
     if impl == "pallas":
@@ -306,14 +350,15 @@ def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,
             prefill_attention as pallas_prefill)
         return pallas_prefill(q[0], k_pages, v_pages, page_table, start,
                               valid, layer=layer, k_scale=k_scale,
-                              v_scale=v_scale)[None]
+                              v_scale=v_scale, scale=scale)[None]
     return _xla_prefill_attention(q, k_pages, v_pages, page_table, start,
                                   valid, layer, k_scale=k_scale,
-                                  v_scale=v_scale)
+                                  v_scale=v_scale, scale=scale)
 
 
 def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
-                 quant=False, parity=True, num_pages=None) -> str:
+                 quant=False, parity=True, num_pages=None,
+                 grouped=False) -> str:
     """Resolve (and COUNT) the prefill-attention impl for one program
     build — the registry is the only selector (`kernels/registry.py`;
     ``FLAGS_tpu_prefill_impl`` forces, ``auto`` measures via
@@ -321,7 +366,9 @@ def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
     arm does NOT read the page pool (the one-shot `prefill_step` over a
     narrowing pool dtype), which drops the pallas candidate rather than
     silently changing numerics. ``num_pages`` is the pool's size, which
-    the measurement reproduces (`autotune.paged_winner`)."""
+    the measurement reproduces (`autotune.paged_winner`). A ``grouped``
+    signature (more query heads than the pool has) has the xla arm alone:
+    the Pallas arm takes one K/V head a query head."""
     from paddle_tpu.kernels import registry
     try:
         from paddle_tpu.framework.flags import flag_value
@@ -347,24 +394,29 @@ def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
                               num_pages=num_pages)
 
     return registry.dispatch("prefill_attention", forced=forced,
-                             ctx={"parity": parity}, winner=winner)
+                             ctx={"parity": parity, "grouped": grouped},
+                             winner=None if grouped else winner,
+                             require_viable=grouped)
 
 
 def prefill_attention(q, k_pages, v_pages, page_table, start, valid,
-                      k_scale=None, v_scale=None, *, layer=None):
+                      k_scale=None, v_scale=None, *, layer=None,
+                      scale=None):
     """One CHUNK of ragged prefill attention for ONE sequence, over pages
     the chunk's K/V were just written to — the dispatch switch the
     registry routes (`prefill_step` / `prefill_chunk_step` / the PTKS1
     streaming path all land here or on :func:`prefill_impl`):
 
     q          : [1, C, nh, dh] chunk queries (leading batch of 1 — the
-                 step programs' native layout)
+                 step programs' native layout); ``nh`` may be ``g`` x the
+                 pool's heads (grouped queries)
     k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
                  read at ``layer`` (without ``layer``: one layer's pool,
                  as :func:`paged_attention`)
     page_table : [pages_per_slot] int32 — this sequence's page row
     start      : scalar int32 absolute position of the chunk's first token
     valid      : scalar int32 true token count in this chunk
+    scale      : what multiplies the scores (None: ``1 / sqrt(dh)``)
     returns    : [1, C, nh, dh] in q.dtype — token-identical between arms
                  (rows < valid; parity-tested in interpret mode off-TPU)
     """
@@ -373,9 +425,12 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid,
     impl = prefill_impl(q.shape[1], page_table.shape[0], k_pages.shape[2],
                         q.shape[2], q.shape[3], q.dtype,
                         quant=k_scale is not None,
-                        num_pages=k_pages.shape[1])
+                        num_pages=k_pages.shape[1],
+                        grouped=query_groups(q.shape[2], q.shape[3],
+                                             k_pages.shape[-1]) > 1)
     return _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start,
-                              valid, layer, k_scale=k_scale, v_scale=v_scale)
+                              valid, layer, k_scale=k_scale, v_scale=v_scale,
+                              scale=scale)
 
 
 def token_page_coords(page_table, pos, active, page_size):

@@ -156,9 +156,12 @@ def _lossless_dot(a, b, dims):
 
 
 def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
-                   page_size, nh, bp, scale, quant=False, has_visits=False):
-    # one grid cell per sequence b, all heads at once: q_ref [1, 1, nh*dh]
-    # in VMEM, k_hbm/v_hbm the stacked [nl, num_pages, page_size, nh*dh]
+                   page_size, nh, bp, scale, g=1, quant=False,
+                   has_visits=False):
+    # one grid cell per sequence b, all heads at once: q_ref [1, g, nkv*dh]
+    # in VMEM (``nh`` query heads over ``nkv = nh / g`` K/V heads; row j
+    # holds, on K/V head k's lanes, query head k * g + j; g = 1: the one
+    # row of GPT-2's one-to-one heads), k_hbm/v_hbm the stacked [nl, num_pages, page_size, nh*dh]
     # pools in HBM, pos/page_table/layer scalar-prefetched into SMEM (the
     # layer is an operand, not a constant: every layer of a program runs
     # this one kernel). Operand order is
@@ -190,7 +193,8 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     pos = pos_ref[b]
     lyr = layer_ref[0]
     nslots, tokens, hd = kbuf.shape
-    dh = hd // nh
+    nkv = nh // g
+    dh = hd // nkv
     last_page = k_hbm.shape[1] - 1
 
     def npages_of(seq):
@@ -260,15 +264,25 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         # read)
         visits_ref[...] = jnp.full(visits_ref.shape, npages, jnp.int32)
 
-    # heads live on SUBLANES here, padded to whole bf16 tiles: row h of
-    # ``own`` marks head h's dh lanes (a padded row marks none, so it
-    # scores 0 everywhere and is dropped by the final reduce); q goes in
-    # block-diagonal, [nhp, nh*dh]
+    # heads live on SUBLANES here, padded to whole bf16 tiles: row r =
+    # j * nkv + k is query head k * g + j, and ``own`` marks the dh lanes
+    # of ITS K/V head k (a padded row marks none, so it scores 0 everywhere
+    # and is dropped by the final reduce); q goes in block-diagonal,
+    # [nhp, nkv*dh]
     nhp = -(-nh // 16) * 16
     lane = jax.lax.broadcasted_iota(jnp.int32, (nhp, hd), 1)
-    lane0 = jax.lax.broadcasted_iota(jnp.int32, (nhp, hd), 0) * dh
-    own = ((lane >= lane0) & (lane < lane0 + dh)).astype(jnp.float32)
-    qd = (own * q_ref[0].astype(jnp.float32)).astype(q_ref.dtype)
+    head = jax.lax.broadcasted_iota(jnp.int32, (nhp, hd), 0)
+    lane0 = jax.lax.rem(head, nkv) * dh
+    own = ((lane >= lane0) & (lane < lane0 + dh)
+           & (head < nh)).astype(jnp.float32)
+    if g == 1:
+        q_rows = q_ref[0]
+    else:
+        q_rows = jnp.concatenate(
+            [jnp.broadcast_to(q_ref[0, j:j + 1], (nkv, hd))
+             for j in range(g)]
+            + [jnp.zeros((nhp - nh, hd), q_ref.dtype)] * (nhp > nh), axis=0)
+    qd = (own * q_rows.astype(jnp.float32)).astype(q_ref.dtype)
     nt = ((1,), (1,))                  # [r, c] x [t, c] -> [r, t]
     nn = ((1,), (0,))                  # [r, t] x [t, c] -> [r, c]
     # rows of a block a turn may reduce: an eighth, a half (where those
@@ -286,8 +300,8 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             # dequantize in-register AFTER the copies: the DMAs moved int8
             # bytes; only the VMEM-resident working block widens
             at = pl.ds(pl.multiple_of(j * tokens, tokens), rows)
-            k = k * exact_dot(ks_ref[0, at, :], own[:nh])
-            v = v * exact_dot(vs_ref[0, at, :], own[:nh])
+            k = k * exact_dot(ks_ref[0, at, :], own[:nkv])
+            v = v * exact_dot(vs_ref[0, at, :], own[:nkv])
         s = _lossless_dot(qd, k, nt) * scale               # [nhp, rows]
         kpos = j * tokens + jax.lax.broadcasted_iota(
             jnp.int32, (1, rows), 1)
@@ -315,11 +329,15 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     l0 = jnp.zeros((nhp, 1), jnp.float32)
     a0 = jnp.zeros((nhp, hd), jnp.float32)
     m, l, acc = jax.lax.fori_loop(0, nblocks, body, (m0, l0, a0))
-    # row h of acc holds head h's sum over ALL lanes; keep its own dh (one
-    # nonzero term a lane, so these reduces are exact)
-    o_ref[0] = (jnp.sum(acc * own, axis=0, keepdims=True)
-                / jnp.sum(jnp.maximum(l, 1e-30) * own, axis=0,
-                          keepdims=True)).astype(o_ref.dtype)
+    # row r of acc holds its head's sum over ALL lanes; keep its own dh
+    # (one nonzero term a lane among the nkv rows of one j, so these
+    # reduces are exact)
+    num, den = acc * own, jnp.maximum(l, 1e-30) * own
+    for j in range(g):
+        rows = slice(j * nkv, (j + 1) * nkv) if g > 1 else slice(None)
+        o_ref[0, j:j + 1] = (
+            jnp.sum(num[rows], axis=0, keepdims=True)
+            / jnp.sum(den[rows], axis=0, keepdims=True)).astype(o_ref.dtype)
 
 
 def scale_window(scales, page_table, layer):
@@ -332,12 +350,14 @@ def scale_window(scales, page_table, layer):
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
                     interpret=None, return_visits=False, k_scale=None,
-                    v_scale=None):
+                    v_scale=None, scale=None):
     """One decode step of ragged paged attention. Same contract as the XLA
     reference `kernels.paged_attention.paged_attention`:
 
-    q          : [B, nh, dh] current-token query
-    k_pages    : [nl, num_pages, page_size, nh * dh] — the stored pool,
+    q          : [B, nh, dh] current-token query; ``nh`` may be ``g`` x the
+                 pool's heads (grouped queries: head h reads K/V head
+                 h // g)
+    k_pages    : [nl, num_pages, page_size, nkv * dh] — the stored pool,
                  read at ``layer`` (without ``layer``: one layer's pool,
                  see "Layout")
     v_pages    : as k_pages
@@ -347,6 +367,7 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
                  pools: the dequant runs in-register after a block's
                  copies, so the kernel's page traffic is the int8 bytes
                  (~1/4 of f32)
+    scale      : what multiplies the scores (None: ``1 / sqrt(dh)``)
     returns    : [B, nh, dh] in q.dtype; with ``return_visits=True`` also
                  the pages fetched [B, nh] int32 (one walk serves every
                  head of a sequence, so a row repeats one count) — the
@@ -367,28 +388,32 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
     return _stored_call(q, k_pages, v_pages, page_table, pos,
                         jnp.asarray(layer, jnp.int32), k_scale, v_scale,
                         interpret=bool(interpret),
-                        return_visits=bool(return_visits))
+                        return_visits=bool(return_visits),
+                        scale=None if scale is None else float(scale))
 
 
-@functools.partial(jax.jit, static_argnames=("interpret", "return_visits"))
+@functools.partial(jax.jit, static_argnames=("interpret", "return_visits",
+                                             "scale"))
 def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
-                 v_scale, *, interpret, return_visits):
+                 v_scale, *, interpret, return_visits, scale=None):
     # the kernel over the stored pools at a TRACED layer, as a function of
     # its own: every layer of a step program is the same call of it, so a
     # program traces and lowers the kernel once, not once a layer (which
     # was 4 s of each start for GPT-2 medium's 24)
     quant = k_scale is not None
     b, nh, dh = q.shape
-    ps = k_pages.shape[2]
-    hd = nh * dh
-    scale = 1.0 / (dh ** 0.5)
+    ps, hd = k_pages.shape[2:]
+    from paddle_tpu.kernels.paged_attention import query_groups
+    g = query_groups(nh, dh, hd)
+    nkv = nh // g
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
     bp = block_pages(ps, hd, k_pages.dtype.itemsize)
     kern = functools.partial(_decode_kernel, page_size=ps, nh=nh, bp=bp,
-                             scale=float(scale), quant=quant,
+                             scale=float(scale), g=g, quant=quant,
                              has_visits=return_visits)
-    row = pl.BlockSpec((1, 1, hd), lambda i, *_: (i, 0, 0))
+    row = pl.BlockSpec((1, g, hd), lambda i, *_: (i, 0, 0))
     out_specs = [row]
-    out_shape = [jax.ShapeDtypeStruct((b, 1, hd), q.dtype)]
+    out_shape = [jax.ShapeDtypeStruct((b, g, hd), q.dtype)]
     if return_visits:
         out_specs.append(pl.BlockSpec((1, 1, 128), lambda i, *_: (i, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b, 1, 128), jnp.int32))
@@ -397,7 +422,9 @@ def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
         pl.BlockSpec(memory_space=pl.ANY),            # K pool stays in HBM
         pl.BlockSpec(memory_space=pl.ANY),            # V pool stays in HBM
     ]
-    operands = [q.reshape(b, 1, hd), k_pages, v_pages]
+    # row j of a sequence's q: query head k * g + j on K/V head k's lanes
+    operands = [q.reshape(b, nkv, g, dh).swapaxes(1, 2).reshape(b, g, hd),
+                k_pages, v_pages]
     if quant:
         # whole blocks of scales, so that the last block's rows exist; and
         # zeros past the pages a sequence has, whose table entries may name
@@ -406,7 +433,7 @@ def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
         rows = -(-maxp // bp) * bp * ps
         live = jnp.minimum(pages_needed(pos, ps), maxp) * ps
         keep = (jnp.arange(rows) < live[:, None])[..., None]
-        win = pl.BlockSpec((1, rows, nh), lambda i, *_: (i, 0, 0))
+        win = pl.BlockSpec((1, rows, nkv), lambda i, *_: (i, 0, 0))
         in_specs += [win, win]
         operands += [jnp.where(keep, jnp.pad(
             scale_window(s, page_table, layer),
@@ -437,7 +464,7 @@ def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
             interpret=interpret,
         )(pos.astype(jnp.int32), page_table.astype(jnp.int32),
           layer.reshape(1), *operands)
-    out = outs[0].reshape(b, nh, dh)
+    out = outs[0].reshape(b, g, nkv, dh).swapaxes(1, 2).reshape(b, nh, dh)
     if return_visits:
         return out, jnp.broadcast_to(outs[1][:, 0, :1], (b, nh))
     return out

@@ -176,7 +176,7 @@ def _prefill_kernel(meta_ref, pt_ref, q_ref, k_hbm, v_hbm, *rest,
 
 def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
                       layer=None, interpret=None, return_visits=False,
-                      block_q=None, k_scale=None, v_scale=None):
+                      block_q=None, k_scale=None, v_scale=None, scale=None):
     """One CHUNK of ragged prefill attention for ONE sequence over paged
     K/V (the chunk's own K/V already written to its pages):
 
@@ -191,6 +191,7 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
     valid      : scalar int32 — true token count in this chunk
     k_scale/v_scale : optional [nl, num_pages, page_size, nh] f32 (int8
                  pools)
+    scale      : what multiplies the scores (None: ``1 / sqrt(dh)``)
     returns    : [C, nh, dh] in q.dtype; with ``return_visits=True`` also
                  the page-loop trip counts [ceil(C / block_q), nh] int32
                  (one walk serves every head of a q block, so a row
@@ -209,9 +210,13 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
     c, nh, dh = q.shape
     ps = k_pages.shape[2]
     hd = nh * dh
+    if k_pages.shape[-1] != hd:
+        raise ValueError(f"{nh} query heads of width {dh} over a pool row of "
+                         f"{k_pages.shape[-1]}: grouped queries take the "
+                         "xla arm")
     bq = default_block_q(c) if block_q is None else min(int(block_q), c)
     nq = pl.cdiv(c, bq)
-    scale = 1.0 / (dh ** 0.5)
+    scale = 1.0 / (dh ** 0.5) if scale is None else scale
     kern = functools.partial(_prefill_kernel, page_size=ps, block_q=bq,
                              nh=nh, scale=float(scale), quant=quant,
                              has_visits=bool(return_visits))

@@ -378,8 +378,14 @@ def _flash_cands(ctx):
 
 def _paged_cands(ctx):
     from paddle_tpu.kernels import autotune
-    return autotune._paged_candidates(
+    cands = autotune._paged_candidates(
         ctx.get("backend", autotune._backend_kind()))
+    if ctx.get("grouped"):
+        # grouped queries are not measured (`kernels/paged_attention.py`):
+        # where the kernel is viable it is preferred, as it read ten times
+        # faster at 32 heads over 8 (PERF.md section 6, PR 31)
+        cands = cands[::-1]
+    return cands
 
 
 def _prefill_cands(ctx):
@@ -391,6 +397,9 @@ def _prefill_cands(ctx):
         # the compute dtype (bf16 pages under f32 weights, non-quant), the
         # one-shot XLA arm attends the raw full-precision K/V — offering
         # pallas there would silently change numerics, so it is not viable
+        cands = [c for c in cands if c != "pallas"]
+    if ctx.get("grouped"):
+        # the Pallas prefill arm takes one K/V head a query head
         cands = [c for c in cands if c != "pallas"]
     return cands
 

@@ -532,6 +532,12 @@ class TestServeGenerate:
         assert cli.ping()
         cli.shutdown_server()
         cli.close()
+        # srv2 was never served, but its engine thread runs: stop it, or it
+        # outlives this file in its xdist worker and takes the next armed
+        # process-wide fault (tests/test_chaos.py arms `engine.crash` once)
+        srv2._stop.set()
+        srv2._engine_thread.join(timeout=10)
+        assert not srv2._engine_thread.is_alive()
         srv2._sock.close()
 
     def test_legacy_model_prefix_client_with_env_token(self, monkeypatch):

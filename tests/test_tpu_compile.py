@@ -366,6 +366,140 @@ def test_hybrid_step_program_copies_no_pool_or_state_on_v5e(chip, program):
                                      else 0.2e9)
 
 
+# Granite-4.0-H-Small as benchmarks/configs/granite-4.0-h-small.json serves
+# it: one chip's share (10 layers, 36 of 72 experts, half the vocabulary)
+GRANITE = dict(slots=64, page=16, per_slot=256, pages=16385, chunk=512)
+
+
+def _granite_config():
+    from paddle_tpu.models import granitemoehybrid as gm
+    return gm.GraniteMoeHybridConfig(
+        vocab_size=50176, layer_types=gm.PERIOD, experts_held=(0, 36))
+
+
+@pytest.mark.parametrize("kernel", ["paged_attention", "ssm2_update"])
+def test_granite_decode_kernel_compiles_for_v5e(chip, kernel):
+    """The two Pallas kernels of Granite-4.0-H's decode step, each alone:
+    paged attention at 32 query heads of 128 over a pool of 8 (rows of 1,024
+    lanes), scores times 1/128; and the SSM update over the stored stack of
+    9 layers x 64 slots x 128 x 8,192 float32 at a traced layer, aliased. (A
+    grouped prefill has the xla arm alone.)"""
+    g = GRANITE
+
+    def spec(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    if kernel == "paged_attention":
+        from paddle_tpu.kernels.pallas.paged_attention import paged_attention
+        pool = spec((1, g["pages"], g["page"], 8 * 128), BF16)
+        _compiles_to_a_kernel(
+            lambda q, k, v, t, p: paged_attention(
+                q, k, v, t, p, layer=0, interpret=False, scale=1 / 128),
+            spec((g["slots"], 32, 128), BF16), pool, pool,
+            spec((g["slots"], g["per_slot"])), spec((g["slots"],)))
+    else:
+        from paddle_tpu.kernels.ssm2 import ssm2_update
+        b, h, p, n = g["slots"], 128, 64, 128
+        f32 = jnp.float32
+        _compiles_to_a_kernel(
+            lambda s, dt, x, bm, cm, a, d, act, lyr: ssm2_update(
+                s, dt, x, bm, cm, a, d, act, layer=lyr, impl="pallas",
+                interpret=False),
+            spec((9, b, n, h * p), f32), spec((b, h), f32),
+            spec((b, h, p), f32), spec((b, n), f32), spec((b, n), f32),
+            spec((h,), f32), spec((h,), f32), spec((b,), jnp.bool_),
+            spec(()))
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_granite_step_program_copies_no_state_or_expert_on_v5e(
+        chip, program, monkeypatch):
+    """Granite-4.0-H-Small's decode step, one prefill chunk of 512 and a
+    one-shot prefill of that length, whole, at the published widths with
+    the chip's share of the experts, compiled for the described chip with
+    pool and state donated and the routing counts behind the token chain,
+    with the arms a TPU run takes (the code that picks them asks JAX for its
+    backend, which here is the CPU: the test steers it). The optimized HLO
+    holds no copy, slice, transpose or fusion of the size of the SSM stack
+    (9 x 64 x 128 x 8,192 float32, 2.4 GB: stored with heads and head width
+    as two axes it was relaid whole in and out of the prefill programs), of
+    the page pool or of one layer's held experts (36 x 768 x 4,096 and up:
+    the ragged product copied them out of their stack) but the stacks'
+    in-place updates; everything donated is aliased; the decode step updates
+    the SSM stack inside a kernel (the plain arm wrote a layer's new slab,
+    268 MB, beside the stack first); and the program fits the chip beside
+    its 13 GB of arguments."""
+    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels.pallas import _compat
+    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
+    from paddle_tpu.inference.cache import DeviceCache
+    from paddle_tpu.inference.programs import (decode_program,
+                                               prefill_program,
+                                               prefill_upload, step_upload)
+    from paddle_tpu.models import granitemoehybrid as gm
+    cfg = _granite_config()
+    g = GRANITE
+    slots, per_slot = g["slots"], g["per_slot"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+    params = {k: sds(s, BF16) for k, s in gm.leaf_shapes(cfg).items()}
+    pool = sds((cfg.n_attention, g["pages"], g["page"], cfg.kv_width), BF16)
+    specs = gm.state_arrays(cfg, slots, g["page"], BF16)
+    cache = DeviceCache(k=pool, v=pool, k_scale=None, v_scale=None,
+                        state=tuple(sds(s, d) for _, _, s, d in specs),
+                        keys=None, heads=cfg.num_kv_heads)
+    n = gm.step_counts(cfg)
+    if program == "decode_step":
+        up = step_upload(slots, per_slot, sampling=False)
+        step = decode_program(gm, cfg, up, n)
+    else:
+        up = prefill_upload(g["chunk"], per_slot, sampling=False,
+                            chunk=program == "prefill_chunk_step")
+        step = prefill_program(gm, cfg, up, n)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((slots + n,), jnp.int32),
+        up.spec(sharding=chip)).compile()
+    chain, _ = compiled.out_info
+    assert chain.shape == (slots + n,) and chain.dtype == jnp.int32
+    elems = {name: int(np.prod(s)) for name, _, s, _ in specs}
+    experts = cfg.n_held * cfg.hidden_size * cfg.intermediate_size
+    text = compiled.as_text()
+    big = pool_sized_ops(text, experts, ("scatter", "dynamic-update-slice"))
+    assert big == [], big
+    # decode: paged attention and the SSM update (one a run of Mamba
+    # layers) are kernels; a prefill takes the plain attention arm
+    kernels = text.count("custom_call_target=\"tpu_custom_call\"")
+    assert kernels == (1 + len([r for r in cfg.runs() if r[0] == "mamba"])
+                       if program == "decode_step" else 0)
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 2 * (2 * int(np.prod(pool.shape))
+                                           + elems["conv"]) + 4 * elems["ssm"]
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.6e9
+    assert mem.temp_size_in_bytes < (0.05e9 if program == "decode_step"
+                                     else 0.4e9)
+    # the op families by which the cell's kernel shares find these kernels
+    # in a device trace (opcode and result shape, the sizes filled in from
+    # the committed configuration) are in the program that makes them
+    import json
+    import re
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    sys.path.insert(0, bench)
+    from harness import granite_bytes, spec as harness_spec, trace
+    with open(os.path.join(bench, "configs",
+                           "granite-4.0-h-small.json")) as f:
+        shapes = granite_bytes.trace_shapes(json.load(f))
+    families = {trace.family(ln.strip().removeprefix("ROOT "))
+                for ln in text.splitlines() if " = " in ln}
+    made_by = {"decode_step": ("moe_experts", "ssm2_update"),
+               "prefill_chunk_step": ("ssm2_scan",), "prefill_step": ()}
+    for kernel in made_by[program]:
+        metric = harness_spec.layer_metric(f"{kernel}_roofline_share")
+        for pattern in metric["patterns"]:
+            want = pattern.format(**shapes)
+            assert [f for f in families if re.search(want, f)], want
+
+
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "bwd"])
 @pytest.mark.parametrize("width", WIDTHS)
 def test_fused_layernorm_compiles_for_v5e(chip, width, bwd):
