@@ -2,7 +2,9 @@
 updates in place, and only this module knows what is in it.
 
 `DeviceCache` is a pytree of the K and V page pools (stored as the
-attention kernels read them: `kernels/paged_attention.py`), the int8 pool's
+attention kernels read them: `kernels/paged_attention.py`; empty, of zero
+layers and the trash page alone, for a family that keeps nothing in pages:
+`family.kv_layers` 0), the int8 pool's
 scale pools or None, the family's state arrays beside the pool (window
 rings, recurrent state: `inference/family.py`), and the fused sampler's
 per-slot PRNG key chains or None. Every step program is
@@ -192,7 +194,10 @@ class PageAllocator:
 
     def __init__(self, num_pages: int):
         if num_pages < 2:
-            raise ValueError(f"need >= 2 pages (1 is reserved), got {num_pages}")
+            # a family with no layer in the pool gets no allocator at all
+            # (`DecodeEngine`): this is the pooled families' rule
+            raise ValueError(f"a page pool needs >= 2 pages (1 is reserved), "
+                             f"got {num_pages}")
         self.num_pages = num_pages
         self._free = deque(range(1, num_pages))
         self._refcnt = [0] * num_pages

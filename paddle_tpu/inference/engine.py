@@ -755,11 +755,21 @@ class DecodeEngine:
         max_seq = ecfg.max_seq_len or fam.max_positions
         max_seq = min(max_seq, fam.max_positions)
         self.max_seq_len = max_seq
-        self.pages_per_slot = -(-max_seq // ps)           # ceil
-        self.slot_capacity = self.pages_per_slot * ps     # tokens per slot
-        num_pages = ecfg.num_pages or \
-            1 + ecfg.max_slots * self.pages_per_slot
-        self.allocator = PageAllocator(num_pages)
+        # a family with no layer in the page pool (``kv_layers`` 0: all it
+        # keeps of a sequence is fixed-size state) gets no pool, no
+        # allocator and a page table of no width: its admission is bounded
+        # by slots alone and a sequence's length costs it no memory
+        self._pooled = fam.kv_layers > 0
+        if not (self._pooled or self._stateful):
+            raise ValueError(f"the {fam.name} family keeps neither pages "
+                             "nor state: nothing a sequence could live in")
+        self.pages_per_slot = -(-max_seq // ps) if self._pooled else 0
+        self.slot_capacity = self.pages_per_slot * ps \
+            if self._pooled else max_seq                  # tokens per slot
+        num_pages = (ecfg.num_pages or
+                     1 + ecfg.max_slots * self.pages_per_slot) \
+            if self._pooled else 1
+        self.allocator = PageAllocator(num_pages) if self._pooled else None
         if ecfg.donate is None:
             self._donate = jax.default_backend() != "cpu"
         else:
@@ -874,8 +884,9 @@ class DecodeEngine:
         self._prefix_pages: dict[bytes, int] = {}
         self._page_hash: dict[int, bytes] = {}
         self._prefix_idle: OrderedDict[int, None] = OrderedDict()
-        self.allocator.retain_hook = self._retain_page
-        self.allocator.evict_hook = self._evict_prefix_pages
+        if self._pooled:
+            self.allocator.retain_hook = self._retain_page
+            self.allocator.evict_hook = self._evict_prefix_pages
         # KV tiering (docs/SERVING.md "KV tiering"): bounded host-RAM /
         # disk spill tiers under the HBM store — eviction demotes page
         # contents instead of discarding them, and a tier hit re-uploads
@@ -1749,7 +1760,7 @@ class DecodeEngine:
                         req._finish(err)
                     continue
                 total = -(-(req.prompt.size + req.max_new_tokens)
-                          // self.ecfg.page_size)
+                          // self.ecfg.page_size) if self._pooled else 0
                 shared: list[int] = []
                 if self._prefix_enabled and req.cache:
                     shared = self._prefix_lookup(req.page_hashes)
@@ -1764,7 +1775,8 @@ class DecodeEngine:
                     # refcount-0 cached pages under pressure, and claiming
                     # makes these ones live (un-evictable)
                     self._attach_prefix(shared)
-                pages = self.allocator.alloc(total - len(shared))
+                pages = self.allocator.alloc(total - len(shared)) \
+                    if self._pooled else []
                 if pages is None:
                     if shared:
                         self.allocator.free(shared)  # back to idle cache
@@ -2082,7 +2094,8 @@ class DecodeEngine:
             req.u_page_steps += len(self._slot_pages[slot]) * max(
                 0, self.step_seq - req.u_admit_step)
             req.u_admit_step = None
-        self.allocator.free(self._slot_pages[slot])
+        if self._slot_pages[slot]:
+            self.allocator.free(self._slot_pages[slot])
         self._slot_pages[slot] = []
         self._slot_req[slot] = None
         self._slot_draft[slot] = None

@@ -5,22 +5,31 @@ not its business. A model object hands it one :class:`ModelFamily` through
 ``model.engine_family()``: the pure step functions the AOT programs trace,
 where the parameters are, and the kinds and shapes of state a sequence
 keeps. There is no flag and no `EngineConfig` field that picks a model —
-the model object decides. `models/gpt.py`, `models/phi4flash.py` and
-`models/granitemoehybrid.py` each supply one; the GPT family describes
-exactly what the engine used to import, so its programs trace as before.
+the model object decides. `models/gpt.py`, `models/phi4flash.py`,
+`models/granitemoehybrid.py` and `models/brumby.py` each supply one; the GPT
+family describes exactly what the engine used to import, so its programs
+trace as before.
 
 The step functions' contracts (``steps`` is any namespace that has them):
 
 - ``decode_step(params, ids, cache, slot_mask, *, cfg)`` -> ``(logits
   [B, V] f32, cache)``; ``cache`` holds ``k_pages``, ``v_pages``,
-  ``page_table``, ``lengths``, ``k_scale`` / ``v_scale`` on an int8 pool,
-  and ``state`` (a tuple, in ``state(...)``'s order) for a family that
-  keeps any;
+  ``page_table``, ``lengths`` (a slot's tokens so far: its new token's
+  position), ``k_scale`` / ``v_scale`` on an int8 pool, and ``state`` (a
+  tuple, in ``state(...)``'s order) for a family that keeps any;
 - ``prefill_step(params, ids, length, row, k_pages, v_pages, *, cfg, ...)``
   and ``prefill_chunk_step(params, ids, start, valid, row, k_pages,
   v_pages, *, cfg, ...)`` -> ``(logits [V] f32, k_pages, v_pages, ...)``;
   a family with state also takes ``state=`` and ``slot=`` and returns the
   state arrays after the pools;
+
+A family whose ``kv_layers`` is 0 keeps nothing in a page pool (all it
+remembers of a sequence is fixed-size state): the engine then allocates no
+pool and no page, ``k_pages`` / ``v_pages`` are empty (``[0, 1, page,
+width]``) and ``page_table`` / ``row`` have no width; its step functions
+pass the pools through untouched, its admission is bounded by slots alone,
+and a sequence's length costs it no memory (docs/SERVING.md "A family
+without a page pool");
 - ``verify_step`` (optional): the speculative k-token step. A family
   without one cannot speculate, and the engine refuses ``speculate_k``.
 
@@ -62,6 +71,7 @@ class ModelFamily:
     params: Callable[[Any], dict]   # model -> {leaf name: device array}
     table_key: str                  # the leaf whose dtype is the served one
     kv_layers: int                  # layers that own rows of the page pool
+    #                                 (0: no pool at all)
     kv_heads: int
     head_dim: int
     max_positions: int              # longest sequence the model can see

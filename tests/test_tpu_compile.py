@@ -500,6 +500,115 @@ def test_granite_step_program_copies_no_state_or_expert_on_v5e(
             assert [f for f in families if re.search(want, f)], want
 
 
+# Brumby-14B-Base as benchmarks/configs/brumby-14b-base.json serves it: one
+# pipeline stage of five (8 layers, the embedding and the head)
+BRUMBY = dict(slots=16, page=16, chunk=512, layers=8)
+
+
+def test_retention_update_kernel_compiles_for_v5e(chip):
+    """The retention decode update alone at the published widths: 40 query
+    heads over 8 key-value heads of 128, the state stack 8 x 16 x 8 x 65 x
+    128 x 128 float32 (4.36 GB) at a traced layer, aliased with the
+    normaliser; lane rotations, a transpose and a lane sum inside."""
+    from paddle_tpu.kernels import retention
+    b = BRUMBY
+    f32 = jnp.float32
+
+    def spec(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    s, z = retention.state_shapes(b["layers"], b["slots"], 8, 128)
+    _compiles_to_a_kernel(
+        lambda s, z, lg, q, k, v, act, lyr: retention.retention_update(
+            s, z, lg, q, k, v, act, layer=lyr, impl="pallas",
+            interpret=False),
+        spec(s), spec(z), spec((b["slots"], 8)), spec((b["slots"], 40, 128)),
+        spec((b["slots"], 8, 128)), spec((b["slots"], 8, 128)),
+        spec((b["slots"],), jnp.bool_), spec((), jnp.int32))
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+def test_brumby_step_program_relays_no_state_and_copies_no_weight_on_v5e(
+        chip, program, monkeypatch):
+    """Brumby-14B-Base's decode step and one prefill chunk of 512, whole, at
+    the published widths and the benchmark's 8 layers, with NO page pool
+    (empty pools, uploads with a page table of no width), compiled for the
+    described chip with the state donated and the arms a TPU run takes. The
+    eight layers are one loop over stacked leaves read at a traced index:
+    the optimized HLO holds no copy, slice, transpose or fusion of the size
+    of one layer's smallest large matrix (5,120 x 5,120) but the stacks'
+    in-place updates, so no stacked weight is copied out and neither state
+    stack (2.18 G and 17 M elements) is relaid; everything donated is
+    aliased; the decode step updates the state inside ONE kernel, the chunk
+    in plain XLA; the program fits the chip beside its 12.8 GB of
+    arguments; and the op families by which the cell's two kernel shares
+    find their time in a device trace are in the program that makes them,
+    one family a pattern."""
+    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels.pallas import _compat
+    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
+    from paddle_tpu.inference.cache import DeviceCache
+    from paddle_tpu.inference.programs import (decode_program,
+                                               prefill_program,
+                                               prefill_upload, step_upload)
+    from paddle_tpu.models import brumby as bm
+    b = BRUMBY
+    cfg = bm.BrumbyConfig(num_layers=b["layers"])
+    slots = b["slots"]
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+    params = {k: sds(s, BF16) for k, s in bm.leaf_shapes(cfg).items()}
+    pool = sds((0, 1, b["page"], cfg.kv_width), BF16)
+    specs = bm.state_arrays(cfg, slots, b["page"], BF16)
+    cache = DeviceCache(k=pool, v=pool, k_scale=None, v_scale=None,
+                        state=tuple(sds(s, d) for _, _, s, d in specs),
+                        keys=None, heads=cfg.num_kv_heads)
+    if program == "decode_step":
+        up = step_upload(slots, 0, sampling=False)
+        assert up.shape == (slots, 3)
+        step = decode_program(bm, cfg, up)
+    else:
+        up = prefill_upload(b["chunk"], 0, sampling=False, chunk=True)
+        step = prefill_program(bm, cfg, up)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((slots,), jnp.int32),
+        up.spec(sharding=chip)).compile()
+    text = compiled.as_text()
+    big = pool_sized_ops(text, cfg.hidden_size * cfg.q_width,
+                         ("scatter", "dynamic-update-slice"))
+    assert big == [], big
+    kernels = text.count("custom_call_target=\"tpu_custom_call\"")
+    assert kernels == (1 if program == "decode_step" else 0)
+    elems = {name: int(np.prod(s)) for name, _, s, _ in specs}
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * sum(elems.values())
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.0e9
+    assert mem.temp_size_in_bytes < (1e6 if program == "decode_step"
+                                     else 0.2e9)
+    import json
+    import re
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    sys.path.insert(0, bench)
+    from harness import brumby_bytes, spec as harness_spec, trace
+    with open(os.path.join(bench, "configs", "brumby-14b-base.json")) as f:
+        shapes = brumby_bytes.trace_shapes(json.load(f))
+    families = {trace.family(ln.strip().removeprefix("ROOT "))
+                for ln in text.splitlines() if " = " in ln}
+    kernel = {"decode_step": "retention_update",
+              "prefill_chunk_step": "retention_chunk"}[program]
+    metric = harness_spec.layer_metric(f"{kernel}_roofline_share")
+    for pattern in metric["patterns"]:
+        want = pattern.format(**shapes)
+        assert len([f for f in families if re.search(want, f)]) == 1, want
+    other = {"decode_step": "retention_chunk",
+             "prefill_chunk_step": "retention_update"}[program]
+    for pattern in harness_spec.layer_metric(
+            f"{other}_roofline_share")["patterns"]:
+        want = pattern.format(**shapes)
+        assert not [f for f in families if re.search(want, f)], want
+
+
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "bwd"])
 @pytest.mark.parametrize("width", WIDTHS)
 def test_fused_layernorm_compiles_for_v5e(chip, width, bwd):
