@@ -922,7 +922,7 @@ def _int8_kv_prefill_parity(model, cfg, prompt, pps, page_size):
     from paddle_tpu.models import gpt as gpt_mod
     from paddle_tpu.quantization.serving import margin_gated_parity
 
-    params = {k: t._data for k, t in model.state_dict().items()}
+    params = gpt_mod.serving_params(model.state_dict())
     nh, nl = cfg.num_heads, cfg.num_layers
     s0 = int(prompt.size)
     need = -(-s0 // page_size)

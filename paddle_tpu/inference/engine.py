@@ -741,16 +741,11 @@ class DecodeEngine:
         fam = self._fam = family_of(model)
         self._steps = fam.steps
         self._stateful = fam.state is not None
-        self._params = fam.params(model)
-        self._served_dtype = self._params[fam.table_key].dtype
         self._nh, self._dh = fam.kv_heads, fam.head_dim
         self._nl = fam.kv_layers
         self._refuse_stateful_config(ecfg)
-        if ecfg.weight_dtype not in ("native", None):
-            # matmul leaves -> int8 + per-channel scales, dequantized at
-            # use inside the same AOT programs (quantization/serving.py);
-            # the conversion wall lands in engine.quant_dequant_ms
-            self._params = self._quantized(self._params)
+        self._load_params(model)
+        self._served_dtype = self._params[fam.table_key].dtype
         ps = ecfg.page_size
         max_seq = ecfg.max_seq_len or fam.max_positions
         max_seq = min(max_seq, fam.max_positions)
@@ -981,12 +976,25 @@ class DecodeEngine:
 
     # ------------------------------------------------------- the model seam
 
-    def _quantized(self, params):
-        if self._fam.quantize is None:
-            raise ValueError(
-                f"weight_dtype={self.ecfg.weight_dtype!r}: the "
-                f"{self._fam.name} family supplies no weight quantizer")
-        return self._fam.quantize(params, self.ecfg.weight_dtype)
+    def _load_params(self, model):
+        """The engine's one copy of the served weights, as every launch
+        hands it to the runtime: the family's leaves (stacked by layer),
+        matmul leaves int8 + per-channel scales under ``weight_dtype``
+        (dequantized at use inside the same AOT programs; the conversion
+        wall lands in engine.quant_dequant_ms). A QuantizedLeaf is part of
+        the traced pytree STRUCTURE, so a refresh quantizes again or the
+        next warm call would be a structure mismatch, not a hot swap."""
+        params = self._fam.params(model)
+        if self.ecfg.weight_dtype not in ("native", None):
+            if self._fam.quantize is None:
+                raise ValueError(
+                    f"weight_dtype={self.ecfg.weight_dtype!r}: the "
+                    f"{self._fam.name} family supplies no weight quantizer")
+            params = self._fam.quantize(params, self.ecfg.weight_dtype)
+        self._params = params
+        # what a launch flattens, checks and holds: docs/OBSERVABILITY.md
+        metrics.gauge("engine.param_leaves").set(
+            len(jax.tree_util.tree_leaves(params)))
 
     def _refuse_stateful(self, what: str):
         """A model that keeps window or recurrent state beside the page
@@ -1122,12 +1130,7 @@ class DecodeEngine:
         spill tiers included: cached OR spilled pages hold KV computed
         under the old weights, and a hit (or tier re-upload) after the
         swap would silently condition new-weights decode on stale KV."""
-        self._params = self._fam.params(model)
-        if self.ecfg.weight_dtype not in ("native", None):
-            # re-quantize: a QuantizedLeaf is part of the traced pytree
-            # STRUCTURE, so the swapped-in params must keep it or the next
-            # warm call would be a structure mismatch, not a hot swap
-            self._params = self._quantized(self._params)
+        self._load_params(model)
         self._flush_prefix()
 
     # --------------------------------------------------------- prefix cache

@@ -68,7 +68,8 @@ __all__ = ["ModelFamily", "family_of"]
 class ModelFamily:
     name: str
     steps: Any                      # namespace of the step functions
-    params: Callable[[Any], dict]   # model -> {leaf name: device array}
+    params: Callable[[Any], dict]   # model -> {leaf name: device array, or
+    #                                 a tuple of them, one a layer}
     table_key: str                  # the leaf whose dtype is the served one
     kv_layers: int                  # layers that own rows of the page pool
     #                                 (0: no pool at all)

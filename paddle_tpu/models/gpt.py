@@ -69,7 +69,8 @@ INIT_CACHE = "init"
 
 
 # --------------------------------------------------------------------------
-# Pure decode math over the state_dict weight layout. `fast_generate`, the
+# Pure decode math over the served weight layout (`serving_params`: the
+# state_dict's block leaves stacked by layer). `fast_generate`, the
 # paged `decode_step`/`prefill_step` (inference/engine.py), and the sampled
 # `generate` path all run THESE functions, so their numerics agree by
 # construction — token-identical output across cache layouts is the
@@ -83,8 +84,15 @@ def _deq(v):
     return v.dequant() if hasattr(v, "dequant") else v
 
 
+_SERVED_BLOCK = "blocks."       # + a BLOCK_SUFFIXES entry: indexed by layer
+
+
 def _pget(p, layer, suffix):
-    return _deq(p[f"gpt.h.{layer}.{suffix}"])
+    """Layer ``layer``'s leaf of the served layout (`serving_params`): a
+    STATIC index, into a stack of vectors (read in place by the consuming
+    fusion) or into a tuple of matrices (int8: widened a layer at a
+    time)."""
+    return _deq(p[_SERVED_BLOCK + suffix][layer])
 
 
 def _ln_ref(x, w, b):
@@ -169,7 +177,7 @@ def decode_step(params, ids, cache, slot_mask, *, cfg):
     page and freezes their lengths — so slots can join/retire between steps
     with zero recompiles (continuous batching).
 
-    params    : state_dict arrays (the `fast_generate` weight layout)
+    params    : `serving_params` arrays (the `fast_generate` weight layout)
     ids       : [B] int32 — current token per slot
     cache     : dict with
                   k_pages/v_pages : [nl, num_pages, page_size, nh * dh]
@@ -613,6 +621,29 @@ def stack_gpt_params(params, mesh=None):
     return {"blocks": blocks, "top": top}
 
 
+def serving_params(params):
+    """state_dict layout -> what every decode path reads (`_pget`): ONE
+    flat dict, the top leaves under their own names and each block leaf
+    under ``blocks.<suffix>``, indexed by layer. The 8 vectors (norms and
+    biases) are stacked ``[nl, width]``; the 4 matrices stay a tuple of the
+    model's own per-layer arrays. A launch costs the host a microsecond a
+    leaf, so 12 leaves a layer become 4 (108 for 24 layers, not 292); a
+    matrix stays an operand of its own because the TPU compiler prefetches
+    whole operands into fast memory under the ops before, and a 24-layer
+    stack is too large an operand to prefetch (PERF.md, PR 37: with the
+    matrices stacked too the decode program ran 2.04 ms a step on the
+    chip, not 1.85)."""
+    arrs = {k: _leaf_array(v) for k, v in params.items()}
+    nl = stacked_num_layers(arrs)
+    out = {k: v for k, v in arrs.items() if not k.startswith(_BLOCK_PREFIX)}
+    for suffix in BLOCK_SUFFIXES:
+        leaves = tuple(arrs[f"{_BLOCK_PREFIX}{i}.{suffix}"]
+                       for i in range(nl))
+        out[_SERVED_BLOCK + suffix] = \
+            leaves if leaves[0].ndim == 2 else jnp.stack(leaves)
+    return out
+
+
 def unstack_gpt_params(stacked):
     """Inverse of :func:`stack_gpt_params`: back to the per-layer
     state_dict layout (checkpoints, decode paths, Layer parameters)."""
@@ -926,8 +957,10 @@ class GPTForCausalLM(nn.Layer):
 
     def engine_family(self):
         """What `DecodeEngine` takes from this model (inference/family.py):
-        this module's step functions over the state_dict's arrays, one
-        page-pool row per layer, and no state beside the pool."""
+        this module's step functions over the state_dict's arrays stacked
+        by layer (`serving_params`: the engine's own copy of the block
+        weights), one page-pool row per layer, and no state beside the
+        pool."""
         import sys
         from paddle_tpu.inference.family import ModelFamily
         cfg = self.cfg
@@ -937,8 +970,7 @@ class GPTForCausalLM(nn.Layer):
             return quantize_gpt_params(params, weight_dtype)
         return ModelFamily(
             name="gpt", steps=sys.modules[__name__],
-            params=lambda m: {k: t._data
-                              for k, t in m.state_dict().items()},
+            params=lambda m: serving_params(m.state_dict()),
             table_key="gpt.wte.weight", kv_layers=cfg.num_layers,
             kv_heads=cfg.num_heads,
             head_dim=cfg.hidden_size // cfg.num_heads,
@@ -1047,8 +1079,7 @@ class GPTForCausalLM(nn.Layer):
                 "positions past the table would silently clamp")
         nh, dh = cfg.num_heads, cfg.hidden_size // cfg.num_heads
         nl = cfg.num_layers
-        state = self.state_dict()
-        params = {k: t._data for k, t in state.items()}
+        params = serving_params(self.state_dict())
         cdtype = params["gpt.wte.weight"].dtype
 
         sig = (B, S0, N, float(temperature), int(top_k), str(cdtype))

@@ -49,8 +49,8 @@ def margin_gated_parity(lg_f, lg_q, bound=QUANT_LOGIT_BOUND):
     ok = diff <= bound and bool(jnp.all(jnp.where(gated, same, True)))
     return diff, ok
 
-# the state_dict matmul leaves that convert ([in, out] per layer, or
-# [nl, in, out] stacked) — everything else passes through untouched
+# the matmul leaves that convert ([in, out] per layer, or [nl, in, out]
+# stacked) — everything else passes through untouched
 GPT_MATMUL_SUFFIXES = (
     "attn.qkv_proj.weight", "attn.out_proj.weight",
     "mlp.fc_in.weight", "mlp.fc_out.weight",
@@ -128,6 +128,13 @@ def _quantize_leaf(arr) -> QuantizedLeaf:
     return QuantizedLeaf(q, s, str(a.dtype))
 
 
+def _quantize(v):
+    """One matmul leaf, or the served layout's tuple of them, a layer each
+    (`models/gpt.py::serving_params`)."""
+    return tuple(map(_quantize_leaf, v)) if isinstance(v, tuple) \
+        else _quantize_leaf(v)
+
+
 def _is_matmul_key(key: str) -> bool:
     return any(key.endswith(suf) for suf in GPT_MATMUL_SUFFIXES)
 
@@ -136,8 +143,11 @@ def quantize_gpt_params(params, dtype: str = "int8"):
     """Convert a GPT params pytree's matmul leaves to int8 + per-channel
     scales, in place of the float arrays. Accepts BOTH weight layouts:
 
-    - the per-layer state_dict dict (``gpt.h.<i>.attn.qkv_proj.weight``
-      ...) the decode engine and `fast_generate` consume, and
+    - a flat dict whose matmul leaves are found by the END of their names:
+      the served layout the decode engine and `fast_generate` consume
+      (`models/gpt.py::serving_params`: ``blocks.attn.qkv_proj.weight`` ...,
+      a tuple of ``[in, out]`` arrays, a layer each) or a per-layer
+      state_dict (``gpt.h.<i>.attn.qkv_proj.weight`` ...), and
     - the stacked ``{"blocks": {suffix: [nl, ...]}, "top": {...}}`` layout
       from `models/gpt.py::stack_gpt_params` — the per-leaf mp/sp shardings
       survive (int8 values keep the leaf's NamedSharding; the scale drops
@@ -157,7 +167,7 @@ def quantize_gpt_params(params, dtype: str = "int8"):
                           for suf, v in params["blocks"].items()},
                "top": dict(params["top"])}
     else:
-        out = {k: (_quantize_leaf(v) if _is_matmul_key(k) else v)
+        out = {k: (_quantize(v) if _is_matmul_key(k) else v)
                for k, v in params.items()}
     metrics.histogram("engine.quant_dequant_ms").observe(
         (time.perf_counter() - t0) * 1e3)

@@ -95,7 +95,7 @@ class TestInt8KV:
         documented bound with margin-gated top-1 agreement."""
         m = _tiny_model()
         cfg = m.cfg
-        params = {k: t._data for k, t in m.state_dict().items()}
+        params = gpt_mod.serving_params(m.state_dict())
         ps, s0 = 4, 10                      # prompt spans 2.5 pages
         npg = 8
         row = jnp.pad(jnp.arange(1, 5, dtype=jnp.int32), (0, 12))[:16]
@@ -365,12 +365,12 @@ class TestWeightInt8:
         base, _ = _run_engine(m, prompt, 4)
         out, eng = _run_engine(m, prompt, 4, weight_dtype="int8")
         assert out.shape == base.shape
-        assert isinstance(eng._params["gpt.h.0.mlp.fc_in.weight"],
+        assert isinstance(eng._params["blocks.mlp.fc_in.weight"][0],
                           QuantizedLeaf)
         # refresh keeps the quantized pytree STRUCTURE (hot swap, not a
         # structure mismatch at the next warm call)
         eng.refresh_params(m)
-        assert isinstance(eng._params["gpt.h.0.mlp.fc_in.weight"],
+        assert isinstance(eng._params["blocks.mlp.fc_in.weight"][0],
                           QuantizedLeaf)
         r = eng.submit(prompt, max_new_tokens=2)
         eng.run_until_idle(max_steps=60)
@@ -379,7 +379,7 @@ class TestWeightInt8:
     def test_weight_int8_logits_bound(self):
         m = _tiny_model()
         cfg = m.cfg
-        params = {k: t._data for k, t in m.state_dict().items()}
+        params = gpt_mod.serving_params(m.state_dict())
         qp = quantize_gpt_params(params)
         ids = jnp.asarray(np.random.RandomState(1)
                           .randint(0, 64, 6).astype(np.int32))

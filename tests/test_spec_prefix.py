@@ -472,10 +472,10 @@ class TestVerifyStepSampled:
         import jax.numpy as jnp
         from paddle_tpu.kernels.paged_attention import TRASH_PAGE
         from paddle_tpu.models.gpt import (_make_sampler, prefill_step,
-                                           verify_step)
+                                           serving_params, verify_step)
         m = _tiny_model()
         cfg = m.cfg
-        params = {k: t._data for k, t in m.state_dict().items()}
+        params = serving_params(m.state_dict())
         rng = np.random.RandomState(seed + 1)
         prompt = rng.randint(0, 97, 7).astype(np.int32)
         N, K, ps, maxp = 12, 3, 4, 8
@@ -527,10 +527,11 @@ class TestVerifyStepSampled:
         import jax
         import jax.numpy as jnp
         from paddle_tpu.kernels.paged_attention import TRASH_PAGE
-        from paddle_tpu.models.gpt import _make_sampler, verify_step
+        from paddle_tpu.models.gpt import (_make_sampler, serving_params,
+                                           verify_step)
         m = _tiny_model()
         cfg = m.cfg
-        params = {k: t._data for k, t in m.state_dict().items()}
+        params = serving_params(m.state_dict())
         ps, maxp, K = 4, 4, 2
         kc = jnp.zeros((cfg.num_layers, 1 + 2 * maxp, ps, 2 * 16),
                        jnp.float32)
@@ -565,7 +566,7 @@ def test_xla_arms_gather_from_the_stack_without_slicing_a_layer(program, kv):
     from paddle_tpu.models import gpt
     m = _tiny_model()
     cfg = m.cfg
-    params = {k: t._data for k, t in m.state_dict().items()}
+    params = gpt.serving_params(m.state_dict())
     nl, nh, hd, npages, ps, maxp, b = 2, 2, 32, 9, 4, 4, 2
     quant = kv == "int8"
     pool = jnp.zeros((nl, npages, ps, hd), jnp.int8 if quant

@@ -149,21 +149,24 @@ MEDIUM = dict(layers=24, heads=16, hidden=1024, vocab=50304, positions=1024,
 
 
 def _medium_params(chip):
-    """Shapes of gpt2-medium's state_dict in bf16 (no array is made)."""
-    h, v = MEDIUM["hidden"], MEDIUM["vocab"]
+    """Shapes of gpt2-medium's served parameters in bf16 (no array is
+    made): `models/gpt.py::serving_params`, a block's 8 vectors stacked by
+    layer, its 4 matrices a tuple of per-layer leaves, and the 4 top
+    leaves."""
+    h, v, nl = MEDIUM["hidden"], MEDIUM["vocab"], MEDIUM["layers"]
 
     def leaf(*shape):
         return jax.ShapeDtypeStruct(shape, BF16, sharding=chip)
     params = {"gpt.wte.weight": leaf(v, h),
               "gpt.wpe.weight": leaf(MEDIUM["positions"], h),
               "gpt.ln_f.weight": leaf(h), "gpt.ln_f.bias": leaf(h)}
-    for i in range(MEDIUM["layers"]):
-        for name, shape in [
-                ("ln_1", (h,)), ("ln_2", (h,)), ("attn.qkv_proj", (h, 3 * h)),
-                ("attn.out_proj", (h, h)), ("mlp.fc_in", (h, 4 * h)),
-                ("mlp.fc_out", (4 * h, h))]:
-            params[f"gpt.h.{i}.{name}.weight"] = leaf(*shape)
-            params[f"gpt.h.{i}.{name}.bias"] = leaf(shape[-1])
+    for name, shape in [
+            ("ln_1", (h,)), ("ln_2", (h,)), ("attn.qkv_proj", (h, 3 * h)),
+            ("attn.out_proj", (h, h)), ("mlp.fc_in", (h, 4 * h)),
+            ("mlp.fc_out", (4 * h, h))]:
+        params[f"blocks.{name}.weight"] = leaf(nl, *shape) \
+            if len(shape) == 1 else tuple(leaf(*shape) for _ in range(nl))
+        params[f"blocks.{name}.bias"] = leaf(nl, shape[-1])
     return params
 
 
@@ -233,16 +236,14 @@ ENTRY %main (a: bf16[2,8,4,16]) -> bf16[2,8,4,16] {
 PROGRAMS = ["decode_step", "prefill_chunk_step", "prefill_step"]
 
 
-@pytest.mark.parametrize("program", PROGRAMS)
-def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
-                                                  monkeypatch):
-    """The engine's decode step, one prefill chunk and a one-shot prefill
-    (each takes the token chain and returns the next one), whole, at
-    gpt2-medium's serving shapes with the Pallas arms pinned, compiled for
-    the described chip with the pools donated: the optimized HLO holds no
-    copy, slice, transpose or fusion of a layer pool's size (1537 x 16 x
-    1024 elements) but the in-place update, and the compiler's temporaries
-    stay under one layer's pool (they were 7.3 GB: PERF.md, PR 26)."""
+@pytest.fixture(scope="module")
+def medium_compiled(chip):
+    """``get(program)``: one of the engine's step programs (decode step, a
+    prefill chunk, a one-shot prefill: each takes the token chain and
+    returns the next one), whole, at gpt2-medium's serving shapes with the
+    Pallas arms pinned, compiled for the described chip as the engine
+    lowers it: ``(params, cache, *small)``, the cache donated whole.
+    Compiled once for the tests that read it."""
     from paddle_tpu.framework.flags import set_flags
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
@@ -250,34 +251,52 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
                                                prefill_upload, step_upload)
     from paddle_tpu.kernels.pallas import _compat
     from paddle_tpu.models import gpt
-    # the kernels ask the default backend, which is the CPU here
-    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
-    m = MEDIUM
+    m, done = MEDIUM, {}
     cfg = gpt.GPTConfig(vocab_size=m["vocab"], hidden_size=m["hidden"],
                         num_layers=m["layers"], num_heads=m["heads"],
                         max_position_embeddings=m["positions"])
     pool = jax.ShapeDtypeStruct(
         (m["layers"], m["pages"], m["page"], m["hidden"]), BF16,
         sharding=chip)
-    cache = DeviceCache(k=pool, v=pool, k_scale=None, v_scale=None,
-                        state=(), keys=None, heads=m["heads"])
-    # the engine's own program functions, lowered as the engine lowers
-    # them: (params, cache, *small), the cache donated whole
-    if program == "decode_step":
-        up = step_upload(m["slots"], m["per_slot"], sampling=False)
-        step = decode_program(gpt, cfg, up)
-    else:
-        up = prefill_upload(m["chunk"], m["per_slot"], sampling=False,
-                            chunk=program == "prefill_chunk_step")
-        step = prefill_program(gpt, cfg, up)
-    small = (jax.ShapeDtypeStruct((m["slots"],), jnp.int32, sharding=chip),
-             up.spec(sharding=chip))
-    set_flags({"tpu_paged_impl": "pallas", "tpu_prefill_impl": "pallas"})
-    try:
-        compiled = jax.jit(step, donate_argnums=(1,)).lower(
-            _medium_params(chip), cache, *small).compile()
-    finally:
-        set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
+
+    def get(program):
+        if program in done:
+            return done[program]
+        cache = DeviceCache(k=pool, v=pool, k_scale=None, v_scale=None,
+                            state=(), keys=None, heads=m["heads"])
+        if program == "decode_step":
+            up = step_upload(m["slots"], m["per_slot"], sampling=False)
+            step = decode_program(gpt, cfg, up)
+        else:
+            up = prefill_upload(m["chunk"], m["per_slot"], sampling=False,
+                                chunk=program == "prefill_chunk_step")
+            step = prefill_program(gpt, cfg, up)
+        small = (jax.ShapeDtypeStruct((m["slots"],), jnp.int32,
+                                      sharding=chip),
+                 up.spec(sharding=chip))
+        # the kernels ask the default backend, which is the CPU here
+        was = _compat.default_interpret
+        _compat.default_interpret = lambda: False
+        set_flags({"tpu_paged_impl": "pallas", "tpu_prefill_impl": "pallas"})
+        try:
+            done[program] = jax.jit(step, donate_argnums=(1,)).lower(
+                _medium_params(chip), cache, *small).compile()
+        finally:
+            set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
+            _compat.default_interpret = was
+        return done[program]
+    return get
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_step_program_copies_no_layer_pool_on_v5e(medium_compiled, program):
+    """The engine's decode step, one prefill chunk and a one-shot prefill
+    (`medium_compiled`): the optimized HLO holds no copy, slice,
+    transpose or fusion of a layer pool's size (1537 x 16 x 1024 elements)
+    but the in-place update, and the compiler's temporaries stay under one
+    layer's pool (they were 7.3 GB: PERF.md, PR 26)."""
+    m = MEDIUM
+    compiled = medium_compiled(program)
     text = compiled.as_text()
     chain, _ = compiled.out_info
     assert chain.shape == (m["slots"],) and chain.dtype == jnp.int32
@@ -287,6 +306,88 @@ def test_step_program_copies_no_layer_pool_on_v5e(chip, program,
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 2 * layer_pool          # bf16 bytes
     assert mem.alias_size_in_bytes >= 2 * 2 * m["layers"] * layer_pool
+
+
+def weight_shaped_ops(hlo_text, shapes):
+    """``[(opcode, name, shape)]`` of every ``copy``, ``slice``,
+    ``dynamic-slice``, ``transpose`` or ``fusion`` of the optimized HLO's
+    ENTRY computation with a result of one of ``shapes`` (dimensions of 1
+    dropped) that lives in HBM: a layer's weight copied out of its stack.
+    A result in the chip's fast memory (``S(1)`` in its layout: the
+    compiler's own prefetch of an operand) is no copy in HBM, and neither
+    is what a fusion's body holds."""
+    import re
+    shapes = {tuple(s) for s in shapes}
+    found = []
+    for ln in hlo_text[hlo_text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.+?) ([a-z][a-z\-]*)\(",
+                     ln)
+        if not m or m.group(3) not in ("copy", "slice", "dynamic-slice",
+                                       "transpose", "fusion"):
+            continue
+        for dims, layout in re.findall(r"\w+\[([\d,]*)\](\{[^}]*\})?",
+                                       m.group(2)):
+            shape = tuple(int(d) for d in dims.split(",") if d and d != "1")
+            if shape in shapes and "S(1)" not in layout:
+                found.append((m.group(3), m.group(1), m.group(2)))
+    return found
+
+
+def test_weight_shaped_ops_sees_a_layer_copied_out_of_its_stack():
+    """The reader the next test rests on, on a few lines of HLO: a slice, a
+    copy and a fusion result of a weight's shape in HBM count (a leading 1
+    or not); a prefetch into fast memory, a smaller result, a parameter and
+    a fusion's inside do not."""
+    text = """\
+%fused_dot (p: bf16[3,8,16]) -> bf16[4,16] {
+  %s = bf16[1,8,16]{2,1,0} slice(%p), slice={[1:2], [0:8], [0:16]}
+  ROOT %d = bf16[4,16]{1,0} convolution(%x, %s)
+}
+ENTRY %main (a: bf16[3,8,16]) -> bf16[4,16] {
+  %a = bf16[3,8,16]{2,1,0:T(8,128)(2,1)} parameter(0)
+  %slice.1 = bf16[1,8,16]{2,1,0:T(8,128)(2,1)} slice(%a), slice={[0:1], [0:8], [0:16]}
+  %copy.2 = bf16[8,16]{1,0:T(8,128)(2,1)} copy(%b)
+  %copy.3 = bf16[8,16]{1,0:T(8,128)(2,1)S(1)} copy(%b)
+  %fusion.4 = bf16[8,16]{1,0:T(8,128)(2,1)} fusion(%a), kind=kLoop, calls=%widen
+  %copy.5 = bf16[16]{0} copy(%c)
+  ROOT %fusion.6 = bf16[4,16]{1,0} fusion(%a), kind=kOutput, calls=%fused_dot
+}
+"""
+    assert [(op, name) for op, name, _ in
+            weight_shaped_ops(text, [(8, 16)])] == \
+        [("slice", "slice.1"), ("copy", "copy.2"), ("fusion", "fusion.4")]
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_step_program_prefetches_its_matrices_and_copies_none_on_v5e(
+        medium_compiled, program):
+    """The same three programs (`medium_compiled`) take 108 parameters (4
+    matrices a layer, 8 stacks of vectors, 4 top leaves; the state_dict's
+    292 cost a launch 0.25 ms more of the host's time) and read a layer's
+    vectors out of their stacks in place. The optimized HLO holds no copy,
+    slice, transpose or fusion result IN HBM of a matrix's shape, and the
+    compiler still prefetches matrices into fast memory under the ops
+    before their products, which it can do for an operand of a layer's
+    size and not for a 24-layer stack (PERF.md, PR 37: with the matrices
+    stacked too the programs held 0 such prefetches and the decode program
+    ran 2.04 ms a step on the chip, not 1.85)."""
+    import re
+    m = MEDIUM
+    compiled = medium_compiled(program)
+    h, nl = m["hidden"], m["layers"]
+    assert len(jax.tree_util.tree_leaves(compiled.args_info[0][0])) \
+        == 4 * nl + 8 + 4
+    mats = [(h, 3 * h), (h, 4 * h), (4 * h, h), (h, h)]
+    text = compiled.as_text()
+    assert weight_shaped_ops(text, mats) == []
+    # a prefetched matrix: the result, in fast memory (S(1)), of an async
+    # copy, or of the pieces of a sliced one put together (ConcatBitcast)
+    fast = re.findall(r"= bf16\[(\d+),(\d+)\]\{[^}]*S\(1\)\} (?:copy-done\(|"
+                      r"custom-call\(.*custom_call_target=\"ConcatBitcast\")",
+                      text)
+    assert sum((int(a), int(b)) in mats for a, b in fast) >= nl
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes > 2 * 12 * nl * h * h  # all weights
 
 
 # Phi-4-mini-flash as benchmarks/configs/phi-4-mini-flash.json serves it
