@@ -7,7 +7,16 @@ collectives instead of NCCL process groups. Blueprint: SURVEY.md at the repo roo
 """
 from __future__ import annotations
 
-import jax as _jax
+import sys as _sys
+import time as _time
+
+# the registry first (stdlib only): its epoch is the stamp `package.import`
+# starts from, and whether jax was loaded before us says which import this
+# was (well under a second after jax, several seconds with it)
+_JAX_PRELOADED = "jax" in _sys.modules
+from paddle_tpu.observability import _EPOCH as _T_IMPORT  # noqa: E402
+
+import jax as _jax  # noqa: E402
 
 # float64/int64 must exist as real dtypes (the reference supports them; grad checks
 # need f64 on CPU). Defaults remain float32 — see core/dtype.py.
@@ -74,6 +83,10 @@ from paddle_tpu import sysconfig  # noqa: F401
 from paddle_tpu import tensor  # noqa: F401
 
 from paddle_tpu.nn.functional.common import linear  # noqa: F401  (paddle exposes it)
+
+observability.metrics.add_span(
+    "package.import", _T_IMPORT, _time.perf_counter() - _T_IMPORT,
+    cat="startup", args={"jax_preloaded": _JAX_PRELOADED})
 
 
 def disable_static(place=None):

@@ -126,6 +126,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.framework import compile_cache
 from paddle_tpu.inference.cache import DeviceCache, PageAllocator
 from paddle_tpu.inference.errors import (Cancelled, DeadlineExceeded,
                                          HandoffCorrupt, Overloaded,
@@ -144,6 +145,10 @@ from paddle_tpu.observability.flight_recorder import (Watchdog,
 from paddle_tpu.observability.tracing import RequestTrace, new_span_id
 from paddle_tpu.observability.usage import emit_request as _emit_usage
 from paddle_tpu.testing import faults
+
+# JAX's trace, lower and compile events land on the span ring from here on,
+# under whatever span is open (`engine.compile:<program>`)
+compile_cache.listen()
 
 __all__ = ["EngineConfig", "PageAllocator", "GenerateRequest", "DecodeEngine",
            "KVHandoff", "MigrationItem", "DeadlineExceeded", "Cancelled",
@@ -733,7 +738,10 @@ class DecodeEngine:
     """
 
     def __init__(self, model, engine_config: EngineConfig | None = None):
-        ecfg = engine_config or EngineConfig()
+        with metrics.span("engine.init", cat="startup"):
+            self._init(model, engine_config or EngineConfig())
+
+    def _init(self, model, ecfg: EngineConfig):
         self.cfg = model.cfg
         self.ecfg = ecfg
         # the model seam (inference/family.py): the model object says which
@@ -775,8 +783,11 @@ class DecodeEngine:
         # pool's scales, the family's state beside the pool, the sampler's
         # key chains. Every program takes it whole, donated, and returns it
         # (inference/cache.py); `_kc` ... `_state` below are views of it
-        self._cache = DeviceCache.allocate(fam, ecfg, num_pages,
-                                           self._served_dtype)
+        with metrics.span("engine.cache_alloc", cat="startup") as sp:
+            self._cache = DeviceCache.allocate(fam, ecfg, num_pages,
+                                               self._served_dtype)
+            sp.args["bytes"] = sum(
+                int(a.nbytes) for a in jax.tree_util.tree_leaves(self._cache))
         self._cdtype = self._cache.k.dtype
         self._quant_kv = self._cache.k_scale is not None
         self.kv_bytes_per_token = self._cache.bytes_per_token
@@ -821,6 +832,9 @@ class DecodeEngine:
         self._qlock = threading.Lock()
         self._work = threading.Condition(self._qlock)
         self._programs: dict = {}     # the engine's ProgramCache analog
+        # ids of the programs compiled and not launched yet: a program's
+        # first launch says `first=true` on its span (`_note_first`)
+        self._unlaunched: set[int] = set()
         self._dead: str | None = None  # set by abort(); submits then fail fast
         self._draining = False        # drain(): refuse NEW submits only
         self._queue_tokens = 0        # sum of queued prompt tokens (_qlock)
@@ -984,17 +998,20 @@ class DecodeEngine:
         wall lands in engine.quant_dequant_ms). A QuantizedLeaf is part of
         the traced pytree STRUCTURE, so a refresh quantizes again or the
         next warm call would be a structure mismatch, not a hot swap."""
-        params = self._fam.params(model)
-        if self.ecfg.weight_dtype not in ("native", None):
-            if self._fam.quantize is None:
-                raise ValueError(
-                    f"weight_dtype={self.ecfg.weight_dtype!r}: the "
-                    f"{self._fam.name} family supplies no weight quantizer")
-            params = self._fam.quantize(params, self.ecfg.weight_dtype)
-        self._params = params
-        # what a launch flattens, checks and holds: docs/OBSERVABILITY.md
-        metrics.gauge("engine.param_leaves").set(
-            len(jax.tree_util.tree_leaves(params)))
+        with metrics.span("engine.load_params", cat="startup") as sp:
+            params = self._fam.params(model)
+            if self.ecfg.weight_dtype not in ("native", None):
+                if self._fam.quantize is None:
+                    raise ValueError(
+                        f"weight_dtype={self.ecfg.weight_dtype!r}: the "
+                        f"{self._fam.name} family supplies no weight "
+                        "quantizer")
+                params = self._fam.quantize(params, self.ecfg.weight_dtype)
+            self._params = params
+            # what a launch flattens, checks and holds:
+            # docs/OBSERVABILITY.md
+            sp.args["leaves"] = len(jax.tree_util.tree_leaves(params))
+        metrics.gauge("engine.param_leaves").set(sp.args["leaves"])
 
     def _refuse_stateful(self, what: str):
         """A model that keeps window or recurrent state beside the page
@@ -1037,11 +1054,20 @@ class DecodeEngine:
             with metrics.span(f"engine.compile:{key[0]}",
                               cat="compile") as sp:
                 exe = self._programs[key] = build()
+            self._unlaunched.add(id(exe))
             self._m_compiles.inc()
             metrics.histogram("engine.compile_seconds").observe(sp.dur)
         else:
             self._m_hit.inc()
         return exe
+
+    def _note_first(self, exe, sp):
+        """``first=true`` on the span of a compiled program's FIRST launch
+        (`engine.dispatch`, `engine.prefill_launch`): the runtime loads the
+        executable onto the chip inside that call, so it is the long one."""
+        if id(exe) in self._unlaunched:
+            self._unlaunched.discard(id(exe))
+            sp.args["first"] = True
 
     def _build(self, program, *small):
         """Compile one step program ahead of time against this engine's
@@ -1107,22 +1133,26 @@ class DecodeEngine:
         front-loads the prefix-cache TAIL chunk programs (one per pow-2
         tail bucket) so a server's first cache hit doesn't pay a compile
         inside a request's TTFT. Optional — programs also compile lazily on
-        first use — but lets servers front-load compiles before traffic."""
-        self._step_exe()
-        need_chunk = False
-        for s in prompt_lens:
-            if self._use_chunked(int(s)):
-                need_chunk = True
-            else:
-                self._prefill_exe(self.bucket_for(int(s)))
-        for t in tail_lens:
-            if self.ecfg.prefill_chunk_tokens is not None:
-                need_chunk = True
-            else:
-                self._prefill_exe(self.bucket_for(int(t)), chunk=True)
-        if need_chunk:
-            self._prefill_exe(int(self.ecfg.prefill_chunk_tokens),
-                              chunk=True)
+        first use — but lets servers front-load compiles before traffic.
+        One `engine.warmup` span, the parent of its `engine.compile:*`."""
+        with metrics.span("engine.warmup", cat="startup") as sp:
+            before = len(self._programs)
+            self._step_exe()
+            need_chunk = False
+            for s in prompt_lens:
+                if self._use_chunked(int(s)):
+                    need_chunk = True
+                else:
+                    self._prefill_exe(self.bucket_for(int(s)))
+            for t in tail_lens:
+                if self.ecfg.prefill_chunk_tokens is not None:
+                    need_chunk = True
+                else:
+                    self._prefill_exe(self.bucket_for(int(t)), chunk=True)
+            if need_chunk:
+                self._prefill_exe(int(self.ecfg.prefill_chunk_tokens),
+                                  chunk=True)
+            sp.args["compiled"] = len(self._programs) - before
 
     def refresh_params(self, model):
         """Swap in current weights; programs take params as inputs, so this
@@ -1878,7 +1908,7 @@ class DecodeEngine:
                 packed[up["seed"]] = req._seed_key.view(np.int32)
             packed[up["top_k"]] = int(req.top_k)
 
-    def _launch_prefill(self, tokens: int, chunk: bool, ids: np.ndarray,
+    def _launch_prefill(self, sp, tokens: int, chunk: bool, ids: np.ndarray,
                         where: dict, row: np.ndarray, slot, req,
                         final=None):
         """Pack ONE prefill upload (``ids`` into a ``tokens``-wide program,
@@ -1890,7 +1920,8 @@ class DecodeEngine:
         gets its token in entry 0 of a chain that only its caller reads;
         the engine's chain is not donated and stays as it was. The single
         owner of the host side of `prefill_upload` for the one-shot,
-        interleaved, back-to-back and prefix-tail paths."""
+        interleaved, back-to-back and prefix-tail paths. ``sp`` is the
+        caller's open `engine.prefill_launch` span (`_note_first`)."""
         up = self._prefill_upload(tokens, chunk)
         packed = np.zeros(up.shape, np.int32)
         packed[up["ids"]][:ids.size] = ids
@@ -1903,6 +1934,7 @@ class DecodeEngine:
         if self._sampling:
             self._put_sampler(up, packed, slot, req, final)
         exe = self._prefill_exe(tokens, chunk)
+        self._note_first(exe, sp)
         self._m_h2d.inc()
         self._m_prefill_launches.inc()
         self._m_prefill_tokens.inc(int(ids.size))
@@ -1943,11 +1975,12 @@ class DecodeEngine:
         else:
             with metrics.span("engine.prefill_launch", cat="engine",
                               kind="oneshot", tokens=int(s0),
-                              request_id=req and req.request_id):
+                              request_id=req and req.request_id) as sp:
                 if self._stateful:
                     self._count_window_pages(0, s0)
-                toks = self._launch_prefill(self.bucket_for(s0), False, ids,
-                                            dict(length=s0), row, slot, req)
+                toks = self._launch_prefill(sp, self.bucket_for(s0), False,
+                                            ids, dict(length=s0), row, slot,
+                                            req)
         return toks
 
     def _count_window_pages(self, lo: int, hi: int):
@@ -1989,13 +2022,14 @@ class DecodeEngine:
         carried = {"state_carried": done > 0} if self._stateful else {}
         with metrics.span("engine.prefill_launch", cat="engine", kind=kind,
                           tokens=int(chunk.size),
-                          request_id=req and req.request_id, **carried):
+                          request_id=req and req.request_id,
+                          **carried) as sp:
             if self._stateful:
                 if done > 0:
                     self._m_state_carries.inc()
                 self._count_window_pages(done, done + int(chunk.size))
             toks = self._launch_prefill(
-                c, True, chunk, dict(start=done, valid=chunk.size), row,
+                sp, c, True, chunk, dict(start=done, valid=chunk.size), row,
                 slot, req, final)
         self._m_chunks.inc()
         return toks
@@ -2144,8 +2178,9 @@ class DecodeEngine:
         upload, no readback — tokens (and, on a sampling engine, the
         per-slot PRNG key chains) stay on device for the next step."""
         with metrics.span("engine.dispatch", cat="engine",
-                          active=int(np.count_nonzero(self._active))):
+                          active=int(np.count_nonzero(self._active))) as sp:
             exe = self._step_exe()
+            self._note_first(exe, sp)
             self._m_h2d.inc()
             state = jax.device_put(self._packed_state())
             t0 = time.perf_counter()
@@ -2200,8 +2235,9 @@ class DecodeEngine:
                 drafts[slot, :n] = d[:n]
                 draft_lens[slot] = n
         with metrics.span("engine.dispatch", cat="engine",
-                          active=int(np.count_nonzero(self._active))):
+                          active=int(np.count_nonzero(self._active))) as sp:
             exe = self._step_exe()
+            self._note_first(exe, sp)
             self._m_h2d.inc()
             state = jax.device_put(self._packed_state(drafts, draft_lens))
             emitted_dev, n_emit_dev, self._tok_dev, self._cache = exe(

@@ -14,6 +14,7 @@ Capture protocol:
 """
 from __future__ import annotations
 
+import contextlib
 import functools
 import time
 import weakref
@@ -25,8 +26,13 @@ import jax.numpy as jnp
 
 from paddle_tpu.core import tensor as tensor_mod
 from paddle_tpu.core.tensor import Tensor
+from paddle_tpu.framework import compile_cache
 from paddle_tpu.framework.flags import flag_value
 from paddle_tpu.observability import metrics
+
+# JAX's trace, lower and compile events land on the span ring from here
+# on, under whatever span is open (`jit.capture`, `jit.first_dispatch`)
+compile_cache.listen()
 
 # ProgramCache telemetry (docs/OBSERVABILITY.md): a hit is a signature that
 # resolved to an existing compiled variant; a miss triggers _capture
@@ -36,6 +42,7 @@ _M_COMPILES = metrics.counter("jit.compile_count")
 _M_COMPILE_S = metrics.histogram("jit.compile_seconds")
 _M_DONATED = metrics.counter("jit.donated_bytes")
 _M_DISPATCH_S = metrics.histogram("jit.dispatch_seconds")
+_NO_SPAN = contextlib.nullcontext()   # every call of a signature but its first
 
 
 def _array_nbytes(arrays) -> int:
@@ -233,7 +240,8 @@ class StaticFunction:
             if cand.mask_matches():
                 compiled = cand
                 break
-        if compiled is None:
+        first = compiled is None
+        if first:
             _M_CACHE_MISS.inc()
             compiled = self._capture(key, args, kwargs)
         else:
@@ -255,7 +263,8 @@ class StaticFunction:
         if self._donate:
             _M_DONATED.inc(_array_nbytes(state_in) + _array_nbytes(grad_in))
         _t0 = time.perf_counter()
-        outs = compiled.jitted(state_in, grad_in, arg_in)
+        with self._first_dispatch_span() if first else _NO_SPAN:
+            outs = compiled.jitted(state_in, grad_in, arg_in)
         _M_DISPATCH_S.observe(time.perf_counter() - _t0)
         out_arrays, new_state, new_grads = outs
         for t, arr in zip(compiled.state_tensors, new_state):
@@ -278,14 +287,26 @@ class StaticFunction:
 
     # ------------------------------------------------------------------ capture
 
+    def _span_name(self, phase: str) -> str:
+        return f"jit.{phase}:{getattr(self._fn, '__name__', '?')}"
+
+    def _first_dispatch_span(self):
+        """The span around the FIRST call of a captured signature's
+        program, `jit.capture:<fn>`'s sibling: jax traces the pure
+        function, lowers it and compiles it (or loads it from the
+        persistent cache) inside that call, and JAX's own `xla.trace`,
+        `xla.lower` and `xla.compile` spans land under it
+        (framework/compile_cache.py). No later call has a span."""
+        return metrics.span(self._span_name("first_dispatch"), cat="compile")
+
     def _capture(self, key, args, kwargs):
         """Probe, trace and register one signature's program, as one
         `jit.capture:<fn>` span. Its wall time covers the abstract probe +
         pure-fn construction; XLA's own compile lands inside the first
-        dispatch (jit.dispatch_seconds max vs p50 separates compile from
-        steady-state)."""
-        with metrics.span(f"jit.capture:{getattr(self._fn, '__name__', '?')}",
-                          cat="compile") as sp:
+        dispatch, under `jit.first_dispatch:<fn>`, whose `xla.compile`
+        child says how long it took and whether the persistent cache had
+        it."""
+        with metrics.span(self._span_name("capture"), cat="compile") as sp:
             compiled = self._probe_and_trace(key, args, kwargs)
         _M_COMPILES.inc()
         _M_COMPILE_S.observe(sp.dur)
@@ -485,7 +506,8 @@ class MultiStepFunction:
             if cand.mask_matches():
                 compiled, jitted_k = cand, jk
                 break
-        if compiled is None:
+        first = compiled is None
+        if first:
             _M_CACHE_MISS.inc()
             compiled, jitted_k = self._build(sig, step_args, step_kwargs)
         else:
@@ -507,8 +529,9 @@ class MultiStepFunction:
                            _array_nbytes(g for g in grads_full
                                          if g is not None))
         _t0 = time.perf_counter()
-        outs_stacked, new_state, new_grads = jitted_k(state_in, grads_full,
-                                                      stacked)
+        with self._sf._first_dispatch_span() if first else _NO_SPAN:
+            outs_stacked, new_state, new_grads = jitted_k(
+                state_in, grads_full, stacked)
         _M_DISPATCH_S.observe(time.perf_counter() - _t0)
         for t, arr in zip(compiled.state_tensors, new_state):
             if hasattr(t, "_offload_host"):

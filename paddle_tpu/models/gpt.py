@@ -953,7 +953,12 @@ class GPTForCausalLM(nn.Layer):
     def __init__(self, cfg: GPTConfig):
         super().__init__()
         self.cfg = cfg
-        self.gpt = GPTModel(cfg)
+        # every parameter is drawn by an eager op of its own: 0.8 s of a
+        # warm start at gpt2-medium's 292, 11 s of a cold one (PERF.md,
+        # PR 38), also where the caller then writes its own weights over them
+        with metrics.span("model.init:GPTForCausalLM", cat="startup",
+                          layers=cfg.num_layers):
+            self.gpt = GPTModel(cfg)
 
     def engine_family(self):
         """What `DecodeEngine` takes from this model (inference/family.py):
@@ -1179,7 +1184,6 @@ class GPTForCausalLM(nn.Layer):
         if compiled_now:
             # first execution of this signature: XLA compile dominates
             metrics.histogram("generate.compile_seconds").observe(dt)
-            metrics.add_span("generate.compile", t0, dt, cat="compile")
         else:
             metrics.histogram("generate.decode_seconds").observe(dt)
             metrics.gauge("generate.tokens_per_s").set(B * N / dt if dt > 0

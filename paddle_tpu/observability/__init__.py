@@ -55,7 +55,7 @@ __all__ = [
     "counter", "gauge", "histogram", "timer", "snapshot", "reset",
     "chrome_trace", "export_chrome_trace", "to_prometheus",
     "set_node_identity", "node_identity", "spans_for_trace",
-    "Span", "SpanRecord", "span", "spans", "add_span",
+    "Span", "SpanRecord", "span", "spans", "add_span", "open_span",
 ]
 
 # perf_counter origin for span timestamps — one epoch per process so spans
@@ -211,7 +211,7 @@ class SpanRecord(NamedTuple):
 
 
 _span_ids = itertools.count(1)    # next() is atomic under the GIL
-_open = threading.local()         # .stack: ids of this thread's open spans
+_open = threading.local()         # .stack: this thread's open spans
 _TRACE_ANNOTATION = None          # jax.profiler.TraceAnnotation, once found
 
 
@@ -261,9 +261,9 @@ class Span:
             stack = _open.stack
         except AttributeError:
             stack = _open.stack = []
-        self.parent = stack[-1] if stack else None
+        self.parent = stack[-1].id if stack else None
         self.id = next(_span_ids)
-        stack.append(self.id)
+        stack.append(self)
         ann = _TRACE_ANNOTATION or _find_annotation()
         if ann is not None and ann.is_enabled():
             self._ann = ann(self.name)
@@ -277,10 +277,10 @@ class Span:
             self._ann.__exit__(*exc)
             self._ann = None
         stack = getattr(_open, "stack", ())
-        if stack and stack[-1] == self.id:
+        if stack and stack[-1] is self:
             stack.pop()
-        elif self.id in stack:      # ended out of order (RecordEvent.end)
-            stack.remove(self.id)
+        elif self in stack:         # ended out of order (RecordEvent.end)
+            stack.remove(self)
         if self._hist is not None:
             self._hist.observe(self.dur)
         if self._keep:
@@ -386,6 +386,14 @@ class MetricsRegistry:
         profiler session running (PERF.md, PR 24): once an engine step or
         once a request, never once a token."""
         return Span(self, name, cat, args, fleet=fleet)
+
+    def open_span(self) -> Span | None:
+        """The innermost span open on the calling thread, or None: what a
+        range reported from a callback passes to :meth:`add_span` as
+        ``under`` (framework/compile_cache.py files JAX's compile events
+        under the span that caused them)."""
+        stack = getattr(_open, "stack", None)
+        return stack[-1] if stack else None
 
     def _record(self, name, cat, t0_perf, dur_s, args, span_id, parent_id,
                 fleet=None):
@@ -570,3 +578,4 @@ spans_for_trace = metrics.spans_for_trace
 span = metrics.span
 spans = metrics.spans
 add_span = metrics.add_span
+open_span = metrics.open_span

@@ -540,8 +540,6 @@ class ScanTrainStep:
         if compiled:
             self._compiles += 1
             metrics.counter("train.compile_count").inc()
-            metrics.gauge("train.compile_ms").set(dt * 1e3)
-            metrics.add_span("train.compile", t0, dt, cat="compile")
         elif okb:
             metrics.gauge("train.step_ms").set(dt * 1e3)
             metrics.histogram("train.step_seconds").observe(dt)
