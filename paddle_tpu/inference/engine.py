@@ -1051,9 +1051,18 @@ class DecodeEngine:
         if exe is None:
             self._m_miss.inc()
             flight.record("engine.compile_start", program=str(key))
+            blocks = [metrics.counter("model.block_traces"),
+                      metrics.counter("model.block_calls")]
+            was = [c.value for c in blocks]
             with metrics.span(f"engine.compile:{key[0]}",
                               cat="compile") as sp:
                 exe = self._programs[key] = build()
+                # a family that runs its block as one traced function
+                # (models/gpt.py::_block_stack) says how often the block's
+                # code was traced and how often it was applied: 1 and nl
+                traces, calls = (c.value - w for c, w in zip(blocks, was))
+                if calls:
+                    sp.args.update(block_traces=traces, block_calls=calls)
             self._unlaunched.add(id(exe))
             self._m_compiles.inc()
             metrics.histogram("engine.compile_seconds").observe(sp.dur)

@@ -254,7 +254,8 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
     on ``FLAGS_tpu_paged_impl`` (module docstring); the selection runs at
     trace time, so the winner string is baked into each compiled program and
     the ``paged_attention.impl.*`` counters count program builds (once per
-    layer per trace), not steps.
+    trace of the calling code: once a GPT step program, whose block is one
+    traced function), not steps.
     """
     from paddle_tpu.kernels import registry
     k_pages, v_pages, k_scale, v_scale, layer = stored_pools(

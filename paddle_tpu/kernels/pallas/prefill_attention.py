@@ -206,6 +206,27 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
     from paddle_tpu.kernels.paged_attention import stored_pools
     k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
         "prefill_attention", k_pages, v_pages, k_scale, v_scale, layer)
+    return _stored_call(q, k_pages, v_pages, page_table,
+                        jnp.asarray(start, jnp.int32),
+                        jnp.asarray(valid, jnp.int32),
+                        jnp.asarray(layer, jnp.int32), k_scale, v_scale,
+                        interpret=bool(interpret),
+                        return_visits=bool(return_visits),
+                        block_q=None if block_q is None else int(block_q),
+                        scale=None if scale is None else float(scale))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret", "return_visits",
+                                             "block_q", "scale"))
+def _stored_call(q, k_pages, v_pages, page_table, start, valid, layer,
+                 k_scale, v_scale, *, interpret, return_visits, block_q=None,
+                 scale=None):
+    # the kernel over the stored pools at a TRACED layer, as a function of
+    # its own (the decode kernel's `_stored_call` is its twin): every layer
+    # of a prefill program is the same call of it, so a program traces the
+    # kernel's body and lowers it through Mosaic once, not once a layer
+    # (which was 4.8-5.2 s of each prefill program's 5.8-6.9 at GPT-2
+    # medium's 24: PERF.md, PR 39)
     quant = k_scale is not None
     c, nh, dh = q.shape
     ps = k_pages.shape[2]
@@ -219,7 +240,7 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
     scale = 1.0 / (dh ** 0.5) if scale is None else scale
     kern = functools.partial(_prefill_kernel, page_size=ps, block_q=bq,
                              nh=nh, scale=float(scale), quant=quant,
-                             has_visits=bool(return_visits))
+                             has_visits=return_visits)
     rows = pl.BlockSpec((bq, hd), lambda i, *_: (i, 0))
     out_specs = [rows]
     out_shape = [jax.ShapeDtypeStruct((c, hd), q.dtype)]
@@ -238,9 +259,7 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
         in_specs += [win, win]
         operands += [scale_window(k_scale, page_table, layer),
                      scale_window(v_scale, page_table, layer)]
-    meta = jnp.stack([jnp.asarray(start, jnp.int32),
-                      jnp.asarray(valid, jnp.int32),
-                      jnp.asarray(layer, jnp.int32)])
+    meta = jnp.stack([start, valid, layer])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nq,),
@@ -260,7 +279,7 @@ def prefill_attention(q, k_pages, v_pages, page_table, start, valid, *,
             kern,
             grid_spec=grid_spec,
             out_shape=out_shape,
-            interpret=bool(interpret),
+            interpret=interpret,
         )(meta, page_table.astype(jnp.int32), *operands)
     out = outs[0].reshape(c, nh, dh)
     if return_visits:

@@ -86,13 +86,10 @@ def _deq(v):
 
 _SERVED_BLOCK = "blocks."       # + a BLOCK_SUFFIXES entry: indexed by layer
 
-
-def _pget(p, layer, suffix):
-    """Layer ``layer``'s leaf of the served layout (`serving_params`): a
-    STATIC index, into a stack of vectors (read in place by the consuming
-    fusion) or into a tuple of matrices (int8: widened a layer at a
-    time)."""
-    return _deq(p[_SERVED_BLOCK + suffix][layer])
+# the block's Python body ran under a trace / a layer's application of it
+# was bound (docs/OBSERVABILITY.md): 1 and nl for every step program
+_BLOCK_TRACES = metrics.counter("model.block_traces")
+_BLOCK_CALLS = metrics.counter("model.block_calls")
 
 
 def _ln_ref(x, w, b):
@@ -103,31 +100,56 @@ def _ln_ref(x, w, b):
     return (y * w + b).astype(x.dtype)
 
 
-def _block_stack(p, x, nl, nh, dh, attend):
-    """All nl transformer blocks over x ([..., H], H = nh*dh). ``attend(i, q,
-    k, v)`` gets [..., nh, dh] q/k/v for layer i and returns the attention
-    context in x.dtype with q's shape — the ONLY thing that differs between
-    the dense-cache and paged-cache decode paths."""
+def _block_stack(p, x, nl, nh, dh, attend, carry):
+    """All nl transformer blocks over x ([..., H], H = nh*dh): ``(x,
+    carry)``. ``attend(layer, q, k, v, carry) -> (att, carry)`` gets [...,
+    nh, dh] q/k/v and returns the attention context in x.dtype with q's
+    shape — the ONLY thing that differs between the dense-cache and
+    paged-cache decode paths — and ``carry`` is whatever it rewrites (the
+    K and V pools, an int8 pool's scales).
+
+    The layers differ only by their weights, so the block is ONE traced
+    function called nl times: ``layer`` is a traced int32 that indexes the
+    vector stacks and the pools, and a layer's four matrices (the model's
+    own arrays, or `QuantizedLeaf`s widened here, a layer at a time) are
+    its arguments. A program traces and lowers a layer's code once, not
+    once a layer (24 times for GPT-2 medium: 32 s of a 57 s warm start;
+    PERF.md, PR 39). The compiler inlines the calls before any other pass,
+    so each layer's matrices stay operands of their own (`serving_params`
+    says why that matters) and the compiled program is the unrolled one."""
     lead = x.shape[:-1]
-    for i in range(nl):
-        hpre = _ln_ref(x, _pget(p, i, "ln_1.weight"), _pget(p, i, "ln_1.bias"))
-        qkv = hpre @ _pget(p, i, "attn.qkv_proj.weight") + \
-            _pget(p, i, "attn.qkv_proj.bias")
+    served = {s: p[_SERVED_BLOCK + s] for s in BLOCK_SUFFIXES}
+    # a tuple holds a leaf a layer (`serving_params`: the matrices)
+    per_layer = [s for s, leaf in served.items() if isinstance(leaf, tuple)]
+
+    @jax.jit
+    def block(x, layer, mats, carry):
+        _BLOCK_TRACES.inc()
+
+        def get(suffix):
+            if suffix in mats:
+                return _deq(mats[suffix])
+            return served[suffix][layer]
+        hpre = _ln_ref(x, get("ln_1.weight"), get("ln_1.bias"))
+        qkv = hpre @ get("attn.qkv_proj.weight") + get("attn.qkv_proj.bias")
         q, k, v = jnp.split(qkv, 3, axis=-1)
-        att = attend(i, q.reshape(*lead, nh, dh), k.reshape(*lead, nh, dh),
-                     v.reshape(*lead, nh, dh))
+        att, carry = attend(layer, q.reshape(*lead, nh, dh),
+                            k.reshape(*lead, nh, dh),
+                            v.reshape(*lead, nh, dh), carry)
         att = att.reshape(*lead, nh * dh)
-        att = att @ _pget(p, i, "attn.out_proj.weight") + \
-            _pget(p, i, "attn.out_proj.bias")
+        att = att @ get("attn.out_proj.weight") + get("attn.out_proj.bias")
         x = x + att
-        hpre = _ln_ref(x, _pget(p, i, "ln_2.weight"), _pget(p, i, "ln_2.bias"))
-        m = hpre @ _pget(p, i, "mlp.fc_in.weight") + \
-            _pget(p, i, "mlp.fc_in.bias")
+        hpre = _ln_ref(x, get("ln_2.weight"), get("ln_2.bias"))
+        m = hpre @ get("mlp.fc_in.weight") + get("mlp.fc_in.bias")
         m = jax.nn.gelu(m, approximate=True)
-        m = m @ _pget(p, i, "mlp.fc_out.weight") + \
-            _pget(p, i, "mlp.fc_out.bias")
-        x = x + m
-    return x
+        m = m @ get("mlp.fc_out.weight") + get("mlp.fc_out.bias")
+        return x + m, carry
+
+    _BLOCK_CALLS.inc(nl)
+    for i in range(nl):
+        x, carry = block(x, jnp.int32(i),
+                         {s: served[s][i] for s in per_layer}, carry)
+    return x, carry
 
 
 def _final_logits(p, x):
@@ -208,8 +230,8 @@ def decode_step(params, ids, cache, slot_mask, *, cfg):
     pos = jnp.clip(lengths, 0, params["gpt.wpe.weight"].shape[0] - 1)
     x = params["gpt.wte.weight"][ids] + params["gpt.wpe.weight"][pos]
 
-    def attend(i, q, k, v):
-        nonlocal kc, vc, ks, vs
+    def attend(i, q, k, v, pools):
+        kc, vc, ks, vs = pools
         page, off = pa.token_page_coords(page_table, pos, slot_mask, ps)
         if ks is not None:
             k, sk = pa.quantize_kv(k)
@@ -218,10 +240,12 @@ def decode_step(params, ids, cache, slot_mask, *, cfg):
             vs = vs.at[i, page, off].set(sv)
         kc = kc.at[i, page, off].set(pa.kv_rows(k, kc))
         vc = vc.at[i, page, off].set(pa.kv_rows(v, vc))
-        return pa.paged_attention(q, kc, vc, page_table, pos,
-                                  k_scale=ks, v_scale=vs, layer=i)
+        att = pa.paged_attention(q, kc, vc, page_table, pos,
+                                 k_scale=ks, v_scale=vs, layer=i)
+        return att, (kc, vc, ks, vs)
 
-    x = _block_stack(params, x, nl, nh, dh, attend)
+    x, (kc, vc, ks, vs) = _block_stack(params, x, nl, nh, dh, attend,
+                                       (kc, vc, ks, vs))
     logits = _final_logits(params, x)
     new_cache = dict(k_pages=kc, v_pages=vc, page_table=page_table,
                      lengths=jnp.where(slot_mask, lengths + 1, lengths))
@@ -273,8 +297,8 @@ def prefill_step(params, ids, length, page_table, k_pages, v_pages, *, cfg,
         parity=quant or k_pages.dtype == x.dtype,
         num_pages=k_pages.shape[1])
 
-    def attend(i, q, k, v):
-        nonlocal k_pages, v_pages, k_scale, v_scale
+    def attend(i, q, k, v, pools):
+        k_pages, v_pages, k_scale, v_scale = pools
         page, off = pa.prompt_page_coords(page_table, length, s, ps)
         if k_scale is not None:
             qk, sk = pa.quantize_kv(k[0])
@@ -288,15 +312,18 @@ def prefill_step(params, ids, length, page_table, k_pages, v_pages, *, cfg,
         else:
             k_pages = k_pages.at[i, page, off].set(pa.kv_rows(k[0], k_pages))
             v_pages = v_pages.at[i, page, off].set(pa.kv_rows(v[0], v_pages))
+        pools = k_pages, v_pages, k_scale, v_scale
         if impl == "pallas":
             # length-aware: the page walk stops at ceil(length/page_size),
             # not at the pow-2 bucket the queries are padded to
             return pa._prefill_impl_call(
                 "pallas", q, k_pages, v_pages, page_table, jnp.int32(0),
-                length, i, k_scale=k_scale, v_scale=v_scale).astype(x.dtype)
-        return causal(i, q, k, v)
+                length, i, k_scale=k_scale,
+                v_scale=v_scale).astype(x.dtype), pools
+        return causal(i, q, k, v), pools
 
-    x = _block_stack(params, x, nl, nh, dh, attend)
+    x, (k_pages, v_pages, k_scale, v_scale) = _block_stack(
+        params, x, nl, nh, dh, attend, (k_pages, v_pages, k_scale, v_scale))
     last = x[0, jnp.clip(length - 1, 0, s - 1)]
     logits = _final_logits(params, last)
     if k_scale is not None:
@@ -336,8 +363,8 @@ def prefill_chunk_step(params, ids, start, valid, page_table, k_pages,
     x = params["gpt.wte.weight"][ids][None] + \
         wpe[jnp.clip(pos, 0, wpe.shape[0] - 1)][None]        # [1, C, H]
 
-    def attend(i, q, k, v):
-        nonlocal k_pages, v_pages, k_scale, v_scale
+    def attend(i, q, k, v, pools):
+        k_pages, v_pages, k_scale, v_scale = pools
         page, off = pa.chunk_page_coords(page_table, start, valid, c, ps)
         if k_scale is not None:
             k, sk = pa.quantize_kv(k[0])
@@ -353,11 +380,13 @@ def prefill_chunk_step(params, ids, start, valid, page_table, k_pages,
         # (kernels/registry.py): xla gathers the full window, pallas
         # streams only ceil((start+valid)/page_size) pages per (q block,
         # head) cell
-        return pa.prefill_attention(
+        att = pa.prefill_attention(
             q, k_pages, v_pages, page_table, start, valid,
             k_scale=k_scale, v_scale=v_scale, layer=i).astype(x.dtype)
+        return att, (k_pages, v_pages, k_scale, v_scale)
 
-    x = _block_stack(params, x, nl, nh, dh, attend)
+    x, (k_pages, v_pages, k_scale, v_scale) = _block_stack(
+        params, x, nl, nh, dh, attend, (k_pages, v_pages, k_scale, v_scale))
     last = x[0, jnp.clip(valid - 1, 0, c - 1)]
     logits = _final_logits(params, last)
     if k_scale is not None:
@@ -428,8 +457,8 @@ def verify_step(params, tok_seq, draft_len, cache, slot_mask, *, cfg,
     x = params["gpt.wte.weight"][tok_seq] + \
         wpe[jnp.clip(pos, 0, wpe.shape[0] - 1)]                # [B, K+1, H]
 
-    def attend(i, q, k, v):
-        nonlocal kc, vc, ks, vs
+    def attend(i, q, k, v, pools):
+        kc, vc, ks, vs = pools
         page, off = pa.verify_page_coords(page_table, pos, valid, ps)
         if ks is not None:
             k, sk = pa.quantize_kv(k)
@@ -450,9 +479,11 @@ def verify_step(params, tok_seq, draft_len, cache, slot_mask, *, cfg,
         mask = jnp.arange(lmax)[None, None, :] <= pos[:, :, None]
         sc = jnp.where(mask[:, None], sc, -1e30)
         pr = jax.nn.softmax(sc, axis=-1)
-        return jnp.einsum("bhqk,bkhd->bqhd", pr, vv).astype(x.dtype)
+        att = jnp.einsum("bhqk,bkhd->bqhd", pr, vv).astype(x.dtype)
+        return att, (kc, vc, ks, vs)
 
-    x = _block_stack(params, x, nl, nh, dh, attend)
+    x, (kc, vc, ks, vs) = _block_stack(params, x, nl, nh, dh, attend,
+                                       (kc, vc, ks, vs))
     logits = _final_logits(params, x)                          # [B, K+1, V]
 
     if sampler is not None and sample_state is not None:
@@ -622,8 +653,8 @@ def stack_gpt_params(params, mesh=None):
 
 
 def serving_params(params):
-    """state_dict layout -> what every decode path reads (`_pget`): ONE
-    flat dict, the top leaves under their own names and each block leaf
+    """state_dict layout -> what every decode path reads (`_block_stack`):
+    ONE flat dict, the top leaves under their own names and each block leaf
     under ``blocks.<suffix>``, indexed by layer. The 8 vectors (norms and
     biases) are stacked ``[nl, width]``; the 4 matrices stay a tuple of the
     model's own per-layer arrays. A launch costs the host a microsecond a
@@ -1116,15 +1147,20 @@ class GPTForCausalLM(nn.Layer):
                 cmask = jnp.tril(jnp.ones((S0, S0), bool))
                 causal = _causal_attend(scale, cmask, x.dtype)
 
-                def attend_prefill(i, q, k, v):
-                    nonlocal kc, vc
-                    kc = jax.lax.dynamic_update_slice(
-                        kc, k[None], (i, 0, 0, 0, 0))
-                    vc = jax.lax.dynamic_update_slice(
-                        vc, v[None], (i, 0, 0, 0, 0))
-                    return causal(i, q, k, v)
+                def at(i, pos=0):
+                    # position ``pos`` of layer ``i`` of the dense cache:
+                    # indices of one type (the layer's is traced int32)
+                    return tuple(jnp.asarray(v, jnp.int32)
+                                 for v in (i, 0, pos, 0, 0))
 
-                x = _block_stack(p, x, nl, nh, dh, attend_prefill)
+                def attend_prefill(i, q, k, v, kv):
+                    kc, vc = kv
+                    kc = jax.lax.dynamic_update_slice(kc, k[None], at(i))
+                    vc = jax.lax.dynamic_update_slice(vc, v[None], at(i))
+                    return causal(i, q, k, v), (kc, vc)
+
+                x, (kc, vc) = _block_stack(p, x, nl, nh, dh, attend_prefill,
+                                           (kc, vc))
                 logits0 = _final_logits(p, x[:, -1])
                 first, key = sample(logits0, key)
                 first = first.astype(ids.dtype)
@@ -1136,23 +1172,25 @@ class GPTForCausalLM(nn.Layer):
                     x = p["gpt.wte.weight"][tok] + \
                         p["gpt.wpe.weight"][pos][None, :]    # [B, H]
 
-                    def attend(i, q, k, v):
-                        nonlocal kc, vc
+                    def attend(i, q, k, v, kv):
+                        kc, vc = kv
                         kc = jax.lax.dynamic_update_slice(
-                            kc, k[None, :, None], (i, 0, pos, 0, 0))
+                            kc, k[None, :, None], at(i, pos))
                         vc = jax.lax.dynamic_update_slice(
-                            vc, v[None, :, None], (i, 0, pos, 0, 0))
+                            vc, v[None, :, None], at(i, pos))
                         sc = jnp.einsum("bhd,blhd->bhl",
                                         q.astype(jnp.float32) * scale,
                                         kc[i].astype(jnp.float32))
                         mask = jnp.arange(L) <= pos
                         sc = jnp.where(mask[None, None], sc, -1e30)
                         pr = jax.nn.softmax(sc, axis=-1)
-                        return jnp.einsum(
+                        att = jnp.einsum(
                             "bhl,blhd->bhd", pr,
                             vc[i].astype(jnp.float32)).astype(q.dtype)
+                        return att, (kc, vc)
 
-                    x = _block_stack(p, x, nl, nh, dh, attend)
+                    x, (kc, vc) = _block_stack(p, x, nl, nh, dh, attend,
+                                               (kc, vc))
                     logits = _final_logits(p, x)
                     nxt, key = sample(logits, key)
                     nxt = nxt.astype(tok.dtype)

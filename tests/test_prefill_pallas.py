@@ -124,6 +124,42 @@ class TestKernelParity:
                                    rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("kv", ["f32", "int8"])
+def test_a_traced_layer_is_one_function_for_every_layer(kv):
+    """The layer as a TRACED value, as `models/gpt.py::_block_stack` passes
+    it: every layer's call gives the bits of the same call with the layer a
+    constant, and a program that calls the kernel once a layer holds ONE
+    function for it (`_stored_call`) and a call a layer, not a kernel body
+    a layer (PERF.md, PR 39: 4.8-5.2 s of each prefill program's start)."""
+    import re
+    import jax
+    rng = np.random.RandomState(13)
+    kp, vp, row = _pool(rng)
+    scales = {}
+    if kv == "int8":
+        (kp, ks), (vp, vs) = _quantized(kp), _quantized(vp)
+        scales = dict(k_scale=ks, v_scale=vs)
+    q = jnp.asarray(rng.randn(8, 2, 8).astype(np.float32))
+    start, valid = jnp.int32(4), jnp.int32(6)
+
+    def at(layer, kp_, vp_, sc):
+        return pallas_prefill(q, kp_, vp_, row, start, valid, layer=layer,
+                              interpret=True, **sc)
+
+    def every_layer(kp_, vp_, sc):
+        return [at(jnp.int32(i) + 0 * start, kp_, vp_, sc)
+                for i in range(NL)]
+    lowered = jax.jit(every_layer).lower(kp, vp, scales)
+    text = lowered.as_text()
+    assert len(re.findall(r"func\.func private @_stored_call\(", text)) == 1
+    assert len(re.findall(r"call @_stored_call\(", text)) == NL
+    got = lowered.compile()(kp, vp, scales)
+    for i in range(NL):
+        np.testing.assert_array_equal(np.asarray(got[i]),
+                                      np.asarray(at(i, kp, vp, scales)))
+    assert not np.array_equal(np.asarray(got[0]), np.asarray(got[NL - 1]))
+
+
 class TestLengthScaling:
     """The ragged-stop proof: per-cell trip counts scale with the
     request's TRUE context (start + valid), never with pages_per_slot or

@@ -5,8 +5,9 @@ about a microsecond of host time a leaf, so the GPT family serves from
 `models/gpt.py::serving_params` and not from the state_dict: the 8 vectors
 of a block (norms and biases) are stacked ``[nl, width]`` and the 4 matrices
 stay a tuple of the model's own per-layer arrays (4 leaves a layer where
-the state_dict has 12). The arithmetic is the per-layer program's: `_pget`
-reads ``leaf[i]`` with a static index. These tests hold the layout to that:
+the state_dict has 12). The arithmetic is the per-layer program's: the
+block reads ``leaf[layer]``, a layer's own matrices as its arguments
+(`models/gpt.py::_block_stack`). These tests hold the layout to that:
 the gauge that says how many leaves a launch takes, bit-identical logits
 against the per-layer reading of the same weights (float and int8), a
 weight swap without a compile, and int8 matrices widened a layer at a time.
@@ -23,6 +24,8 @@ from paddle_tpu.observability import metrics
 from paddle_tpu.quantization.serving import (GPT_MATMUL_SUFFIXES,
                                              QuantizedLeaf,
                                              quantize_gpt_params)
+
+from inlined_block import inlined_block_stack, state_dict_get
 
 NL = 3
 PROGRAMS = ["decode_step", "prefill_step", "prefill_chunk_step",
@@ -102,11 +105,6 @@ def test_the_other_families_hand_over_what_they_did(family):
 
 # ------------------------------ the per-layer reading of the same weights
 
-def _per_layer_pget(p, layer, suffix):
-    """`_pget` as it was while the family served the state_dict itself."""
-    return gpt._deq(p[f"gpt.h.{layer}.{suffix}"])
-
-
 def _run(program, params, cfg):
     """One call of a step function at a tiny size, the pools not empty
     (a first call fills them, a second reads them back)."""
@@ -158,7 +156,8 @@ def test_the_served_layout_gives_the_per_layer_programs_output(
     set_flags({"tpu_paged_impl": "xla", "tpu_prefill_impl": "xla"})
     try:
         got = _run(program, served, model.cfg)
-        monkeypatch.setattr(gpt, "_pget", _per_layer_pget)
+        monkeypatch.setattr(gpt, "_block_stack",
+                            inlined_block_stack(state_dict_get))
         want = _run(program, state, model.cfg)
     finally:
         set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
@@ -213,7 +212,7 @@ def test_refresh_params_swaps_the_served_leaves_without_a_compile(weights):
 
 @pytest.mark.parametrize("program", PROGRAMS)
 def test_an_int8_step_widens_one_layer_at_a_time(program):
-    """`_pget` hands `dequant` one layer's int8 matrix and its scales: each
+    """The block hands `dequant` one layer's int8 matrix and its scales: each
     matmul leaf is widened once a layer where it is used, and no value of
     the traced program is a float array of several layers' matrices."""
     from paddle_tpu.framework.flags import set_flags

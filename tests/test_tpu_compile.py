@@ -243,7 +243,8 @@ def medium_compiled(chip):
     returns the next one), whole, at gpt2-medium's serving shapes with the
     Pallas arms pinned, compiled for the described chip as the engine
     lowers it: ``(params, cache, *small)``, the cache donated whole.
-    Compiled once for the tests that read it."""
+    Compiled once for the tests that read it; ``get.lowered[program]`` is
+    the text the compiler was handed."""
     from paddle_tpu.framework.flags import set_flags
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
@@ -279,12 +280,15 @@ def medium_compiled(chip):
         _compat.default_interpret = lambda: False
         set_flags({"tpu_paged_impl": "pallas", "tpu_prefill_impl": "pallas"})
         try:
-            done[program] = jax.jit(step, donate_argnums=(1,)).lower(
-                _medium_params(chip), cache, *small).compile()
+            lowered = jax.jit(step, donate_argnums=(1,)).lower(
+                _medium_params(chip), cache, *small)
+            get.lowered[program] = lowered.as_text()
+            done[program] = lowered.compile()
         finally:
             set_flags({"tpu_paged_impl": "auto", "tpu_prefill_impl": "auto"})
             _compat.default_interpret = was
         return done[program]
+    get.lowered = {}
     return get
 
 
@@ -388,6 +392,53 @@ def test_step_program_prefetches_its_matrices_and_copies_none_on_v5e(
     assert sum((int(a), int(b)) in mats for a, b in fast) >= nl
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes > 2 * 12 * nl * h * h  # all weights
+
+
+def entry_ops(hlo_text):
+    """``Counter`` of ``(opcode, result shape)`` over the optimized HLO's
+    ENTRY computation, layouts dropped."""
+    import collections
+    import re
+    found = collections.Counter()
+    for ln in hlo_text[hlo_text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%[\w.\-]+ = (.+?) ([a-z][a-z\-]*)\(", ln)
+        if m:
+            found[m.group(2), re.sub(r"\{[^}]*\}", "", m.group(1))] += 1
+    return found
+
+
+def test_decode_step_keeps_its_op_families_on_v5e(medium_compiled):
+    """The decode step compiled for the chip is the program it was while
+    the block was inlined a layer at a time (the compiler inlines the
+    block's calls before any other pass: PERF.md, PR 39). A layer is two
+    in-place scatters into the pools, two fusions that end in a layer norm's
+    statistics (and one for the final norm) and one attention kernel."""
+    m = MEDIUM
+    nl, b, h = m["layers"], m["slots"], m["hidden"]
+    ops = entry_ops(medium_compiled("decode_step").as_text())
+    pool = f"bf16[{nl},{m['pages']},{m['page']},{h}]"
+    assert ops["fusion", pool] == 2 * nl
+    assert ops["fusion", f"(f32[{b}], bf16[{b},{h}])"] == 2 * nl + 1
+    assert ops["custom-call", f"bf16[{b},1,{h}]"] == nl
+
+
+@pytest.mark.parametrize("program", ["prefill_chunk_step", "prefill_step"])
+def test_prefill_program_lowers_one_kernel_body_and_one_block_on_v5e(
+        medium_compiled, program):
+    """What a prefill program hands the compiler holds ONE Mosaic kernel
+    body, in the prefill kernel's one function, called by the block's one
+    function, which the program calls once a layer: it held 24 bodies, and
+    tracing and lowering them was 4.8-5.2 s of each prefill program's 5.8-
+    6.9 s of every start (PERF.md, PR 39). The guard that keeps a start
+    from growing back needs no clock."""
+    import re
+    medium_compiled(program)
+    text = medium_compiled.lowered[program]
+    nl = MEDIUM["layers"]
+    assert text.count("tpu_custom_call") == 1
+    for fn, calls in [("_stored_call", 1), ("block", nl)]:
+        assert len(re.findall(rf"func\.func private @{fn}\(", text)) == 1
+        assert len(re.findall(rf"call @{fn}\(", text)) == calls
 
 
 # Phi-4-mini-flash as benchmarks/configs/phi-4-mini-flash.json serves it
