@@ -1,0 +1,53 @@
+"""Tiny overrides for rehearsing the ``dots3_note`` cell on the CPU, as
+``tiny_granite.py`` does for the ``granitemoehybrid`` cell: all control flow
+of a run — the seeded weights, the engine through the model seam (a latent
+pool and an index keys' pool, rings), the routing and selection counts on
+the tokens' readback, the wire, the closed loop, the walk of the plain
+reference with the same share of the experts — at sizes a test can hold (a
+dense first layer and one period, ``index_topk`` 8, window 5, 8 experts of
+which 4 are held, 2 a token, prompts of 12 to 44 so that the selection and
+the window bite)."""
+import os
+
+import tiny  # noqa: F401 — puts the benchmark on sys.path
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+MODEL = {"hidden_size": 64, "num_hidden_layers": 5,
+         "intermediate_size": 96, "moe_intermediate_size": 16,
+         "vocab_size": 160, "n_routed_experts": 4, "router_outputs": 8,
+         "num_experts_per_tok": 2, "num_attention_heads": 4,
+         "q_lora_rank": 16, "kv_lora_rank": 8, "qk_nope_head_dim": 8,
+         "qk_rope_head_dim": 4, "v_head_dim": 8, "rope_theta": 10000,
+         "index_n_heads": 2, "index_head_dim": 8, "index_topk": 8,
+         "swa_num_attention_heads": 2, "swa_q_lora_rank": 16,
+         "swa_kv_lora_rank": 16, "swa_qk_nope_head_dim": 12,
+         "swa_qk_rope_head_dim": 4, "swa_v_head_dim": 8,
+         "swa_rope_theta": 1000, "sliding_window_size": 5,
+         "assumed": {"index_rope_dim": 4}}
+_UN = lambda lo, hi: {"dist": "uniform", "min": lo, "max": hi}  # noqa: E731
+CELL = "dots3note-serve-notes"
+TINY = {
+    CELL: {
+        "config": dict(MODEL, serve={
+            "precision": "f32", "page_size": 4, "max_slots": 4,
+            "max_seq_len": 64, "num_pages": 65,
+            "prefill_chunk_tokens": 8}, limits_meta={"check_requests": 3}),
+        "traffic": {"clients": 4, "table_size": 16, "block": 1,
+                    "classes": [{"name": "unshared", "per_block": 1,
+                                 "prompt": _UN(12, 44),
+                                 "answer": _UN(4, 16)}]}},
+}
+
+
+def rehearse(workload=CELL, seed=1, seconds=1.5, trace=False, **kw):
+    import time
+    import run as bench_run
+    return bench_run.run_cell(workload, seed, seconds, trace,
+                              rehearsal=TINY[workload],
+                              t_start=time.perf_counter(), **kw)
+
+
+if __name__ == "__main__":
+    import json
+    print(json.dumps(rehearse(seed=4000000019)))

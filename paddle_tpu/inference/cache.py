@@ -4,7 +4,8 @@ updates in place, and only this module knows what is in it.
 `DeviceCache` is a pytree of the K and V page pools (stored as the
 attention kernels read them: `kernels/paged_attention.py`; empty, of zero
 layers and the trash page alone, for a family that keeps nothing in pages:
-`family.kv_layers` 0), the int8 pool's
+`family.kv_layers` 0; for a family whose page row is not K and V,
+`family.page_rows`, one pool a part of the row and no twin), the int8 pool's
 scale pools or None, the family's state arrays beside the pool (window
 rings, recurrent state: `inference/family.py`), and the fused sampler's
 per-slot PRNG key chains or None. Every step program is
@@ -69,13 +70,25 @@ class DeviceCache:
         nl, nh, ps, B = (fam.kv_layers, fam.kv_heads, ecfg.page_size,
                          ecfg.max_slots)
         k = jnp.zeros((nl, num_pages, ps, nh * fam.head_dim), dtype)
+        v = jnp.zeros_like(k)
+        if fam.page_rows:
+            # rows that are not K and V (inference/family.py): one pool a
+            # part, no twin
+            if ecfg.kv_dtype == "int8":
+                raise ValueError("kv_dtype='int8': the scale pools are per "
+                                 "K/V head, and this family's page rows "
+                                 "have none")
+            widths = [int(w) for _, w in fam.page_rows]
+            k = jnp.zeros((nl, num_pages, ps, widths[0]), dtype)
+            v = jnp.zeros((nl, num_pages, ps, widths[1]), dtype) \
+                if len(widths) == 2 else jnp.zeros((0, 1, ps, 0), dtype)
         # int8 pool: per-token-slot per-head f32 scales, written by the
         # same scatters that write the pages (docs/QUANTIZATION.md)
         ks = jnp.zeros((nl, num_pages, ps, nh), jnp.float32) \
             if ecfg.kv_dtype == "int8" else None
         specs = fam.state(B, ps, served_dtype) if fam.state else ()
         cache = cls(
-            k=k, v=jnp.zeros_like(k), k_scale=ks,
+            k=k, v=v, k_scale=ks,
             v_scale=None if ks is None else jnp.zeros_like(ks),
             state=tuple(jnp.zeros(shape, dt) for _, _, shape, dt in specs),
             keys=jnp.zeros((B + 1, 2), jnp.uint32) if ecfg.sampling
@@ -86,7 +99,11 @@ class DeviceCache:
                        in zip(cache.state, specs) if kd == kind)
         metrics.gauge("engine.kv_bytes_per_token").set(cache.bytes_per_token)
         metrics.gauge("engine.cache_bytes.paged").set(
-            2 * int(k.nbytes) + (0 if ks is None else 2 * int(ks.nbytes)))
+            int(k.nbytes) + int(v.nbytes)
+            + (0 if ks is None else 2 * int(ks.nbytes)))
+        for (name, _), pool in zip(fam.page_rows, (k, v)):
+            metrics.gauge(f"engine.cache_bytes.paged.{name}").set(
+                int(pool.nbytes))
         metrics.gauge("engine.cache_bytes.window").set(nbytes("window"))
         metrics.gauge("engine.cache_bytes.state").set(nbytes("recurrent"))
         metrics.gauge("engine.state_bytes_per_slot").set(
@@ -95,12 +112,14 @@ class DeviceCache:
 
     @property
     def bytes_per_token(self) -> int:
-        """Bytes each cached token costs across all layers (K + V values,
-        plus scales when quantized): the capacity yardstick bench_quant's
+        """Bytes each cached token costs across all layers (the values of
+        both pools' rows: K + V, or a family's own parts; plus scales when
+        quantized): the capacity yardstick bench_quant's
         slots-at-fixed-pool-bytes assertion is computed from."""
-        nl, _, _, width = self.k.shape
-        return nl * 2 * (width * jnp.dtype(self.k.dtype).itemsize
-                         + (0 if self.k_scale is None else self.heads * 4))
+        return sum(
+            p.shape[0] * (p.shape[3] * jnp.dtype(p.dtype).itemsize
+                          + (0 if self.k_scale is None else self.heads * 4))
+            for p in (self.k, self.v))
 
     # ---- what the family's step functions take and return (family.py) ----
 

@@ -6,7 +6,8 @@ not its business. A model object hands it one :class:`ModelFamily` through
 where the parameters are, and the kinds and shapes of state a sequence
 keeps. There is no flag and no `EngineConfig` field that picks a model —
 the model object decides. `models/gpt.py`, `models/phi4flash.py`,
-`models/granitemoehybrid.py` and `models/brumby.py` each supply one; the GPT
+`models/granitemoehybrid.py`, `models/brumby.py` and `models/dots3note.py`
+each supply one; the GPT
 family describes exactly what the engine used to import, so its programs
 trace as before.
 
@@ -44,6 +45,18 @@ alone then cannot restore a sequence — the engine refuses prefix
 reuse, speculation, hand-off, migration and tier spill by typed error
 (`errors.RecurrentStateUnsupported`; docs/SERVING.md "The model seam").
 
+A page row need not be K and V. A family whose pooled layers keep
+something else of a token (a latent row that every head reads, a key of a
+selector beside it) says so with ``page_rows``: ``((name, values a token),
+...)``, one or two parts. The first part is the row of ``k_pages``, the
+second (if any) the row of ``v_pages``, each ``[kv_layers, P, page,
+values]`` in the pool's type; there is no twin: with one part ``v_pages``
+is empty (``[0, 1, page, 0]``). ``kv_heads`` and ``head_dim`` then describe
+nothing and are left at 1 and the first part's width. The pool's sizing,
+``engine.kv_bytes_per_token`` and ``engine.cache_bytes.paged`` count what
+the parts hold, and each part's bytes are the gauge
+``engine.cache_bytes.paged.<name>`` (inference/cache.py).
+
 A step may hand back COUNTS with its tokens. A family with ``step_counts``
 = n > 0 (sparse experts: which held expert took how many tokens) has its
 token chain lengthened by n int32 entries: every program gives the tail to
@@ -80,6 +93,8 @@ class ModelFamily:
     window_tokens: int = 0          # window of the ``window`` state, if any
     quantize: Callable | None = None  # (params, weight_dtype) -> params
     step_counts: int = 0            # int32 entries a step adds to (above)
+    page_rows: tuple = ()           # ((name, values), ...): a page row that
+    #                                 is not K and V heads (above); () = K, V
     on_counts: Callable | None = None  # (grown: np.ndarray) -> None
 
 

@@ -17,8 +17,8 @@ An expert is gated: ``[u | v] = x W1_e`` (the FIRST half is activated),
 ``(silu(u) * v) W2_e``. Weights arrive as the held experts' stacks, ``w1
 [hi - lo, d, 2 f]`` and ``w2 [hi - lo, f, d]``.
 
-One arm (registered as ``moe_experts``; counted per program build in
-``kernel.dispatch.moe_experts.dense``): every token through every held
+Two arms (registered as ``moe_experts``; counted per program build in
+``kernel.dispatch.moe_experts.<arm>``). ``dense``: every token through every held
 expert, the gate (zero where the expert was not chosen) folded in before the
 second product, whose contraction runs over experts and expert width at
 once. It reads each held expert once and computes ``held / top_k`` times the
@@ -26,10 +26,36 @@ needed FLOPs: right where the step is bound by reading the experts anyway (a
 decode step of 64 tokens hits every one of 36 held experts). On the chip at
 Granite-4.0-H's sizes it also beat a sorted `jax.lax.ragged_dot` product at
 every token count tried, 64 to 1,024 (PERF.md section 6, PR 31), because the
-ragged product copies a layer's experts out of the stack first. A grouped
-product that reads the stack in place is a Pallas kernel this file does not
-have (ROADMAP Reach A2); it comes as an arm of its own with the chip reading
-that shows it winning.
+ragged product copies a layer's experts out of the stack first.
+
+``grouped``: the assignments that landed on a held expert, sorted by
+expert into groups of unequal size (an expert with no row is a group of
+none; no row is dropped, the buffer holds every assignment a call can
+make), and only those rows multiplied: `jax.lax.ragged_dot` twice, the gate
+between. A token that is not ``valid`` (a dead slot of a decode step, a
+chunk's padding) has no row, gets zero and reads no expert. It makes the
+needed FLOPs and reads the experts that were HIT; it
+wants a layer's experts as arrays of their own (a family hands them as a
+tuple of leaves, one a layer: a slice of a stack is copied first, which is
+what lost PR 31's trial). The registry takes it first where the dense arm
+would make ``experts / top_k`` >= `GROUPED_FROM` times the needed FLOPs (32
+at 256 experts and 8 a token) AND a call has at most `GROUPED_UP_TO` tokens:
+on the chip, a layer of 32 held experts of 256 took 1.42 ms grouped against
+2.15 dense at 24 tokens (a decode step hits a third of the experts), and
+5.64 against 4.59 at 512 (a chunk hits them all, and the sorted rows' gather
+and the ragged product's own overhead outweigh the FLOPs saved); Granite's
+36 of 72 at 10 a token read 1.51 against 1.06 at 64 tokens and 3.35 against
+2.13 at 512, so it stays dense (my chip run, PR 40; PERF.md section 6).
+Both thresholds lie BETWEEN measured points and no reading lies near either:
+``experts / top_k`` was read at 7.2 and 32 and nowhere between, tokens a
+call at 24, 64 (Granite's ratio alone) and 512. A configuration that falls
+between them wants a reading of its own before it trusts the order here.
+
+A router scores with a softmax over the chosen logits (``scoring``
+``"softmax"``) or with a sigmoid (``"sigmoid"``: scores ``sigmoid(logits)``,
+the ``top_k`` largest of ``score + bias`` chosen, the chosen SCORES divided
+by their sum and times ``scale``: DeepSeek-V3's ``noaux_tc`` with one
+group).
 
 With ``counts`` (int32 ``[hi - lo + 1]``) the call also returns the vector
 with this call's routing added: one entry a held expert (assignments of
@@ -46,19 +72,42 @@ from paddle_tpu.kernels import registry
 
 __all__ = ["routed_experts", "route"]
 
-registry.register_op("moe_experts", impls=("dense",))
+GROUPED_FROM = 16      # ``experts / top_k`` from which (read: 7.2, 32), and
+GROUPED_UP_TO = 64     # tokens a call up to which (read: 24, 512),
+#                        ``grouped`` is first
+
+
+def _candidates(ctx):
+    wasteful = ctx.get("experts", 0) >= GROUPED_FROM * ctx.get("top_k", 1)
+    few = ctx.get("tokens", GROUPED_UP_TO + 1) <= GROUPED_UP_TO
+    return ["grouped", "dense"] if wasteful and few else ["dense", "grouped"]
+
+
+registry.register_op("moe_experts", impls=("dense", "grouped"),
+                     candidates=_candidates)
 
 _HI = jax.lax.Precision.HIGHEST
 
 
-def route(x, w_router, top_k):
+def route(x, w_router, top_k, scoring="softmax", bias=None, scale=1.0):
     """(expert ids [T, top_k] int32, gates [T, top_k] f32): float32 logits
-    over every expert of the router, the ``top_k`` largest, a softmax over
-    those logits alone."""
+    over every expert of the router. ``"softmax"``: the ``top_k`` largest, a
+    softmax over those logits alone. ``"sigmoid"``: the ``top_k`` largest of
+    ``sigmoid(logits) + bias``, the chosen sigmoids over their sum, times
+    ``scale``."""
     logits = jnp.dot(x.astype(jnp.float32), w_router.astype(jnp.float32),
                      precision=_HI)
-    top, idx = jax.lax.top_k(logits, top_k)
-    return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if scoring == "softmax":
+        top, idx = jax.lax.top_k(logits, top_k)
+        return idx.astype(jnp.int32), jax.nn.softmax(top, axis=-1)
+    if scoring != "sigmoid":
+        raise ValueError(f"scoring {scoring!r}: 'softmax' or 'sigmoid'")
+    sc = jax.nn.sigmoid(logits)
+    by = sc if bias is None else sc + bias.astype(jnp.float32)
+    _, idx = jax.lax.top_k(by, top_k)
+    top = jnp.take_along_axis(sc, idx, axis=-1)
+    return idx.astype(jnp.int32), \
+        top / jnp.sum(top, axis=-1, keepdims=True) * scale
 
 
 def _gated(h, gate):
@@ -78,14 +127,43 @@ def _dense(x, w1, w2, idx, gates, lo):
                       preferred_element_type=jnp.float32)
 
 
+def _grouped(x, w1, w2, idx, gates, lo, valid=None):
+    t, k = idx.shape
+    held = w1.shape[0]
+    e = idx.reshape(-1) - lo
+    on = (e >= 0) & (e < held)
+    if valid is not None:         # a dead slot's row reads no expert
+        on &= jnp.repeat(valid, k)
+    key = jnp.where(on, e, held)                  # the others' rows last
+    order = jnp.argsort(key, stable=True)
+    sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
+                    dtype=jnp.int32)
+    gate = jnp.where(on, gates.reshape(-1), 0.0)[order]
+    h = jax.lax.ragged_dot(x[order // k], w1, sizes,
+                           preferred_element_type=jnp.float32)
+    act = _gated(h, gate[:, None]).astype(x.dtype)
+    y = jax.lax.ragged_dot(act, w2, sizes,
+                           preferred_element_type=jnp.float32)
+    # a row past the last group is no expert's: whatever the product left
+    # there is not added
+    y = jnp.where((gate != 0.0)[:, None], y, 0.0).astype(x.dtype)
+    back = jnp.zeros(t * k, jnp.int32).at[order].set(
+        jnp.arange(t * k, dtype=jnp.int32))
+    return jnp.sum(y[back].reshape(t, k, -1), axis=1, dtype=jnp.float32)
+
+
 def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
-                   valid=None):
+                   valid=None, scoring="softmax", bias=None, scale=1.0,
+                   impl=None):
     """This chip's part of a routed-expert layer for tokens ``x`` [T, d].
 
     w_router : [d, E] over ALL experts; w1 : [hi - lo, d, 2 f]; w2 :
     [hi - lo, f, d] of the held experts ``held`` = (lo, hi); top_k : experts
-    a token; valid : [T] bool, the tokens ``counts`` counts (None: all). Returns
-    ``y`` [T, d] in ``x``'s type, or ``(y, counts)`` when ``counts`` came.
+    a token; valid : [T] bool, the tokens ``counts`` counts (None: all; what
+    the others get is unspecified: zero from ``grouped``);
+    scoring, bias [E], scale : the router's gates (`route`); impl : an arm
+    by name (None: the registry's). Returns ``y`` [T, d] in ``x``'s type, or
+    ``(y, counts)`` when ``counts`` came.
     """
     lo, hi = held
     if w1.shape[0] != hi - lo or w2.shape[0] != hi - lo:
@@ -93,9 +171,13 @@ def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
     if not 0 <= lo < hi <= w_router.shape[1]:
         raise ValueError(f"held experts {held} of a router over "
                          f"{w_router.shape[1]}")
-    registry.dispatch("moe_experts")
-    idx, gates = route(x, w_router, top_k)
-    y = _dense(x, w1, w2, idx, gates, lo).astype(x.dtype)
+    arm = registry.dispatch("moe_experts", forced=impl, ctx=dict(
+        experts=w_router.shape[1], top_k=top_k, held=hi - lo,
+        tokens=x.shape[0]))
+    idx, gates = route(x, w_router, top_k, scoring, bias, scale)
+    y = _dense(x, w1, w2, idx, gates, lo) if arm == "dense" \
+        else _grouped(x, w1, w2, idx, gates, lo, valid)
+    y = y.astype(x.dtype)
     if counts is None:
         return y
     ok = jnp.ones(x.shape[0], bool) if valid is None else valid

@@ -450,18 +450,22 @@ class MetricsRegistry:
         ``metrics.spans_dropped``."""
         with self._span_lock:
             raw = list(self._spans)
+        # the edges in the ring's own unit, through the arithmetic that
+        # stored a start: an edge given as a span's own start then always
+        # holds it (back through seconds, the round trip can land an ulp
+        # under it)
+        lo = None if since is None else (since - _EPOCH) * 1e6
+        hi = None if until is None else (until - _EPOCH) * 1e6
         out = []
         for n, cat, ts, dur, tid, args, sid, pid in raw:
             if name is not None and n != name:
                 continue
             if prefix is not None and not n.startswith(prefix):
                 continue
-            t0 = _EPOCH + ts * 1e-6
-            if (since is not None and t0 < since) or \
-                    (until is not None and t0 >= until):
+            if (lo is not None and ts < lo) or (hi is not None and ts >= hi):
                 continue
-            out.append(SpanRecord(n, cat, t0, dur * 1e-6, tid, args, sid,
-                                  pid))
+            out.append(SpanRecord(n, cat, _EPOCH + ts * 1e-6, dur * 1e-6,
+                                  tid, args, sid, pid))
         return out
 
     def spans_for_trace(self, trace_id) -> list:

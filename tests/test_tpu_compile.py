@@ -652,6 +652,82 @@ def test_granite_step_program_copies_no_state_or_expert_on_v5e(
             assert [f for f in families if re.search(want, f)], want
 
 
+# dots3-note-prev as benchmarks/configs/dots3-note-prev.json serves it: one
+# chip's share of eight (layers 0-4, 32 of 256 experts, 19,008 rows)
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
+        chip, program):
+    """dots3-note's decode step and one prefill chunk of 512, whole, at the
+    published widths with the chip's share, compiled for the described
+    chip with the pools and rings donated and the counts behind the token
+    chain. The latent pool's rows are 640 wide: the optimized HLO holds no
+    copy, slice, transpose or fusion of the size of a pool but the pools'
+    in-place scatters (rows of 576 made the chip store the pool with its
+    pages as the minor dimension, and both programs copied 0.93 GB in and
+    out: PERF.md, PR 40); everything donated is aliased; the program fits
+    the chip beside its 10.8 GB of arguments; and the op families by which
+    the cell's kernel shares find these kernels in a device trace are in
+    the program that makes them."""
+    import json
+    import re
+    from paddle_tpu.inference.cache import DeviceCache
+    from paddle_tpu.inference.programs import (decode_program,
+                                               prefill_program,
+                                               prefill_upload, step_upload)
+    from paddle_tpu.models import dots3note as dm
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    sys.path.insert(0, bench)
+    from harness import dots3_bytes, spec as harness_spec, trace
+    with open(os.path.join(bench, "configs", "dots3-note-prev.json")) as f:
+        cfgj = json.load(f)
+    cfg = harness_spec._module("runners", "serve_dots3").model_config(cfgj)
+    sv = cfgj["serve"]
+    slots, page, pages = sv["max_slots"], sv["page_size"], sv["num_pages"]
+    per_slot = sv["max_seq_len"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+    params = {k: sds(s, BF16) for k, s in dm.leaf_shapes(cfg).items()}
+    lat = sds((2, pages, page, cfg.latent_width), BF16)
+    kix = sds((2, pages, page, cfg.index_head_dim), BF16)
+    specs = dm.state_arrays(cfg, slots, page, BF16)
+    cache = DeviceCache(k=lat, v=kix, k_scale=None, v_scale=None,
+                        state=tuple(sds(s, d) for _, _, s, d in specs),
+                        keys=None, heads=1)
+    n = dm.step_counts(cfg)
+    if program == "decode_step":
+        up = step_upload(slots, per_slot, sampling=False)
+        step = decode_program(dm, cfg, up, n)
+    else:
+        up = prefill_upload(sv["prefill_chunk_tokens"], per_slot,
+                            sampling=False, chunk=True)
+        step = prefill_program(dm, cfg, up, n)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((slots + n,), jnp.int32),
+        up.spec(sharding=chip)).compile()
+    text = compiled.as_text()
+    big = pool_sized_ops(text, int(np.prod(kix.shape)))
+    assert big == [], big
+    mem = compiled.memory_analysis()
+    donated = 2 * (int(np.prod(lat.shape)) + int(np.prod(kix.shape))
+                   + sum(int(np.prod(s)) for _, _, s, _ in specs))
+    assert mem.alias_size_in_bytes >= donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    shapes = dots3_bytes.trace_shapes(cfgj)
+    families = {trace.family(ln.strip().removeprefix("ROOT "))
+                for ln in text.splitlines() if " = " in ln}
+    for name in ("latent_attn", "index_select", "window_latent_attn",
+                 "dots3_experts"):
+        metric = harness_spec.layer_metric(f"{name}_roofline_share")
+        found = [bool([f for f in families
+                       if re.search(p.format(**shapes), f)])
+                 for p in metric["patterns"]]
+        # each kernel's patterns name a chunk's ops and a decode step's:
+        # some of them are in each program, all of them in the two
+        assert any(found), (name, metric["patterns"], found)
+        print(program, name, found)
+
+
 # Brumby-14B-Base as benchmarks/configs/brumby-14b-base.json serves it: one
 # pipeline stage of five (8 layers, the embedding and the head)
 BRUMBY = dict(slots=16, page=16, chunk=512, layers=8)
