@@ -324,7 +324,8 @@ def _impl_call(impl, qt, kt, vt, causal, scale, tileable):
         return _pallas_flash(qt, kt, vt, causal, sm)
     if impl == "authored":
         # the in-repo Pallas kernels (kernels/pallas/flash_attention.py),
-        # forward AND backward
+        # forward AND backward. They read [B, S, H * D]: the swap of axes
+        # that brought these arrays here and the one they make cancel
         from paddle_tpu.kernels.pallas import flash_attention as _authored
         return _authored(qt, kt, vt, causal=causal, sm_scale=scale)
     return _xla_flash(qt, kt, vt, causal, scale)

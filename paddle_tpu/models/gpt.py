@@ -858,8 +858,11 @@ class GPTAttention(nn.Layer):
     def forward(self, x, cache=None):
         B, S = x.shape[0], x.shape[1]
         qkv = self.qkv_proj(x)                       # [B, S, 3H] (mp-sharded)
-        qkv = qkv.reshape([B, S, 3, self.num_heads, self.head_dim])
-        q, k, v = qkv.unbind(axis=2)
+        # split the columns first, then view each third by heads: a
+        # [B, S, 3, nh, dh] view of the whole makes XLA keep q, k and v
+        # sequence-minor and relay them for every consumer that is not
+        heads = [B, S, self.num_heads, self.head_dim]
+        q, k, v = (t.reshape(heads) for t in qkv.split(3, axis=-1))
         if cache == INIT_CACHE:
             # prime an empty cache WITHOUT a zero-length [B, 0, ...] tensor:
             # concat-with-empty is a no-op anyway

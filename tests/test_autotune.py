@@ -39,7 +39,7 @@ class TestFlashWinner:
         # candidates are measured in _flash_candidates order
         order = iter(["xla", "dense", "mosaic", "splash", "authored"])
 
-        def fake_measure2(fn, args, warmup=1, reps=3):
+        def fake_measure2(fn, args, **kw):
             return timings[next(order)]
 
         monkeypatch.setattr(autotune, "_measure", fake_measure2)
@@ -60,7 +60,7 @@ class TestFlashWinner:
         at WARNING, so whoever reads `registry.table()` sees the refusal."""
         monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
         monkeypatch.setattr(autotune, "_measure",
-                            lambda fn, args: (fn(*args), 1.0)[1])
+                            lambda fn, args, **kw: (fn(*args), 1.0)[1])
 
         def run_impl(impl, q, k, v):
             if impl != "xla":
@@ -116,7 +116,7 @@ class TestMeasurementInsideATrace:
             lambda *a, **k: ["xla", "dense"])
         concrete = []
 
-        def measure(fn, args, warmup=1, reps=3):
+        def measure(fn, args, **kw):
             leaves = jax.tree_util.tree_leaves((args, fn(*args)))
             concrete.append(not any(isinstance(a, jax.core.Tracer)
                                     for a in leaves))

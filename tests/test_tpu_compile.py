@@ -65,18 +65,111 @@ def _sq(fn):
     return lambda *a: (fn(*a).astype(jnp.float32) ** 2).sum()
 
 
+# [B, H, S, D] of the calls compiled: the two widths at batch 8, and the
+# training cell's own call (gpt2s-train-b16s1024)
+FLASH_CALLS = {"small": (8, 12, 1024, 64), "345m": (8, 16, 1024, 64),
+               "cell": (16, 12, 1024, 64)}
+
+
 @pytest.mark.parametrize("bwd", [False, True], ids=["fwd", "bwd"])
-@pytest.mark.parametrize("width", WIDTHS)
-def test_flash_attention_compiles_for_v5e(chip, width, bwd):
+@pytest.mark.parametrize("call", FLASH_CALLS)
+def test_flash_attention_compiles_for_v5e(chip, call, bwd):
     from paddle_tpu.kernels.pallas.flash_attention import flash_attention
-    nh, dh, _ = WIDTHS[width]
-    q = jax.ShapeDtypeStruct((8, nh, 1024, dh), BF16, sharding=chip)
+    q = jax.ShapeDtypeStruct(FLASH_CALLS[call], BF16, sharding=chip)
 
     def fn(q_, k_, v_):
         return flash_attention(q_, k_, v_, causal=True, interpret=False)
 
     _compiles_to_a_kernel(
         jax.grad(_sq(fn), argnums=(0, 1, 2)) if bwd else fn, q, q, q)
+
+
+def score_block_ops(hlo_text, seq_k, rows=256):
+    """``[(opcode, name, shape)]`` of every instruction of the optimized
+    HLO, outside fusion bodies, whose result (or a member of its tuple)
+    ends in ``[.., r, seq_k]`` with ``r >= rows``: a block of attention
+    scores or probabilities that exists in HBM. A kernel's custom call
+    holds its blocks in VMEM and gives back ``[B, S, H * D]`` and one
+    statistic a query (``[B, H, 1, S]``: one row, not a block)."""
+    import re
+    bodies, name = {}, None
+    for line in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", line)
+        if head:
+            name = head.group(1)
+            bodies[name] = []
+        elif name is not None and " = " in line:
+            bodies[name].append(line)
+    fused = {m.group(1) for lines in bodies.values() for ln in lines
+             for m in [re.search(r" fusion\(.*calls=%([\w.\-]+)", ln)] if m}
+    found = []
+    for comp, lines in bodies.items():
+        if comp in fused:
+            continue
+        for ln in lines:
+            m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (.+?) "
+                         r"([a-z][a-z\-]*)\(", ln)
+            if not m or m.group(3) in ("parameter", "get-tuple-element"):
+                continue
+            for dims in re.findall(r"\w+\[([\d,]*)\]", m.group(2)):
+                d = [int(x) for x in dims.split(",") if x]
+                if len(d) >= 2 and d[-1] == seq_k and d[-2] >= rows:
+                    found.append((m.group(3), m.group(1), m.group(2)))
+                    break
+    return found
+
+
+def test_score_block_ops_sees_a_block_of_scores():
+    """The reader the next test rests on, on a hand-written module: a
+    product and a fusion that give a block of scores count, what a
+    fusion holds inside, the custom call's ``[B, S, H * D]`` output and
+    its row of statistics a head, and a block of fewer rows do not."""
+    text = """\
+%fused_exp (p: bf16[16,12,256,1024]) -> bf16[16,12,256,1024] {
+  %e = f32[16,12,256,1024]{3,2,1,0} exponential(%p)
+  ROOT %c = bf16[16,12,256,1024]{3,2,1,0} convert(%e)
+}
+ENTRY %main (q: bf16[16,12,1024,64]) -> bf16[16,12,1024,64] {
+  %q = bf16[16,12,1024,64]{3,2,1,0} parameter(0)
+  %convolution.1 = bf16[16,12,256,1024]{3,2,1,0} convolution(%qb, %k), dim_labels=0bf_0oi->0bf
+  %fusion.2 = (f32[16,12,256]{2,1,0}, bf16[16,12,256,1024]{3,2,1,0}) fusion(%convolution.1), kind=kLoop, calls=%fused_exp
+  %dot.3 = f32[1024,1024]{1,0} dot(%a, %b), lhs_contracting_dims={1}, rhs_contracting_dims={1}
+  %fusion.4 = bf16[16,12,128,1024]{3,2,1,0} fusion(%x), kind=kLoop, calls=%fused_small
+  %custom-call.5 = (bf16[16,1024,768]{2,1,0}, f32[16,12,1,1024]{3,2,1,0}) custom-call(%q, %k, %v), custom_call_target="tpu_custom_call"
+  ROOT %copy.6 = bf16[16,12,1024,64]{3,2,1,0} copy(%o)
+}
+"""
+    assert [(op, name) for op, name, _ in score_block_ops(text, 1024)] \
+        == [("convolution", "convolution.1"), ("fusion", "fusion.2"),
+            ("dot", "dot.3")]
+
+
+@pytest.mark.parametrize("impl,clean", [("authored", True), ("xla", False)])
+def test_attention_layer_keeps_its_scores_off_hbm_on_v5e(chip, monkeypatch,
+                                                         impl, clean):
+    """One attention layer's forward + backward at the training cell's
+    shape, through ``flash_attention_fn`` with the arm forced: under the
+    Pallas arm no instruction outside the custom calls gives a block of
+    scores; under the XLA arm the reader finds the blocks it unrolls."""
+    from paddle_tpu.framework.flags import flag_value, set_flags
+    from paddle_tpu.kernels.flash_attention import flash_attention_fn
+    from paddle_tpu.kernels.pallas import _compat
+    was = flag_value("tpu_flash_impl")
+    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
+    set_flags({"tpu_flash_impl": impl})
+    try:
+        q = jax.ShapeDtypeStruct((16, 1024, 12, 64), BF16, sharding=chip)
+        step = jax.grad(_sq(flash_attention_fn(causal=True)),
+                        argnums=(0, 1, 2))
+        text = jax.jit(step).lower(q, q, q).compile().as_text()
+    finally:
+        set_flags({"tpu_flash_impl": was})
+    blocks = score_block_ops(text, 1024)
+    if clean:
+        assert text.count("tpu_custom_call") >= 2       # forward, backward
+        assert blocks == []
+    else:
+        assert "tpu_custom_call" not in text and blocks
 
 
 LAYERS = 12             # of the stored pool the kernels are handed
