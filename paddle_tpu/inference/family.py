@@ -6,8 +6,8 @@ not its business. A model object hands it one :class:`ModelFamily` through
 where the parameters are, and the kinds and shapes of state a sequence
 keeps. There is no flag and no `EngineConfig` field that picks a model —
 the model object decides. `models/gpt.py`, `models/phi4flash.py`,
-`models/granitemoehybrid.py`, `models/brumby.py` and `models/dots3note.py`
-each supply one; the GPT
+`models/granitemoehybrid.py`, `models/brumby.py`, `models/dots3note.py`
+and `models/gigachat35.py` each supply one; the GPT
 family describes exactly what the engine used to import, so its programs
 trace as before.
 
@@ -55,7 +55,14 @@ is empty (``[0, 1, page, 0]``). ``kv_heads`` and ``head_dim`` then describe
 nothing and are left at 1 and the first part's width. The pool's sizing,
 ``engine.kv_bytes_per_token`` and ``engine.cache_bytes.paged`` count what
 the parts hold, and each part's bytes are the gauge
-``engine.cache_bytes.paged.<name>`` (inference/cache.py).
+``engine.cache_bytes.paged.<name>`` (inference/cache.py). The fields
+compose: a family may declare ``page_rows`` of ONE part (a latent row, no
+second pool), ``state`` of several ``recurrent`` arrays a layer and
+``step_counts`` together (`models/gigachat35.py`); a prefill step then
+returns its logits, the two pools (the second the engine's empty one,
+passed through), the state arrays in ``state(...)``'s order, and the counts
+last, which is the order `cache.py::after_prefill` and
+`programs.py::prefill_program` take them in.
 
 A step may hand back COUNTS with its tokens. A family with ``step_counts``
 = n > 0 (sparse experts: which held expert took how many tokens) has its

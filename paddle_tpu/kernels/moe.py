@@ -14,7 +14,8 @@ assignment to a held expert is computed, which `incubate/moe.py`'s
 capacity-padded dispatch does not promise and a served model needs.
 
 An expert is gated: ``[u | v] = x W1_e`` (the FIRST half is activated),
-``(silu(u) * v) W2_e``. Weights arrive as the held experts' stacks, ``w1
+``(silu(u) * v) W2_e``; with ``limit`` (a published ``swiglu_limit``) the
+halves are clamped first, ``u`` from above and ``v`` both ways. Weights arrive as the held experts' stacks, ``w1
 [hi - lo, d, 2 f]`` and ``w2 [hi - lo, f, d]``.
 
 Two arms (registered as ``moe_experts``; counted per program build in
@@ -50,6 +51,15 @@ Both thresholds lie BETWEEN measured points and no reading lies near either:
 ``experts / top_k`` was read at 7.2 and 32 and nowhere between, tokens a
 call at 24, 64 (Granite's ratio alone) and 512. A configuration that falls
 between them wants a reading of its own before it trusts the order here.
+One such reading (my chip run, PR 42): 16 held of 256 at 8 a token and a
+width of 2,048 over a hidden size of 7,168, a layer, ``grouped`` against
+``dense``: 1.15 / 1.95 ms at 24 tokens, 4.01 / 1.95 at 64, 2.99 / 1.97 at
+96, 4.65 / 1.97 at 128 (a decode step of that family: ``dense`` is taken,
+and is right), 5.33 / 4.24 at 512. ``dense`` reads its 16 experts once
+(1.41 GB, 1.72 ms at the memory's rate) whatever the tokens up to 128;
+``grouped`` sorts 8 rows a token and its ragged products walk every row
+of the buffer, so it loses from 64 tokens on: for THIS share the cut lies
+under 64, between 24 and 64, and no cell runs there.
 
 A router scores with a softmax over the chosen logits (``scoring``
 ``"softmax"``) or with a sigmoid (``"sigmoid"``: scores ``sigmoid(logits)``,
@@ -73,7 +83,8 @@ from paddle_tpu.kernels import registry
 __all__ = ["routed_experts", "route"]
 
 GROUPED_FROM = 16      # ``experts / top_k`` from which (read: 7.2, 32), and
-GROUPED_UP_TO = 64     # tokens a call up to which (read: 24, 512),
+GROUPED_UP_TO = 64     # tokens a call up to which (read: 24, 512; and with
+#                        16 held, where 64 already loses: docstring),
 #                        ``grouped`` is first
 
 
@@ -110,24 +121,28 @@ def route(x, w_router, top_k, scoring="softmax", bias=None, scale=1.0):
         top / jnp.sum(top, axis=-1, keepdims=True) * scale
 
 
-def _gated(h, gate):
-    """[u | v] in f32 -> silu(u) * v, times the assignment's gate."""
+def _gated(h, gate, limit=None):
+    """[u | v] in f32 -> silu(u) * v, times the assignment's gate; with
+    ``limit`` the activated half is clamped from above and the linear half
+    both ways first."""
     u, v = jnp.split(h, 2, axis=-1)
+    if limit is not None:
+        u, v = jnp.minimum(u, limit), jnp.clip(v, -limit, limit)
     return u * jax.nn.sigmoid(u) * v * gate
 
 
-def _dense(x, w1, w2, idx, gates, lo):
+def _dense(x, w1, w2, idx, gates, lo, limit=None):
     held = w1.shape[0]
     chosen = idx[:, :, None] == lo + jnp.arange(held)          # [T, K, E]
     gate = jnp.sum(jnp.where(chosen, gates[:, :, None], 0.0), axis=1)
     h = jnp.einsum("td,edf->tef", x, w1,
                    preferred_element_type=jnp.float32)         # [T, E, 2f]
-    act = _gated(h, gate[:, :, None]).astype(x.dtype)
+    act = _gated(h, gate[:, :, None], limit).astype(x.dtype)
     return jnp.einsum("tef,efd->td", act, w2,
                       preferred_element_type=jnp.float32)
 
 
-def _grouped(x, w1, w2, idx, gates, lo, valid=None):
+def _grouped(x, w1, w2, idx, gates, lo, valid=None, limit=None):
     t, k = idx.shape
     held = w1.shape[0]
     e = idx.reshape(-1) - lo
@@ -141,7 +156,7 @@ def _grouped(x, w1, w2, idx, gates, lo, valid=None):
     gate = jnp.where(on, gates.reshape(-1), 0.0)[order]
     h = jax.lax.ragged_dot(x[order // k], w1, sizes,
                            preferred_element_type=jnp.float32)
-    act = _gated(h, gate[:, None]).astype(x.dtype)
+    act = _gated(h, gate[:, None], limit).astype(x.dtype)
     y = jax.lax.ragged_dot(act, w2, sizes,
                            preferred_element_type=jnp.float32)
     # a row past the last group is no expert's: whatever the product left
@@ -154,14 +169,16 @@ def _grouped(x, w1, w2, idx, gates, lo, valid=None):
 
 def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
                    valid=None, scoring="softmax", bias=None, scale=1.0,
-                   impl=None):
+                   limit=None, impl=None):
     """This chip's part of a routed-expert layer for tokens ``x`` [T, d].
 
     w_router : [d, E] over ALL experts; w1 : [hi - lo, d, 2 f]; w2 :
     [hi - lo, f, d] of the held experts ``held`` = (lo, hi); top_k : experts
     a token; valid : [T] bool, the tokens ``counts`` counts (None: all; what
     the others get is unspecified: zero from ``grouped``);
-    scoring, bias [E], scale : the router's gates (`route`); impl : an arm
+    scoring, bias [E], scale : the router's gates (`route`); limit : the
+    gated MLP's clamp (``swiglu_limit``: ``min(u, limit)``, ``clip(v, -limit,
+    limit)``; None: none); impl : an arm
     by name (None: the registry's). Returns ``y`` [T, d] in ``x``'s type, or
     ``(y, counts)`` when ``counts`` came.
     """
@@ -175,8 +192,8 @@ def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
         experts=w_router.shape[1], top_k=top_k, held=hi - lo,
         tokens=x.shape[0]))
     idx, gates = route(x, w_router, top_k, scoring, bias, scale)
-    y = _dense(x, w1, w2, idx, gates, lo) if arm == "dense" \
-        else _grouped(x, w1, w2, idx, gates, lo, valid)
+    y = _dense(x, w1, w2, idx, gates, lo, limit) if arm == "dense" \
+        else _grouped(x, w1, w2, idx, gates, lo, valid, limit)
     y = y.astype(x.dtype)
     if counts is None:
         return y

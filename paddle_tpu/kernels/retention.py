@@ -49,7 +49,11 @@ and the chunk's closing state written back. A padded token carries ``log g
 
 ``rotary`` is the half-rotation rotary embedding at absolute positions
 (``kernel.dispatch.rotary.{xla|pallas}``; the pallas arm is
-`kernels/pallas/rotary.py`).
+`kernels/pallas/rotary.py`). ``rotary_pairs`` is the INTERLEAVED form
+(element ``2 i`` pairs with ``2 i + 1``) at given frequencies, and
+``yarn_inv_freq`` / ``yarn_mscale`` are YaRN's per-dimension frequencies
+and attention scale (arXiv:2309.00071, as DeepSeek-V3's public modelling
+code computes them).
 """
 from __future__ import annotations
 
@@ -61,8 +65,9 @@ import jax.numpy as jnp
 
 from paddle_tpu.kernels import registry
 
-__all__ = ["retention_update", "retention_chunk", "rotary", "phi",
-           "diagonals", "state_shapes"]
+__all__ = ["retention_update", "retention_chunk", "rotary", "rotary_pairs",
+           "yarn_inv_freq", "yarn_mscale", "phi", "diagonals",
+           "state_shapes"]
 
 
 def _tpu_first(ctx):
@@ -382,3 +387,42 @@ def rotary(x, positions, theta, *, impl=None, interpret=None):
     x1, x2 = x[..., :half], x[..., half:]
     c, s = cos[:, None], sin[:, None]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+
+
+def yarn_inv_freq(dim, theta, factor, beta_fast, beta_slow, original_max):
+    """YaRN's ``dim / 2`` rotary frequencies: a dimension that turns more
+    than ``beta_fast`` times over the original context keeps its frequency,
+    one that turns fewer than ``beta_slow`` times has it divided by
+    ``factor``, a linear ramp between. A numpy float32 vector: a constant
+    of the program."""
+    import numpy as np
+
+    def turns_dim(turns):
+        return dim * math.log(original_max / (turns * 2 * math.pi)) \
+            / (2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    plain = 1.0 / theta ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (plain / factor * ramp + plain * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor, mscale=1.0):
+    """YaRN's attention scale ``0.1 mscale ln(factor) + 1`` (1 at a factor
+    of at most 1)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary_pairs(x, positions, inv_freq):
+    """Interleaved rotary embedding: ``x`` [T, heads, hd] at absolute
+    ``positions`` [T]; elements ``2 i`` and ``2 i + 1`` turn by
+    ``positions * inv_freq[i]``. Returns float32, the pairs where they
+    were."""
+    registry.count("rotary", "xla")
+    t, n, hd = x.shape
+    ang = positions.astype(jnp.float32)[:, None] * jnp.asarray(inv_freq)[None]
+    c, s = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]          # [T, 1, hd/2]
+    x = x.astype(jnp.float32).reshape(t, n, hd // 2, 2)
+    a, b = x[..., 0], x[..., 1]
+    return jnp.stack([a * c - b * s, b * c + a * s], axis=-1).reshape(t, n, hd)

@@ -821,6 +821,134 @@ def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
         print(program, name, found)
 
 
+# GigaChat3.5-432B-A28B as benchmarks/configs/gigachat3.5-432b-a28b.json
+# serves it: one chip's share of sixteen (published layers 2-6, 16 of 256
+# experts, 16,032 rows)
+def test_deltanet_update_kernel_compiles_for_v5e(chip):
+    """The delta rule's decode update alone at the published widths: 64
+    value heads of 128 x 128, the state stack 4 x 128 x 64 x 128 x 128
+    float32 (2.15 GB) at a traced layer, aliased; two transposes of a
+    broadcast key and two sublane sums a head inside."""
+    from paddle_tpu.kernels import deltanet
+    f32 = jnp.float32
+
+    def spec(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    b, h, d = 128, 64, 128
+    compiled = jax.jit(
+        lambda s, g, beta, q, k, v, act, lyr: deltanet.deltanet_update(
+            s, g, beta, q, k, v, act, layer=lyr, impl="pallas",
+            interpret=False), donate_argnums=(0,)).lower(
+        spec(deltanet.state_shape(4, b, h, d, d)), spec((b, h)),
+        spec((b, h)), spec((b, h, d)), spec((b, h, d)), spec((b, h, d)),
+        spec((b,), jnp.bool_), spec((), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= 4 * 4 * b * h * d * d
+    assert mem.temp_size_in_bytes < 0.1e9
+
+
+@pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
+def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
+        chip, program, monkeypatch):
+    """GigaChat3.5's decode step and one prefill chunk of 512, whole, at
+    the published widths with the chip's share, compiled for the described
+    chip with the arms a TPU run takes, the latent pool and the recurrent
+    state donated and the counts behind the token chain: everything
+    donated is aliased (no copy of the 2.15 GB state stack), the decode
+    step updates the state inside a kernel a linear layer, the program fits
+    the chip beside its 12.3 GB of arguments, and the op families by which
+    the cell's kernel shares find these kernels in a device trace are in
+    the program that makes them."""
+    import json
+    import re
+    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels.pallas import _compat
+    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
+    from paddle_tpu.inference.cache import DeviceCache
+    from paddle_tpu.inference.programs import (decode_program,
+                                               prefill_program,
+                                               prefill_upload, step_upload)
+    from paddle_tpu.models import gigachat35 as gm
+    bench = os.path.join(os.path.dirname(__file__), "..", "benchmarks")
+    sys.path.insert(0, bench)
+    from harness import giga_bytes, spec as harness_spec, trace
+    with open(os.path.join(bench, "configs",
+                           "gigachat3.5-432b-a28b.json")) as f:
+        cfgj = json.load(f)
+    cfg = harness_spec._module("runners", "serve_giga").model_config(cfgj)
+    assert sum(int(np.prod(s)) for s in gm.leaf_shapes(cfg).values()) \
+        == cfgj["assumed"]["parameters"] == 4731722752
+    sv = cfgj["serve"]
+    slots, page, pages = sv["max_slots"], sv["page_size"], sv["num_pages"]
+    per_slot = sv["max_seq_len"] // page
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=chip)
+    params = {k: sds(s, BF16) for k, s in gm.leaf_shapes(cfg).items()}
+    lat = sds((1, pages, page, cfg.latent_width), BF16)
+    specs = gm.state_arrays(cfg, slots, page, BF16)
+    cache = DeviceCache(k=lat, v=sds((0, 1, page, 0), BF16), k_scale=None,
+                        v_scale=None,
+                        state=tuple(sds(s, d) for _, _, s, d in specs),
+                        keys=None, heads=1)
+    n = gm.step_counts(cfg)
+    if program == "decode_step":
+        up = step_upload(slots, per_slot, sampling=False)
+        step = decode_program(gm, cfg, up, n)
+    else:
+        up = prefill_upload(sv["prefill_chunk_tokens"], per_slot,
+                            sampling=False, chunk=True)
+        step = prefill_program(gm, cfg, up, n)
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        params, cache, sds((slots + n,), jnp.int32),
+        up.spec(sharding=chip)).compile()
+    text = compiled.as_text()
+    kernels = text.count("custom_call_target=\"tpu_custom_call\"")
+    assert kernels == (len(cfg.linear_layers) if program == "decode_step"
+                       else 0)
+    # no rematerialized instruction reads a donated array: short of memory
+    # at 128 slots the compiler rematerializes, and a clone of an in-place
+    # update that reads what it replaces ran TWICE on the chip (the
+    # convolution's state as a stack: kernels/deltanet.py)
+    clones = [ln.strip()[:160] for ln in text.splitlines()
+              if re.match(r"\s*%[\w.\-]*remat[\w.\-]* = ", ln)
+              and "%cache_" in ln]
+    assert clones == [], clones
+    mem = compiled.memory_analysis()
+    donated = 2 * int(np.prod(lat.shape)) \
+        + 4 * sum(int(np.prod(s)) for _, _, s, _ in specs)
+    assert mem.alias_size_in_bytes >= donated
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.0e9
+    assert mem.temp_size_in_bytes < 0.4e9
+    shapes = giga_bytes.trace_shapes(cfgj)
+    families = {trace.family(ln.strip().removeprefix("ROOT "))
+                for ln in text.splitlines() if " = " in ln}
+    made_by = {"decode_step": ("deltanet_update", "latent_paged_attn",
+                               "giga_experts"),
+               "prefill_chunk_step": ("deltanet_chunk", "latent_paged_attn",
+                                      "giga_experts")}
+    for name in made_by[program]:
+        metric = harness_spec.layer_metric(f"{name}_roofline_share")
+        found = [bool([f for f in families
+                       if re.search(p.format(**shapes), f)])
+                 for p in metric["patterns"]]
+        # a kernel's patterns name both arms' ops, a chunk's and a decode
+        # step's: some of them are in each program
+        assert any(found), (name, metric["patterns"], found)
+        print(program, name, found)
+    other = "deltanet_chunk" if program == "decode_step" \
+        else "deltanet_update"
+    # the decode update's own patterns match nothing a chunk makes (the
+    # chunk's closing state is the chunk metric's)
+    if other == "deltanet_update":
+        metric = harness_spec.layer_metric(f"{other}_roofline_share")
+        for p in metric["patterns"]:
+            assert not [f for f in families
+                        if re.search(p.format(**shapes), f)], p
+
+
 # Brumby-14B-Base as benchmarks/configs/brumby-14b-base.json serves it: one
 # pipeline stage of five (8 layers, the embedding and the head)
 BRUMBY = dict(slots=16, page=16, chunk=512, layers=8)
