@@ -566,6 +566,23 @@ def phase_kernels(sz, seed, dev):
                                       (q, k, cos, sin), on_tpu, fails)
             want = jax.jit(rope_ref)(q, k, cos, sin)
         compare(name, "pallas", got, want, secs, BF16_KERNEL_TOL)
+    # the fused cross-entropy head's forward kernel (logsumexp and the
+    # label's logit a row, taken from tiles in VMEM) against XLA's body
+    from paddle_tpu.kernels import fused_ce
+    from paddle_tpu.kernels.pallas import fused_ce as ce_kernel
+    n, hid, v = (-(-x // 128) * 128 for x in (sz.ln_rows, sz.hidden,
+                                                sz.vocab))
+    h, w = rand(n, hid), rand(v, hid) * 0.05
+    lab = jnp.asarray(rng.randint(0, v, n), jnp.int32)
+    plan = ce_kernel._plan(n, hid, v)
+    name = f"fused_ce_fwd {n}x{hid}x{v}"
+    with x64_off_scope():
+        got, secs = _run_compiled(
+            name, lambda *a: ce_kernel.forward(
+                *a, plan=plan, interpret=not on_tpu)[1:], (h, w, lab),
+            on_tpu, fails)
+        want = jax.jit(fused_ce._xla_stats)(h, w, lab)
+    compare(name, "pallas", got, want, secs, 1e-4)
     return {"phase": "kernels", "kernels": rows,
             "peak_bytes_in_use": peak_bytes(dev)}, fails
 

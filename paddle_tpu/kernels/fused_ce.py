@@ -1,14 +1,36 @@
 """Fused LM-head + softmax cross-entropy (TPU memory/bandwidth kernel).
 
 Counterpart of the reference's fused ``c_softmax_with_cross_entropy`` idea
-(`paddle/fluid/operators/collective/c_softmax_with_cross_entropy_op.cc`) but
-designed for XLA: the ``[N, V]`` logits tensor (e.g. 8192 x 50304, ~0.8 GB in
-bf16 and double that in f32) is never materialized in HBM. The vocab dimension
-is processed in chunks under ``lax.scan`` with an online logsumexp; the
-backward pass recomputes each chunk's logits and feeds the two grad matmuls
-directly. Costs one extra LM-head matmul (~10% of model FLOPs) and saves
-~2.5 GB of HBM traffic + residency per step on GPT-2-small at 8x1024 —
-which is what lets the whole model train without full-block remat.
+(`paddle/fluid/operators/collective/c_softmax_with_cross_entropy_op.cc`):
+the loss of ``h @ w.T`` against integer labels as ONE op with a custom VJP,
+so autodiff never sees the ``[N, V]`` logits (8 x 1024 x 50304: 1.6 GB of
+float32) and nothing of a softmax over them is saved for the backward.
+
+One algorithm, a forward with two bodies; which one runs follows from what
+the code can see (`_pallas_plan`: the backend, the shapes, whether the
+program is one GSPMD partitions), no flag:
+
+- the Pallas body (`kernels/pallas/fused_ce.py`; a TPU, no multi-device
+  mesh, ``hidden`` and ``V`` multiples of 128, ``N`` a multiple of the row
+  block): one kernel makes the
+  product and takes the row maximum, ``sum(exp)`` and the label's logit
+  from each tile while it is in VMEM. It writes the float32 logits ONCE, as
+  ``[V, N]``, and they are HELD from forward to backward beside the rows'
+  logsumexp: the backward forms ``dlogits`` in bf16 from them straight into
+  its two products (XLA fuses it into their operand). It does not make the
+  product again: the forward is a custom call, XLA has nothing to merge a
+  recompute with, and a real fourth product (6.4 ms at GPT-2 small's
+  16 x 1024) costs more than the pass over the logits that the kernel
+  removes;
+- the XLA body (anything else): the vocabulary in up to four slices, each
+  a product with its own maximum and ``sum(exp)``, merged; the backward
+  recomputes a slice's logits. Inside one compiled program XLA merges that
+  recompute with the forward's product, so there too the logits are held
+  in HBM from forward to backward (3.3 GB of temporaries in the captured
+  GPT-2 small step): "never materialized" is true of autodiff's residuals
+  and of the eager path, not of a compiled step.
+
+Which body a trace took: counters ``kernel.fused_ce.forward.{pallas,xla}``.
 """
 from __future__ import annotations
 
@@ -17,15 +39,36 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.distributed.mesh import get_mesh
+from paddle_tpu.observability import metrics
+
 
 def _pick_chunks(v: int) -> int:
     """Chunk count <= 4 that divides the (padded) vocab. Chunks are UNROLLED
     (python loop) so the per-chunk matmuls stay independent in the graph —
-    lax.scan would serialize them behind the cheap online-logsumexp carry."""
+    lax.scan would serialize them behind the cheap online-logsumexp carry.
+    The XLA body's forward and backward are split by it; the Pallas forward
+    tiles the vocabulary itself and its backward takes the held logits
+    whole."""
     for nc in (4, 3, 2):
         if v % nc == 0 and v // nc >= 4096:
             return nc
     return 1
+
+
+def _pallas_plan(n, hid, v):
+    """The Pallas forward's plan where it fits the call (on a TPU; in the
+    interpreter where a test steers the backend's name), else None: the
+    XLA body runs. Under an installed multi-device mesh
+    the trace becomes a program GSPMD partitions, which a Mosaic kernel
+    cannot join."""
+    from paddle_tpu.kernels import autotune
+    mesh = get_mesh()
+    if autotune._backend_kind() != "tpu" or (mesh is not None
+                                             and mesh.size > 1):
+        return None
+    from paddle_tpu.kernels.pallas import fused_ce as kernel
+    return kernel._plan(n, hid, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=())
@@ -41,13 +84,11 @@ def _chunk_logits(h, w_c):
         (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
 
 
-def _flce_fwd(h, w, labels):
-    n, hid = h.shape
+def _xla_stats(h, w, labels):
+    """(lse, picked logit) a row, the vocabulary in `_pick_chunks` slices."""
     v = w.shape[0]
     nc = _pick_chunks(v)
     vc = v // nc
-    labels = labels.astype(jnp.int32)
-
     # independent per-chunk (max, sumexp-at-own-max, picked-logit) ...
     ms, ls, picks = [], [], []
     for c in range(nc):
@@ -71,19 +112,41 @@ def _flce_fwd(h, w, labels):
     picked = picks[0]
     for pk in picks[1:]:
         picked = jnp.maximum(picked, pk)
-    lse = m + jnp.log(l)
+    return m + jnp.log(l), picked
+
+
+def _flce_fwd(h, w, labels):
+    n, hid = h.shape
+    v = w.shape[0]
+    labels = labels.astype(jnp.int32)
+    plan = _pallas_plan(n, hid, v)
+    metrics.counter(
+        f"kernel.fused_ce.forward.{'pallas' if plan else 'xla'}").inc()
+    if plan:
+        from paddle_tpu.kernels.pallas import _compat, fused_ce as kernel
+        held, lse, picked = kernel.forward(
+            h, w, labels, plan=plan, interpret=_compat.default_interpret())
+    else:
+        held = None
+        lse, picked = _xla_stats(h, w, labels)
     # out-of-range labels (e.g. the conventional -100 padding / ignore_index)
     # contribute zero loss and zero gradient, matching F.cross_entropy
     valid = (labels >= 0) & (labels < v)
     loss = jnp.where(valid, lse - picked, 0.0)
-    return loss, (h, w, labels, lse)
+    return loss, (h, w, labels, lse, held)
 
 
 def _flce_bwd(res, dloss):
-    h, w, labels, lse = res
+    # held: the Pallas forward's float32 logits [V, N], or None (the XLA
+    # body: a slice's logits are made again, which a compiled program
+    # merges with the forward's product)
+    h, w, labels, lse, held = res
     n, hid = h.shape
     v = w.shape[0]
-    nc = _pick_chunks(v)
+    # held logits leave nothing to keep independent: one slice (at GPT-2
+    # small's 16 x 1024 no slower than four, and the step's temporaries
+    # are 0.13 GB less: the partial sums of dh have no freed slice to lie in)
+    nc = _pick_chunks(v) if held is None else 1
     vc = v // nc
     valid = (labels >= 0) & (labels < v)
     dl = dloss.astype(jnp.float32) * valid.astype(jnp.float32)
@@ -92,7 +155,8 @@ def _flce_bwd(res, dloss):
     dws = []
     for c in range(nc):
         w_c = w[c * vc:(c + 1) * vc]
-        logits = _chunk_logits(h, w_c)                      # recompute [N, vc]
+        logits = (_chunk_logits(h, w_c) if held is None
+                  else held[c * vc:(c + 1) * vc].T)         # [N, vc]
         p = jnp.exp(logits - lse[:, None])                  # softmax chunk
         idx = labels - c * vc
         in_chunk = (idx >= 0) & (idx < vc)

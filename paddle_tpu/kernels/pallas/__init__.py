@@ -19,6 +19,10 @@ Kernels:
   bounded by the request's true uncached tail — chunked prefill, prefix
   tails, and the PTKS1 prefill-worker stream all ride it
   (`FLAGS_tpu_prefill_impl`, selection in `kernels/registry.py`).
+- :mod:`fused_ce` — forward of the fused LM-head + cross-entropy: one
+  product whose tiles give their row maximum, ``sum(exp)`` and label's
+  logit while in VMEM (≈ `c_softmax_with_cross_entropy_op.cu`; the custom
+  VJP and the selection live in `kernels/fused_ce.py`).
 - :mod:`fused_layernorm` — single-pass layernorm fwd + analytic bwd
   (≈ `fused_layernorm` kernels in `phi/kernels/fusion/`).
 - :mod:`rotary` — fused rotary position embedding

@@ -59,7 +59,7 @@ class GPTConfig:
                                      # measured speed LOSSES on the
                                      # bandwidth-bound single-chip step
                                      # (PERF.md r5), so default "full"
-    fused_ce: bool = True            # chunked lm-head+CE, no [N,V] logits in HBM
+    fused_ce: bool = True            # lm-head+CE as one op (kernels/fused_ce.py)
 
 
 # cache-priming sentinel: generate()'s first step passes this instead of
@@ -538,9 +538,10 @@ def verify_step(params, tok_seq, draft_len, cache, slot_mask, *, cfg,
 
 def _fused_ce_impl(cfg) -> str:
     """Registry-routed LM-head CE selection (`kernels/registry.py`,
-    op ``fused_ce``): "fused" = chunked-vocab fused_linear_cross_entropy
-    (never materializes the [N, V] logits), "dense" = logits +
-    log-softmax. The fused arm is viable only without an mp axis (the
+    op ``fused_ce``): "fused" = fused_linear_cross_entropy (one op with a
+    custom VJP, autodiff never sees the [N, V] logits; its forward is a
+    Pallas kernel where the shapes fit, `kernels/fused_ce.py`), "dense" =
+    logits + log-softmax. The fused arm is viable only without an mp axis (the
     vocab is sharded under mp and only the parallel CE is correct);
     ``cfg.fused_ce=False`` forces dense. Counted per trace in
     ``kernel.dispatch.fused_ce.{fused|dense}``."""

@@ -107,6 +107,91 @@ class TestFusedCE:
         np.testing.assert_array_equal(np.asarray(dh[1]), 0.0)
 
 
+    # (N, hidden, V) -> the forward's body: the Pallas kernel where `_plan`
+    # fits the shapes (several row blocks and vocabulary tiles at these
+    # sizes), XLA's where the vocabulary is no multiple of a tile or the
+    # rows of a block
+    BODIES = {"3x5_tiles": ((384, 128, 640), "pallas"),
+              "1x2_tiles": ((256, 128, 1024), "pallas"),
+              "ragged_vocabulary": ((384, 128, 600), "xla"),
+              "ragged_rows": ((200, 128, 640), "xla")}
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                             ids=["f32", "bf16"])
+    @pytest.mark.parametrize("case", BODIES)
+    def test_forward_bodies_agree(self, monkeypatch, case, dtype):
+        """On a TPU's name the forward is the Pallas kernel (here in the
+        interpreter) wherever the plan fits, else XLA's body; either way
+        loss, dh and dW are the reference's and the other body's, and the
+        trace counts the body it took, once."""
+        from paddle_tpu.kernels import autotune
+        from paddle_tpu.kernels.fused_ce import fused_linear_cross_entropy
+        from paddle_tpu.kernels.pallas import fused_ce as kernel
+        from paddle_tpu.observability import metrics
+        (n, hid, v), body = self.BODIES[case]
+        plan = kernel._plan(n, hid, v)
+        assert (plan is not None) == (body == "pallas")
+        rng = np.random.RandomState(1)
+        h = jnp.asarray(rng.randn(n, hid), dtype)
+        w = jnp.asarray(rng.randn(v, hid) * 0.1, dtype)
+        lab = rng.randint(0, v, n)
+        tile = plan.tile_v if plan else 128
+        # ignored rows, a label in the last tile (its last column), one on
+        # a tile's first column
+        lab[[3, n - 1]], lab[5], lab[6] = -100, v - 1, tile
+        lab = jnp.asarray(lab, jnp.int32)
+
+        def head(h, w):
+            loss = fused_linear_cross_entropy(h, w, lab)
+            return loss.sum() / (n - 2), loss
+
+        step = lambda: jax.jit(jax.value_and_grad(  # noqa: E731
+            head, argnums=(0, 1), has_aux=True))(h, w)
+        count = lambda b: metrics.counter(  # noqa: E731
+            f"kernel.fused_ce.forward.{b}").value
+        was = {b: count(b) for b in ("pallas", "xla")}
+        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        (_, loss), (dh, dw) = step()
+        other = "xla" if body == "pallas" else "pallas"
+        assert count(body) == was[body] + 1 and count(other) == was[other]
+        monkeypatch.undo()
+        (_, loss_x), (dh_x, dw_x) = step()
+        assert count("xla") == was["xla"] + 1 + (body == "xla")
+
+        f32 = lambda x: np.asarray(x.astype(jnp.float32))  # noqa: E731
+        assert f32(loss)[3] == 0.0 and f32(loss)[n - 1] == 0.0
+        assert not f32(dh)[3].any()
+        np.testing.assert_allclose(f32(loss), f32(loss_x), rtol=0, atol=2e-6)
+        ref, (rdh, rdw) = jax.value_and_grad(
+            lambda h, w: self._ref(h, w, lab).sum() / (n - 2),
+            argnums=(0, 1))(h.astype(jnp.float32), w.astype(jnp.float32))
+        np.testing.assert_allclose(f32(loss).sum() / (n - 2), float(ref),
+                                   rtol=2e-3)
+        # dlogits is rounded to bf16 by both bodies, and the results to
+        # the inputs' dtype
+        near = 1e-4 if dtype == jnp.float32 else 1e-2
+        for got, xla, want in ((dh, dh_x, rdh), (dw, dw_x, rdw)):
+            scale = float(np.abs(f32(want)).max())
+            np.testing.assert_allclose(f32(got), f32(xla), rtol=0,
+                                       atol=near * scale)
+            np.testing.assert_allclose(f32(got), f32(want), rtol=0,
+                                       atol=3e-2 * scale)
+
+    def test_partitioned_program_takes_xla_body(self, monkeypatch):
+        """Under an installed multi-device mesh the trace is a program
+        GSPMD partitions; a Mosaic kernel cannot join it."""
+        import types
+        from paddle_tpu.kernels import autotune, fused_ce
+        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        assert fused_ce._pallas_plan(384, 128, 640) is not None
+        monkeypatch.setattr(fused_ce, "get_mesh",
+                            lambda: types.SimpleNamespace(size=1))
+        assert fused_ce._pallas_plan(384, 128, 640) is not None
+        monkeypatch.setattr(fused_ce, "get_mesh",
+                            lambda: types.SimpleNamespace(size=4))
+        assert fused_ce._pallas_plan(384, 128, 640) is None
+
+
 class TestFusedOptimizerStateRetention:
     def test_freeze_unfreeze_keeps_moments(self):
         """Changing the grad-bearing param set must spill+reseed flat state,

@@ -51,6 +51,21 @@ def _by_parent(spans):
     return out
 
 
+@pytest.fixture(autouse=True, scope="module")
+def cache_as_a_fresh_process_has_it():
+    """An entry point's ``main`` run inside the worker by an earlier file
+    (``router.main`` in tests/test_router.py) leaves JAX's persistent
+    cache on for the rest of the process; these tests read ``cache: off``
+    of one in which nothing has enabled it."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_compilation_cache_dir", was)
+    cc.reset_cache()
+
+
 @pytest.fixture
 def every_event(monkeypatch):
     """A compile on the CPU at a test's size can take under a millisecond:

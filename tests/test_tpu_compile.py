@@ -172,6 +172,132 @@ def test_attention_layer_keeps_its_scores_off_hbm_on_v5e(chip, monkeypatch,
         assert "tpu_custom_call" not in text and blocks
 
 
+# the training cell's head: rows, hidden, vocabulary (gpt2s-train-b16s1024)
+HEAD = (16 * 1024, 768, 50304)
+
+
+def logit_reduce_ops(hlo_text, rows, cols):
+    """``[(name, operand)]`` of every fusion of the optimized HLO's ENTRY
+    computation that holds a ``reduce`` and takes a float32 operand of
+    ``rows`` by ``cols`` or more, either way round: a pass over a block of
+    logits in HBM for a row statistic. A product that gives the row
+    maximum as a second result reads ``h`` and ``W`` and not logits; the
+    backward's products read logits and hold no ``reduce``."""
+    import re
+    bodies = dict(re.findall(r"^%([\w.\-]+) \(.*?\{$(.*?)^\}", hlo_text,
+                             re.M | re.S))
+    found = []
+    for ln in hlo_text[hlo_text.index("ENTRY "):].splitlines():
+        m = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = .+? fusion\(.*"
+                     r"calls=%([\w.\-]+)", ln)
+        if not m or " reduce(" not in bodies[m.group(2)]:
+            continue
+        for shape, a, b in re.findall(r"= (f32\[(\d+),(\d+)\])\S* parameter\(",
+                                      bodies[m.group(2)]):
+            a, b = int(a), int(b)
+            if (a == rows and b >= cols) or (b == rows and a >= cols):
+                found.append((m.group(1), shape))
+                break
+    return found
+
+
+def test_logit_reduce_ops_sees_a_pass_over_logits():
+    """The reader the next test rests on, on a hand-written module: the
+    sum of exponentials over a held block of logits counts, whichever way
+    round the block lies; the product with the row maximum beside it, a
+    product fed from the logits and a reduction of something narrower do
+    not."""
+    text = """\
+%fused_sum (p0: f32[16384,12576], p1: f32[16384]) -> f32[16384] {
+  %p0 = f32[16384,12576]{0,1:T(8,128)} parameter(0)
+  %p1 = f32[16384]{0:T(1024)} parameter(1)
+  %e = f32[16384,12576]{0,1:T(8,128)} exponential(%p0)
+  ROOT %r = f32[16384]{0:T(1024)} reduce(%e, %c), dimensions={1}, to_apply=%add
+}
+%fused_sum_t (p0: f32[50304,16384]) -> f32[16384] {
+  %p0 = f32[50304,16384]{1,0:T(8,128)} parameter(0)
+  ROOT %r = f32[16384]{0:T(1024)} reduce(%p0, %c), dimensions={0}, to_apply=%add
+}
+%fused_product (p0: bf16[16384,768], p1: bf16[50304,768]) -> (f32[16384], f32[16384,12576]) {
+  %p0 = bf16[16384,768]{1,0:T(8,128)(2,1)} parameter(0)
+  %p1 = bf16[50304,768]{1,0:T(8,128)(2,1)} parameter(1)
+  %conv = f32[16384,12576]{0,1:T(8,128)} convolution(%p0, %s), dim_labels=bf_oi->bf
+  %m = f32[16384]{0:T(1024)} reduce(%conv, %c), dimensions={1}, to_apply=%max
+  ROOT %t = (f32[16384]{0:T(1024)}, f32[16384,12576]{0,1:T(8,128)}) tuple(%m, %conv)
+}
+%fused_grad (p0: f32[16384,12576], p1: bf16[16384,768]) -> bf16[12576,768] {
+  %p0 = f32[16384,12576]{0,1:T(8,128)} parameter(0)
+  %p1 = bf16[16384,768]{1,0:T(8,128)(2,1)} parameter(1)
+  ROOT %conv = bf16[12576,768]{1,0:T(8,128)(2,1)} convolution(%d, %p1), dim_labels=fb_io->bf
+}
+%fused_narrow (p0: f32[16384,768]) -> f32[16384] {
+  %p0 = f32[16384,768]{1,0:T(8,128)} parameter(0)
+  ROOT %r = f32[16384]{0:T(1024)} reduce(%p0, %c), dimensions={1}, to_apply=%add
+}
+ENTRY %main (h: bf16[16384,768], w: bf16[50304,768]) -> f32[] {
+  %fusion.1 = (f32[16384]{0:T(1024)}, f32[16384,12576]{0,1:T(8,128)}) fusion(%h, %w), kind=kOutput, calls=%fused_product
+  %exponential_reduce_fusion = f32[16384]{0:T(1024)} fusion(%gte.1, %gte.0), kind=kLoop, calls=%fused_sum
+  %reduce_fusion.2 = f32[16384]{0:T(1024)} fusion(%custom-call.3), kind=kLoop, calls=%fused_sum_t
+  %fusion.4 = bf16[12576,768]{1,0:T(8,128)(2,1)} fusion(%gte.1, %h), kind=kOutput, calls=%fused_grad
+  %fusion.5 = f32[16384]{0:T(1024)} fusion(%dh), kind=kLoop, calls=%fused_narrow
+}
+"""
+    assert logit_reduce_ops(text, 16384, 4096) == [
+        ("exponential_reduce_fusion", "f32[16384,12576]"),
+        ("reduce_fusion.2", "f32[50304,16384]")]
+
+
+def test_fused_ce_forward_kernel_compiles_for_v5e(chip):
+    from paddle_tpu.kernels.pallas import fused_ce as kernel
+    n, hid, v = HEAD
+    plan = kernel._plan(n, hid, v)
+    assert plan is not None
+    _compiles_to_a_kernel(
+        lambda h, w, lab: kernel.forward(h, w, lab, plan=plan,
+                                         interpret=False),
+        jax.ShapeDtypeStruct((n, hid), BF16, sharding=chip),
+        jax.ShapeDtypeStruct((v, hid), BF16, sharding=chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip))
+
+
+@pytest.mark.parametrize("body", ["pallas", "xla"])
+def test_head_takes_its_statistics_in_the_kernel_on_v5e(chip, monkeypatch,
+                                                        body):
+    """The head's forward + backward at the training cell's shape. Under
+    the Pallas body: one custom call and the backward's two products (a
+    third would be the forward's made again), no pass over the logits
+    for a statistic, nothing but the kernel gives a result as large as a
+    slice of them (no copy or transpose in front of the backward), and
+    the logits are held once. Under XLA's body, which the CPU's name
+    selects: twelve products (four slices of the vocabulary), four
+    passes."""
+    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels.fused_ce import fused_linear_cross_entropy
+    from paddle_tpu.kernels.pallas import _compat
+    if body == "pallas":
+        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        monkeypatch.setattr(_compat, "default_interpret", lambda: False)
+    n, hid, v = HEAD
+    compiled = jax.jit(jax.value_and_grad(
+        lambda h, w, lab: fused_linear_cross_entropy(h, w, lab).mean(),
+        argnums=(0, 1))).lower(
+        jax.ShapeDtypeStruct((n, hid), BF16, sharding=chip),
+        jax.ShapeDtypeStruct((v, hid), BF16, sharding=chip),
+        jax.ShapeDtypeStruct((n,), jnp.int32, sharding=chip)).compile()
+    text = compiled.as_text()
+    products = text.count(" convolution(")
+    kernels = text.count('custom_call_target="tpu_custom_call"')
+    passes = logit_reduce_ops(text, n, 4096)
+    slice_sized = pool_sized_ops(text, n * (v // 4))
+    assert compiled.memory_analysis().temp_size_in_bytes <= 3.4e9
+    if body == "pallas":
+        assert (products, kernels) == (2, 1)
+        assert passes == [] and slice_sized == []
+    else:
+        assert (products, kernels) == (12, 0)
+        assert len(passes) == 4 and len(slice_sized) == 4
+
+
 LAYERS = 12             # of the stored pool the kernels are handed
 
 
@@ -1143,7 +1269,7 @@ def test_chip_smoke_kernels_phase_at_toy_size(smoke, toy):
     names = {row["kernel"].split()[0] for row in rec["kernels"]}
     assert names == {"flash_fwd", "flash_bwd", "paged", "paged_int8",
                      "prefill", "prefill_int8", "layernorm_fwd",
-                     "layernorm_bwd", "rope"}
+                     "layernorm_bwd", "rope", "fused_ce_fwd"}
 
 
 def test_chip_smoke_four_chip_phase_on_virtual_devices(smoke, toy):
