@@ -34,7 +34,26 @@ attention:
   queries between them pick nearly every key of a context up to 33,000, so
   every row is read either way, and reading pages whole beats gathering
   each query's own 2,048 rows (21 ns a row on a v5e) up to some 26,000 keys
-  in sight.
+  in sight. One algorithm, two arms (op ``mla_attention``, ``xla`` and
+  ``pallas``); which one a program is built with follows from what the
+  code can see (`_prefill_plan`: the backend, the mesh, the shapes), no
+  flag and nothing timed:
+
+  - the Pallas arm (`kernels/pallas/latent_prefill.py`; a TPU, no
+    multi-device mesh, ``rank``, ``dn``, ``dv`` and the chunk length
+    multiples of 128, the head count a multiple of the kernel's group): a
+    tile of scores lives in VMEM from the product that makes it to the
+    product with the values. The sequence's whole pages are gathered once
+    into ``[keys, width]`` and the selection's mask is made ONCE, before
+    the kernel, as int8 ``[queries, keys]`` (`chosen_mask`: `chosen`
+    walked over the whole context with its count carried, so a tie on the
+    cut still goes to the lower position across any block edge);
+  - the XLA arm (anything else: the CPU, a mesh, odd shapes): the walk
+    below, whose ``[heads, queries, keys]`` float32 scores go through HBM
+    once for the row maximum, once for ``exp`` and the row sum and once
+    for the product with the values.
+
+  Both count the same attended pairs and round at the same points.
 
 **The indexer** (DeepSeek-V3.2's sparse attention): every token also keeps
 an index key ``kI`` (pool ``[layers, P, page, index width]``); a query
@@ -61,28 +80,31 @@ absorbed form over the slot's ring; a chunk is the per-head form over the
 ring as it was before the chunk beside the chunk's own rows, which then
 overwrite the ring's oldest.
 
-All of it is plain XLA (ops ``mla_attention``, ``mla_decode_paged``,
-``mla_index``, ``mla_window``, each with the single arm ``xla``): products in the served
-type with float32 accumulation, scores and softmax in float32. A Pallas arm
-(the indexer's scores reduced over heads in VMEM; a gather that DMAs rows
-straight into the product) comes with the chip reading that shows it
-winning.
+All but the chunk's per-head walk is plain XLA (ops ``mla_attention``
+with the arms ``xla`` and ``pallas``; ``mla_decode_paged``, ``mla_index``,
+``mla_window``, each with the single arm ``xla``): products in the served
+type with float32 accumulation, scores and softmax in float32. Further
+Pallas arms (the indexer's scores reduced over heads in VMEM; a gather
+that DMAs rows straight into the product) come with the chip reading that
+shows them winning. Which arm a program was built with: the trace-time
+counters ``kernel.dispatch.mla_attention.{xla,pallas}``.
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.distributed.mesh import get_mesh
 from paddle_tpu.kernels import registry
 from paddle_tpu.kernels.diff_attention import ring_positions
 from paddle_tpu.kernels.paged_attention import TRASH_PAGE
 
 __all__ = ["index_scores", "index_select", "index_threshold",
-           "chosen", "latent_attention", "latent_decode_paged",
-           "latent_prefill",
+           "chosen", "chosen_mask", "latent_attention",
+           "latent_decode_paged", "latent_prefill",
            "window_latent_decode", "window_latent_prefill"]
 
-registry.register_op("mla_attention", impls=("xla",))
+registry.register_op("mla_attention", impls=("xla", "pallas"))
 registry.register_op("mla_decode_paged", impls=("xla",))
 registry.register_op("mla_index", impls=("xla",))
 registry.register_op("mla_window", impls=("xla",))
@@ -289,6 +311,38 @@ def chosen(scores, cut, room, seen, sight):
     return keep, seen + jnp.sum(level, axis=-1, dtype=jnp.int32)
 
 
+def chosen_mask(select, qpos, width, *, block=KEY_BLOCK):
+    """`chosen` over the WHOLE context in one pass, as what a kernel takes:
+    ``select`` is `index_threshold`'s ``(scores, cut, room)``, qpos : [T]
+    (negative: padding), ``width`` the keys the mask spans, a whole number
+    of ``block``. Returns (keep int8 [T, width], 1 where the query attends
+    the key, and how many pairs that is: an int32 scalar). The walk carries
+    `chosen`'s count of keys on the cut from block to block, so the mask is
+    the one a block-by-block walk applies whatever its block; it stops at
+    the furthest query (a DYNAMIC trip count), and past it the mask is 0:
+    out of every query's sight."""
+    scores, cut, room = select
+    t = qpos.shape[0]
+    if scores.shape[1] < width:
+        scores = jnp.pad(scores, ((0, 0), (0, width - scores.shape[1])),
+                         constant_values=-jnp.inf)
+
+    def body(i, carry):
+        keep, seen, n = carry
+        blk = jax.lax.dynamic_slice_in_dim(scores, i * block, block, axis=1)
+        s = i * block + jnp.arange(block, dtype=jnp.int32)
+        k, seen = chosen(blk, cut, room, seen, s[None, :] <= qpos[:, None])
+        keep = jax.lax.dynamic_update_slice_in_dim(
+            keep, k.astype(jnp.int8), i * block, axis=1)
+        return keep, seen, n + jnp.sum(k, dtype=jnp.int32)
+
+    keep, _, n = jax.lax.fori_loop(
+        0, jnp.minimum((jnp.max(qpos) + block) // block, width // block),
+        body, (jnp.zeros((t, width), jnp.int8), jnp.zeros(t, jnp.int32),
+               jnp.int32(0)))
+    return keep, n
+
+
 # ------------------------------------------------- latent attention, paged
 
 def latent_attention(q, lat_pool, layer, rows, ok, *, rank, scale):
@@ -381,11 +435,29 @@ def latent_prefill(q_nope, q_rope, lat_pool, layer, row, qpos, w_ukv, *, rank,
     unpicked one costs here; the gather of picked rows (`latent_attention`)
     costs 21 ns a row on a v5e whatever is done with it, which is more than
     this walk up to some 26,000 keys in sight (PERF.md section 6, PR
-    40)."""
-    registry.count("mla_attention", "xla")
+    40).
+
+    Two arms of that walk. Where `_prefill_plan` finds the Pallas kernel
+    fits what it can see (a TPU, no multi-device mesh, ``rank``, ``dn``,
+    ``dv`` and ``T`` multiples of 128, the heads a multiple of the
+    kernel's group) the scores stay in VMEM
+    (`kernels/pallas/latent_prefill.py`): the sequence's pages are gathered
+    whole into ``[keys, width]`` once, the selection's mask is made once
+    for the whole context (`chosen_mask`) and the kernel is handed both.
+    Anywhere else the loop below runs, ``key_block`` keys and
+    ``head_block`` heads at a time (the Pallas arm takes its own tiles
+    from the shapes). The same pairs are attended and counted, and every
+    product and rounding is the same."""
     t, h, dn = q_nope.shape
-    ps = lat_pool.shape[2]
-    kb, pages_blk, row_p, n_blocks = _walk(row, qpos, key_block, ps)
+    plan = _prefill_plan(t, h, dn, rope, dv, rank, lat_pool)
+    if plan is not None:
+        registry.count("mla_attention", "pallas")
+        return _pallas_prefill(q_nope, q_rope, lat_pool, layer, row, qpos,
+                               w_ukv, plan, rank=rank, rope=rope, dv=dv,
+                               scale=scale, select=select)
+    registry.count("mla_attention", "xla")
+    kb, pages_blk, row_p, n_blocks = _walk(row, qpos, key_block,
+                                           lat_pool.shape[2])
     g = head_block if h % head_block == 0 else h
     q = jnp.concatenate([q_nope, q_rope], axis=-1)
     q = q.reshape(t, h // g, g, dn + rope).transpose(1, 2, 0, 3)
@@ -435,6 +507,44 @@ def latent_prefill(q_nope, q_rope, lat_pool, layer, row, qpos, w_ukv, *, rank,
          jnp.zeros(t, jnp.int32), jnp.int32(0)))
     out = acc / jnp.maximum(l, 1e-30)[..., None]           # [h/g, g, T, dv]
     return out.reshape(h, t, dv).swapaxes(0, 1), n
+
+
+def _prefill_plan(t, h, dn, rope, dv, rank, lat_pool):
+    """The Pallas arm's tiles where it fits the call (on a TPU; in the
+    interpreter where a test steers the backend's name), else None: the XLA
+    arm runs. Under an installed multi-device mesh the trace becomes a
+    program GSPMD partitions, which a Mosaic kernel cannot join."""
+    from paddle_tpu.kernels import autotune
+    mesh = get_mesh()
+    if autotune._backend_kind() != "tpu" or (mesh is not None
+                                             and mesh.size > 1):
+        return None
+    from paddle_tpu.kernels.pallas import latent_prefill as kernel
+    return kernel.plan(t, h, dn, rope, dv, rank, lat_pool.shape[3],
+                       lat_pool.shape[2])
+
+
+def _pallas_prefill(q_nope, q_rope, lat_pool, layer, row, qpos, w_ukv, plan,
+                    *, rank, rope, dv, scale, select):
+    """`latent_prefill` through the kernel: the sequence's pages gathered
+    whole (the page row padded to whole key blocks with the trash page),
+    the mask and the attended pairs found before the call."""
+    from paddle_tpu.kernels.pallas import _compat, latent_prefill as kernel
+    ps = lat_pool.shape[2]
+    # the mask's pass takes whole blocks of its own: both divide the keys
+    block = max(plan.block, KEY_BLOCK - KEY_BLOCK % plan.block)
+    row_p = jnp.pad(row, (0, -row.shape[0] % (block // ps)),
+                    constant_values=TRASH_PAGE)
+    lat = lat_pool[layer, row_p].reshape(-1, lat_pool.shape[3])
+    if select is None:
+        keep, n = None, jnp.sum(jnp.maximum(qpos + 1, 0), dtype=jnp.int32)
+    else:
+        keep, n = chosen_mask(select, qpos, lat.shape[0], block=block)
+    out = kernel.latent_prefill(
+        q_nope, q_rope, w_ukv, lat, qpos, keep, plan=plan, rank=rank,
+        rope=rope, dv=dv, scale=float(scale),
+        interpret=_compat.default_interpret())
+    return out, n
 
 
 # ------------------------------------------- latent attention, window ring

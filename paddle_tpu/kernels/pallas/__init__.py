@@ -23,6 +23,11 @@ Kernels:
   product whose tiles give their row maximum, ``sum(exp)`` and label's
   logit while in VMEM (≈ `c_softmax_with_cross_entropy_op.cu`; the custom
   VJP and the selection live in `kernels/fused_ce.py`).
+- :mod:`latent_prefill` — a prefill chunk's latent (MLA) attention per
+  head: grid over groups of heads and blocks of keys, a block's keys and
+  values expanded from the latent rows and its scores kept in VMEM under
+  the causal mask or the indexer's selection, blocks past the furthest
+  query skipped (the arm and its plan are chosen in `kernels/mla.py`).
 - :mod:`fused_layernorm` — single-pass layernorm fwd + analytic bwd
   (≈ `fused_layernorm` kernels in `phi/kernels/fusion/`).
 - :mod:`rotary` — fused rotary position embedding

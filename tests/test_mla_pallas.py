@@ -1,0 +1,207 @@
+"""The Pallas arm of `kernels/mla.py::latent_prefill`
+(`kernels/pallas/latent_prefill.py`) against the XLA arm, in the
+interpreter on the CPU at tiny shapes: the causal mask and the selection's,
+padding queries, both groupings of heads, the blocks visited, and the
+one-pass mask against `chosen` walked block by block."""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+from paddle_tpu.kernels import mla, registry
+from paddle_tpu.kernels.pallas import latent_prefill as kernel
+from paddle_tpu.observability import metrics
+
+T, DN, ROPE, DV, RANK, WIDTH = 128, 128, 64, 128, 128, 256
+PAGE, DI, HI = 16, 32, 2
+PAGES = 40                          # a page row of 640 keys
+SCALE = (DN + ROPE) ** -0.5
+
+
+def _inputs(seed, heads, start, valid=T, dtype=jnp.float32):
+    """A sequence of ``start + valid`` tokens in a pool of shuffled pages and
+    a chunk of T queries at positions ``start..``, the last ``T - valid``
+    padding."""
+    rng = np.random.RandomState(seed)
+    pool_pages = PAGES + 3
+    lat = rng.randn(2, pool_pages, PAGE, WIDTH).astype(np.float32)
+    lat[..., RANK + ROPE:] = 0.0
+    row = rng.permutation(np.arange(1, pool_pages))[:PAGES].astype(np.int32)
+    pos = start + np.arange(T)
+    qpos = np.where(np.arange(T) < valid, pos, -1).astype(np.int32)
+    return dict(
+        q_nope=jnp.asarray(rng.randn(T, heads, DN), dtype),
+        q_rope=jnp.asarray(rng.randn(T, heads, ROPE), dtype),
+        lat_pool=jnp.asarray(lat, dtype), layer=1, row=jnp.asarray(row),
+        qpos=jnp.asarray(qpos),
+        w_ukv=jnp.asarray(rng.randn(RANK, heads * (DN + DV)) / RANK ** 0.5,
+                          dtype))
+
+
+def _select(seed, x, topk, ties):
+    """`index_threshold`'s (scores, cut, room) for the chunk; with ``ties``
+    the scores take few distinct values, so many keys sit on each cut."""
+    rng = np.random.RandomState(seed + 1)
+    qi = rng.randn(T, HI, DI).astype(np.float32)
+    w = np.abs(rng.randn(T, HI)).astype(np.float32)
+    kix = rng.randn(2, PAGES + 3, PAGE, DI).astype(np.float32)
+    if ties:
+        qi, kix, w = np.sign(qi), np.sign(kix), np.ones_like(w)
+    return mla.index_threshold(jnp.asarray(qi), jnp.asarray(w),
+                               jnp.asarray(kix), 1, x["row"], x["qpos"],
+                               topk, block=256, score_block=128)
+
+
+def _both(x, select, *, heads, block):
+    want, n_want = mla.latent_prefill(
+        x["q_nope"], x["q_rope"], x["lat_pool"], x["layer"], x["row"],
+        x["qpos"], x["w_ukv"], rank=RANK, rope=ROPE, dv=DV, scale=SCALE,
+        select=select, key_block=128, head_block=2)
+    plan = kernel.plan(T, x["q_nope"].shape[1], DN, ROPE, DV, RANK, WIDTH,
+                       PAGE, heads=heads, block=block)
+    assert plan is not None
+    got, n_got = mla._pallas_prefill(
+        x["q_nope"], x["q_rope"], x["lat_pool"], x["layer"], x["row"],
+        x["qpos"], x["w_ukv"], plan, rank=RANK, rope=ROPE, dv=DV,
+        scale=SCALE, select=select)
+    return (np.asarray(want, np.float32), int(n_want),
+            np.asarray(got, np.float32), int(n_got))
+
+
+CASES = {
+    # (a) the causal mask: nothing before the chunk, one block, several
+    # blocks with a ragged last one
+    "causal-keys-in-sight-0": dict(start=0, block=128),
+    "causal-one-block": dict(start=64, block=256),
+    "causal-ragged-last-block": dict(start=300, block=128),
+    # (b) a selection whose cuts hold ties that straddle block edges
+    "select-ties-across-blocks": dict(start=300, block=128, topk=96,
+                                      ties=True),
+    "select-no-ties": dict(start=480, block=128, topk=64, ties=False),
+    "select-fewer-keys-than-topk": dict(start=0, block=128, topk=256,
+                                        ties=True),
+    # (c) padding queries
+    "causal-padding-queries": dict(start=200, block=128, valid=70),
+    "select-padding-queries": dict(start=200, block=128, valid=70, topk=96,
+                                   ties=True),
+    "all-padding": dict(start=0, block=128, valid=0),
+    # (d) the other grouping of heads
+    "causal-four-heads-in-twos": dict(start=150, block=128, heads=4,
+                                      group=2),
+    "select-four-heads-whole": dict(start=150, block=128, heads=4, group=4,
+                                    topk=96, ties=True),
+    "bf16": dict(start=300, block=128, topk=96, ties=True,
+                 dtype=jnp.bfloat16),
+}
+
+
+@pytest.mark.parametrize("case", CASES)
+def test_pallas_arm_matches_xla_arm(case):
+    c = dict(CASES[case])
+    heads, dtype = c.get("heads", 8), c.get("dtype", jnp.float32)
+    x = _inputs(7, heads, c["start"], c.get("valid", T), dtype)
+    select = _select(7, x, c["topk"], c["ties"]) if "topk" in c else None
+    want, n_want, got, n_got = _both(x, select, heads=c.get("group", 4),
+                                     block=c["block"])
+    assert n_got == n_want
+    live = np.asarray(x["qpos"]) >= 0
+    assert np.all(got[~live] == 0.0) and np.all(want[~live] == 0.0)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    np.testing.assert_allclose(got, want, rtol=tol, atol=tol)
+    if live.any():
+        assert np.abs(want[live]).max() > 0.01
+
+
+@pytest.mark.parametrize("block", [32, 128, 320, 640])
+@pytest.mark.parametrize("start,topk", [(300, 96), (0, 64), (500, 8)])
+def test_one_pass_mask_is_chosen_walked_block_by_block(block, start, topk):
+    """`chosen_mask` at any block equals `chosen` walked in blocks of 128
+    as the XLA arm walks it, bit for bit, with ties on the cut across block
+    edges, and counts the same pairs."""
+    x = _inputs(11, 8, start, valid=100)
+    scores, cut, room = _select(11, x, topk, ties=True)
+    qpos = x["qpos"]
+    width = PAGES * PAGE
+    keep, n = mla.chosen_mask((scores, cut, room), qpos, width, block=block)
+    seen = jnp.zeros(T, jnp.int32)
+    want = np.zeros((T, width), bool)
+    for i in range(width // 128):
+        s = i * 128 + jnp.arange(128)
+        k, seen = mla.chosen(scores[:, i * 128:(i + 1) * 128], cut, room,
+                             seen, s[None, :] <= qpos[:, None])
+        want[:, i * 128:(i + 1) * 128] = np.asarray(k)
+    assert keep.dtype == jnp.int8
+    assert np.array_equal(np.asarray(keep), want.astype(np.int8))
+    assert int(n) == int(want.sum())
+    live = np.asarray(qpos) >= 0
+    # exactly topk keys a live query with that many in sight, ties and all
+    per_query = want.sum(axis=1)
+    assert np.array_equal(per_query[live],
+                          np.minimum(np.asarray(qpos)[live] + 1, topk))
+    on_cut = (np.asarray(scores)[:, :width] == np.asarray(cut)[:, None]) \
+        & (np.arange(width)[None, :] <= np.asarray(qpos)[:, None])
+    if topk < start:
+        assert (on_cut.sum(axis=1)[live] > 1).any()      # there WERE ties
+
+
+@pytest.mark.parametrize("start,valid,visited", [
+    (0, T, 1), (100, T, 2), (300, T, 4), (300, 10, 3), (500, T, 5),
+    (0, 0, 0)])
+def test_blocks_past_the_furthest_query_are_not_visited(start, valid,
+                                                        visited):
+    """(e) every group of heads visits ``(max(qpos) + block) // block`` key
+    blocks of the five the page row holds, whatever the row could hold."""
+    x = _inputs(3, 8, start, valid)
+    plan = kernel.plan(T, 8, DN, ROPE, DV, RANK, WIDTH, PAGE, heads=4,
+                       block=128)
+    lat = x["lat_pool"][1, x["row"]].reshape(-1, WIDTH)
+    out, visits = kernel.latent_prefill(
+        x["q_nope"], x["q_rope"], x["w_ukv"], lat, x["qpos"], plan=plan,
+        rank=RANK, rope=ROPE, dv=DV, scale=SCALE, interpret=True,
+        return_visits=True)
+    assert lat.shape[0] // 128 == 5
+    assert np.asarray(visits).tolist() == [visited, visited]
+    assert np.isfinite(np.asarray(out)).all()
+
+
+def test_plan_follows_the_shapes():
+    """The cell's call (128 heads) and GigaChat's (64) get one plan; odd
+    shapes get none and keep the XLA arm."""
+    assert kernel.plan(512, 128, 128, 64, 128, 512, 640, 16) \
+        == kernel.plan(512, 64, 128, 64, 128, 512, 640, 16) \
+        == kernel.Plan(8, 512)
+    for odd in [dict(t=500), dict(dn=192), dict(rank=576), dict(width=576),
+                dict(rope=0), dict(dv=64), dict(page_size=24)]:
+        a = dict(t=512, h=128, dn=128, rope=64, dv=128, rank=512, width=640,
+                 page_size=16)
+        a.update(odd)
+        assert kernel.plan(**a) is None, odd
+
+
+@pytest.mark.parametrize("backend,arm", [("cpu", "xla"), ("tpu", "pallas")])
+def test_the_arm_follows_the_backend_and_is_counted(backend, arm,
+                                                    monkeypatch):
+    """`latent_prefill` takes the Pallas arm where the backend is a TPU
+    (here: its name steered, the kernel in the interpreter) and the XLA arm
+    on the CPU, counts which in the registry's own counter, and both give
+    one answer."""
+    from paddle_tpu.kernels import autotune
+    assert registry.ops()["mla_attention"].impls == ("xla", "pallas")
+    x = _inputs(5, 8, 200)
+    select = _select(5, x, 96, ties=True)
+    want, n_want = mla.latent_prefill(
+        x["q_nope"], x["q_rope"], x["lat_pool"], x["layer"], x["row"],
+        x["qpos"], x["w_ukv"], rank=RANK, rope=ROPE, dv=DV, scale=SCALE,
+        select=select)
+    monkeypatch.setattr(autotune, "_backend_kind", lambda: backend)
+    name = f"kernel.dispatch.mla_attention.{arm}"
+    before = metrics.counter(name).value
+    got, n_got = mla.latent_prefill(
+        x["q_nope"], x["q_rope"], x["lat_pool"], x["layer"], x["row"],
+        x["qpos"], x["w_ukv"], rank=RANK, rope=ROPE, dv=DV, scale=SCALE,
+        select=select)
+    assert metrics.counter(name).value == before + 1
+    assert int(n_got) == int(n_want)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-5,
+                               atol=2e-5)

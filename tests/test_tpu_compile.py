@@ -871,11 +871,67 @@ def test_granite_step_program_copies_no_state_or_expert_on_v5e(
             assert [f for f in families if re.search(want, f)], want
 
 
+def _under_scope(text, scope):
+    """``[(family, largest float32 result in elements, line)]`` of the
+    materialised instructions of an optimized HLO whose name stack holds
+    ``scope`` innermost among the cell's scopes: what the benchmark's
+    reader adds up as that kernel's device time (`harness/trace.py`).
+    Instructions inside a fusion's body materialise nothing."""
+    import re
+    from harness import trace
+    wanted = frozenset(("mla", "select", "indexer", "window_mla", "moe"))
+    fused = set(re.findall(r" fusion\(.*calls=%([\w.\-]+)", text))
+    found, comp = [], None
+    for ln in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) \(.*\{$", ln)
+        if head:
+            comp = head.group(1)
+        m = trace._OP_NAME.search(ln)
+        if not m or " = " not in ln or comp in fused \
+                or trace._scope(m.group(1), wanted) != scope:
+            continue
+        fam = trace.family(ln.strip().removeprefix("ROOT "))
+        f32 = [int(np.prod([int(d) for d in dims.split(",") if d]))
+               for dims in re.findall(r"f32\[([\d,]*)\]",
+                                      fam.split(" ", 1)[1])]
+        found.append((fam, max(f32, default=0), ln.strip()))
+    return found
+
+
+# the chunk's latent attention alone (kernels/pallas/latent_prefill.py) at
+# the two cells' shapes: dots3-note's 128 heads under the selection's mask
+# and under the causal one, GigaChat's 64 heads under the causal one
+@pytest.mark.parametrize("call", ["dots3-selected", "dots3-causal",
+                                  "giga-causal"])
+def test_latent_prefill_kernel_compiles_for_v5e(chip, call):
+    from paddle_tpu.kernels.pallas import latent_prefill as kernel
+    heads, keys = (64, 3584) if call == "giga-causal" else (128, 33792)
+    t, dn, rope, dv, rank, width = 512, 128, 64, 128, 512, 640
+    plan = kernel.plan(t, heads, dn, rope, dv, rank, width, PAGE)
+    assert plan is not None and heads % plan.heads == 0
+    keys += -keys % plan.block
+
+    def spec(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    args = [spec((t, heads, dn)), spec((t, heads, rope)),
+            spec((rank, heads * (dn + dv))), spec((keys, width)),
+            spec((t,), jnp.int32)]
+    if call == "dots3-selected":
+        args.append(spec((t, keys), jnp.int8))
+    compiled = jax.jit(lambda *a: kernel.latent_prefill(
+        *a, plan=plan, rank=rank, rope=rope, dv=dv, scale=192 ** -0.5,
+        interpret=False)).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # nothing of the size of a group's scores beside the kernel: queries,
+    # rows and mask in, the output out
+    assert compiled.memory_analysis().temp_size_in_bytes < 40e6
+
+
 # dots3-note-prev as benchmarks/configs/dots3-note-prev.json serves it: one
 # chip's share of eight (layers 0-4, 32 of 256 experts, 19,008 rows)
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
 def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
-        chip, program):
+        chip, program, monkeypatch):
     """dots3-note's decode step and one prefill chunk of 512, whole, at the
     published widths with the chip's share, compiled for the described
     chip with the pools and rings donated and the counts behind the token
@@ -886,9 +942,18 @@ def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
     out: PERF.md, PR 40); everything donated is aliased; the program fits
     the chip beside its 10.8 GB of arguments; and the op families by which
     the cell's kernel shares find these kernels in a device trace are in
-    the program that makes them."""
+    the program that makes them. With the arms a TPU run takes (the code
+    that picks them asks JAX for its backend, which here is the CPU: the
+    test steers it), a chunk's latent attention is found as the benchmark's
+    reader finds it since PR 44, by the program's scope: Pallas custom calls
+    whose ``op_name`` holds ``mla`` (one a full layer and mask kind), and no
+    float32 ``[heads, chunk, keys]`` scores left under that scope."""
     import json
     import re
+    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels.pallas import _compat
+    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(_compat, "default_interpret", lambda: False)
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
                                                prefill_program,
@@ -941,10 +1006,26 @@ def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
         found = [bool([f for f in families
                        if re.search(p.format(**shapes), f)])
                  for p in metric["patterns"]]
+        print(program, name, found)
+        if (program, name) == ("prefill_chunk_step", "latent_attn"):
+            # the chunk's walk is a kernel now: none of its four families
+            # is left, and the scope the metric asks for finds it
+            assert not any(found), (metric["patterns"], found)
+            assert metric["scopes"] == ["mla"]
+            continue
         # each kernel's patterns name a chunk's ops and a decode step's:
         # some of them are in each program, all of them in the two
         assert any(found), (name, metric["patterns"], found)
-        print(program, name, found)
+    under = _under_scope(text, "mla")
+    calls = [ln for f, _, ln in under if f.startswith("custom-call")
+             and "tpu_custom_call" in ln]
+    # a full layer's chunk attends under the causal mask or the
+    # selection's: two kernels a layer, one of them runs
+    assert len(calls) == (2 * len(cfg.full_layers)
+                          if program == "prefill_chunk_step" else 0)
+    scores = shapes["head_block"] * shapes["chunk"] * shapes["key_block"]
+    big = [(f, n) for f, n, _ in under if n >= scores]
+    assert big == [], big
 
 
 # GigaChat3.5-432B-A28B as benchmarks/configs/gigachat3.5-432b-a28b.json
@@ -1032,8 +1113,20 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
         up.spec(sharding=chip)).compile()
     text = compiled.as_text()
     kernels = text.count("custom_call_target=\"tpu_custom_call\"")
+    # a chunk's one full layer attends inside the latent-prefill kernel
     assert kernels == (len(cfg.linear_layers) if program == "decode_step"
-                       else 0)
+                       else len(cfg.full_layers))
+    under = _under_scope(text, "mla")
+    calls = [ln for f, _, ln in under if f.startswith("custom-call")
+             and "tpu_custom_call" in ln]
+    assert len(calls) == (0 if program == "decode_step"
+                          else len(cfg.full_layers))
+    if program == "prefill_chunk_step":
+        # no float32 [heads, chunk, keys] scores left under that scope
+        from paddle_tpu.kernels import mla
+        scores = mla.HEAD_BLOCK * sv["prefill_chunk_tokens"] * mla.KEY_BLOCK
+        big = [(f, n) for f, n, _ in under if n >= scores]
+        assert big == [], big
     # no rematerialized instruction reads a donated array: short of memory
     # at 128 slots the compiler rematerializes, and a clone of an in-place
     # update that reads what it replaces ran TWICE on the chip (the
@@ -1053,8 +1146,14 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
                 for ln in text.splitlines() if " = " in ln}
     made_by = {"decode_step": ("deltanet_update", "latent_paged_attn",
                                "giga_experts"),
-               "prefill_chunk_step": ("deltanet_chunk", "latent_paged_attn",
-                                      "giga_experts")}
+               "prefill_chunk_step": ("deltanet_chunk", "giga_experts")}
+    if program == "prefill_chunk_step":
+        # the chunk's walk is the kernel counted above: none of its three
+        # families is left, and the scope the metric asks for finds it
+        metric = harness_spec.layer_metric("latent_paged_attn_roofline_share")
+        assert metric["scopes"] == ["mla"]
+        assert not [f for f in families for p in metric["patterns"]
+                    if re.search(p.format(**shapes), f)]
     for name in made_by[program]:
         metric = harness_spec.layer_metric(f"{name}_roofline_share")
         found = [bool([f for f in families
