@@ -534,7 +534,7 @@ def bench_engine_ragged():
     structured JSON line."""
     import paddle_tpu as paddle
     from paddle_tpu.inference.engine import DecodeEngine, EngineConfig
-    from paddle_tpu.kernels.autotune import cache_table
+    from paddle_tpu.kernels import registry
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
     paddle.seed(0)
@@ -556,7 +556,8 @@ def bench_engine_ragged():
     tps = len(prompts) * N / (time.perf_counter() - t0)
     for r in reqs:
         assert r.done
-    impl = next((v[0] for k, v in cache_table().items() if k[0] == "paged"),
+    impl = next((v[0] for k, v in registry.table().items()
+                 if k[0] == "paged"),
                 "xla")
     return tps, impl
 
@@ -570,7 +571,7 @@ def bench_paged_kernel():
     import jax
     import jax.numpy as jnp
     from paddle_tpu.kernels import paged_attention as pa
-    from paddle_tpu.kernels.autotune import _measure
+    from paddle_tpu.kernels.registry import measure as _measure
 
     B, nh, dh, ps, maxp = 8, 12, 64, 16, 16
     num_pages = 1 + B * maxp
@@ -604,7 +605,7 @@ def bench_prefill_kernel():
     import jax
     import jax.numpy as jnp
     from paddle_tpu.kernels import paged_attention as pa
-    from paddle_tpu.kernels.autotune import _measure
+    from paddle_tpu.kernels.registry import measure as _measure
 
     nh, dh, ps, maxp, c = 12, 64, 16, 16, 64
     num_pages = 1 + maxp

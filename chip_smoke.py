@@ -436,7 +436,7 @@ def phase_kernels(sz, seed, dev):
     from paddle_tpu.framework.flags import set_flags
     from paddle_tpu.kernels import paged_attention as pa
     from paddle_tpu.kernels.flash_attention import flash_attention_fn
-    from paddle_tpu.kernels.pallas import apply_rotary_emb, fused_layer_norm
+    from paddle_tpu.kernels.pallas import fused_layer_norm
 
     on_tpu = dev.platform == "tpu"
     fails, rows = [], []
@@ -525,22 +525,12 @@ def phase_kernels(sz, seed, dev):
         pair(f"prefill_int8 h{h}", "tpu_prefill_impl", "pallas", prefill,
              (qp, kq, vq, table[0], start, valid, ks, vs), select=live)
 
-    # fused layer norm (fwd, bwd) and fused rope: one arm each, compared
-    # with plain jax.numpy
+    # fused layer norm (fwd, bwd): one arm, compared with plain jax.numpy
     def ln_ref(x, g, b_):
         xf = x.astype(jnp.float32)
         mu = xf.mean(-1, keepdims=True)
         rs = jax.lax.rsqrt(((xf - mu) ** 2).mean(-1, keepdims=True) + 1e-5)
         return ((xf - mu) * rs * g + b_).astype(x.dtype)
-
-    def rope_ref(q, k, cos, sin):
-        def rot(x):
-            xf = x.astype(jnp.float32)
-            h2 = x.shape[-1] // 2
-            x1, x2 = xf[..., :h2], xf[..., h2:]
-            return jnp.concatenate(
-                [x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1).astype(x.dtype)
-        return rot(q), rot(k)
 
     for h, d in sz.kernel_widths:
         hid = h * d
@@ -555,17 +545,6 @@ def phase_kernels(sz, seed, dev):
                                           (x, g, b_), on_tpu, fails)
                 want = jax.jit(wrap(ln_ref))(x, g, b_)
             compare(name, "pallas", got, want, secs, tol)
-        b = max(1, sz.batch * sz.heads // h)
-        q, k = rand(b, h, sz.seq, d), rand(b, h, sz.seq, d)
-        ang = np.outer(np.arange(sz.seq), 1e4 ** (-np.arange(d // 2) * 2 / d))
-        cos = jnp.asarray(np.cos(ang), jnp.float32)
-        sin = jnp.asarray(np.sin(ang), jnp.float32)
-        name = f"rope h{h}"
-        with x64_off_scope():
-            got, secs = _run_compiled(name, apply_rotary_emb,
-                                      (q, k, cos, sin), on_tpu, fails)
-            want = jax.jit(rope_ref)(q, k, cos, sin)
-        compare(name, "pallas", got, want, secs, BF16_KERNEL_TOL)
     # the fused cross-entropy head's forward kernel (logsumexp and the
     # label's logit a row, taken from tiles in VMEM) against XLA's body
     from paddle_tpu.kernels import fused_ce

@@ -141,7 +141,7 @@ def apply(prim: Callable, *inputs, op_name: str = "", n_outputs: int | None = No
     ``*_ad_func`` forwards (`eager/auto_code_generator/generator/eager_gen.py`).
 
     ``x64_off``: trace this op's forward AND backward under x64-disabled dtype
-    promotion — required by Pallas kernels (splash/flash attention) that mix
+    promotion — required by Pallas kernels (flash attention) that mix
     int32 iota with weak python ints, which breaks under paddle's global
     jax_enable_x64. The backward scope matters because vjp_fn traces the
     custom-vjp bwd rule at backward time, long after the forward scope exits.

@@ -47,11 +47,6 @@ XPUPlace = TPUPlace
 _current_device = None
 
 
-def _backend_kind() -> str:
-    """The platform as JAX reports it ("cpu", "tpu", ...) — never renamed."""
-    return jax.default_backend()
-
-
 def set_device(device):
     """ref: ``paddle.device.set_device`` — accepts 'cpu', 'tpu', 'tpu:0', and for
     script compatibility 'gpu'/'gpu:0' (routed to the TPU backend)."""
@@ -64,12 +59,12 @@ def set_device(device):
 def get_device() -> str:
     if _current_device is not None:
         return _current_device
-    kind = _backend_kind()
+    kind = jax.default_backend()
     return f"{kind}:0" if kind != "cpu" else "cpu"
 
 
 def get_all_custom_device_type():
-    return ["tpu"] if _backend_kind() == "tpu" else []
+    return ["tpu"] if jax.default_backend() == "tpu" else []
 
 
 def is_compiled_with_cuda():
@@ -102,7 +97,7 @@ def _place_of(arr) -> Place:
         d = next(iter(devs))
         return Place(d.platform, d.id)
     except Exception:
-        return Place(_backend_kind(), 0)
+        return Place(jax.default_backend(), 0)
 
 
 def synchronize(device=None):

@@ -125,15 +125,14 @@ define_flag("dataloader_mp_method", "spawn",
             "for unpicklable datasets at the caller's risk)")
 define_flag("tpu_flash_impl", "auto",
             "flash-attention backend: auto (measured per-shape selection, "
-            "kernels/autotune.py — ref phi/kernels/autotune) | splash "
-            "(Pallas splash kernel) | mosaic (jax-bundled Pallas flash) | "
-            "authored (in-repo Pallas fwd+bwd kernels, "
+            "kernels/registry.py — ref phi's AutoTuneCache) | authored "
+            "(in-repo Pallas fwd+bwd kernels, "
             "kernels/pallas/flash_attention.py) | xla (pure-XLA flash-style "
-            "custom vjp, also the fallback for non-tileable shapes)")
+            "custom vjp)")
 define_flag("tpu_paged_impl", "auto",
             "paged-attention decode backend (serving engine hot kernel): "
             "auto (measured per-signature selection on real TPU, xla "
-            "elsewhere — kernels/autotune.py) | xla (gather + masked f32 "
+            "elsewhere — kernels/registry.py) | xla (gather + masked f32 "
             "softmax reference, traffic scales with pool capacity) | pallas "
             "(authored ragged paged-attention kernel, kernels/pallas/"
             "paged_attention.py — page loop bounded by each sequence's true "
@@ -147,8 +146,6 @@ define_flag("tpu_prefill_impl", "auto",
             "(authored ragged prefill kernel, kernels/pallas/"
             "prefill_attention.py — page loop bounded by each request's "
             "true context; interpret mode off-TPU, parity tests only)")
-define_flag("autotune_verbose", False,
-            "log kernel autotune decisions with measured timings")
 define_flag("dy2static_max_trip_count", 0,
             "when > 0, TRACED loops produced by dy2static conversion "
             "(data-dependent while / for-over-range) lower to a bounded "

@@ -4,8 +4,7 @@ FusedMultiHeadAttention, FusedFeedForward, FusedMultiTransformer).
 On TPU "fused" means: one traced region XLA/Pallas fuses — attention goes through
 the flash-attention kernel, the MLP is a single jit region.
 """
-import functools
-
+from paddle_tpu.kernels import registry
 from paddle_tpu.nn.layer import Layer
 from paddle_tpu.nn.layers.transformer import MultiHeadAttention
 from paddle_tpu.nn.layers.common import Linear, Dropout
@@ -87,6 +86,9 @@ class FusedTransformerEncoderLayer(Layer):
         return self.ffn(out)
 
 
+registry.register_op("fused_layernorm", impls=("pallas",))
+
+
 class FusedLayerNorm(Layer):
     """LayerNorm over the authored Pallas kernel
     (`paddle_tpu/kernels/pallas/fused_layernorm.py` — the counterpart of the
@@ -98,7 +100,6 @@ class FusedLayerNorm(Layer):
         super().__init__()
         import numpy as _np
         from paddle_tpu.core.tensor import Parameter
-        from paddle_tpu.kernels import registry
         if isinstance(normalized_shape, int):
             normalized_shape = (normalized_shape,)
         if len(normalized_shape) != 1:
@@ -125,27 +126,26 @@ class FusedLayerNorm(Layer):
             x, self.weight, self.bias, op_name="fused_layer_norm")
 
 
-@functools.lru_cache(maxsize=1)
-def _rope_impl() -> str:
-    """Resolve (and count) the rope impl ONCE per process — the
-    functional runs eagerly per call, so an uncached dispatch would
-    count per invocation instead of per selection."""
-    from paddle_tpu.kernels import registry
-    return registry.dispatch("fused_rope")
-
-
 def fused_rotary_position_embedding(q, k, cos, sin, name=None):
-    """Fused rope over the authored Pallas kernel
-    (`paddle_tpu/kernels/pallas/rotary.py`; ref newer-branch `fused_rope`).
-    q/k: [B, H, S, D] tensors; cos/sin: [S, D/2]."""
+    """Rotary position embedding of q and k (ref newer-branch `fused_rope`),
+    by the plain half-rotation, which XLA fuses: the pair of element
+    ``i < D / 2`` is ``i + D / 2``. q/k: [B, H, S, D] tensors; cos/sin:
+    [S, D/2]."""
+    import jax.numpy as jnp
     from paddle_tpu.core.autograd import apply
-    from paddle_tpu.kernels.pallas import apply_rotary_emb
     from paddle_tpu.ops.common import ensure_tensor
-    _rope_impl()
+
+    def rotate(a, b, c, s):
+        def one(x):
+            x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+            return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s],
+                                   axis=-1).astype(x.dtype)
+        c, s = c.astype(jnp.float32), s.astype(jnp.float32)
+        return one(a), one(b)
+
     q, k = ensure_tensor(q), ensure_tensor(k)
     cos, sin = ensure_tensor(cos), ensure_tensor(sin)
-    return apply(lambda a, b, c, s: apply_rotary_emb(a, b, c, s),
-                 q, k, cos, sin, op_name="fused_rope", n_outputs=2)
+    return apply(rotate, q, k, cos, sin, op_name="fused_rope", n_outputs=2)
 
 
 class FusedMultiTransformer(Layer):

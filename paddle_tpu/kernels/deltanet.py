@@ -85,14 +85,8 @@ __all__ = ["deltanet_update", "deltanet_chunk", "conv_update", "conv_chunk",
            "state_shape", "HEADS", "SUB"]
 
 
-def _tpu_first(ctx):
-    from paddle_tpu.kernels import autotune
-    backend = ctx.get("backend", autotune._backend_kind())
-    return ["pallas", "xla"] if backend == "tpu" else ["xla"]
-
-
 registry.register_op("deltanet_update", impls=("xla", "pallas"),
-                     candidates=_tpu_first)
+                     candidates=registry.tpu_first)
 registry.register_op("deltanet_chunk", impls=("xla",))
 
 _HI = jax.lax.Precision.HIGHEST

@@ -2,14 +2,17 @@
 
 Counterpart of `paddle/fluid/operators/fused/fused_attention_op.cu` — which is
 non-flash (`fmha_ref.h`), so this is strictly beyond reference parity (SURVEY.md
-§5.7 requires it). Strategy:
+§5.7 requires it). The arms of op ``flash_attention``, chosen per signature
+by `kernels/registry.py` under ``FLAGS_tpu_flash_impl``:
 
-1. Pallas TPU flash kernel (jax.experimental.pallas.ops.tpu.flash_attention) when
-   shapes are TPU-tileable (seq multiple of block, head_dim aligned);
-2. otherwise a blockwise online-softmax attention in pure lax (still O(S) memory
-   via jax.checkpoint-friendly scan), which XLA fuses well.
+- **authored** — the in-repo Pallas kernels, forward and backward
+  (`kernels/pallas/flash_attention.py`): a block's scores live in VMEM only.
+  Offered on a TPU outside a partitioned program, at every shape;
+- **xla** — blockwise attention in plain XLA under a custom VJP (`_xla_flash`):
+  the [S, S] probabilities exist only transiently inside each q-block. The
+  one arm off-TPU and in a partitioned program, where nothing is measured.
 
-Layout note: paddle uses [B, S, H, D]; the pallas op uses [B, H, S, D].
+Layout note: paddle uses [B, S, H, D]; the arms take [B, H, S, D].
 """
 from __future__ import annotations
 
@@ -18,130 +21,9 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from paddle_tpu.core.autograd import x64_off_scope
-
-_PALLAS_OK = None
-
-
-def _try_pallas():
-    global _PALLAS_OK, _fa_mod
-    if _PALLAS_OK is None:
-        from jax.experimental.pallas.ops.tpu import flash_attention as _m
-        _fa_mod = _m
-        _PALLAS_OK = jax.default_backend() == "tpu"
-    return _PALLAS_OK
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _pallas_flash(q, k, v, causal, sm_scale):
-    out, _ = _pallas_flash_fwd(q, k, v, causal, sm_scale)
-    return out
-
-
-def _pallas_flash_fwd(q, k, v, causal, sm_scale):
-    _try_pallas()
-    bs = _fa_mod.BlockSizes.get_default(
-        q.shape[0], q.shape[1], q.shape[2], k.shape[2], q.shape[3])
-    with x64_off_scope():
-        o, res = _fa_mod._flash_attention_fwd(
-            q, k, v, None, None, False, causal, sm_scale, bs, False)
-    return o, res
-
-
-def _pallas_flash_bwd(causal, sm_scale, res, do):
-    _try_pallas()
-    q, k = res[0], res[1]
-    bs = _fa_mod.BlockSizes.get_default(
-        q.shape[0], q.shape[1], q.shape[2], k.shape[2], q.shape[3])
-    with x64_off_scope():
-        dq, dk, dv, _, _ = _fa_mod._flash_attention_bwd(
-            False, causal, sm_scale, bs, False, res, do)
-    return dq, dk, dv
-
-
-_pallas_flash.defvjp(_pallas_flash_fwd, _pallas_flash_bwd)
-
-
-def _blockwise_attention(q, k, v, causal, scale, block_k=512):
-    """Online-softmax attention scanning over K blocks (lax fallback)."""
-    # q,k,v: [B, H, S, D]
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    s = scale if scale is not None else 1.0 / math.sqrt(D)
-    q = q * s
-    nblocks = max((Sk + block_k - 1) // block_k, 1)
-    pad = nblocks * block_k - Sk
-    if pad:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    kb = k.reshape(B, H, nblocks, block_k, D)
-    vb = v.reshape(B, H, nblocks, block_k, D)
-    q_idx = jnp.arange(Sq)
-
-    def body(carry, blk):
-        m_prev, l_prev, acc = carry
-        kk, vv, base = blk
-        logits = jnp.einsum("bhqd,bhkd->bhqk", q, kk,
-                            preferred_element_type=jnp.float32)
-        kpos = base + jnp.arange(block_k)
-        valid = kpos < Sk
-        if causal:
-            valid = valid[None, :] & (kpos[None, :] <= (
-                q_idx + (Sk - Sq))[:, None])
-            logits = jnp.where(valid[None, None], logits, -jnp.inf)
-        else:
-            logits = jnp.where(valid[None, None, None], logits, -jnp.inf)
-        m_cur = jnp.max(logits, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # guard fully-masked rows
-        m_safe = jnp.where(jnp.isfinite(m_new), m_new, 0.0)
-        p = jnp.exp(logits - m_safe[..., None])
-        p = jnp.where(jnp.isfinite(logits), p, 0.0)
-        alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc_new = acc * alpha[..., None] + jnp.einsum(
-            "bhqk,bhkd->bhqd", p.astype(vv.dtype), vv,
-            preferred_element_type=jnp.float32)
-        return (m_new, l_new, acc_new), None
-
-    m0 = jnp.full((B, H, Sq), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((B, H, Sq), jnp.float32)
-    acc0 = jnp.zeros((B, H, Sq, D), jnp.float32)
-    bases = jnp.arange(nblocks) * block_k
-    (m, l, acc), _ = jax.lax.scan(
-        body, (m0, l0, acc0),
-        (jnp.moveaxis(kb, 2, 0), jnp.moveaxis(vb, 2, 0), bases))
-    out = acc / jnp.maximum(l, 1e-30)[..., None]
-    return out.astype(q.dtype)
-
-
-_SPLASH_CACHE: dict = {}
-
-
-def _splash_kernel(n_heads, S, causal):
-    """Cached Splash (Pallas) MHA kernel — the production TPU flash attention.
-    Created under ensure_compile_time_eval so the precomputed mask-info arrays
-    stay concrete even when first touched inside an abstract capture probe."""
-    key = (n_heads, S, causal)
-    if key not in _SPLASH_CACHE:
-        from jax.experimental.pallas.ops.tpu.splash_attention import (
-            splash_attention_kernel as sk, splash_attention_mask as sm)
-        with jax.ensure_compile_time_eval(), x64_off_scope():
-            mask = sm.MultiHeadMask(
-                [sm.CausalMask((S, S)) if causal else sm.FullMask((S, S))
-                 for _ in range(n_heads)])
-            _SPLASH_CACHE[key] = sk.make_splash_mha(
-                mask, head_shards=1, q_seq_shards=1)
-    return _SPLASH_CACHE[key]
-
-
-def _splash_attention(q, k, v, causal, scale):
-    """q,k,v: [B,H,S,D]; caller must hold an x64-off scope across fwd+bwd
-    traces (see autograd.apply(x64_off=True))."""
-    s = scale if scale is not None else 1.0 / math.sqrt(q.shape[-1])
-    kern = _splash_kernel(q.shape[1], q.shape[2], causal)
-    return jax.vmap(kern)((q * s).astype(q.dtype), k, v)
+from paddle_tpu.kernels import registry
 
 
 def _qblocks(S):
@@ -289,39 +171,8 @@ def _xla_flash_bwd(causal, scale, res, do):
 _xla_flash.defvjp(_xla_flash_fwd, _xla_flash_bwd)
 
 
-def _dense_attention(q, k, v, causal, scale):
-    """Full-materialization SDPA: the [B, H, Sq, Sk] scores exist in HBM
-    (bf16 when inputs are bf16) and XLA autodiffs it. At moderate S the
-    S^2 tensor fits easily and the single fused softmax beats chunked
-    flash's loop overhead — the autotuner decides per shape."""
-    B, H, Sq, D = q.shape
-    Sk = k.shape[2]
-    s = scale if scale is not None else 1.0 / math.sqrt(D)
-    acc = jnp.bfloat16 if q.dtype == jnp.bfloat16 else jnp.float32
-    logits = jax.lax.dot_general(
-        q * s, k, (((3,), (3,)), ((0, 1), (0, 1))),
-        preferred_element_type=acc)
-    if causal:
-        qpos = jnp.arange(Sq)
-        kpos = jnp.arange(Sk)
-        mask = kpos[None, :] <= (qpos[:, None] + (Sk - Sq))
-        logits = jnp.where(mask[None, None], logits,
-                           jnp.asarray(-1e30, logits.dtype))
-    p = jax.nn.softmax(logits.astype(jnp.float32), axis=-1).astype(v.dtype)
-    return jax.lax.dot_general(
-        p, v, (((3,), (2,)), ((0, 1), (0, 1))),
-        preferred_element_type=v.dtype)
-
-
-def _impl_call(impl, qt, kt, vt, causal, scale, tileable):
+def _impl_call(impl, qt, kt, vt, causal, scale):
     """Execute one named implementation on [B, H, S, D] arrays."""
-    if impl == "dense":
-        return _dense_attention(qt, kt, vt, causal, scale)
-    if impl == "splash" and tileable:
-        return _splash_attention(qt, kt, vt, causal, scale)
-    if impl == "mosaic" and tileable:
-        sm = scale if scale is not None else 1.0 / math.sqrt(qt.shape[-1])
-        return _pallas_flash(qt, kt, vt, causal, sm)
     if impl == "authored":
         # the in-repo Pallas kernels (kernels/pallas/flash_attention.py),
         # forward AND backward. They read [B, S, H * D]: the swap of axes
@@ -331,46 +182,85 @@ def _impl_call(impl, qt, kt, vt, causal, scale, tileable):
     return _xla_flash(qt, kt, vt, causal, scale)
 
 
+def _candidates(ctx):
+    """Impl names viable for one call (by name, never by execution).
+    ``partitioned``: the call is traced into a program GSPMD splits over
+    a multi-device mesh. A Mosaic kernel cannot be partitioned
+    automatically (jax refuses to lower it outside a ``shard_map``), so
+    only the XLA arm is viable there."""
+    if ctx.get("backend", registry.backend()) == "tpu" \
+            and not ctx.get("partitioned", False):
+        return ["xla", "authored"]
+    return ["xla"]
+
+
+registry.register_op("flash_attention", impls=("xla", "authored"),
+                     candidates=_candidates)
+
+
+def _selection(shape_q, shape_k, dtype, causal, scale, partitioned):
+    """``(key, measure)`` of one signature for `registry.dispatch`: its key
+    in the winner table — ("flash", backend, shape_q, shape_k, dtype,
+    causal[, "partitioned"]), shapes [B, H, S, D] — and ``measure(impl) ->
+    seconds`` of the named arm's forward and backward over seeded arrays.
+    A partitioned call has narrower candidates and keys its own entry."""
+    key = ("flash", registry.backend(), tuple(shape_q), tuple(shape_k),
+           str(dtype), bool(causal)) \
+        + (("partitioned",) if partitioned else ())
+    state = {}
+
+    def measure(impl):
+        if not state:
+            rng = np.random.RandomState(0)
+
+            def seq_major(shape):
+                b, h, s, d = shape
+                return jnp.asarray(rng.randn(b, s, h, d)
+                                   .astype(np.float32)).astype(dtype)
+            state["args"] = (seq_major(shape_q), seq_major(shape_k),
+                             seq_major(shape_k))
+        # the call site's arrays are [B, S, H, D] and reach the impl
+        # through a swap of axes (`flash_attention_fn`): the measured step
+        # does the same, so an arm is timed with the relayouts it would
+        # cost there, or save
+        step = jax.jit(jax.grad(
+            lambda q_, k_, v_: (
+                _impl_call(impl, *(jnp.swapaxes(x, 1, 2)
+                                   for x in (q_, k_, v_)), causal, scale)
+                .astype(jnp.float32) ** 2).sum(), argnums=(0, 1, 2)))
+        # a layer of a step program runs at the device's pace. A single
+        # launch's wall time is a third the host's at the training cell's
+        # shape (a millisecond of 3 to 4), and a busy host once read three
+        # launches in a row 1.8 ms slow and hid an arm 1.5x faster
+        return registry.measure(step, state["args"], calls=8)
+
+    return key, measure
+
+
 def flash_attention_fn(causal=False, scale=None):
     """Returns a pure fn(q, k, v) on paddle-layout [B, S, H, D] tensors."""
 
     def fn(q, k, v):
         from paddle_tpu.distributed.mesh import get_mesh
         from paddle_tpu.framework.flags import flag_value
-        from paddle_tpu.kernels import registry
         # -> [B, H, S, D]
         qt = jnp.swapaxes(q, 1, 2)
         kt = jnp.swapaxes(k, 1, 2)
         vt = jnp.swapaxes(v, 1, 2)
-        S, D = qt.shape[2], qt.shape[3]
-        tileable = (_try_pallas() and S % 128 == 0 and D % 64 == 0
-                    and S == kt.shape[2]
-                    and qt.dtype in (jnp.float32, jnp.bfloat16))
         # under an installed multi-device mesh this trace becomes a program
-        # GSPMD partitions, which the Pallas arms cannot join
+        # GSPMD partitions, which the Pallas arm cannot join
         mesh = get_mesh()
         partitioned = mesh is not None and mesh.size > 1
-
-        def winner():
-            # measured selection, cached per (backend, shape, dtype,
-            # causal) — ref phi/kernels/autotune. Runs eagerly at trace
-            # time; the winner string is baked into this trace (the
-            # program cache keys on the flag + shapes, so retunes key new
-            # programs).
-            from paddle_tpu.kernels.autotune import flash_winner
-            return flash_winner(
-                tuple(qt.shape), tuple(kt.shape), qt.dtype, causal,
-                tileable,
-                lambda i, q_, k_, v_: _impl_call(i, q_, k_, v_, causal,
-                                                 scale, tileable),
-                partitioned=partitioned)
-
+        # measured selection, cached per (backend, shape, dtype, causal) —
+        # ref phi's AutoTuneCache. Runs eagerly at trace time; the winner
+        # string is baked into this trace (the program cache keys on the
+        # flag + shapes, so retunes key new programs)
+        key, measure = _selection(qt.shape, kt.shape, qt.dtype, causal,
+                                  scale, partitioned)
         impl = registry.dispatch(
             "flash_attention", forced=flag_value("tpu_flash_impl"),
-            ctx={"tileable": tileable, "shape_q": tuple(qt.shape),
-                 "shape_k": tuple(kt.shape), "partitioned": partitioned},
-            winner=winner)
-        out = _impl_call(impl, qt, kt, vt, causal, scale, tileable)
+            ctx={"partitioned": partitioned}, key=key, measure=measure)
+        out = _impl_call(impl, qt, kt, vt, causal, scale)
         return jnp.swapaxes(out, 1, 2)
 
     return fn

@@ -40,7 +40,21 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.distributed.mesh import get_mesh
+from paddle_tpu.kernels import registry
 from paddle_tpu.observability import metrics
+
+
+def _candidates(ctx):
+    # the fused chunked-vocab CE assumes the full [V, H] head on every
+    # rank; under mp the vocab is sharded and only the dense parallel CE
+    # is correct
+    return ["fused", "dense"] if ctx.get("mp", 1) == 1 else ["dense"]
+
+
+# dispatched where the model chooses its loss (`models/gpt.py::
+# _fused_ce_impl`)
+registry.register_op("fused_ce", impls=("fused", "dense"),
+                     candidates=_candidates)
 
 
 def _pick_chunks(v: int) -> int:
@@ -62,10 +76,8 @@ def _pallas_plan(n, hid, v):
     XLA body runs. Under an installed multi-device mesh
     the trace becomes a program GSPMD partitions, which a Mosaic kernel
     cannot join."""
-    from paddle_tpu.kernels import autotune
     mesh = get_mesh()
-    if autotune._backend_kind() != "tpu" or (mesh is not None
-                                             and mesh.size > 1):
+    if registry.backend() != "tpu" or (mesh is not None and mesh.size > 1):
         return None
     from paddle_tpu.kernels.pallas import fused_ce as kernel
     return kernel._plan(n, hid, v)

@@ -514,10 +514,8 @@ def _prefill_plan(t, h, dn, rope, dv, rank, lat_pool):
     interpreter where a test steers the backend's name), else None: the XLA
     arm runs. Under an installed multi-device mesh the trace becomes a
     program GSPMD partitions, which a Mosaic kernel cannot join."""
-    from paddle_tpu.kernels import autotune
     mesh = get_mesh()
-    if autotune._backend_kind() != "tpu" or (mesh is not None
-                                             and mesh.size > 1):
+    if registry.backend() != "tpu" or (mesh is not None and mesh.size > 1):
         return None
     from paddle_tpu.kernels.pallas import latent_prefill as kernel
     return kernel.plan(t, h, dn, rope, dv, rank, lat_pool.shape[3],

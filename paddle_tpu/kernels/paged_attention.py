@@ -48,9 +48,9 @@ the keyword decides. A per-layer call pays a copy of that pool on the chip
 and is counted at trace time in ``kernel.pool_relayout.{op}``; the engine's
 programs count none (docs/OBSERVABILITY.md).
 
-``FLAGS_tpu_paged_impl`` picks: ``auto`` (measured winner per signature on
-real TPU via the kernel registry + `kernels/autotune.py`, xla elsewhere —
-backend viability is decided by NAME, `kernels/autotune.py`),
+``FLAGS_tpu_paged_impl`` picks: ``auto`` (on a TPU the winner the kernel
+registry measures per signature over this module's synthetic pool, xla
+elsewhere — backend viability is decided by NAME, `_pool_cands`),
 ``xla``, or ``pallas`` (interpret mode off-TPU: parity tests only). Every
 selection routes through `kernels/registry.py::dispatch` and is counted
 per program build in ``kernel.dispatch.paged_attention.{xla|pallas}``
@@ -69,6 +69,9 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
+
+from paddle_tpu.kernels import registry
 
 # the reserved spill target for masked writes — never allocated to a sequence
 TRASH_PAGE = 0
@@ -131,7 +134,6 @@ def stored_pools(op, k_pages, v_pages, k_scale=None, v_scale=None,
             raise IndexError(f"layer {layer} of a pool of "
                              f"{k_pages.shape[0]} layers")
         return k_pages, v_pages, k_scale, v_scale, layer
-    from paddle_tpu.kernels import registry
     registry.count_relayout(op)
 
     def stack(pool):               # [P, ps, ...] -> [1, P, ps, merged]
@@ -217,7 +219,7 @@ def _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
 def _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
                k_scale=None, v_scale=None, scale=None):
     """Execute one named implementation over the stored pool at ``layer``
-    (also the autotuner's run_impl)."""
+    (also what the selection times)."""
     if impl == "pallas":
         from paddle_tpu.kernels.pallas.paged_attention import (
             paged_attention as pallas_paged)
@@ -227,6 +229,110 @@ def _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
     return _xla_paged_attention(q, k_pages, v_pages, page_table, pos, layer,
                                 k_scale=k_scale, v_scale=v_scale,
                                 scale=scale)
+
+
+def _pool_cands(backend):
+    """The two pool-reading ops' impls viable on this backend (by name,
+    never by execution). Pallas is offered on a TPU only: interpret mode
+    off-TPU is a parity tool, not a serving path."""
+    return ["xla", "pallas"] if backend == "tpu" else ["xla"]
+
+
+def _paged_cands(ctx):
+    cands = _pool_cands(ctx.get("backend", registry.backend()))
+    if ctx.get("grouped"):
+        # grouped queries are not measured (:func:`paged_attention`): where
+        # the kernel is viable it is preferred, as it read ten times
+        # faster at 32 heads over 8 (PERF.md section 6, PR 31)
+        cands = cands[::-1]
+    return cands
+
+
+def _prefill_cands(ctx):
+    cands = _pool_cands(ctx.get("backend", registry.backend()))
+    if not ctx.get("parity", True):
+        # the pallas arm reads the PAGE POOL; when the pool dtype narrows
+        # the compute dtype (bf16 pages under f32 weights, non-quant), the
+        # one-shot XLA arm attends the raw full-precision K/V — offering
+        # pallas there would silently change numerics, so it is not viable
+        cands = [c for c in cands if c != "pallas"]
+    if ctx.get("grouped"):
+        # the Pallas prefill arm takes one K/V head a query head
+        cands = [c for c in cands if c != "pallas"]
+    return cands
+
+
+registry.register_op("paged_attention", impls=("xla", "pallas"),
+                     candidates=_paged_cands,
+                     alias_counter="paged_attention.impl")
+registry.register_op("prefill_attention", impls=("xla", "pallas"),
+                     candidates=_prefill_cands)
+
+
+def _selection_key(tag, dims, dtype, quant, num_pages, suffix=""):
+    """A signature's key in the winner table: ``(tag, backend, *dims[,
+    "pool<num_pages>"], dtype[/kv-int8][suffix])``. An int8 pool keys its
+    own winner: the dequant changes each arm's arithmetic intensity."""
+    return (tag, registry.backend(), *(int(d) for d in dims)) \
+        + (() if num_pages is None else (f"pool{int(num_pages)}",)) \
+        + (str(dtype) + ("/kv-int8" if quant else "") + suffix,)
+
+
+def _synthetic_pools(num_pages, page_size, nh, dh, dtype, quant):
+    """Seeded K and V pools in the engine's stored layout, a stack of one
+    layer ``[1, num_pages, page_size, nh * dh]`` (read with ``layer=0``: no
+    arm's cost depends on how many layers the stack holds), made on the
+    device (a real pool is GBs: no host round trip): ``(k, v)``, or for
+    ``quant`` int8 pools and their unit scales ``(k, v, k_scale,
+    v_scale)``, in the order the arms take them."""
+    kk, kv = jax.random.split(jax.random.PRNGKey(0))
+    shape = (1, num_pages, page_size, nh * dh)
+    kp, vp = (jax.random.normal(k, shape, "float32").astype(dtype)
+              for k in (kk, kv))
+    if not quant:
+        return kp, vp
+    ones = jnp.ones(shape[:3] + (nh,), jnp.float32)
+    return kp.astype(jnp.int8), vp.astype(jnp.int8), ones, ones
+
+
+def _paged_selection(b, pages_per_slot, page_size, nh, dh, dtype, *,
+                     quant=False, num_pages=None):
+    """``(key, measure)`` of one decode signature for `registry.dispatch`:
+    its key in the winner table — ("paged", backend, B, pages_per_slot,
+    page_size, nh, dh[, pool], dtype[/kv-int8]) — and ``measure(impl) ->
+    seconds`` of one launch over a synthetic pool under a ragged position
+    mix, so that the measurement sees the kernel's length-aware stop.
+
+    ``dtype`` is the query's, a REAL dtype (the arrays are built with it).
+    ``num_pages`` is the caller's REAL pool size: the synthetic pool is
+    built that large (and the winner keyed by it), so that an arm whose
+    cost depends on the pool's capacity and not only on the pages it reads
+    is timed as the step programs run it. None keeps the smallest pool that
+    holds every slot."""
+    key = _selection_key("paged", (b, pages_per_slot, page_size, nh, dh),
+                         dtype, quant, num_pages)
+    state = {}
+
+    def measure(impl):
+        if not state:
+            pool = max(1 + b * pages_per_slot, int(num_pages or 0))
+            q = jnp.asarray(np.random.RandomState(0).randn(b, nh, dh)
+                            .astype(np.float32)).astype(dtype)
+            state.update(
+                args=(q, *_synthetic_pools(pool, page_size, nh, dh, dtype,
+                                           quant)),
+                pt=jnp.asarray(1 + np.arange(b * pages_per_slot,
+                                             dtype=np.int32)
+                               .reshape(b, pages_per_slot)),
+                # ragged mix spanning 1..pages_per_slot pages — the
+                # serving shape the pallas kernel's stop is built for
+                pos=jnp.asarray(((np.arange(b) % pages_per_slot) + 1)
+                                * page_size - 1, dtype=jnp.int32))
+        step = jax.jit(lambda q_, k_, v_, *scales: _impl_call(
+            impl, q_, k_, v_, state["pt"], state["pos"], 0, *scales))
+        return registry.measure(step, state["args"])
+
+    return key, measure
 
 
 def paged_attention(q, k_pages, v_pages, page_table, pos,
@@ -257,7 +363,6 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
     trace of the calling code: once a GPT step program, whose block is one
     traced function), not steps.
     """
-    from paddle_tpu.kernels import registry
     k_pages, v_pages, k_scale, v_scale, layer = stored_pools(
         "paged_attention", k_pages, v_pages, k_scale, v_scale, layer)
     try:
@@ -265,37 +370,18 @@ def paged_attention(q, k_pages, v_pages, page_table, pos,
         forced = flag_value("tpu_paged_impl")
     except Exception:          # flags registry unavailable (early import)
         forced = "xla"
-
-    def winner():
-        from paddle_tpu.kernels.autotune import paged_winner
-        run = _impl_call
-        variant = ""
-        if k_scale is not None:
-            # int8 pools measure with synthetic unit scales (the autotuner
-            # builds its own float test pages — here cast to int8) and key
-            # their own winner via the variant suffix: the dequant changes
-            # each candidate's arithmetic intensity. The q dtype stays a
-            # REAL dtype (paged_winner builds arrays with it)
-            variant = "kv-int8"
-
-            def run(impl_, q_, kp_, vp_, pt_, pos_, layer_):
-                ones = jnp.ones(kp_.shape[:3] + (q.shape[1],), jnp.float32)
-                return _impl_call(impl_, q_, kp_.astype(jnp.int8),
-                                  vp_.astype(jnp.int8), pt_, pos_, layer_,
-                                  k_scale=ones, v_scale=ones)
-        return paged_winner(q.shape[0], page_table.shape[1],
-                            k_pages.shape[2], q.shape[1], q.shape[2],
-                            q.dtype, run, variant=variant,
-                            num_pages=k_pages.shape[1])
-
     # a grouped signature is not measured: the probe's pool would be a
     # second pool of the model's own size (every slot's pages are distinct)
     # with the xla arm's gathers beside it, at a size where the first fills
-    # the chip. It takes the registry's preference (`registry._paged_cands`)
+    # the chip. It takes the candidates' preference (`_paged_cands`)
     grouped = query_groups(q.shape[1], q.shape[2], k_pages.shape[-1]) > 1
+    key, measure = (None, None) if grouped else _paged_selection(
+        q.shape[0], page_table.shape[1], k_pages.shape[2], q.shape[1],
+        q.shape[2], q.dtype, quant=k_scale is not None,
+        num_pages=k_pages.shape[1])
     impl = registry.dispatch("paged_attention", forced=forced,
-                             ctx={"grouped": grouped},
-                             winner=None if grouped else winner)
+                             ctx={"grouped": grouped}, key=key,
+                             measure=measure)
     return _impl_call(impl, q, k_pages, v_pages, page_table, pos, layer,
                       k_scale=k_scale, v_scale=v_scale, scale=scale)
 
@@ -345,7 +431,7 @@ def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
 def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,
                        layer, k_scale=None, v_scale=None, scale=None):
     """Execute one named prefill impl over the stored pool at ``layer``
-    (also the autotuner's run_impl)."""
+    (also what the selection times)."""
     if impl == "pallas":
         from paddle_tpu.kernels.pallas.prefill_attention import (
             prefill_attention as pallas_prefill)
@@ -357,46 +443,68 @@ def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,
                                   v_scale=v_scale, scale=scale)
 
 
+def _prefill_selection(chunk, pages_per_slot, page_size, nh, dh, dtype, *,
+                       quant=False, parity=True, num_pages=None):
+    """``(key, measure)`` of one PREFILL signature for `registry.dispatch`
+    — ("prefill", backend, chunk, pages_per_slot, page_size, nh, dh[,
+    pool], dtype[/kv-int8][/no-parity]) — as :func:`_paged_selection`; the
+    measurement runs one mid-pool chunk (a page of prior context + a full
+    chunk of fresh queries, ``[1, chunk, nh, dh]``) so the length-aware
+    stop is exercised. A parity-gated signature (``parity=False``,
+    `_prefill_cands`) keys a DISTINCT entry, so it can't adopt an ungated
+    one's pallas win."""
+    key = _selection_key("prefill",
+                         (chunk, pages_per_slot, page_size, nh, dh), dtype,
+                         quant, num_pages, "" if parity else "/no-parity")
+    state = {}
+
+    def measure(impl):
+        if not state:
+            pool = max(1 + pages_per_slot, int(num_pages or 0))
+            q = jnp.asarray(np.random.RandomState(0).randn(1, chunk, nh, dh)
+                            .astype(np.float32)).astype(dtype)
+            state.update(
+                args=(q, *_synthetic_pools(pool, page_size, nh, dh, dtype,
+                                           quant)),
+                row=jnp.asarray(1 + np.arange(pages_per_slot,
+                                              dtype=np.int32)))
+        # a page of prior context where the slot has room for it: the last
+        # query position must stay inside the slot (start + chunk <=
+        # capacity), past it the page walk leaves the page-table row
+        start = jnp.int32(max(0, min(page_size,
+                                     pages_per_slot * page_size - chunk)))
+        step = jax.jit(lambda q_, k_, v_, *scales: _prefill_impl_call(
+            impl, q_, k_, v_, state["row"], start, jnp.int32(chunk), 0,
+            *scales))
+        return registry.measure(step, state["args"])
+
+    return key, measure
+
+
 def prefill_impl(chunk, pages_per_slot, page_size, nh, dh, dtype,
                  quant=False, parity=True, num_pages=None,
                  grouped=False) -> str:
     """Resolve (and COUNT) the prefill-attention impl for one program
     build — the registry is the only selector (`kernels/registry.py`;
-    ``FLAGS_tpu_prefill_impl`` forces, ``auto`` measures via
-    `autotune.prefill_winner`). ``parity=False`` marks a call whose XLA
-    arm does NOT read the page pool (the one-shot `prefill_step` over a
-    narrowing pool dtype), which drops the pallas candidate rather than
-    silently changing numerics. ``num_pages`` is the pool's size, which
-    the measurement reproduces (`autotune.paged_winner`). A ``grouped``
-    signature (more query heads than the pool has) has the xla arm alone:
-    the Pallas arm takes one K/V head a query head."""
-    from paddle_tpu.kernels import registry
+    ``FLAGS_tpu_prefill_impl`` forces, ``auto`` measures over
+    :func:`_prefill_selection`'s workload). ``parity=False`` marks a call
+    whose XLA arm does NOT read the page pool (the one-shot `prefill_step`
+    over a narrowing pool dtype), which drops the pallas candidate rather
+    than silently changing numerics. ``num_pages`` is the pool's size,
+    which the measurement reproduces. A ``grouped`` signature (more query
+    heads than the pool has) has the xla arm alone: the Pallas arm takes
+    one K/V head a query head."""
     try:
         from paddle_tpu.framework.flags import flag_value
         forced = flag_value("tpu_prefill_impl")
     except Exception:          # flags registry unavailable (early import)
         forced = "xla"
-
-    def winner():
-        from paddle_tpu.kernels.autotune import prefill_winner
-        run = _prefill_impl_call
-        variant = ""
-        if quant:
-            variant = "kv-int8"
-
-            def run(impl_, q_, kp_, vp_, row_, start_, valid_, layer_):
-                ones = jnp.ones(kp_.shape[:3] + (nh,), jnp.float32)
-                return _prefill_impl_call(
-                    impl_, q_, kp_.astype(jnp.int8), vp_.astype(jnp.int8),
-                    row_, start_, valid_, layer_, k_scale=ones,
-                    v_scale=ones)
-        return prefill_winner(chunk, pages_per_slot, page_size, nh, dh,
-                              dtype, run, variant=variant, parity=parity,
-                              num_pages=num_pages)
-
+    key, measure = (None, None) if grouped else _prefill_selection(
+        chunk, pages_per_slot, page_size, nh, dh, dtype, quant=quant,
+        parity=parity, num_pages=num_pages)
     return registry.dispatch("prefill_attention", forced=forced,
                              ctx={"parity": parity, "grouped": grouped},
-                             winner=None if grouped else winner,
+                             key=key, measure=measure,
                              require_viable=grouped)
 
 

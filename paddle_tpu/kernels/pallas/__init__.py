@@ -18,7 +18,8 @@ Kernels:
   chunk-row blocks, scalar-prefetched (start, valid), page walk
   bounded by the request's true uncached tail — chunked prefill, prefix
   tails, and the PTKS1 prefill-worker stream all ride it
-  (`FLAGS_tpu_prefill_impl`, selection in `kernels/registry.py`).
+  (`FLAGS_tpu_prefill_impl`; op `prefill_attention`, registered and
+  measured in `kernels/paged_attention.py`).
 - :mod:`fused_ce` — forward of the fused LM-head + cross-entropy: one
   product whose tiles give their row maximum, ``sum(exp)`` and label's
   logit while in VMEM (≈ `c_softmax_with_cross_entropy_op.cu`; the custom
@@ -30,14 +31,11 @@ Kernels:
   query skipped (the arm and its plan are chosen in `kernels/mla.py`).
 - :mod:`fused_layernorm` — single-pass layernorm fwd + analytic bwd
   (≈ `fused_layernorm` kernels in `phi/kernels/fusion/`).
-- :mod:`rotary` — fused rotary position embedding
-  (≈ `fused_rope` in newer reference branches).
 
 All kernels run under ``interpret=True`` on CPU for tests; on TPU they compile
 through Mosaic (`tests/test_tpu_compile.py` asks the chip's compiler for each).
 """
 from paddle_tpu.kernels.pallas.flash_attention import flash_attention  # noqa: F401
 from paddle_tpu.kernels.pallas.fused_layernorm import fused_layer_norm  # noqa: F401
-from paddle_tpu.kernels.pallas.rotary import apply_rotary_emb  # noqa: F401
 from paddle_tpu.kernels.pallas import paged_attention as paged_attention  # noqa: F401,PLC0414
 from paddle_tpu.kernels.pallas import prefill_attention as prefill_attention  # noqa: F401,PLC0414

@@ -86,7 +86,7 @@ def _bwd(x, gamma, mu, rstd, dy, block_rows, interpret):
     block_rows = min(block_rows, n)
     nb = pl.cdiv(n, block_rows)
     # per-block dgamma/dbeta partials ride a [nb, 1, d] array: a (1, d)
-    # block of an [nb, d] array is not (8, 128)-tileable
+    # block of an [nb, d] array is not (8, 128)-tiled
     with x64_off_scope():
         dx, dg_part, db_part = pl.pallas_call(
             functools.partial(_bwd_kernel, n_rows=n, block_rows=block_rows),

@@ -60,7 +60,7 @@ positions excluded — parity with the XLA path is enforced by
 tests/test_paged_pallas.py in interpret mode on CPU; on TPU the kernel
 compiles through Mosaic (tests/test_tpu_compile.py). Selection between the
 two lives in `kernels/paged_attention.py` (``FLAGS_tpu_paged_impl``),
-measured winners in `kernels/autotune.py`; the block a build chose is
+measured winners in `kernels/registry.py`; the block a build chose is
 counted in ``kernel.paged_block.{pages}`` (docs/OBSERVABILITY.md).
 """
 from __future__ import annotations

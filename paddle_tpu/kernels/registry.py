@@ -1,52 +1,25 @@
-"""ONE kernel registry: named op -> candidate impls -> viability predicate
--> measured winner (ROADMAP item 4).
+"""Kernel selection: the one place that decides a kernel's arm.
 
-Before this module, every switchable kernel carried its own dispatch glue:
-flash attention had `autotune.flash_winner` + a flag switch, paged decode
-attention had `autotune.paged_winner` + its own flag + its own counter,
-ring/Ulysses had a dict lookup in `nn/functional/attention.py`, and the
-fused CE / fused layernorm sites hand-rolled their gating inline. Each new
-kernel (the ragged prefill kernel, the fused sampler) would have added a
-fifth and sixth copy. This module is the single replacement:
+Three boxes, arrows one way: an op's MODULE (its arms, its
+``candidates(ctx)``: the impls viable for one call, by name and in order of
+preference; its synthetic workload) -> THIS module -> `observability.metrics`.
+This module names no op and imports nothing from `paddle_tpu.kernels`.
 
-- **Ops** are registered by NAME with (a) the full impl universe and (b) a
-  viability predicate (`candidates(ctx)`) that returns the impls actually
-  runnable on this backend for this call — backend viability decided by
-  NAME, never by executing an op (`kernels/autotune.py`).
-- **Dispatch** (`dispatch()`) resolves one call site's impl: a forced flag
-  value wins (validated against the op's universe), a single viable
-  candidate pins itself, and multiple candidates defer to the op's
-  measured-winner hook (the synthetic-workload measurement lives with the
-  op's adapter in `kernels/autotune.py`, which calls back into
-  :func:`select` below). Every resolution counts
-  ``kernel.dispatch.{op}.{impl}`` — a TRACE-TIME counter (once per program
-  build per call site), plus any legacy alias counter the op declares
-  (``paged_attention.impl.{impl}`` predates the registry and stays pinned
-  by tests).
-- **The winner table** (`select()`) is the PR 7 measured-selection policy
-  generalized: in-memory cache -> single-candidate short circuit ->
-  persisted winner -> measure every viable candidate and keep the best.
-  Keys are ``(op-tag, backend, shape-class..., dtype[, variant])`` tuples.
-- **Persistence** folds the PR 7 on-disk table in
-  (``PADDLE_AUTOTUNE_CACHE``): same version-1 ``{"winners": {repr(key):
-  impl}}`` schema, so every legacy file written by `flash_winner` /
-  `paged_winner` loads as-is — and a PRE-version bare ``{key: winner}``
-  mapping (the oldest format) is migrated on first load. Corrupt or stale
-  files are ignored, never fatal; a persisted winner outside the current
-  viable set is discarded (a table copied from a TPU host cannot poison a
-  CPU one).
-
-`kernels/autotune.py` keeps the measurement probes (`_measure`,
-`_backend_kind`, the candidate lists) and the back-compat wrappers
-(`flash_winner`/`paged_winner`) — those are the op ADAPTERS; the registry
-is the one dispatch + persistence + observability layer under them.
+:func:`dispatch` resolves one call site: a forced flag value wins, else the
+candidates are computed ONCE and, where the call site gives a measurement,
+:func:`select` decides among exactly those (memory -> single candidate ->
+``PADDLE_AUTOTUNE_CACHE``'s version-1 file -> time each and keep the best;
+keys are ``(op-tag, backend, shape-class..., dtype[, variant])``). Every
+resolution counts ``kernel.dispatch.{op}.{impl}`` at TRACE time, every
+selection is one ``kernel.select:<op>`` span. :func:`backend` and
+:func:`measure` are the measurement's two probes.
 """
 from __future__ import annotations
 
-import ast
 import json
 import logging
 import os
+import time
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -56,7 +29,7 @@ _LOG = logging.getLogger("paddle_tpu.kernels.registry")
 
 __all__ = ["KernelOp", "register_op", "ops", "dispatch", "count",
            "count_relayout", "count_paged_block", "select",
-           "table", "clear"]
+           "table", "clear", "backend", "measure", "tpu_first"]
 
 
 @dataclass
@@ -71,29 +44,26 @@ class KernelOp:
     name: str
     impls: tuple
     candidates: Callable[[dict], list] = field(repr=False, default=None)
-    flag: str | None = None
     alias_counter: str | None = None
 
 
 _OPS: dict[str, KernelOp] = {}
 
-# measured winners {key: (winner, {impl: seconds | error})} —
-# `kernels/autotune.py` aliases this object as its `_CACHE` (tests
-# introspect it there), so it is mutated IN PLACE only, never rebound.
+# measured winners {key: (winner, {impl: seconds | error})}
 _TABLE: dict = {}
 
 _DISK_VERSION = 1
 _DISK_STATE: dict = {"path": None, "table": None}   # loaded-once per path
 
 
-def register_op(name, impls, candidates=None, flag=None, alias_counter=None):
+def register_op(name, impls, candidates=None, alias_counter=None):
     """Register (or re-register) one op. Idempotent by name so re-imports
     in tests never duplicate."""
     if candidates is None:
         all_impls = tuple(impls)
         candidates = lambda ctx: list(all_impls)  # noqa: E731
     _OPS[name] = KernelOp(name=name, impls=tuple(impls),
-                          candidates=candidates, flag=flag,
+                          candidates=candidates,
                           alias_counter=alias_counter)
     return _OPS[name]
 
@@ -142,7 +112,42 @@ def count_paged_block(pages: int):
     metrics.counter(f"kernel.paged_block.{pages}").inc()
 
 
-def dispatch(op: str, *, forced=None, ctx=None, winner=None,
+def backend() -> str:
+    """The backend name winners are keyed by and arms are offered for: the
+    Pallas arms on ``"tpu"`` only, where they compile (interpret mode
+    off-TPU is a parity tool, not a serving path)."""
+    import jax
+    return jax.default_backend()
+
+
+def tpu_first(ctx) -> list:
+    """The candidates of an op whose Pallas arm is preferred wherever it
+    compiles and is not measured: ``pallas`` then ``xla`` on a TPU, ``xla``
+    alone elsewhere (``ctx["backend"]`` stands in for :func:`backend`)."""
+    return ["pallas", "xla"] if ctx.get("backend", backend()) == "tpu" \
+        else ["xla"]
+
+
+def measure(fn, args, warmup=1, reps=3, calls=1):
+    """Best-of-reps wall time of one call of a compiled callable (jax
+    arrays in/out). ``calls`` > 1: that many calls are launched back to
+    back and waited for once, and the time is a call's share: the pace
+    the device keeps, as inside a step program, without the host's part
+    of one launch, and a hiccup of the host's is shared by all of them."""
+    import jax
+    for _ in range(warmup):
+        jax.block_until_ready(fn(*args))
+    best = float("inf")
+    for _ in range(reps):
+        t0 = time.perf_counter()
+        for _ in range(calls - 1):
+            fn(*args)
+        jax.block_until_ready(fn(*args))
+        best = min(best, (time.perf_counter() - t0) / calls)
+    return best
+
+
+def dispatch(op: str, *, forced=None, ctx=None, key=None, measure=None,
              require_viable=False) -> str:
     """Resolve ONE call site's impl and count it.
 
@@ -153,11 +158,13 @@ def dispatch(op: str, *, forced=None, ctx=None, winner=None,
                mode parity testing forces pallas off-TPU on purpose)
                unless ``require_viable`` degrades it to the first viable
                candidate (the fused-CE "fused wanted but mp>1" rule).
-    ctx      : op-specific viability context for ``candidates(ctx)``.
-    winner   : zero-arg measured-selection hook (the op adapter in
-               kernels/autotune.py, which calls :func:`select`); consulted
-               only when >1 candidate is viable. Without one the first
-               viable candidate wins.
+    ctx      : op-specific viability context for ``candidates(ctx)``,
+               which is called once.
+    key, measure : the call site's measurement, the table key of this
+               signature and ``measure(impl) -> seconds`` over a synthetic
+               workload: :func:`select` decides among the candidates just
+               computed (a single candidate is pinned, and still a
+               recorded decision). Without one the first candidate wins.
     """
     o = _OPS.get(op)
     if o is None:
@@ -171,19 +178,8 @@ def dispatch(op: str, *, forced=None, ctx=None, winner=None,
                 f"{list(o.impls)}")
         impl = forced if (forced in cands or not require_viable) \
             else cands[0]
-    elif winner is not None:
-        # the adapter owns the winner-table entry even for a single
-        # candidate (a pinned impl is still a recorded decision)
-        impl = winner()
-        if impl not in cands:
-            # defense in depth: an adapter whose candidate list drifted
-            # from the dispatch-level viability ctx must not smuggle a
-            # non-viable impl past the gate — degrade to the first
-            # viable candidate and say so
-            _LOG.warning(
-                "registry: %s winner %r outside the viable set %s — "
-                "using %r", op, impl, cands, cands[0])
-            impl = cands[0]
+    elif measure is not None:
+        impl = select(op, key, cands, measure)
     else:
         impl = cands[0]
     count(op, impl)
@@ -193,8 +189,7 @@ def dispatch(op: str, *, forced=None, ctx=None, winner=None,
 # ----------------------------------------------------------- winner table
 
 
-def select(op: str, key: tuple, candidates: list, measure,
-           verbose_tag: str | None = None) -> str:
+def select(op: str, key: tuple, candidates: list, measure) -> str:
     """Measured-winner resolution for one (op, signature): in-memory table
     -> single-candidate pin -> persisted winner -> measure every candidate
     (``measure(impl) -> seconds``) and keep the best. A candidate that
@@ -211,17 +206,6 @@ def select(op: str, key: tuple, candidates: list, measure,
         sp.args.update(pick=winner, source=source, timings_ms={
             k: round(v * 1e3, 4) if isinstance(v, float) else v
             for k, v in timings.items()})
-    if source == "measured":
-        try:
-            from paddle_tpu.framework.flags import flag_value
-            verbose = flag_value("autotune_verbose")
-        except Exception:  # noqa: BLE001 — flags registry unavailable
-            verbose = False
-        if verbose:
-            _LOG.warning("autotune %s %s -> %s (%s)", verbose_tag or op,
-                         key, winner,
-                         {k: f"{v * 1e3:.2f}ms" for k, v in timings.items()
-                          if isinstance(v, float)})
     return winner
 
 
@@ -270,48 +254,21 @@ def _disk_path():
     return os.environ.get("PADDLE_AUTOTUNE_CACHE") or None
 
 
-def _parse_disk(data, count_migrated=True) -> dict:
-    """Accept every table generation ever written:
-
-    - version-1 ``{"version": 1, "winners": {repr(key): impl}}`` (the PR 7
-      format `flash_winner`/`paged_winner` wrote — loads as-is, the
-      registry keys those two ops identically);
-    - the PRE-version bare ``{repr(key): impl}`` mapping — migrated in
-      (counted on ``autotune.disk_migrated``) so a fleet's oldest cache
-      files keep their winners;
-    - anything else (future version stamp, wrong shapes) -> empty table.
-    """
-    if not isinstance(data, dict):
-        return {}
-    if "version" in data or "winners" in data:
-        if data.get("version") != _DISK_VERSION:
-            return {}
-        winners = data.get("winners")
-        return winners if isinstance(winners, dict) else {}
-    # legacy pre-version file: a bare {key: winner} mapping. Only migrate
-    # entries that look like our repr'd tuple keys with string winners.
-    migrated = {k: v for k, v in data.items()
-                if isinstance(k, str) and k.startswith("(")
-                and isinstance(v, str)}
-    if migrated and count_migrated:
-        metrics.counter("autotune.disk_migrated").inc(len(migrated))
-    return migrated
-
-
-def _load_disk_table(path, count_migrated=True) -> dict:
-    """Read the persisted winner table; ANY failure (missing, corrupt,
-    wrong schema) degrades to an empty table — never fatal.
-    ``count_migrated=False`` is the store-path re-read: only the
-    lookup-time load counts legacy entries, so `autotune.disk_migrated`
-    reports each migrated entry ONCE."""
+def _load_disk_table(path) -> dict:
+    """Read the persisted winner table, ``{"version": 1, "winners":
+    {repr(key): impl}}``; ANY failure (missing, corrupt, another version
+    or schema) degrades to an empty table — never fatal."""
     try:
         with open(path) as f:
             data = json.load(f)
-        return _parse_disk(data, count_migrated=count_migrated)
     except Exception as e:  # noqa: BLE001 — a bad cache file is advisory
         if not isinstance(e, FileNotFoundError):
             _LOG.info("registry: ignoring unreadable cache %s: %s", path, e)
         return {}
+    if not isinstance(data, dict) or data.get("version") != _DISK_VERSION:
+        return {}
+    winners = data.get("winners")
+    return winners if isinstance(winners, dict) else {}
 
 
 def _disk_lookup(key, viable):
@@ -339,7 +296,7 @@ def _disk_store(key, winner):
     if path is None:
         return
     try:
-        tab = _load_disk_table(path, count_migrated=False)
+        tab = _load_disk_table(path)
         tab[repr(key)] = winner
         tmp = f"{path}.tmp.{os.getpid()}"
         with open(tmp, "w") as f:
@@ -349,87 +306,3 @@ def _disk_store(key, winner):
         _DISK_STATE["path"], _DISK_STATE["table"] = path, tab
     except Exception as e:  # noqa: BLE001
         _LOG.info("registry: cache write to %s failed: %s", path, e)
-
-
-def parse_key(repr_key: str):
-    """Best-effort parse of a persisted key back into its tuple (registry
-    introspection / tests); None when unparseable."""
-    try:
-        return ast.literal_eval(repr_key)
-    except Exception:  # noqa: BLE001
-        return None
-
-
-# ------------------------------------------------------- built-in op set
-#
-# Candidate providers import lazily: viability consults the autotune
-# backend name (`_backend_kind`) at CALL time, so a test that steers it
-# takes effect without re-registration.
-
-
-def _flash_cands(ctx):
-    from paddle_tpu.kernels import autotune
-    return autotune._flash_candidates(
-        ctx.get("backend", autotune._backend_kind()),
-        ctx.get("tileable", False),
-        ctx.get("shape_q", (1, 1, 1, 1)), ctx.get("shape_k", (1, 1, 1, 1)),
-        ctx.get("partitioned", False))
-
-
-def _paged_cands(ctx):
-    from paddle_tpu.kernels import autotune
-    cands = autotune._paged_candidates(
-        ctx.get("backend", autotune._backend_kind()))
-    if ctx.get("grouped"):
-        # grouped queries are not measured (`kernels/paged_attention.py`):
-        # where the kernel is viable it is preferred, as it read ten times
-        # faster at 32 heads over 8 (PERF.md section 6, PR 31)
-        cands = cands[::-1]
-    return cands
-
-
-def _prefill_cands(ctx):
-    from paddle_tpu.kernels import autotune
-    cands = autotune._paged_candidates(
-        ctx.get("backend", autotune._backend_kind()))
-    if not ctx.get("parity", True):
-        # the pallas arm reads the PAGE POOL; when the pool dtype narrows
-        # the compute dtype (bf16 pages under f32 weights, non-quant), the
-        # one-shot XLA arm attends the raw full-precision K/V — offering
-        # pallas there would silently change numerics, so it is not viable
-        cands = [c for c in cands if c != "pallas"]
-    if ctx.get("grouped"):
-        # the Pallas prefill arm takes one K/V head a query head
-        cands = [c for c in cands if c != "pallas"]
-    return cands
-
-
-def _sp_cands(ctx):
-    cands = ["ring"]
-    if ctx.get("heads", 1) % max(ctx.get("sp", 1), 1) == 0:
-        cands.append("ulysses")
-    return cands
-
-
-def _fused_ce_cands(ctx):
-    # the fused chunked-vocab CE assumes the full [V, H] head on every
-    # rank; under mp the vocab is sharded and only the dense parallel CE
-    # is correct
-    return ["fused", "dense"] if ctx.get("mp", 1) == 1 else ["dense"]
-
-
-register_op("flash_attention",
-            impls=("xla", "dense", "splash", "mosaic", "authored"),
-            candidates=_flash_cands, flag="tpu_flash_impl")
-register_op("paged_attention", impls=("xla", "pallas"),
-            candidates=_paged_cands, flag="tpu_paged_impl",
-            alias_counter="paged_attention.impl")
-register_op("prefill_attention", impls=("xla", "pallas"),
-            candidates=_prefill_cands, flag="tpu_prefill_impl")
-register_op("fused_sampling", impls=("xla",))
-register_op("sp_attention", impls=("ring", "ulysses"),
-            candidates=_sp_cands)
-register_op("fused_ce", impls=("fused", "dense"),
-            candidates=_fused_ce_cands)
-register_op("fused_layernorm", impls=("pallas",))
-register_op("fused_rope", impls=("pallas",))

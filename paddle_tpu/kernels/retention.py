@@ -70,17 +70,10 @@ __all__ = ["retention_update", "retention_chunk", "rotary", "rotary_pairs",
            "state_shapes"]
 
 
-def _tpu_first(ctx):
-    from paddle_tpu.kernels import autotune
-    backend = ctx.get("backend", autotune._backend_kind())
-    return ["pallas", "xla"] if backend == "tpu" else ["xla"]
-
-
 registry.register_op("retention_update", impls=("xla", "pallas"),
-                     candidates=_tpu_first)
+                     candidates=registry.tpu_first)
 registry.register_op("retention_chunk", impls=("xla",))
-registry.register_op("rotary", impls=("xla", "pallas"),
-                     candidates=lambda ctx: ["xla"])
+registry.register_op("rotary", impls=("xla",))
 
 _HI = jax.lax.Precision.HIGHEST
 # the chunk's products with the carried state (the read-out of phi(q) and
@@ -367,23 +360,17 @@ def retention_chunk(state, z, log_g, q, k, v, slot, fresh, valid, *, layer,
 
 # ------------------------------------------------------------------ rotary
 
-def rotary(x, positions, theta, *, impl=None, interpret=None):
+def rotary(x, positions, theta):
     """Half-rotation rotary embedding: ``x`` [T, heads, hd] at absolute
     ``positions`` [T]; the pair of element ``i < hd / 2`` is ``i + hd / 2``.
     Returns float32."""
-    impl = registry.dispatch("rotary", forced=impl)
-    t, n, hd = x.shape
+    registry.count("rotary", "xla")
+    hd = x.shape[-1]
     half = hd // 2
     inv = 1.0 / (theta ** (jnp.arange(half, dtype=jnp.float32) * 2 / hd))
     ang = positions.astype(jnp.float32)[:, None] * inv[None]    # [T, half]
     cos, sin = jnp.cos(ang), jnp.sin(ang)
     x = x.astype(jnp.float32)
-    if impl == "pallas" and n % 2 == 0:
-        from paddle_tpu.kernels.pallas.rotary import apply_rotary_emb
-        xt = x.transpose(1, 0, 2)[None]                  # [1, heads, T, hd]
-        a, b = apply_rotary_emb(xt[:, :n // 2], xt[:, n // 2:], cos, sin,
-                                interpret=interpret)
-        return jnp.concatenate([a, b], axis=1)[0].transpose(1, 0, 2)
     x1, x2 = x[..., :half], x[..., half:]
     c, s = cos[:, None], sin[:, None]
     return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)

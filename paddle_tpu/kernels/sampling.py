@@ -40,7 +40,11 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from paddle_tpu.kernels import registry
+
 __all__ = ["sample_one", "fused_sample", "accept_drafts"]
+
+registry.register_op("fused_sampling", impls=("xla",))
 
 NEG_INF = -1e30
 
@@ -82,7 +86,6 @@ def fused_sample(logits, keys, temperatures, top_ks):
     ``(tokens [B] int32, new_keys [B, 2])``. Registry-dispatched
     (``kernel.dispatch.fused_sampling.*`` counts program builds — the
     selection runs at trace time like every kernel op)."""
-    from paddle_tpu.kernels import registry
     impl = registry.dispatch("fused_sampling")
     return _IMPLS[impl](logits, keys, temperatures, top_ks)
 

@@ -63,14 +63,8 @@ from paddle_tpu.observability import metrics
 __all__ = ["ssm2_update", "ssm2_scan", "CHUNK"]
 
 
-def _update_cands(ctx):
-    from paddle_tpu.kernels import autotune
-    backend = ctx.get("backend", autotune._backend_kind())
-    return ["pallas", "xla"] if backend == "tpu" else ["xla"]
-
-
 registry.register_op("ssm2_update", impls=("xla", "pallas"),
-                     candidates=_update_cands)
+                     candidates=registry.tpu_first)
 registry.register_op("ssm2_scan", impls=("xla",))
 
 CHUNK = 256      # tokens a block of the dual form takes (mamba_chunk_size)

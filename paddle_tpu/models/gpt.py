@@ -545,7 +545,8 @@ def _fused_ce_impl(cfg) -> str:
     vocab is sharded under mp and only the parallel CE is correct);
     ``cfg.fused_ce=False`` forces dense. Counted per trace in
     ``kernel.dispatch.fused_ce.{fused|dense}``."""
-    from paddle_tpu.kernels import registry
+    # `kernels/fused_ce.py` registers the op it implements
+    from paddle_tpu.kernels import fused_ce, registry  # noqa: F401
     mesh = get_mesh()
     mp = 1 if mesh is None else mesh.shape.get("mp", 1)
     return registry.dispatch(

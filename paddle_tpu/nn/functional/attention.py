@@ -13,7 +13,19 @@ import jax
 import jax.numpy as jnp
 
 from paddle_tpu.core.autograd import apply
+from paddle_tpu.kernels import registry
 from paddle_tpu.ops.common import ensure_tensor
+
+
+def _sp_candidates(ctx):
+    cands = ["ring"]
+    if ctx.get("heads", 1) % max(ctx.get("sp", 1), 1) == 0:
+        cands.append("ulysses")
+    return cands
+
+
+registry.register_op("sp_attention", impls=("ring", "ulysses"),
+                     candidates=_sp_candidates)
 
 
 def _sdpa_xla(q, k, v, mask, dropout_p, is_causal, scale, rng_key=None):
@@ -70,7 +82,6 @@ def sequence_parallel_attention(query, key, value, is_causal=True, scale=None,
         raise ValueError(
             f"unknown sequence-parallel attention impl {impl!r}; "
             "choose 'ring', 'ulysses', or 'none'")
-    from paddle_tpu.kernels import registry
     from paddle_tpu.kernels.ring_attention import (
         ring_attention, ulysses_attention)
     # registry-routed (kernels/registry.py): the op validates viability

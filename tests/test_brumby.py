@@ -234,12 +234,15 @@ def test_query_heads_read_their_own_key_value_head():
             and moved[4:].max() == 0.0, moved
 
 
-def test_rotary_arms_agree_and_take_absolute_positions():
+def test_rotary_takes_absolute_positions():
     x = jnp.asarray(np.random.RandomState(1).randn(7, 6, 8), jnp.float32)
-    pos = jnp.arange(7) + 100
-    a = R.rotary(x, pos, 1e6, impl="xla")
-    b = R.rotary(x, pos, 1e6, impl="pallas", interpret=True)
-    assert float(jnp.abs(a - b).max()) <= 1e-6
+    pos = np.arange(7) + 100
+    ang = pos[:, None] * 1e6 ** (-np.arange(4) * 2 / 8)          # [T, half]
+    c, s = np.cos(ang)[:, None], np.sin(ang)[:, None]
+    x1, x2 = np.asarray(x[..., :4]), np.asarray(x[..., 4:])
+    assert np.allclose(np.asarray(R.rotary(x, jnp.asarray(pos), 1e6)),
+                       np.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], -1),
+                       atol=1e-5)
     assert np.allclose(np.asarray(R.rotary(x, jnp.zeros(7, jnp.int32), 1e6)),
                        np.asarray(x))
     # the reference's rotation at positions 0 .. T - 1

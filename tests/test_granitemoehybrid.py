@@ -426,8 +426,8 @@ def test_a_grouped_signature_takes_the_registry_preference(backend,
     decides. Decode prefers the kernel where it is viable (a TPU); prefill
     has the xla arm alone, also against a forced flag; and one-to-one heads
     keep both arms in their old order, to be measured."""
-    from paddle_tpu.kernels import autotune, registry
-    monkeypatch.setattr(autotune, "_backend_kind", lambda: backend)
+    from paddle_tpu.kernels import registry
+    monkeypatch.setattr(registry, "backend", lambda: backend)
     ops = registry.ops()
     on_tpu = backend == "tpu"
     assert ops["paged_attention"].candidates({"grouped": True}) == (

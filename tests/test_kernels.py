@@ -124,7 +124,7 @@ class TestFusedCE:
         interpreter) wherever the plan fits, else XLA's body; either way
         loss, dh and dW are the reference's and the other body's, and the
         trace counts the body it took, once."""
-        from paddle_tpu.kernels import autotune
+        from paddle_tpu.kernels import registry
         from paddle_tpu.kernels.fused_ce import fused_linear_cross_entropy
         from paddle_tpu.kernels.pallas import fused_ce as kernel
         from paddle_tpu.observability import metrics
@@ -150,7 +150,7 @@ class TestFusedCE:
         count = lambda b: metrics.counter(  # noqa: E731
             f"kernel.fused_ce.forward.{b}").value
         was = {b: count(b) for b in ("pallas", "xla")}
-        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        monkeypatch.setattr(registry, "backend", lambda: "tpu")
         (_, loss), (dh, dw) = step()
         other = "xla" if body == "pallas" else "pallas"
         assert count(body) == was[body] + 1 and count(other) == was[other]
@@ -181,8 +181,8 @@ class TestFusedCE:
         """Under an installed multi-device mesh the trace is a program
         GSPMD partitions; a Mosaic kernel cannot join it."""
         import types
-        from paddle_tpu.kernels import autotune, fused_ce
-        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        from paddle_tpu.kernels import fused_ce, registry
+        monkeypatch.setattr(registry, "backend", lambda: "tpu")
         assert fused_ce._pallas_plan(384, 128, 640) is not None
         monkeypatch.setattr(fused_ce, "get_mesh",
                             lambda: types.SimpleNamespace(size=1))
@@ -244,30 +244,92 @@ class TestSdpaDropout:
         assert not np.allclose(b, c)
 
 
-class TestDenseAttentionImpl:
-    def test_dense_matches_xla_flash(self):
+class TestFusedRotaryEmbedding:
+    """`incubate.nn.fused_rotary_position_embedding` against a numpy
+    half-rotation (the pair of element i < D / 2 is i + D / 2)."""
+
+    @staticmethod
+    def _case(seed=0, b=2, h=3, s=40, d=16):
+        rng = np.random.RandomState(seed)
+        q, k, w = (rng.randn(b, h, s, d).astype(np.float32)
+                   for _ in range(3))
+        ang = np.outer(np.arange(s), 1e4 ** (-np.arange(d // 2) * 2.0 / d))
+        return q, k, w, np.cos(ang).astype(np.float32), \
+            np.sin(ang).astype(np.float32)
+
+    @staticmethod
+    def _rot(x, cos, sin):
+        x1, x2 = np.split(x, 2, axis=-1)
+        return np.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
+
+    def test_forward_matches_numpy(self):
+        from paddle_tpu.incubate.nn import fused_rotary_position_embedding
+        q, k, _, cos, sin = self._case()
+        qr, kr = fused_rotary_position_embedding(
+            *(paddle.to_tensor(a) for a in (q, k, cos, sin)))
+        assert tuple(qr.shape) == q.shape and tuple(kr.shape) == k.shape
+        np.testing.assert_allclose(np.asarray(qr._data),
+                                   self._rot(q, cos, sin), atol=1e-6)
+        np.testing.assert_allclose(np.asarray(kr._data),
+                                   self._rot(k, cos, sin), atol=1e-6)
+        # a rotation: the norm of every pair, so of every row, is kept
+        np.testing.assert_allclose(np.linalg.norm(np.asarray(qr._data), axis=-1),
+                                   np.linalg.norm(q, axis=-1), rtol=1e-5)
+
+    def test_gradient_is_the_inverse_rotation(self):
+        from paddle_tpu.incubate.nn import fused_rotary_position_embedding
+        q, k, w, cos, sin = self._case(1)
+        qt, kt = paddle.to_tensor(q), paddle.to_tensor(k)
+        qt.stop_gradient = kt.stop_gradient = False
+        qr, kr = fused_rotary_position_embedding(
+            qt, kt, paddle.to_tensor(cos), paddle.to_tensor(sin))
+        ((qr * paddle.to_tensor(w)).sum() + (kr * 2.0).sum()).backward()
+        # d/dq sum(w * R q) = R^T w: the rotation by the negated angle
+        np.testing.assert_allclose(np.asarray(qt.grad._data),
+                                   self._rot(w, cos, -sin), atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(kt.grad._data),
+            self._rot(np.full_like(k, 2.0), cos, -sin), atol=1e-5)
+
+
+class TestXlaFlashAgainstPlainAttention:
+    """`_xla_flash` (the blockwise arm, custom VJP) against plain
+    full-materialization attention written here: Sq != Sk, causal and not,
+    forward and gradient."""
+
+    @staticmethod
+    def _plain(q, k, v, causal):
+        import jax
         import jax.numpy as jnp
-        from paddle_tpu.kernels.flash_attention import (
-            _dense_attention, _xla_flash)
+        sq, sk = q.shape[2], k.shape[2]
+        logits = jnp.einsum("bhqd,bhkd->bhqk", q, k) / np.sqrt(q.shape[-1])
+        if causal:
+            mask = jnp.arange(sk)[None, :] <= (jnp.arange(sq)[:, None]
+                                               + (sk - sq))
+            logits = jnp.where(mask[None, None], logits, -1e30)
+        return jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(logits, -1), v)
+
+    def test_forward_matches(self):
+        import jax.numpy as jnp
+        from paddle_tpu.kernels.flash_attention import _xla_flash
         rng = np.random.RandomState(0)
         q = jnp.asarray(rng.randn(2, 3, 32, 16).astype(np.float32))
         k = jnp.asarray(rng.randn(2, 3, 48, 16).astype(np.float32))
         v = jnp.asarray(rng.randn(2, 3, 48, 16).astype(np.float32))
         for causal in (False, True):
-            a = _dense_attention(q, k, v, causal, None)
+            a = self._plain(q, k, v, causal)
             b = _xla_flash(q, k, v, causal, None)
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=2e-5, atol=2e-5)
 
-    def test_dense_grads_match(self):
+    def test_grads_match(self):
         import jax
         import jax.numpy as jnp
-        from paddle_tpu.kernels.flash_attention import (
-            _dense_attention, _xla_flash)
+        from paddle_tpu.kernels.flash_attention import _xla_flash
         rng = np.random.RandomState(1)
         q = jnp.asarray(rng.randn(1, 2, 16, 8).astype(np.float32))
-        ga = jax.grad(lambda q_: (_dense_attention(
-            q_, q_, q_, True, None) ** 2).sum())(q)
+        ga = jax.grad(lambda q_: (self._plain(
+            q_, q_, q_, True) ** 2).sum())(q)
         gb = jax.grad(lambda q_: (_xla_flash(
             q_, q_, q_, True, None) ** 2).sum())(q)
         np.testing.assert_allclose(np.asarray(ga), np.asarray(gb),

@@ -186,7 +186,6 @@ def test_the_arm_follows_the_backend_and_is_counted(backend, arm,
     (here: its name steered, the kernel in the interpreter) and the XLA arm
     on the CPU, counts which in the registry's own counter, and both give
     one answer."""
-    from paddle_tpu.kernels import autotune
     assert registry.ops()["mla_attention"].impls == ("xla", "pallas")
     x = _inputs(5, 8, 200)
     select = _select(5, x, 96, ties=True)
@@ -194,7 +193,7 @@ def test_the_arm_follows_the_backend_and_is_counted(backend, arm,
         x["q_nope"], x["q_rope"], x["lat_pool"], x["layer"], x["row"],
         x["qpos"], x["w_ukv"], rank=RANK, rope=ROPE, dv=DV, scale=SCALE,
         select=select)
-    monkeypatch.setattr(autotune, "_backend_kind", lambda: backend)
+    monkeypatch.setattr(registry, "backend", lambda: backend)
     name = f"kernel.dispatch.mla_attention.{arm}"
     before = metrics.counter(name).value
     got, n_got = mla.latent_prefill(

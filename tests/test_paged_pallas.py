@@ -350,49 +350,50 @@ class TestDispatchSwitch:
 
     def test_auto_pins_xla_off_tpu(self):
         from paddle_tpu.framework.flags import set_flags
-        from paddle_tpu.kernels import autotune
-        autotune.clear_cache()
+        from paddle_tpu.kernels import registry
+        registry.clear()
         set_flags({"tpu_paged_impl": "auto"})
         q, kp, vp, pt, pos = self._case()
         before = metrics.counter("paged_attention.impl.xla").value
         pa.paged_attention(q, kp, vp, pt, pos, layer=1)
         assert metrics.counter("paged_attention.impl.xla").value == before + 1
-        key = [k for k in autotune.cache_table() if k[0] == "paged"]
-        assert key and autotune.cache_table()[key[0]][0] == "xla"
-        autotune.clear_cache()
+        key = [k for k in registry.table() if k[0] == "paged"]
+        assert key and registry.table()[key[0]][0] == "xla"
+        registry.clear()
 
 
 class TestPagedAutotune:
+    @staticmethod
+    def _select():
+        from paddle_tpu.kernels import registry
+        key, measure = pa._paged_selection(2, 4, 4, 2, 8, jnp.float32)
+        return registry.dispatch("paged_attention", key=key, measure=measure)
+
     def test_tpu_measures_both_candidates(self, monkeypatch):
-        from paddle_tpu.kernels import autotune
-        autotune.clear_cache()
-        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        from paddle_tpu.kernels import registry
+        registry.clear()
+        monkeypatch.setattr(registry, "backend", lambda: "tpu")
         measured = []
 
         def fake_measure(fn, args, warmup=1, reps=3):
             measured.append(len(measured))
             return [5.0, 1.0][len(measured) - 1]     # pallas wins
 
-        monkeypatch.setattr(autotune, "_measure", fake_measure)
-        w = autotune.paged_winner(2, 4, 4, 2, 8, jnp.float32,
-                                  lambda impl, *a: a[0])
-        assert w == "pallas"
+        monkeypatch.setattr(registry, "measure", fake_measure)
+        assert self._select() == "pallas"
         assert len(measured) == 2        # both candidates timed
         # cached: second lookup measures nothing
-        w2 = autotune.paged_winner(2, 4, 4, 2, 8, jnp.float32,
-                                   lambda *a: (_ for _ in ()).throw(
-                                       AssertionError("must not execute")))
-        assert w2 == "pallas"
-        autotune.clear_cache()
+        assert self._select() == "pallas" and len(measured) == 2
+        registry.clear()
 
-    def test_cpu_pins_xla_without_measuring(self):
-        from paddle_tpu.kernels import autotune
-        autotune.clear_cache()
-        w = autotune.paged_winner(2, 4, 4, 2, 8, jnp.float32,
-                                  lambda *a: (_ for _ in ()).throw(
-                                      AssertionError("must not execute")))
-        assert w == "xla"
-        autotune.clear_cache()
+    def test_cpu_pins_xla_without_measuring(self, monkeypatch):
+        from paddle_tpu.kernels import registry
+        registry.clear()
+        monkeypatch.setattr(
+            registry, "measure",
+            lambda *a, **kw: pytest.fail("one candidate: nothing to time"))
+        assert self._select() == "xla"
+        registry.clear()
 
 
 class TestOverflowToTrash:

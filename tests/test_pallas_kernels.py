@@ -5,9 +5,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from paddle_tpu.kernels.pallas import (
-    flash_attention, fused_layer_norm, apply_rotary_emb,
-)
+from paddle_tpu.kernels.pallas import flash_attention, fused_layer_norm
 from paddle_tpu.kernels.pallas.flash_attention import _reference
 
 R = np.random.RandomState(3)
@@ -129,37 +127,6 @@ class TestFusedLayerNorm:
         for a, b_, name in zip(ga, gb, ["dx", "dgamma", "dbeta"]):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                        rtol=1e-3, atol=1e-3, err_msg=name)
-
-
-class TestRotary:
-    def test_matches_reference(self):
-        S, D = 64, 32
-        q, k, _ = _qkv(s=S, d=D)
-        inv = 1.0 / (10000 ** (np.arange(0, D // 2) / (D // 2)))
-        ang = np.outer(np.arange(S), inv).astype(np.float32)
-        cos, sin = jnp.asarray(np.cos(ang)), jnp.asarray(np.sin(ang))
-        qr, kr = apply_rotary_emb(q, k, cos, sin, block_s=32)
-
-        def ref(x):
-            x1, x2 = x[..., :D // 2], x[..., D // 2:]
-            return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-        np.testing.assert_allclose(np.asarray(qr), np.asarray(ref(q)),
-                                   rtol=1e-5, atol=1e-5)
-        np.testing.assert_allclose(np.asarray(kr), np.asarray(ref(k)),
-                                   rtol=1e-5, atol=1e-5)
-
-    def test_norm_preserved(self):
-        # rotation preserves the per-pair norm
-        S, D = 32, 16
-        q, k, _ = _qkv(s=S, d=D)
-        inv = 1.0 / (10000 ** (np.arange(0, D // 2) / (D // 2)))
-        ang = np.outer(np.arange(S), inv).astype(np.float32)
-        qr, _ = apply_rotary_emb(q, k, jnp.asarray(np.cos(ang)),
-                                 jnp.asarray(np.sin(ang)))
-        n0 = np.linalg.norm(np.asarray(q), axis=-1)
-        n1 = np.linalg.norm(np.asarray(qr), axis=-1)
-        np.testing.assert_allclose(n0, n1, rtol=1e-4)
 
 
 # (b, h, sq, sk, d, dtype, forced (block_q, block_k) or None for `_plan`'s)

@@ -242,16 +242,15 @@ class TestInt8KV:
 
     def test_autotune_int8_measures_with_real_dtype(self, monkeypatch):
         """`auto` dispatch on an int8 pool must MEASURE when the backend
-        has >1 candidate: paged_winner builds its synthetic arrays from the
-        real q dtype and the int8-ness rides the `variant` key suffix — a
+        has >1 candidate: the selection builds its synthetic arrays from the
+        real q dtype and the int8-ness rides the key's suffix — a
         composite dtype string would crash `.astype` on the TPU path the
         feature targets (single-candidate CPU short-circuits never reach
         it, hence this forced two-candidate pin)."""
         from paddle_tpu.framework.flags import set_flags
-        from paddle_tpu.kernels import autotune
-        from paddle_tpu.kernels import paged_attention as pa
-        monkeypatch.setattr(autotune, "_paged_candidates",
-                            lambda backend: ["xla", "pallas"])
+        from paddle_tpu.kernels import paged_attention as pa, registry
+        monkeypatch.setattr(registry.ops()["paged_attention"], "candidates",
+                            lambda ctx: ["xla", "pallas"])
         rng = np.random.RandomState(2)
         b, nh, dh, ps, maxp = 2, 1, 8, 4, 3   # unique geometry: fresh key
         npages = 1 + b * maxp
@@ -273,7 +272,7 @@ class TestInt8KV:
                                    atol=1e-5)
         # the measured winner landed under the variant-suffixed key
         assert any(k[0] == "paged" and str(k[-1]).endswith("/kv-int8")
-                   for k in autotune._CACHE), autotune._CACHE.keys()
+                   for k in registry.table()), registry.table().keys()
 
     @pytest.mark.parametrize("layer", [0, 2])
     def test_pallas_int8_parity(self, layer):

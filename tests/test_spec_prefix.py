@@ -652,31 +652,27 @@ class TestAutotuneDiskCache:
 
     def _run_winner(self, monkeypatch, tmp_path, measure_values,
                     cache_file=None):
-        from paddle_tpu.kernels import autotune
-        from paddle_tpu.kernels.paged_attention import _impl_call
-        autotune.clear_cache()
+        from paddle_tpu.kernels import paged_attention as pa, registry
+        registry.clear()
         path = str(cache_file if cache_file is not None
                    else tmp_path / "autotune.json")
         monkeypatch.setenv("PADDLE_AUTOTUNE_CACHE", path)
-        monkeypatch.setattr(autotune, "_paged_candidates",
-                            lambda backend: ["xla", "alt"])
+        monkeypatch.setattr(registry.ops()["paged_attention"], "candidates",
+                            lambda ctx: ["xla", "alt"])
         calls = []
 
         def fake_measure(fn, args, **kw):
             calls.append(1)
             return measure_values[len(calls) - 1]
 
-        monkeypatch.setattr(autotune, "_measure", fake_measure)
-
-        def run_impl(impl, q, k, v, pt, pos, layer):
-            return _impl_call("xla", q, k, v, pt, pos, layer)
-
-        win = autotune.paged_winner(1, 2, 2, 1, 2, "float32", run_impl)
+        monkeypatch.setattr(registry, "measure", fake_measure)
+        key, measure = pa._paged_selection(1, 2, 2, 1, 2, "float32")
+        win = registry.dispatch("paged_attention", key=key, measure=measure)
         return win, len(calls), path
 
     def test_winner_persists_and_skips_remeasure(self, monkeypatch,
                                                  tmp_path):
-        from paddle_tpu.kernels import autotune
+        from paddle_tpu.kernels import registry
         win, n_measured, path = self._run_winner(
             monkeypatch, tmp_path, measure_values=[0.002, 0.001])
         assert win == "alt" and n_measured == 2
@@ -687,10 +683,10 @@ class TestAutotuneDiskCache:
                                        measure_values=[0.001, 0.002])
         assert win2 == "alt"           # disk answer, NOT the new timings
         assert n2 == 0, "disk hit must skip measurement"
-        autotune.clear_cache()
+        registry.clear()
 
     def test_corrupt_cache_ignored_never_fatal(self, monkeypatch, tmp_path):
-        from paddle_tpu.kernels import autotune
+        from paddle_tpu.kernels import registry
         bad = tmp_path / "autotune.json"
         bad.write_text("{not json")
         win, n_measured, path = self._run_winner(
@@ -699,14 +695,13 @@ class TestAutotuneDiskCache:
         assert win == "xla" and n_measured == 2     # measured fallback
         # and the table was REWRITTEN healthy
         assert json.load(open(path))["winners"]
-        autotune.clear_cache()
+        registry.clear()
 
     def test_stale_winner_outside_viable_set_ignored(self, monkeypatch,
                                                      tmp_path):
         """A table copied from another backend naming a non-viable impl
         must not poison this host: the entry is ignored and re-measured."""
-        from paddle_tpu.kernels import autotune
-        autotune.clear_cache()
+        from paddle_tpu.kernels import registry
         path = tmp_path / "autotune.json"
         # seed the file with the right KEY but a winner this backend
         # cannot run
@@ -720,23 +715,22 @@ class TestAutotuneDiskCache:
             monkeypatch, tmp_path, measure_values=[0.001, 0.002],
             cache_file=path)
         assert win == "xla" and n_measured == 2
-        autotune.clear_cache()
+        registry.clear()
 
     def test_no_env_knob_no_file(self, monkeypatch, tmp_path):
-        from paddle_tpu.kernels import autotune
-        autotune.clear_cache()
+        from paddle_tpu.kernels import paged_attention as pa, registry
+        registry.clear()
         monkeypatch.delenv("PADDLE_AUTOTUNE_CACHE", raising=False)
-        monkeypatch.setattr(autotune, "_paged_candidates",
-                            lambda backend: ["xla", "alt"])
-        monkeypatch.setattr(autotune, "_measure",
+        monkeypatch.chdir(tmp_path)
+        monkeypatch.setattr(registry.ops()["paged_attention"], "candidates",
+                            lambda ctx: ["xla", "alt"])
+        monkeypatch.setattr(registry, "measure",
                             lambda fn, args, **kw: 0.001)
-        from paddle_tpu.kernels.paged_attention import _impl_call
-        autotune.paged_winner(
-            1, 2, 2, 1, 2, "float32",
-            lambda impl, q, k, v, pt, pos, layer: _impl_call(
-                "xla", q, k, v, pt, pos, layer))
+        key, measure = pa._paged_selection(1, 2, 2, 1, 2, "float32")
+        registry.dispatch("paged_attention", key=key, measure=measure)
+        assert registry.table()[key][1] == {"xla": 0.001, "alt": 0.001}
         assert not list(tmp_path.iterdir())
-        autotune.clear_cache()
+        registry.clear()
 
 
 class TestServeKnobs:

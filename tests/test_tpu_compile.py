@@ -271,11 +271,11 @@ def test_head_takes_its_statistics_in_the_kernel_on_v5e(chip, monkeypatch,
     the logits are held once. Under XLA's body, which the CPU's name
     selects: twelve products (four slices of the vocabulary), four
     passes."""
-    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels import registry
     from paddle_tpu.kernels.fused_ce import fused_linear_cross_entropy
     from paddle_tpu.kernels.pallas import _compat
     if body == "pallas":
-        monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+        monkeypatch.setattr(registry, "backend", lambda: "tpu")
         monkeypatch.setattr(_compat, "default_interpret", lambda: False)
     n, hid, v = HEAD
     compiled = jax.jit(jax.value_and_grad(
@@ -799,9 +799,9 @@ def test_granite_step_program_copies_no_state_or_expert_on_v5e(
     the SSM stack inside a kernel (the plain arm wrote a layer's new slab,
     268 MB, beside the stack first); and the program fits the chip beside
     its 13 GB of arguments."""
-    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels import registry
     from paddle_tpu.kernels.pallas import _compat
-    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(registry, "backend", lambda: "tpu")
     monkeypatch.setattr(_compat, "default_interpret", lambda: False)
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
@@ -950,9 +950,9 @@ def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
     float32 ``[heads, chunk, keys]`` scores left under that scope."""
     import json
     import re
-    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels import registry
     from paddle_tpu.kernels.pallas import _compat
-    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(registry, "backend", lambda: "tpu")
     monkeypatch.setattr(_compat, "default_interpret", lambda: False)
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
@@ -1069,9 +1069,9 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
     the program that makes them."""
     import json
     import re
-    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels import registry
     from paddle_tpu.kernels.pallas import _compat
-    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(registry, "backend", lambda: "tpu")
     monkeypatch.setattr(_compat, "default_interpret", lambda: False)
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
@@ -1217,9 +1217,9 @@ def test_brumby_step_program_relays_no_state_and_copies_no_weight_on_v5e(
     arguments; and the op families by which the cell's two kernel shares
     find their time in a device trace are in the program that makes them,
     one family a pattern."""
-    from paddle_tpu.kernels import autotune
+    from paddle_tpu.kernels import registry
     from paddle_tpu.kernels.pallas import _compat
-    monkeypatch.setattr(autotune, "_backend_kind", lambda: "tpu")
+    monkeypatch.setattr(registry, "backend", lambda: "tpu")
     monkeypatch.setattr(_compat, "default_interpret", lambda: False)
     from paddle_tpu.inference.cache import DeviceCache
     from paddle_tpu.inference.programs import (decode_program,
@@ -1298,18 +1298,6 @@ def test_fused_layernorm_compiles_for_v5e(chip, width, bwd):
         jax.grad(_sq(fn), argnums=(0, 1, 2)) if bwd else fn, x, g, g)
 
 
-@pytest.mark.parametrize("width", WIDTHS)
-def test_fused_rope_compiles_for_v5e(chip, width):
-    from paddle_tpu.kernels.pallas.rotary import apply_rotary_emb
-    nh, dh, _ = WIDTHS[width]
-    q = jax.ShapeDtypeStruct((8, nh, 1024, dh), BF16, sharding=chip)
-    cs = jax.ShapeDtypeStruct((1024, dh // 2), jnp.float32, sharding=chip)
-    _compiles_to_a_kernel(
-        lambda q_, k_, c_, s_: apply_rotary_emb(q_, k_, c_, s_,
-                                                interpret=False),
-        q, q, cs, cs)
-
-
 # ------------------------------------------------ chip_smoke, rehearsed
 
 
@@ -1368,7 +1356,7 @@ def test_chip_smoke_kernels_phase_at_toy_size(smoke, toy):
     names = {row["kernel"].split()[0] for row in rec["kernels"]}
     assert names == {"flash_fwd", "flash_bwd", "paged", "paged_int8",
                      "prefill", "prefill_int8", "layernorm_fwd",
-                     "layernorm_bwd", "rope", "fused_ce_fwd"}
+                     "layernorm_bwd", "fused_ce_fwd"}
 
 
 def test_chip_smoke_four_chip_phase_on_virtual_devices(smoke, toy):
