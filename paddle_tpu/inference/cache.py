@@ -69,8 +69,10 @@ class DeviceCache:
             dtype = jnp.dtype(KV_DTYPES[ecfg.kv_dtype])
         nl, nh, ps, B = (fam.kv_layers, fam.kv_heads, ecfg.page_size,
                          ecfg.max_slots)
-        k = jnp.zeros((nl, num_pages, ps, nh * fam.head_dim), dtype)
-        v = jnp.zeros_like(k)
+        # the pools' shapes first, each pool made once: a pool can be a
+        # quarter of the chip, and a K-and-V pair made before a family's
+        # own parts replaced it did not fit beside the weights
+        k_shape = v_shape = (nl, num_pages, ps, nh * fam.head_dim)
         if fam.page_rows:
             # rows that are not K and V (inference/family.py): one pool a
             # part, no twin
@@ -79,9 +81,10 @@ class DeviceCache:
                                  "K/V head, and this family's page rows "
                                  "have none")
             widths = [int(w) for _, w in fam.page_rows]
-            k = jnp.zeros((nl, num_pages, ps, widths[0]), dtype)
-            v = jnp.zeros((nl, num_pages, ps, widths[1]), dtype) \
-                if len(widths) == 2 else jnp.zeros((0, 1, ps, 0), dtype)
+            k_shape = (nl, num_pages, ps, widths[0])
+            v_shape = (nl, num_pages, ps, widths[1]) \
+                if len(widths) == 2 else (0, 1, ps, 0)
+        k, v = jnp.zeros(k_shape, dtype), jnp.zeros(v_shape, dtype)
         # int8 pool: per-token-slot per-head f32 scales, written by the
         # same scatters that write the pages (docs/QUANTIZATION.md)
         ks = jnp.zeros((nl, num_pages, ps, nh), jnp.float32) \

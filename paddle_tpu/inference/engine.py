@@ -130,6 +130,7 @@ from paddle_tpu.framework import compile_cache
 from paddle_tpu.inference.cache import DeviceCache, PageAllocator
 from paddle_tpu.inference.errors import (Cancelled, DeadlineExceeded,
                                          HandoffCorrupt, Overloaded,
+                                         PageLayoutUnsupported,
                                          RecurrentStateUnsupported,
                                          from_wire)
 from paddle_tpu.inference.family import family_of
@@ -751,7 +752,7 @@ class DecodeEngine:
         self._stateful = fam.state is not None
         self._nh, self._dh = fam.kv_heads, fam.head_dim
         self._nl = fam.kv_layers
-        self._refuse_stateful_config(ecfg)
+        self._refuse_config(ecfg)
         self._load_params(model)
         self._served_dtype = self._params[fam.table_key].dtype
         ps = ecfg.page_size
@@ -1024,8 +1025,32 @@ class DecodeEngine:
                 "recurrent state per sequence beside the page pool; pages "
                 "alone cannot restore a sequence")
 
-    def _refuse_stateful_config(self, ecfg: EngineConfig):
+    def _refuse_unmovable(self, what: str):
+        """Whatever ships a sequence's pages through a wire blob (hand-off,
+        migration, the spill tiers): refused for a model with state beside
+        the pool, and for one whose page row is not K and V heads
+        (`ModelFamily.page_rows`), since every blob is laid out as twin K
+        and V pools of ``[.., kv heads, head dim]``."""
+        self._refuse_stateful(what)
+        if self._fam.page_rows:
+            parts = " + ".join(f"{n} ({w})" for n, w in self._fam.page_rows)
+            raise PageLayoutUnsupported(
+                f"{what}: the {self._fam.name} family's page row is "
+                f"{parts}, not K and V heads, and the blob's layout is "
+                "twin K and V pools")
+
+    def _refuse_config(self, ecfg: EngineConfig):
+        """What this family cannot be configured with, refused before
+        anything is allocated."""
+        if ecfg.kv_host_tier_bytes or ecfg.kv_disk_tier_bytes:
+            self._refuse_unmovable("EngineConfig.kv_*_tier_bytes (tier "
+                                   "spill)")
         if not self._stateful:
+            if ecfg.speculate_k is not None \
+                    and not hasattr(self._steps, "verify_step"):
+                raise ValueError(
+                    f"speculate_k={ecfg.speculate_k}: the {self._fam.name} "
+                    "family supplies no verify_step")
             return
         if ecfg.prefix_cache:
             self._refuse_stateful("EngineConfig.prefix_cache=True (prefix "
@@ -1034,9 +1059,6 @@ class DecodeEngine:
             self._refuse_stateful("EngineConfig.speculate_k (speculation "
                                   "rolls rejected tokens back by length "
                                   "alone)")
-        if ecfg.kv_host_tier_bytes or ecfg.kv_disk_tier_bytes:
-            self._refuse_stateful("EngineConfig.kv_*_tier_bytes (tier "
-                                  "spill)")
         if ecfg.kv_dtype == "int8":
             raise ValueError(
                 f"kv_dtype='int8': the {self._fam.name} family's step "
@@ -1805,18 +1827,25 @@ class DecodeEngine:
                           // self.ecfg.page_size) if self._pooled else 0
                 shared: list[int] = []
                 if self._prefix_enabled and req.cache:
-                    shared = self._prefix_lookup(req.page_hashes)
-                    # the page holding the LAST prompt token is always
-                    # recomputed, never shared (the copy-on-write "last
-                    # partial page" copy): the tail prefill needs >= 1 real
-                    # token to produce the first sampled output
-                    shared = shared[:(req.prompt.size - 1)
-                                    // self.ecfg.page_size]
-                if shared:
-                    # claim the cached pages BEFORE alloc: alloc may evict
-                    # refcount-0 cached pages under pressure, and claiming
-                    # makes these ones live (un-evictable)
-                    self._attach_prefix(shared)
+                    # a long shared context is thousands of pages a hit:
+                    # the walk of the hash chain and the refcounts are a
+                    # span of their own (docs/OBSERVABILITY.md)
+                    with metrics.span("engine.prefix_attach",
+                                      cat="engine") as psp:
+                        shared = self._prefix_lookup(req.page_hashes)
+                        # the page holding the LAST prompt token is always
+                        # recomputed, never shared (the copy-on-write "last
+                        # partial page" copy): the tail prefill needs >= 1
+                        # real token to produce the first sampled output
+                        shared = shared[:(req.prompt.size - 1)
+                                        // self.ecfg.page_size]
+                        if shared:
+                            # claim the cached pages BEFORE alloc: alloc
+                            # may evict refcount-0 cached pages under
+                            # pressure, and claiming makes these ones live
+                            # (un-evictable)
+                            self._attach_prefix(shared)
+                        psp.args["pages"] = len(shared)
                 pages = self.allocator.alloc(total - len(shared)) \
                     if self._pooled else []
                 if pages is None:
@@ -2469,7 +2498,7 @@ class DecodeEngine:
         — the prefill half of prefill/decode disaggregation. Pages are
         borrowed from the pool for the duration of the call and freed
         before returning. Driver-thread only (runs device programs)."""
-        self._refuse_stateful("prefill_export (KV hand-off)")
+        self._refuse_unmovable("prefill_export (KV hand-off)")
         ids = np.asarray(
             prompt_ids._data if hasattr(prompt_ids, "_data") else prompt_ids)
         ids = np.ascontiguousarray(ids).reshape(-1).astype(np.int32)
@@ -2557,7 +2586,7 @@ class DecodeEngine:
         (docs/OBSERVABILITY.md "Fleet tracing"): it rides the PTKS1
         header so the decode side joins the same stitched trace, and the
         prefill wall lands as a span in this process's trace ring."""
-        self._refuse_stateful("submit_prefill_stream (KV hand-off)")
+        self._refuse_unmovable("submit_prefill_stream (KV hand-off)")
         ids = np.asarray(
             prompt_ids._data if hasattr(prompt_ids, "_data") else prompt_ids)
         ids = np.ascontiguousarray(ids).reshape(-1).astype(np.int32)
@@ -2708,7 +2737,7 @@ class DecodeEngine:
         queueing. Pass the ORIGINATING request's ``trace`` to keep SLO
         accounting honest across the transfer — with the default fresh
         trace, TTFT on this engine measures only the import itself."""
-        self._refuse_stateful("import_request (KV hand-off)")
+        self._refuse_unmovable("import_request (KV hand-off)")
         req = self._build_import_request(handoff, max_new_tokens,
                                          trace=trace, cache=cache,
                                          speculate=speculate)
@@ -2868,7 +2897,7 @@ class DecodeEngine:
         (tests/test_no_retrace.py). Unlike `import_request`, a full
         engine DEFERS the placement to a later step instead of raising;
         an engine that could never fit it answers a typed error."""
-        self._refuse_stateful("submit_import (migration)")
+        self._refuse_unmovable("submit_import (migration)")
         # double-checked like submit(): fail a draining/dead engine fast,
         # BEFORE the O(context) blake2b pass in _build_import_request —
         # the drain fallback chain probes peers exactly when that pass
@@ -3155,7 +3184,7 @@ class DecodeEngine:
         futures. Scale-down then costs one step + the transfer, not the
         longest running generation."""
         if migrate:
-            self._refuse_stateful("drain(migrate=True) (migration)")
+            self._refuse_unmovable("drain(migrate=True) (migration)")
         with self._work:
             self._draining = True
             if migrate:

@@ -28,7 +28,7 @@ doing — another replica would change neither).
 from __future__ import annotations
 
 __all__ = ["DeadlineExceeded", "Cancelled", "Overloaded", "HandoffCorrupt",
-           "RecurrentStateUnsupported", "from_wire"]
+           "RecurrentStateUnsupported", "PageLayoutUnsupported", "from_wire"]
 
 
 class DeadlineExceeded(RuntimeError):
@@ -67,9 +67,22 @@ class RecurrentStateUnsupported(RuntimeError):
     refuses"). Retrying elsewhere does not help: it is the model's."""
 
 
+class PageLayoutUnsupported(RuntimeError):
+    """The served model's page row is not K and V heads
+    (`ModelFamily.page_rows`: a latent row with no V twin, or two parts of
+    different widths), and the operation would ship pages through a blob
+    that is laid out as twin K and V pools of ``[.., kv heads, head dim]``
+    (``PTKV1`` hand-off, ``PTMG1`` migration, ``PTKT1`` tier frames).
+    Refused at configuration or call time — never served from half a page
+    row (docs/SERVING.md "What refuses"). Prefix reuse INSIDE one engine
+    moves no blob and is served. Retrying elsewhere does not help: it is
+    the model's."""
+
+
 _BY_NAME = {c.__name__: c for c in (DeadlineExceeded, Cancelled,
                                     Overloaded, HandoffCorrupt,
-                                    RecurrentStateUnsupported)}
+                                    RecurrentStateUnsupported,
+                                    PageLayoutUnsupported)}
 
 
 def from_wire(msg: str) -> Exception:

@@ -6,8 +6,8 @@ not its business. A model object hands it one :class:`ModelFamily` through
 where the parameters are, and the kinds and shapes of state a sequence
 keeps. There is no flag and no `EngineConfig` field that picks a model —
 the model object decides. `models/gpt.py`, `models/phi4flash.py`,
-`models/granitemoehybrid.py`, `models/brumby.py`, `models/dots3note.py`
-and `models/gigachat35.py` each supply one; the GPT
+`models/granitemoehybrid.py`, `models/brumby.py`, `models/dots3note.py`,
+`models/gigachat35.py` and `models/kimi_k2.py` each supply one; the GPT
 family describes exactly what the engine used to import, so its programs
 trace as before.
 
@@ -62,7 +62,11 @@ second pool), ``state`` of several ``recurrent`` arrays a layer and
 returns its logits, the two pools (the second the engine's empty one,
 passed through), the state arrays in ``state(...)``'s order, and the counts
 last, which is the order `cache.py::after_prefill` and
-`programs.py::prefill_program` take them in.
+`programs.py::prefill_program` take them in. A family with ``page_rows``
+and NO ``state`` is all pages (the seventh family): the engine's prefix
+store serves it, and what would ship its pages through a wire blob (every
+blob is laid out as twin K and V pools: hand-off, migration, the spill
+tiers) refuses by `errors.PageLayoutUnsupported`.
 
 A step may hand back COUNTS with its tokens. A family with ``step_counts``
 = n > 0 (sparse experts: which held expert took how many tokens) has its
