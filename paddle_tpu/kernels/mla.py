@@ -22,8 +22,22 @@ attention:
   a model with NO indexer, whose decode query attends everything in its
   sight: the context is walked a block of keys at a time through the page
   table, pages read whole (a row gather costs 21 ns a row on a v5e: 8 ms a
-  step for 128 sequences of 3,000), the softmax carried across blocks,
-  the trip count dynamic, by the longest live sequence.
+  step for 128 sequences of 3,000), the softmax carried across blocks.
+  One algorithm, two arms (op ``mla_decode_paged``, ``xla`` and
+  ``pallas``), picked as `latent_prefill`'s are, by what the code can see
+  (`_decode_plan`: the backend, the mesh, the shapes):
+
+  - the Pallas arm (`kernels/pallas/latent_decode.py`; a TPU, no
+    multi-device mesh, the row width and ``rank`` whole lane tiles, a page
+    whole sublane tiles of the pool's type): a grid cell a sequence, its
+    pages copied from the pool WHERE THEY LIE into a ring of VMEM buffers
+    (a run of consecutive page ids by one copy), each sequence to its own
+    length, a block's scores in VMEM from the product that makes them to
+    the mix, the block read once as keys and as values;
+  - the XLA arm (anything else): a `fori_loop` with a DYNAMIC trip count,
+    by the longest live sequence, whose every turn gathers all slots'
+    pages of the block into a copy and sends the ``[slots, heads, keys]``
+    float32 scores through HBM three times.
 - **per head** (`latent_prefill`): the context is walked a block of keys
   at a time (a DYNAMIC trip count: what the sequence has), each block's
   keys and values expanded once for all heads, and a chunk of queries
@@ -80,14 +94,16 @@ absorbed form over the slot's ring; a chunk is the per-head form over the
 ring as it was before the chunk beside the chunk's own rows, which then
 overwrite the ring's oldest.
 
-All but the chunk's per-head walk is plain XLA (ops ``mla_attention``
-with the arms ``xla`` and ``pallas``; ``mla_decode_paged``, ``mla_index``,
-``mla_window``, each with the single arm ``xla``): products in the served
-type with float32 accumulation, scores and softmax in float32. Further
-Pallas arms (the indexer's scores reduced over heads in VMEM; a gather
-that DMAs rows straight into the product) come with the chip reading that
-shows them winning. Which arm a program was built with: the trace-time
-counters ``kernel.dispatch.mla_attention.{xla,pallas}``.
+All but the chunk's per-head walk and the paged absorbed decode is plain
+XLA (ops ``mla_attention`` and ``mla_decode_paged`` with the arms ``xla``
+and ``pallas``; ``mla_index``, ``mla_window``, each with the single arm
+``xla``): products in the served type with float32 accumulation, scores and
+softmax in float32. Further Pallas arms (the indexer's scores reduced over
+heads in VMEM; a gather that DMAs rows straight into the product; a paged
+walk that reads a context several sequences share ONCE) come with the chip
+reading that shows them winning. Which arm a program was built with: the
+trace-time counters ``kernel.dispatch.mla_attention.{xla,pallas}`` and
+``kernel.dispatch.mla_decode_paged.{xla,pallas}``.
 """
 from __future__ import annotations
 
@@ -105,7 +121,7 @@ __all__ = ["index_scores", "index_select", "index_threshold",
            "window_latent_decode", "window_latent_prefill"]
 
 registry.register_op("mla_attention", impls=("xla", "pallas"))
-registry.register_op("mla_decode_paged", impls=("xla",))
+registry.register_op("mla_decode_paged", impls=("xla", "pallas"))
 registry.register_op("mla_index", impls=("xla",))
 registry.register_op("mla_window", impls=("xla",))
 
@@ -378,11 +394,27 @@ def latent_decode_paged(q, lat_pool, layer, table, qpos, *, rank, scale,
     dead slot, sees nothing and gets zeros). Returns ``o_lat`` [B, H, rank]
     in q's type: the mix of ``ckv`` rows, before ``W_uv``.
 
-    Walks the context ``key_block`` keys at a time with a DYNAMIC trip
-    count (to the furthest query), every slot's block of pages gathered at
-    once ([B, block, W]: whole pages, 20 KB each at a page of 16), scored
+    Two arms of one walk (op ``mla_decode_paged``). Where `_decode_plan`
+    finds the Pallas kernel fits what it can see (a TPU, no multi-device
+    mesh, the row width and ``rank`` whole lane tiles, a page whole sublane
+    tiles of the pool's type) the pages are read where they lie, a block of
+    them a turn, each sequence to ITS OWN length, and a turn's scores stay
+    in VMEM (`kernels/pallas/latent_decode.py`; its block comes from the
+    shapes, not from ``key_block``). Anywhere else the loop below runs: the
+    context walked ``key_block`` keys at a time with a DYNAMIC trip count
+    (to the furthest query), every slot's block of pages gathered at once
+    ([B, block, W]: whole pages, 20 KB each at a page of 16), scored
     against the slot's heads, and mixed into the carried output with the
-    softmax's running maximum and sum a head."""
+    softmax's running maximum and sum a head. Every product, accumulation
+    and rounding is at the same point in both."""
+    plan = _decode_plan(q, lat_pool, table, rank)
+    if plan is not None:
+        from paddle_tpu.kernels.pallas import _compat, latent_decode as kernel
+        registry.count("mla_decode_paged", "pallas")
+        registry.count_paged_block(plan.block, op="mla_decode_paged")
+        return kernel.latent_decode_paged(
+            q, lat_pool, layer, table, qpos, plan=plan, rank=rank,
+            scale=float(scale), interpret=_compat.default_interpret())
     registry.count("mla_decode_paged", "xla")
     b, h, _ = q.shape
     ps = lat_pool.shape[2]
@@ -509,13 +541,29 @@ def latent_prefill(q_nope, q_rope, lat_pool, layer, row, qpos, w_ukv, *, rank,
     return out.reshape(h, t, dv).swapaxes(0, 1), n
 
 
-def _prefill_plan(t, h, dn, rope, dv, rank, lat_pool):
-    """The Pallas arm's tiles where it fits the call (on a TPU; in the
-    interpreter where a test steers the backend's name), else None: the XLA
-    arm runs. Under an installed multi-device mesh the trace becomes a
-    program GSPMD partitions, which a Mosaic kernel cannot join."""
+def _on_one_tpu():
+    """Whether a Mosaic kernel can be part of the program being traced: the
+    backend is a TPU (in the interpreter: a test steers its name) and no
+    multi-device mesh is installed, under which the trace becomes a program
+    GSPMD partitions, which a Mosaic kernel cannot join."""
     mesh = get_mesh()
-    if registry.backend() != "tpu" or (mesh is not None and mesh.size > 1):
+    return registry.backend() == "tpu" and (mesh is None or mesh.size <= 1)
+
+
+def _decode_plan(q, lat_pool, table, rank):
+    """The Pallas arm's block where it fits `latent_decode_paged`'s call,
+    else None: the XLA arm runs."""
+    if not _on_one_tpu():
+        return None
+    from paddle_tpu.kernels.pallas import latent_decode as kernel
+    return kernel.plan(q.shape[1], lat_pool.shape[3], rank, lat_pool.shape[2],
+                       lat_pool.dtype.itemsize, *table.shape)
+
+
+def _prefill_plan(t, h, dn, rope, dv, rank, lat_pool):
+    """The Pallas arm's tiles where it fits `latent_prefill`'s call, else
+    None: the XLA arm runs."""
+    if not _on_one_tpu():
         return None
     from paddle_tpu.kernels.pallas import latent_prefill as kernel
     return kernel.plan(t, h, dn, rope, dv, rank, lat_pool.shape[3],

@@ -29,6 +29,12 @@ Kernels:
   values expanded from the latent rows and its scores kept in VMEM under
   the causal mask or the indexer's selection, blocks past the furthest
   query skipped (the arm and its plan are chosen in `kernels/mla.py`).
+- :mod:`latent_decode` — a decode step's latent (MLA) attention in the
+  absorbed form over the paged latent pool: grid over sequences, pages
+  copied from the pool where they lie into a ring of VMEM buffers (a run
+  of consecutive page ids by one copy), each sequence to its own length,
+  a block's scores kept in VMEM (the arm and its plan are chosen in
+  `kernels/mla.py`).
 - :mod:`fused_layernorm` — single-pass layernorm fwd + analytic bwd
   (≈ `fused_layernorm` kernels in `phi/kernels/fusion/`).
 

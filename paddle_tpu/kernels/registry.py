@@ -103,13 +103,15 @@ def count_relayout(op: str):
     metrics.counter(f"kernel.pool_relayout.{op}").inc()
 
 
-def count_paged_block(pages: int):
-    """Trace-time, once per build of the Pallas paged-attention decode
-    kernel: the pages a loop turn of it takes, which the kernel reads off
-    the pool's shape (`kernels/pallas/paged_attention.py::block_pages`) —
-    ``kernel.paged_block.{pages}`` says which shape a program runs,
-    without a chip."""
-    metrics.counter(f"kernel.paged_block.{pages}").inc()
+def count_paged_block(pages: int, op: str | None = None):
+    """Trace-time, once per build of a Pallas kernel that walks a paged
+    pool: the pages a loop turn of it takes, which the kernel reads off the
+    shapes — ``kernel.paged_block.{pages}`` for the paged-attention decode
+    kernel (`kernels/pallas/paged_attention.py::block_pages`),
+    ``kernel.paged_block.{op}.{pages}`` for another ``op``'s — says which
+    shape a program runs, without a chip."""
+    metrics.counter(
+        f"kernel.paged_block.{op + '.' if op else ''}{pages}").inc()
 
 
 def backend() -> str:

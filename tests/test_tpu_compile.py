@@ -927,6 +927,33 @@ def test_latent_prefill_kernel_compiles_for_v5e(chip, call):
     assert compiled.memory_analysis().temp_size_in_bytes < 40e6
 
 
+# a decode step's paged absorbed walk alone
+# (kernels/pallas/latent_decode.py) at the two cells' shapes: Kimi's 32
+# slots over rows of 1,632 pages in a pool of five layers, GigaChat's 128
+# slots over rows of 224 in a pool of one
+@pytest.mark.parametrize("call", ["kimi", "giga"])
+def test_latent_decode_kernel_compiles_for_v5e(chip, call):
+    from paddle_tpu.kernels.pallas import latent_decode as kernel
+    slots, row, pages, layers = (32, 1632, 40961, 5) if call == "kimi" \
+        else (128, 224, 28673, 1)
+    heads, rank, width = 64, 512, 640
+    plan = kernel.plan(heads, width, rank, PAGE, 2, slots, row)
+    assert plan is not None and plan.block % plan.run == 0
+
+    def spec(shape, dtype=BF16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+    compiled = jax.jit(lambda *a: kernel.latent_decode_paged(
+        *a, plan=plan, rank=rank, scale=192 ** -0.5,
+        interpret=False)).lower(
+        spec((slots, heads, width)), spec((layers, pages, PAGE, width)),
+        spec((), jnp.int32), spec((slots, row), jnp.int32),
+        spec((slots,), jnp.int32)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    # the pool is read where it lies: beside the kernel only what is found
+    # in the page table (the clipped table, its runs, the pages a slot has)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e6
+
+
 # dots3-note-prev as benchmarks/configs/dots3-note-prev.json serves it: one
 # chip's share of eight (layers 0-4, 32 of 256 experts, 19,008 rows)
 @pytest.mark.parametrize("program", ["decode_step", "prefill_chunk_step"])
@@ -1113,20 +1140,29 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
         up.spec(sharding=chip)).compile()
     text = compiled.as_text()
     kernels = text.count("custom_call_target=\"tpu_custom_call\"")
-    # a chunk's one full layer attends inside the latent-prefill kernel
-    assert kernels == (len(cfg.linear_layers) if program == "decode_step"
-                       else len(cfg.full_layers))
+    # the one full layer attends inside a kernel in both programs: a decode
+    # step's paged absorbed walk (kernels/pallas/latent_decode.py) beside
+    # the linear layers' updates, a chunk's per-head walk (latent_prefill)
+    assert kernels == len(cfg.full_layers) + (
+        len(cfg.linear_layers) if program == "decode_step" else 0)
     under = _under_scope(text, "mla")
     calls = [ln for f, _, ln in under if f.startswith("custom-call")
              and "tpu_custom_call" in ln]
-    assert len(calls) == (0 if program == "decode_step"
-                          else len(cfg.full_layers))
-    if program == "prefill_chunk_step":
+    assert len(calls) == len(cfg.full_layers)
+    from paddle_tpu.kernels import mla
+    if program == "decode_step":
+        # nothing of the XLA walk is left under that scope: no `while`, no
+        # gather of every slot's block of pages into a copy, no float32
+        # [slots, heads, block] scores
+        assert not [f for f, _, _ in under if f.startswith("while")]
+        gathered = slots * mla.DECODE_KEY_BLOCK // page
+        assert f"bf16[{gathered},{page},{cfg.latent_width}]" not in text
+        scores = slots * cfg.num_heads * mla.DECODE_KEY_BLOCK
+    else:
         # no float32 [heads, chunk, keys] scores left under that scope
-        from paddle_tpu.kernels import mla
         scores = mla.HEAD_BLOCK * sv["prefill_chunk_tokens"] * mla.KEY_BLOCK
-        big = [(f, n) for f, n, _ in under if n >= scores]
-        assert big == [], big
+    big = [(f, n) for f, n, _ in under if n >= scores]
+    assert big == [], big
     # no rematerialized instruction reads a donated array: short of memory
     # at 128 slots the compiler rematerializes, and a clone of an in-place
     # update that reads what it replaces ran TWICE on the chip (the
@@ -1144,16 +1180,14 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
     shapes = giga_bytes.trace_shapes(cfgj)
     families = {trace.family(ln.strip().removeprefix("ROOT "))
                 for ln in text.splitlines() if " = " in ln}
-    made_by = {"decode_step": ("deltanet_update", "latent_paged_attn",
-                               "giga_experts"),
+    made_by = {"decode_step": ("deltanet_update", "giga_experts"),
                "prefill_chunk_step": ("deltanet_chunk", "giga_experts")}
-    if program == "prefill_chunk_step":
-        # the chunk's walk is the kernel counted above: none of its three
-        # families is left, and the scope the metric asks for finds it
-        metric = harness_spec.layer_metric("latent_paged_attn_roofline_share")
-        assert metric["scopes"] == ["mla"]
-        assert not [f for f in families for p in metric["patterns"]
-                    if re.search(p.format(**shapes), f)]
+    # both programs' walks are the kernels counted above: none of the XLA
+    # walks' families is left, and the scope the metric asks for finds them
+    metric = harness_spec.layer_metric("latent_paged_attn_roofline_share")
+    assert metric["scopes"] == ["mla"]
+    assert not [f for f in families for p in metric["patterns"]
+                if re.search(p.format(**shapes), f)]
     for name in made_by[program]:
         metric = harness_spec.layer_metric(f"{name}_roofline_share")
         found = [bool([f for f in families

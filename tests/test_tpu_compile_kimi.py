@@ -80,6 +80,11 @@ def test_kimi_step_program_fits_and_names_its_kernels_on_v5e(
     cache = DeviceCache(k=lat, v=sds((0, 1, page, 0), BF16), k_scale=None,
                         v_scale=None, state=(), keys=None, heads=1)
     n = km.step_counts(cfg)
+    from paddle_tpu.observability import metrics
+    arms = {a: metrics.counter(f"kernel.dispatch.mla_decode_paged.{a}")
+            for a in ("xla", "pallas")}
+    block = metrics.counter("kernel.paged_block.mla_decode_paged.64")
+    built = [arms["xla"].value, arms["pallas"].value, block.value]
     if program == "decode_step":
         up = step_upload(slots, per_slot, sampling=False)
         step = decode_program(km, cfg, up, n)
@@ -91,6 +96,11 @@ def test_kimi_step_program_fits_and_names_its_kernels_on_v5e(
         params, cache, sds((slots + n,), jnp.int32),
         up.spec(sharding=chip)).compile()
     text = compiled.as_text()
+    # which arm the program was built with, and the block it took: a layer
+    # of a decode step each, a chunk none
+    walks = cfg.num_layers if program == "decode_step" else 0
+    assert [arms["xla"].value, arms["pallas"].value, block.value] \
+        == [built[0], built[1] + walks, built[2] + walks]
     # the ops a device trace will show, by the scope the reader finds them
     # under (`harness/trace.py`: the innermost wanted scope of a name stack)
     under = {s: [] for s in SCOPES}
@@ -104,20 +114,26 @@ def test_kimi_step_program_fits_and_names_its_kernels_on_v5e(
     other = "mla_chunk" if program == "decode_step" else "mla_decode"
     assert under[mine] and under["moe_experts"] and not under[other]
     kernels = [ln for ln in under[mine] if "tpu_custom_call" in ln]
+    # one Mosaic call a latent layer, its op name carrying the scope: the
+    # paged absorbed walk (kernels/pallas/latent_decode.py) or the chunk's
+    # per-head walk (latent_prefill.py)
+    assert len(kernels) == cfg.num_layers
     if program == "decode_step":
-        # the paged absorbed walk is XLA's: one `while` a layer
-        assert kernels == []
-        walks = [ln for ln in under[mine] if re.search(r" while\(", ln)]
-        assert len(walks) == cfg.num_layers
+        # nothing of the XLA walk is left: no `while` a layer, no gather of
+        # every slot's block of pages into a copy, no float32 [slots,
+        # heads, block] scores
+        assert not [ln for ln in under[mine] if re.search(r" while\(", ln)]
+        gathered = slots * mla.DECODE_KEY_BLOCK // page
+        assert f"bf16[{gathered},{page},{cfg.latent_width}]" not in text
+        scores = slots * cfg.num_heads * mla.DECODE_KEY_BLOCK
     else:
-        assert len(kernels) == cfg.num_layers
         scores = mla.HEAD_BLOCK * sv["prefill_chunk_tokens"] * mla.KEY_BLOCK
-        for ln in under[mine]:
-            fam = trace.family(ln.removeprefix("ROOT "))
-            big = [d for d in re.findall(r"f32\[([\d,]*)\]",
-                                         fam.split(" ", 1)[1])
-                   if np.prod([int(x) for x in d.split(",") if x]) >= scores]
-            assert big == [], ln[:200]
+    for ln in under[mine]:
+        fam = trace.family(ln.removeprefix("ROOT "))
+        big = [d for d in re.findall(r"f32\[([\d,]*)\]",
+                                     fam.split(" ", 1)[1])
+               if np.prod([int(x) for x in d.split(",") if x]) >= scores]
+        assert big == [], ln[:200]
     clones = [ln.strip()[:160] for ln in text.splitlines()
               if re.match(r"\s*%[\w.\-]*remat[\w.\-]* = ", ln)
               and "%cache_" in ln]
@@ -126,5 +142,9 @@ def test_kimi_step_program_fits_and_names_its_kernels_on_v5e(
     assert mem.alias_size_in_bytes >= 2 * int(np.prod(lat.shape))
     assert 11.1e9 < mem.argument_size_in_bytes < 11.3e9
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 13.5e9
+    # no larger than under the XLA walk (0.066 GB a decode step, 0.114 a
+    # chunk: PERF.md section 4, PR 47)
+    assert mem.temp_size_in_bytes \
+        <= (0.066e9 if program == "decode_step" else 0.115e9)
     print(program, "temp", mem.temp_size_in_bytes, "args",
           mem.argument_size_in_bytes)
