@@ -110,7 +110,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from paddle_tpu.distributed.mesh import get_mesh
 from paddle_tpu.kernels import registry
 from paddle_tpu.kernels.diff_attention import ring_positions
 from paddle_tpu.kernels.paged_attention import TRASH_PAGE
@@ -541,19 +540,10 @@ def latent_prefill(q_nope, q_rope, lat_pool, layer, row, qpos, w_ukv, *, rank,
     return out.reshape(h, t, dv).swapaxes(0, 1), n
 
 
-def _on_one_tpu():
-    """Whether a Mosaic kernel can be part of the program being traced: the
-    backend is a TPU (in the interpreter: a test steers its name) and no
-    multi-device mesh is installed, under which the trace becomes a program
-    GSPMD partitions, which a Mosaic kernel cannot join."""
-    mesh = get_mesh()
-    return registry.backend() == "tpu" and (mesh is None or mesh.size <= 1)
-
-
 def _decode_plan(q, lat_pool, table, rank):
     """The Pallas arm's block where it fits `latent_decode_paged`'s call,
     else None: the XLA arm runs."""
-    if not _on_one_tpu():
+    if not registry.on_one_tpu():
         return None
     from paddle_tpu.kernels.pallas import latent_decode as kernel
     return kernel.plan(q.shape[1], lat_pool.shape[3], rank, lat_pool.shape[2],
@@ -563,7 +553,7 @@ def _decode_plan(q, lat_pool, table, rank):
 def _prefill_plan(t, h, dn, rope, dv, rank, lat_pool):
     """The Pallas arm's tiles where it fits `latent_prefill`'s call, else
     None: the XLA arm runs."""
-    if not _on_one_tpu():
+    if not registry.on_one_tpu():
         return None
     from paddle_tpu.kernels.pallas import latent_prefill as kernel
     return kernel.plan(t, h, dn, rope, dv, rank, lat_pool.shape[3],

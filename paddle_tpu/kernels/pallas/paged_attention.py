@@ -40,6 +40,24 @@ bf16 pass with f32 accumulation, whose products are exact in f32; an f32
 pool, and an int8 pool dequantised after its copies land, take the f32
 ``HIGHEST`` form (:func:`_lossless_dot`).
 
+**Differential pairs** (``value_heads=2``; `kernels/diff_attention.py::
+diff_attention_paged`, Phi-4-mini-flash's shared cache: 40 query heads over
+20 K/V heads of 64). The same walk, plan, ring and stop; what differs is a
+fact of the heads, in two places. Which query head reads which K head:
+query head ``(p * g + j) * 2 + c`` reads K head ``2p + c``, the wrapper's
+one transpose on the way in and one on the way out. And what a row keeps
+of its mix: not its own head's ``dh`` lanes but its PAIR's ``2 * dh`` (V
+heads ``2p`` and ``2p + 1`` are one value head of twice the width), so the
+two rows of a pair cannot share an output row: the result is ``[B, 2 * g,
+nkv * dh]`` float32, each row's softmax normalised, and the difference of a
+pair's two softmaxes and its norm are the caller's. This form mixes from
+probabilities ROUNDED to the pool's type, as its XLA arm does (one bf16
+pass where `_lossless_dot` makes three of float32 probabilities: 48 rows
+against a ``[tokens, 1280]`` block are paced by loading the block into the
+MXU, and three passes would pace the kernel), and a sequence whose ``pos``
+is negative is a dead slot: no turn, no copy, zeros. With ``value_heads=1``
+none of this is traced.
+
 **Layout.** The chip's compiler only slices a page out of a pool whose last
 two dims are tile-aligned, and ``(nh, dh) = (12, 64)`` or ``(16, 64)`` is
 not (dh < 128 lanes). The engine therefore STORES the pool merged and
@@ -156,7 +174,7 @@ def _lossless_dot(a, b, dims):
 
 
 def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
-                   page_size, nh, bp, scale, g=1, quant=False,
+                   page_size, nh, bp, scale, g=1, vh=1, quant=False,
                    has_visits=False):
     # one grid cell per sequence b, all heads at once: q_ref [1, g, nkv*dh]
     # in VMEM (``nh`` query heads over ``nkv = nh / g`` K/V heads; row j
@@ -164,7 +182,12 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     # row of GPT-2's one-to-one heads), k_hbm/v_hbm the stacked [nl, num_pages, page_size, nh*dh]
     # pools in HBM, pos/page_table/layer scalar-prefetched into SMEM (the
     # layer is an operand, not a constant: every layer of a program runs
-    # this one kernel). Operand order is
+    # this one kernel). ``vh`` K/V heads' lanes are what a row keeps of its
+    # mix: 1, its own head's (o_ref [1, g, nkv*dh]); 2, its PAIR's (the
+    # differential form, module docstring: o_ref [1, 2*g, nkv*dh] float32,
+    # and row j of q holds, on K/V head p*2+c's lanes, query head
+    # (p*g+j)*2+c).
+    # Operand order is
     # inputs (q, k, v[, k_scale, v_scale]), outputs (o[, visits]), scratch
     # (kbuf, vbuf, sem, ring); ``quant`` and ``has_visits`` are static
     # flags, never inferred from argument counts. Under ``quant`` the pools
@@ -201,11 +224,23 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         # never walk past the page-table row: an out-of-range page index
         # is a wild DMA, which halts the chip (the XLA arm clamps the same
         # way). Entries of the row past this count are never read.
-        return jnp.clip(pages_needed(pos_ref[seq], page_size), 1,
-                        pt_ref.shape[1])
+        n = jnp.clip(pages_needed(pos_ref[seq], page_size), 1,
+                     pt_ref.shape[1])
+        if vh > 1:
+            n = jnp.where(pos_ref[seq] < 0, 0, n)           # a dead slot
+        return n
 
     def nblocks_of(seq):
         return (npages_of(seq) + bp - 1) // bp
+
+    def after(seq):
+        # the sequence whose block 0 follows ``seq``'s last block: the next
+        # one, or (the differential form) the next that is not dead
+        if vh == 1:
+            return seq + 1
+        return jax.lax.while_loop(
+            lambda s: (s < nb) & (nblocks_of(jnp.minimum(s, nb - 1)) == 0),
+            lambda s: s + 1, seq + 1)
 
     def copies(seq, j, turn, act):
         # block j of sequence seq <-> the ring slot of its turn: only the
@@ -237,7 +272,7 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         def _():
             copies(seq, j, turn, lambda c: c.start())
             more = j + 1 < nblocks_of(seq)
-            ring[1] = jnp.where(more, seq, seq + 1)
+            ring[1] = jnp.where(more, seq, after(seq))
             ring[2] = jnp.where(more, j + 1, 0)
 
     @pl.when(b == 0)
@@ -247,7 +282,7 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         # fresh VMEM need not be
         vbuf[...] = jnp.zeros(vbuf.shape, vbuf.dtype)
         ring[0] = 0
-        ring[1] = 0
+        ring[1] = after(-1)
         ring[2] = 0
         for turn in range(nslots - 1):
             prefetch(turn)
@@ -310,6 +345,10 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         l_new = l * alpha + jnp.sum(p, axis=1, keepdims=True)
+        if vh > 1:
+            # the differential form mixes from probabilities rounded to
+            # the pool's type, as its XLA arm does: one pass
+            p = p.astype(v.dtype)
         acc_new = acc * alpha + _lossless_dot(p, v, nn)    # [nhp, nh*dh]
         return m_new, l_new, acc_new
 
@@ -332,12 +371,29 @@ def _decode_kernel(pos_ref, pt_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     # row r of acc holds its head's sum over ALL lanes; keep its own dh
     # (one nonzero term a lane among the nkv rows of one j, so these
     # reduces are exact)
-    num, den = acc * own, jnp.maximum(l, 1e-30) * own
+    if vh == 1:
+        num, den = acc * own, jnp.maximum(l, 1e-30) * own
+        for j in range(g):
+            rows = slice(j * nkv, (j + 1) * nkv) if g > 1 else slice(None)
+            o_ref[0, j:j + 1] = (
+                jnp.sum(num[rows], axis=0, keepdims=True)
+                / jnp.sum(den[rows], axis=0, keepdims=True)
+            ).astype(o_ref.dtype)
+        return
+    # a row keeps the lanes of its GROUP of vh K/V heads (k // vh), which
+    # the group's vh rows of one j share: output row j * vh + c sums the
+    # rows whose K/V head is c within its group, again one term a lane. A
+    # dead slot took no turn: 0 / 1e-30
+    normed = acc / jnp.maximum(l, 1e-30)
+    kv = jax.lax.rem(head, nkv)
+    group0 = jax.lax.div(kv, vh) * (vh * dh)
+    mine = (lane >= group0) & (lane < group0 + vh * dh) & (head < nh)
     for j in range(g):
-        rows = slice(j * nkv, (j + 1) * nkv) if g > 1 else slice(None)
-        o_ref[0, j:j + 1] = (
-            jnp.sum(num[rows], axis=0, keepdims=True)
-            / jnp.sum(den[rows], axis=0, keepdims=True)).astype(o_ref.dtype)
+        for c in range(vh):
+            keep = mine & (jax.lax.div(head, nkv) == j) \
+                & (jax.lax.rem(kv, vh) == c)
+            o_ref[0, j * vh + c:j * vh + c + 1] = jnp.sum(
+                jnp.where(keep, normed, 0.0), axis=0, keepdims=True)
 
 
 def scale_window(scales, page_table, layer):
@@ -350,7 +406,7 @@ def scale_window(scales, page_table, layer):
 
 def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
                     interpret=None, return_visits=False, k_scale=None,
-                    v_scale=None, scale=None):
+                    v_scale=None, scale=None, value_heads=1, op=None):
     """One decode step of ragged paged attention. Same contract as the XLA
     reference `kernels.paged_attention.paged_attention`:
 
@@ -368,6 +424,12 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
                  copies, so the kernel's page traffic is the int8 bytes
                  (~1/4 of f32)
     scale      : what multiplies the scores (None: ``1 / sqrt(dh)``)
+    value_heads: 2 is the differential form ("Differential pairs" above):
+                 returns [B, nh, 2 * dh] float32, head h's mix over the
+                 lanes of its K/V head's PAIR, not normalised against its
+                 twin; ``pos`` < 0 is a dead slot: no page fetched, zeros
+    op         : the op the block is counted for, where it is not this
+                 module's own (``kernel.paged_block.{op}.{pages}``)
     returns    : [B, nh, dh] in q.dtype; with ``return_visits=True`` also
                  the pages fetched [B, nh] int32 (one walk serves every
                  head of a sequence, so a row repeats one count) — the
@@ -384,18 +446,20 @@ def paged_attention(q, k_pages, v_pages, page_table, pos, *, layer=None,
         "paged_attention", k_pages, v_pages, k_scale, v_scale, layer)
     from paddle_tpu.kernels import registry
     registry.count_paged_block(block_pages(
-        k_pages.shape[2], k_pages.shape[3], k_pages.dtype.itemsize))
+        k_pages.shape[2], k_pages.shape[3], k_pages.dtype.itemsize), op=op)
     return _stored_call(q, k_pages, v_pages, page_table, pos,
                         jnp.asarray(layer, jnp.int32), k_scale, v_scale,
                         interpret=bool(interpret),
                         return_visits=bool(return_visits),
-                        scale=None if scale is None else float(scale))
+                        scale=None if scale is None else float(scale),
+                        value_heads=int(value_heads))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "return_visits",
-                                             "scale"))
+                                             "scale", "value_heads"))
 def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
-                 v_scale, *, interpret, return_visits, scale=None):
+                 v_scale, *, interpret, return_visits, scale=None,
+                 value_heads=1):
     # the kernel over the stored pools at a TRACED layer, as a function of
     # its own: every layer of a step program is the same call of it, so a
     # program traces and lowers the kernel once, not once a layer (which
@@ -406,14 +470,19 @@ def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
     from paddle_tpu.kernels.paged_attention import query_groups
     g = query_groups(nh, dh, hd)
     nkv = nh // g
+    vh = value_heads
+    if nkv % vh:
+        raise ValueError(f"{nkv} K/V heads in groups of {vh}")
     scale = 1.0 / (dh ** 0.5) if scale is None else scale
     bp = block_pages(ps, hd, k_pages.dtype.itemsize)
     kern = functools.partial(_decode_kernel, page_size=ps, nh=nh, bp=bp,
-                             scale=float(scale), g=g, quant=quant,
+                             scale=float(scale), g=g, vh=vh, quant=quant,
                              has_visits=return_visits)
     row = pl.BlockSpec((1, g, hd), lambda i, *_: (i, 0, 0))
-    out_specs = [row]
-    out_shape = [jax.ShapeDtypeStruct((b, g, hd), q.dtype)]
+    out_specs = [row if vh == 1 else
+                 pl.BlockSpec((1, g * vh, hd), lambda i, *_: (i, 0, 0))]
+    out_shape = [jax.ShapeDtypeStruct(
+        (b, g * vh, hd), q.dtype if vh == 1 else jnp.float32)]
     if return_visits:
         out_specs.append(pl.BlockSpec((1, 1, 128), lambda i, *_: (i, 0, 0)))
         out_shape.append(jax.ShapeDtypeStruct((b, 1, 128), jnp.int32))
@@ -423,8 +492,10 @@ def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
         pl.BlockSpec(memory_space=pl.ANY),            # V pool stays in HBM
     ]
     # row j of a sequence's q: query head k * g + j on K/V head k's lanes
-    operands = [q.reshape(b, nkv, g, dh).swapaxes(1, 2).reshape(b, g, hd),
-                k_pages, v_pages]
+    # (vh > 1: head (p * g + j) * vh + c on those of K/V head p * vh + c)
+    q_rows = q.reshape(b, nkv, g, dh).swapaxes(1, 2) if vh == 1 else \
+        q.reshape(b, nkv // vh, g, vh, dh).transpose(0, 2, 1, 3, 4)
+    operands = [q_rows.reshape(b, g, hd), k_pages, v_pages]
     if quant:
         # whole blocks of scales, so that the last block's rows exist; and
         # zeros past the pages a sequence has, whose table entries may name
@@ -464,7 +535,13 @@ def _stored_call(q, k_pages, v_pages, page_table, pos, layer, k_scale,
             interpret=interpret,
         )(pos.astype(jnp.int32), page_table.astype(jnp.int32),
           layer.reshape(1), *operands)
-    out = outs[0].reshape(b, g, nkv, dh).swapaxes(1, 2).reshape(b, nh, dh)
+    if vh == 1:
+        out = outs[0].reshape(b, g, nkv, dh).swapaxes(1, 2) \
+            .reshape(b, nh, dh)
+    else:
+        # row j * vh + c, group p's lanes -> head (p * g + j) * vh + c
+        out = outs[0].reshape(b, g, vh, nkv // vh, vh * dh) \
+            .transpose(0, 3, 1, 2, 4).reshape(b, nh, vh * dh)
     if return_visits:
         return out, jnp.broadcast_to(outs[1][:, 0, :1], (b, nh))
     return out

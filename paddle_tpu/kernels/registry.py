@@ -12,7 +12,8 @@ candidates are computed ONCE and, where the call site gives a measurement,
 keys are ``(op-tag, backend, shape-class..., dtype[, variant])``). Every
 resolution counts ``kernel.dispatch.{op}.{impl}`` at TRACE time, every
 selection is one ``kernel.select:<op>`` span. :func:`backend` and
-:func:`measure` are the measurement's two probes.
+:func:`measure` are the measurement's two probes; :func:`on_one_tpu` is
+what an op that picks a Mosaic arm from what it can see asks first.
 """
 from __future__ import annotations
 
@@ -29,7 +30,8 @@ _LOG = logging.getLogger("paddle_tpu.kernels.registry")
 
 __all__ = ["KernelOp", "register_op", "ops", "dispatch", "count",
            "count_relayout", "count_paged_block", "select",
-           "table", "clear", "backend", "measure", "tpu_first"]
+           "table", "clear", "backend", "on_one_tpu", "measure",
+           "tpu_first"]
 
 
 @dataclass
@@ -120,6 +122,16 @@ def backend() -> str:
     off-TPU is a parity tool, not a serving path)."""
     import jax
     return jax.default_backend()
+
+
+def on_one_tpu() -> bool:
+    """Whether a Mosaic kernel can be part of the program being traced: the
+    backend is a TPU (in the interpreter: a test steers its name) and no
+    multi-device mesh is installed, under which the trace becomes a program
+    GSPMD partitions, which a Mosaic kernel cannot join."""
+    from paddle_tpu.distributed.mesh import get_mesh
+    mesh = get_mesh()
+    return backend() == "tpu" and (mesh is None or mesh.size <= 1)
 
 
 def tpu_first(ctx) -> list:

@@ -309,13 +309,10 @@ def _gmu(h, memory, p, cfg):
     return _mlp(h + out, p, cfg)
 
 
-def _cross(h, kg, vg, mask, p, l0, cfg, batched):
+def _cross(h, read, p, l0, cfg):
     r = _ln(h, p["ln1.w"], p["ln1.b"], cfg.layer_norm_eps)
-    q = r @ p["q.w"] + p["q.b"]
-    q = q[:, None] if batched else q[None]
-    att = da.diff_attention(q, kg, vg, mask, _lam(p) + l0, l0, p["subln.w"],
-                            eps=cfg.layer_norm_eps, **_heads(cfg))
-    att = att[:, 0] if batched else att[0]
+    att = read(r @ p["q.w"] + p["q.b"], _lam(p) + l0, l0, p["subln.w"],
+               eps=cfg.layer_norm_eps)
     return _attn_out(h, att, p, cfg)
 
 
@@ -324,15 +321,17 @@ def _logits(params, h, cfg):
     return (h @ params["embed"].T).astype(jnp.float32)
 
 
-def _back(params, h, memory, kg, vg, mask, cfg, batched):
-    """The [memory unit, cross attention] periods over stacked leaves."""
+def _back(params, h, memory, read, cfg):
+    """The [memory unit, cross attention] periods over stacked leaves;
+    ``read`` is layer ``half + 1``'s attention over its K and V
+    (`da.paged_decode` / `da.paged_prefill`)."""
     if cfg.n_back == 0:
         return h
 
     def period(h, xs):
         pg, pc, l0 = xs
         h = _gmu(h, memory, pg, cfg)
-        return _cross(h, kg, vg, mask, pc, l0, cfg, batched), None
+        return _cross(h, read, pc, l0, cfg), None
 
     l0s = jnp.asarray([lambda_init(cfg.half + 3 + 2 * b)
                        for b in range(cfg.n_back)], jnp.float32)
@@ -374,11 +373,11 @@ def decode_step(params, ids, cache, slot_mask, *, cfg):
     pa = _sub(params, "mid.a.")
     l0 = lambda_init(cfg.half + 1)
     q, k, v = _qkv(h, pa, cfg)
-    att, kc, vc, kg, vg, mask = da.paged_decode(
+    att, kc, vc, read = da.paged_decode(
         q, k, v, kc, vc, table, pos, slot_mask, _lam(pa) + l0, l0,
         pa["subln.w"], **hk)
     h = _attn_out(h, att, pa, cfg)
-    h = _back(params, h, memory, kg, vg, mask, cfg, batched=True)
+    h = _back(params, h, memory, read, cfg)
     new_cache = dict(k_pages=kc, v_pages=vc, page_table=table,
                      lengths=jnp.where(slot_mask, pos + 1, pos),
                      state=(*wk, *wv, conv, ssm))
@@ -414,11 +413,11 @@ def prefill_chunk_step(params, ids, start, valid, page_table, k_pages,
     pa = _sub(params, "mid.a.")
     l0 = lambda_init(cfg.half + 1)
     q, k, v = _qkv(h, pa, cfg)
-    att, k_pages, v_pages, kg, vg, mask = da.paged_prefill(
+    att, k_pages, v_pages, read = da.paged_prefill(
         q, k, v, k_pages, v_pages, page_table, start, valid, _lam(pa) + l0,
         l0, pa["subln.w"], **hk)
     h = _attn_out(h, att, pa, cfg)
-    h = _back(params, h, memory, kg, vg, mask, cfg, batched=False)
+    h = _back(params, h, memory, read, cfg)
     last = h[jnp.clip(valid - 1, 0, h.shape[0] - 1)]
     return (_logits(params, last, cfg), k_pages, v_pages, *wk, *wv, conv,
             ssm)
