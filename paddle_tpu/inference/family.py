@@ -7,9 +7,9 @@ where the parameters are, and the kinds and shapes of state a sequence
 keeps. There is no flag and no `EngineConfig` field that picks a model —
 the model object decides. `models/gpt.py`, `models/phi4flash.py`,
 `models/granitemoehybrid.py`, `models/brumby.py`, `models/dots3note.py`,
-`models/gigachat35.py` and `models/kimi_k2.py` each supply one; the GPT
-family describes exactly what the engine used to import, so its programs
-trace as before.
+`models/gigachat35.py`, `models/kimi_k2.py` and `models/solar_open2.py`
+each supply one; the GPT family describes exactly what the engine used to
+import, so its programs trace as before.
 
 The step functions' contracts (``steps`` is any namespace that has them):
 
@@ -62,7 +62,10 @@ second pool), ``state`` of several ``recurrent`` arrays a layer and
 returns its logits, the two pools (the second the engine's empty one,
 passed through), the state arrays in ``state(...)``'s order, and the counts
 last, which is the order `cache.py::after_prefill` and
-`programs.py::prefill_program` take them in. A family with ``page_rows``
+`programs.py::prefill_program` take them in. The same holds WITHOUT
+``page_rows``: twin K and V pools, ``state`` of several ``recurrent``
+arrays a layer and ``step_counts`` (`models/solar_open2.py`, the eighth
+family: K/V pages beside a delta-rule state). A family with ``page_rows``
 and NO ``state`` is all pages (the seventh family): the engine's prefix
 store serves it, and what would ship its pages through a wire blob (every
 blob is laid out as twin K and V pools: hand-off, migration, the spill

@@ -1,5 +1,6 @@
 """Gated delta-rule state ops over the serving engine's recurrent state
-(Gated DeltaNet, arXiv:2412.06464).
+(Gated DeltaNet, arXiv:2412.06464; with a decay per key CHANNEL, Kimi Delta
+Attention, arXiv:2510.26692).
 
 The repo's other recurrences (kernels/ssm.py, ssm2.py, retention.py) ADD an
 outer product to a decayed state. The delta rule first READS the state with
@@ -7,13 +8,24 @@ the new key and writes back only the error. Per value head, with ``S`` a
 ``dk x dv`` float32 matrix (keys on the sublanes, values on the lanes)::
 
     S <- exp(g) S                     g <= 0 the token's log decay
-    u  = beta (v - S^T k)             beta in (0, 1) the write strength
+    u  = beta (v - S^T k)             beta in (0, 2) the write strength
     S <- S + k u^T
     o  = S^T q                        the state AFTER the token
 
 so a token reads the state twice where the others read it once, and the
 tokens of a chunk are coupled: token ``i``'s ``u`` depends on every earlier
 ``u`` of the chunk through the keys' products.
+
+**One contract, two decays.** ``log_g`` is ``[.., H]``, ONE log decay a
+head (``exp(g)`` a scalar: Gated DeltaNet, `models/gigachat35.py`), or
+``[.., H, dk]``, one a KEY CHANNEL (``exp(g)`` scales the state's ROWS:
+``S <- Diag(exp(g)) S``, Kimi Delta Attention, `models/solar_open2.py`).
+``beta`` is any value in (0, 2): under 1 from a plain sigmoid, up to 2 where
+a model lets ``I - beta k k^T`` have a negative eigenvalue; nothing here
+depends on which. The scalar form's programs are what they were before the
+per-channel form came (`tests/test_tpu_compile.py` holds their op
+families); the per-channel form is registered and counted apart
+(``kernel.dispatch.kda_update.{xla|pallas}``, ``kda_chunk.xla``).
 
     S : [layers, slots, value heads, dk, dv]  float32
 
@@ -31,9 +43,11 @@ one contract (``kernel.dispatch.deltanet_update.{xla|pallas}``):
   result; a grid cell takes `HEADS` heads of one slot through VMEM, makes
   both reads and the rank-one write there and rewrites the block where it
   lay: the state crosses HBM once each way however often the rule reads
-  it. A dead slot's block comes back the same bits (it is still moved: a
-  block that a grid cell owns is written back; skipping the move takes
-  hand-made DMA and is left for the reading that shows dead slots cost).
+  it. A dead slot's block comes back the same bits, and it crosses HBM
+  like a live one's: a block that a grid cell owns is read and written
+  back (skipping the move takes hand-made DMA and waits for a reading that
+  shows dead slots cost). Per channel the decay arrives as a row of the
+  small operand like the key and is turned down the sublanes with it.
   Taken on a TPU.
 
 ``deltanet_chunk`` advances ONE slot by a chunk of a prompt (arm ``xla``),
@@ -58,6 +72,36 @@ sub-chunks of a 512-token launch: 8.6 GFLOP a layer, float32 operands at
 product accumulates in float32. A padded token carries ``g = 0``, ``beta =
 0`` and ``k = 0``: the state passes it unchanged.
 
+**The chunk form per channel** (`_sub_chunk_channels`). With ``G_i`` now a
+vector over the key channels the decay no longer factors out of the keys'
+products: ``A_ij = beta_i sum_d k_id k_jd exp(G_id - G_jd)``, likewise ``Q
+K^T``. They stay matrix products by splitting ``exp(G_i - G_j) = exp(G_i -
+R) exp(R - G_j)`` around a REFERENCE POINT ``R`` and folding one factor
+into each operand. A sub-chunk's `SUB` tokens are cut into blocks of
+`BLOCK` = 16 rows; for the rows ``i`` of block ``I`` the reference is
+``R_I``, the cumulative decay just BEFORE the block's first token. Then
+``exp(G_i - R_I) <= 1`` for every row, ``exp(R_I - G_j) <= 1`` for every
+column ``j`` of an earlier block, and only the columns of the SAME block
+take a positive exponent, what the block's own at most 16 tokens lose; the
+columns of later blocks are never read and get a factor of 0. That positive
+exponent is held to `MAX_EXP` = 80 (``e^80`` = 5.5e34, inside float32 with
+keys of norm 1): NO POSITIVE NUMBER LARGER THAN 80 IS EVER EXPONENTIATED,
+and every term of a product is at most 1 in size. The form is exact while
+a channel loses at most 80 nats inside one block of 16 tokens (5 nats a
+token sustained, a retention of 0.7% a token; the family's published
+initial ranges reach 1.6). Past that, the pairs ``j < i`` of that channel
+inside that block are read LOW, by the factor ``exp(80 - loss)``: nothing
+where the channel keeps losing that fast (their weight ``exp(G_i - G_j)``
+is then under ``e^-5`` a token apart), a pair's whole weight where ONE
+token wiped the channel and both tokens of the pair come after it. A
+token's read of its own write (``i = j``, weight 1) is the plain ``q . k``
+and takes no reference point. The other factors are ``exp(G_i)`` and
+``exp(G_C - G_j)`` (from the sub-chunk's start, to its end: never
+positive). ``S`` is carried between sub-chunks and a padded token passes as
+in the scalar form. The triangular system is solved BY BLOCKS
+(`_invert_unit_lower`), not by the doubling product: with ``beta`` up to 2
+the powers of ``A`` grow before they cancel.
+
 **The convolution before it** (`conv_update`, `conv_chunk`): depthwise,
 causal, no bias, over the last ``K - 1`` inputs carried per sequence,
 ``[slots, (K - 1) * width]`` with the taps side by side on the lanes
@@ -78,21 +122,28 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from paddle_tpu.kernels import registry
 
 __all__ = ["deltanet_update", "deltanet_chunk", "conv_update", "conv_chunk",
-           "state_shape", "HEADS", "SUB"]
+           "state_shape", "HEADS", "SUB", "BLOCK", "MAX_EXP"]
 
 
 registry.register_op("deltanet_update", impls=("xla", "pallas"),
                      candidates=registry.tpu_first)
 registry.register_op("deltanet_chunk", impls=("xla",))
+# the same ops with a decay per key channel (module docstring)
+registry.register_op("kda_update", impls=("xla", "pallas"),
+                     candidates=registry.tpu_first)
+registry.register_op("kda_chunk", impls=("xla",))
 
 _HI = jax.lax.Precision.HIGHEST
 HEADS = 8            # value heads a grid cell of the update kernel takes
 ROWS = 8             # sublanes of the update kernel's small operand
 SUB = 64             # tokens of a sub-chunk of `deltanet_chunk`
+BLOCK = 16           # rows that share a reference point (per channel)
+MAX_EXP = 80.0       # the largest positive number ever exponentiated
 
 
 def state_shape(layers: int, slots: int, heads: int, dk: int, dv: int):
@@ -106,13 +157,14 @@ def _mm(eq, a, b):
 
 # ------------------------------------------------------------------ decode
 
-def _update_kernel(layer_ref, active_ref, aux_ref, s_ref, so_ref, y_ref):
+def _update_kernel(layer_ref, active_ref, aux_ref, s_ref, so_ref, y_ref, *,
+                   channels):
     # one grid cell per (slot, block of HEADS value heads): s_ref / so_ref
     # the [HEADS, dk, dv] block of the layer's slab (the same HBM,
     # aliased); aux_ref [HEADS, ROWS, d]: rows k, q, v, exp(g) and beta (on
-    # every lane); y_ref [HEADS, dv]. A key is wanted down the sublanes:
-    # its row is broadcast and transposed (a [d, 1] operand would be a
-    # tile a number).
+    # every lane; with ``channels`` exp(g) is a row over dk like the key);
+    # y_ref [HEADS, dv]. A key is wanted down the sublanes: its row is
+    # broadcast and transposed (a [d, 1] operand would be a tile a number).
     from jax.experimental import pallas as pl
     del layer_ref
     live = active_ref[pl.program_id(0)] != 0
@@ -120,10 +172,13 @@ def _update_kernel(layer_ref, active_ref, aux_ref, s_ref, so_ref, y_ref):
     outs = []
     for h in range(n):
         rows = aux_ref[h]
-        kcol = jnp.broadcast_to(rows[0:1, :dk], (dv, dk)).T      # [dk, dv]
-        qcol = jnp.broadcast_to(rows[1:2, :dk], (dv, dk)).T
+
+        def down(r, rows=rows):                                  # [dk, dv]
+            return jnp.broadcast_to(rows[r:r + 1, :dk], (dv, dk)).T
+
+        kcol, qcol = down(0), down(1)
         old = s_ref[h]
-        s = rows[3:4, :dv] * old
+        s = (down(3) if channels else rows[3:4, :dv]) * old
         read = jnp.sum(s * kcol, axis=0, keepdims=True)          # [1, dv]
         u = rows[4:5, :dv] * (rows[2:3, :dv] - read)
         s = s + kcol * u
@@ -132,8 +187,8 @@ def _update_kernel(layer_ref, active_ref, aux_ref, s_ref, so_ref, y_ref):
     y_ref[...] = jnp.concatenate(outs, axis=0)
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def _pallas_update(state, layer, active, aux, *, interpret):
+@functools.partial(jax.jit, static_argnames=("interpret", "channels"))
+def _pallas_update(state, layer, active, aux, *, interpret, channels=False):
     """(y [B, H, dv], state): the kernel over the stored stack at a traced
     layer."""
     from jax.experimental import pallas as pl
@@ -153,7 +208,8 @@ def _pallas_update(state, layer, active, aux, *, interpret):
     vmem = 4 * n * dk * dv * 4 + (16 << 20)
     with x64_off_scope():
         new, y = pl.pallas_call(
-            _update_kernel, grid_spec=grid_spec,
+            functools.partial(_update_kernel, channels=channels),
+            grid_spec=grid_spec,
             out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
                        jax.ShapeDtypeStruct((b, h, dv), jnp.float32)],
             # operand 3 (after the two prefetched scalars and the small
@@ -171,14 +227,17 @@ def deltanet_update(state, log_g, beta, q, k, v, active, *, layer, impl=None,
                     interpret=None):
     """The delta-rule decode update: one token for every slot.
 
-    state : the stored stack [layers, B, H, dk, dv] float32; log_g, beta :
-    [B, H] f32 (``log_g <= 0``); q, k : [B, H, dk] (per VALUE head,
+    state : the stored stack [layers, B, H, dk, dv] float32; log_g : [B, H]
+    f32, one log decay a head, or [B, H, dk], one a key channel (``log_g <=
+    0``); beta : [B, H] f32 in (0, 2); q, k : [B, H, dk] (per VALUE head,
     normalised and scaled by the caller); v : [B, H, dv]; active : [B] bool:
     an inactive slot's state is left as it was (what it reads is
     unspecified); impl : ``xla`` / ``pallas`` / None (pallas on a TPU).
     Returns (o [B, H, dv] f32, state): the read-out of the state AFTER this
     token."""
-    impl = registry.dispatch("deltanet_update", forced=impl)
+    channels = log_g.ndim == 3
+    impl = registry.dispatch("kda_update" if channels else "deltanet_update",
+                             forced=impl)
     f32 = jnp.float32
     b, h, dk = k.shape
     dv = v.shape[-1]
@@ -199,12 +258,14 @@ def deltanet_update(state, log_g, beta, q, k, v, active, *, layer, impl=None,
             return jnp.broadcast_to(x[..., None, None], (b, h, 1, d))
 
         aux = jnp.concatenate(
-            [row(kf), row(qf), row(vf), lanes(decay), lanes(bf),
+            [row(kf), row(qf), row(vf),
+             row(decay) if channels else lanes(decay), lanes(bf),
              jnp.zeros((b, h, ROWS - 5, d), f32)], axis=2)
         return _pallas_update(state, jnp.asarray(layer, jnp.int32), active,
-                              aux, interpret=bool(interpret))
+                              aux, interpret=bool(interpret),
+                              channels=channels)
     old = state[layer].astype(f32)                          # [B, H, dk, dv]
-    s = decay[..., None, None] * old
+    s = (decay[..., None] if channels else decay[..., None, None]) * old
     u = bf[..., None] * (vf - jnp.einsum("bhkv,bhk->bhv", s, kf,
                                          precision=_HI))
     s = s + kf[..., :, None] * u[..., None, :]
@@ -230,6 +291,31 @@ def _solve_unit_lower(a):
     return inv
 
 
+def _invert_unit_lower(a):
+    """``(I + a)^-1`` for ``a`` [..., C, C] strictly lower triangular, BY
+    BLOCKS: the inverses of the diagonal blocks of 1, 2, 4, ... rows are
+    merged in pairs, ``[[L11, 0], [L21, L22]]^-1 = [[L11^-1, 0], [-L22^-1
+    L21 L11^-1, L22^-1]]``, two products of ``C x C`` a level (as many as
+    `_solve_unit_lower` makes) and no power of ``a``: with ``beta`` up to 2
+    and keys that lie close to one another the entries of ``a`` reach 1 and
+    its powers grow by orders before they cancel, which float32 does not
+    survive (64 tokens of neighbouring keys with ``beta`` near 2: the
+    doubling product read 2e-3 off the token recurrence, this form 6e-7)."""
+    c = a.shape[-1]
+    i = np.arange(c)             # the masks are constants of the program
+    inv = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape)
+    size = 1
+    while size < c:
+        # the lower-left block of every pair of neighbouring blocks
+        half, pair = i // size % 2, i // (2 * size)
+        l21 = (half[:, None] == 1) & (half[None, :] == 0) \
+            & (pair[:, None] == pair[None, :])
+        inv = inv - _mm("...ij,...jk->...ik", inv, _mm(
+            "...ij,...jk->...ik", jnp.where(l21, a, 0.0), inv))
+        size *= 2
+    return inv
+
+
 def _sub_chunk(s0, x):
     """One sub-chunk of one head: s0 [dk, dv]; x = (g [C], beta [C], q, k
     [C, dk], v [C, dv]). Returns (state after, o [C, dv])."""
@@ -252,22 +338,57 @@ def _sub_chunk(s0, x):
     return s1, o
 
 
+def _sub_chunk_channels(s0, x):
+    """`_sub_chunk` with a log decay per key channel: g [C, dk]. The keys'
+    products around one reference point a block of `BLOCK` rows (module
+    docstring)."""
+    g, beta, q, k, v = x
+    c, dk = g.shape
+    r = BLOCK if c % BLOCK == 0 else c
+    n = c // r
+    cum = jnp.cumsum(g, axis=0)                                  # [C, dk]
+    ref = (cum - g).reshape(n, r, dk)[:, 0]      # before a block's first row
+    rows = jnp.exp(cum.reshape(n, r, dk) - ref[:, None])         # <= 1
+    # columns seen from block I: earlier blocks <= 1, its own up to what
+    # the block loses (held to MAX_EXP), later blocks never read
+    seen = np.arange(c)[None, :] // r <= np.arange(n)[:, None]    # [n, C]
+    cols = jnp.where(seen[..., None], jnp.exp(jnp.minimum(
+        ref[:, None] - cum[None], MAX_EXP)), 0.0) * k[None]      # [n, C, dk]
+    kk = _mm("nrd,njd->nrj", rows * k.reshape(n, r, dk), cols).reshape(c, c)
+    qk = _mm("nrd,njd->nrj", rows * q.reshape(n, r, dk), cols).reshape(c, c)
+    below = np.tril(np.ones((c, c), bool), -1)
+    a = jnp.where(below, beta[:, None] * kk, 0.0)
+    up = jnp.exp(cum)
+    rhs = beta[:, None] * (v - _mm("id,dv->iv", up * k, s0))
+    u = _mm("ij,jv->iv", _invert_unit_lower(a), rhs)
+    # a token's own write is read undecayed: the diagonal is q . k itself
+    qk = jnp.where(below, qk, jnp.where(
+        np.eye(c, dtype=bool), jnp.sum(q * k, axis=-1)[:, None], 0.0))
+    o = _mm("id,dv->iv", up * q, s0) + _mm("ij,jv->iv", qk, u)
+    to_end = jnp.exp(cum[-1] - cum)
+    s1 = jnp.exp(cum[-1])[:, None] * s0 + _mm("id,iv->dv", to_end * k, u)
+    return s1, o
+
+
 def deltanet_chunk(state, log_g, beta, q, k, v, slot, fresh, valid, *, layer,
                    sub=SUB):
     """The delta-rule prefill: T tokens of ONE slot from its carried-in
     state (zero when ``fresh``), the closing state written back.
 
-    log_g, beta : [T, H] f32; q, k : [T, H, dk] (per VALUE head, normalised
-    and scaled); v : [T, H, dv]; tokens from ``valid`` on are padding and
-    leave the state alone; sub : tokens of a sub-chunk (T is cut into
-    ``T / sub`` of them, or taken whole when ``sub`` does not divide it).
-    Returns (o [T, H, dv] f32, state)."""
-    registry.count("deltanet_chunk", "xla")
+    log_g : [T, H] f32, one log decay a head, or [T, H, dk], one a key
+    channel; beta : [T, H] f32 in (0, 2); q, k : [T, H, dk] (per VALUE
+    head, normalised and scaled); v : [T, H, dv]; tokens from ``valid`` on
+    are padding and leave the state alone; sub : tokens of a sub-chunk (T
+    is cut into ``T / sub`` of them, or taken whole when ``sub`` does not
+    divide it). Returns (o [T, H, dv] f32, state)."""
+    channels = log_g.ndim == 3
+    registry.count("kda_chunk" if channels else "deltanet_chunk", "xla")
     f32 = jnp.float32
     t, h, dk = k.shape
     dv = v.shape[-1]
     live = (jnp.arange(t) < valid)[:, None]
-    g = jnp.where(live, log_g.astype(f32), 0.0)
+    g = jnp.where(live[..., None] if channels else live,
+                  log_g.astype(f32), 0.0)
     bf = jnp.where(live, beta.astype(f32), 0.0)
     kf = jnp.where(live[..., None], k.astype(f32), 0.0)
     c = sub if t % sub == 0 else t
@@ -277,7 +398,8 @@ def deltanet_chunk(state, log_g, beta, q, k, v, slot, fresh, valid, *, layer,
 
     s0 = jnp.where(fresh, 0, state[layer, slot]).astype(f32)
     s1, o = jax.lax.scan(
-        lambda s, x: jax.vmap(_sub_chunk)(s, x), s0,
+        lambda s, x: jax.vmap(_sub_chunk_channels if channels
+                              else _sub_chunk)(s, x), s0,
         (cut(g), cut(bf), cut(q.astype(f32)), cut(kf), cut(v.astype(f32))))
     o = jnp.moveaxis(o, 1, 2).reshape(t, h, dv)
     return o, state.at[layer, slot].set(s1.astype(state.dtype))

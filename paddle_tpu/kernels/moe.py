@@ -47,10 +47,10 @@ on the chip, a layer of 32 held experts of 256 took 1.42 ms grouped against
 and the ragged product's own overhead outweigh the FLOPs saved); Granite's
 36 of 72 at 10 a token read 1.51 against 1.06 at 64 tokens and 3.35 against
 2.13 at 512, so it stays dense (my chip run, PR 40; PERF.md section 6).
-Both thresholds lie BETWEEN measured points and no reading lies near either:
-``experts / top_k`` was read at 7.2 and 32 and nowhere between, tokens a
-call at 24, 64 (Granite's ratio alone) and 512. A configuration that falls
-between them wants a reading of its own before it trusts the order here.
+Both thresholds lie BETWEEN measured points: ``experts / top_k`` was read
+at 7.2 and 32 and nowhere between, tokens a call at 24, 64 (Granite's ratio
+alone) and 512. A configuration that falls between them wants a reading of
+its own before it trusts the order here.
 One such reading (my chip run, PR 42): 16 held of 256 at 8 a token and a
 width of 2,048 over a hidden size of 7,168, a layer, ``grouped`` against
 ``dense``: 1.15 / 1.95 ms at 24 tokens, 4.01 / 1.95 at 64, 2.99 / 1.97 at
@@ -60,6 +60,20 @@ and is right), 5.33 / 4.24 at 512. ``dense`` reads its 16 experts once
 ``grouped`` sorts 8 rows a token and its ragged products walk every row
 of the buffer, so it loses from 64 tokens on: for THIS share the cut lies
 under 64, between 24 and 64, and no cell runs there.
+A second (my chip run, PR 50): 40 held of 320 at 8 a token (``experts /
+top_k`` 40) and a width of 1,280 over a hidden size of 4,096, a layer
+alone, ``grouped`` against ``dense``: 1.17 / 1.76 ms at 24 tokens, 1.60 /
+1.77 at 48 (a decode step of that family: ``grouped`` is taken, and is
+right by a tenth), 3.52 / 1.76 at 64, 2.73 / 1.78 at 96, 4.08 / 1.78 at
+128, 4.29 / 2.00 at 256, 4.60 / 3.80 at 512 (a chunk: ``dense`` is taken,
+and is right; it is bound by its FLOPs from some 240 tokens on, 0.64 TFLOP
+at 512), 5.46 / 7.32 at 1,024. In that family's cell, one run each, the
+rule's choice read 952-972 tokens/s at 47.1 ms a token, ``grouped`` in
+step and chunk 905 at 50.8, ``dense`` in both 940 at 48.2. So the cut in
+tokens lies between 48 and 64 for both shares read there, and
+`GROUPED_UP_TO` stands at 48, the largest call at which ``grouped`` has
+won (it stood at 64, where it has lost twice; no cell makes a call of 49
+to 64 tokens for a router that wasteful, so no cell's arm moved).
 
 A router scores with a softmax over the chosen logits (``scoring``
 ``"softmax"``) or with a sigmoid (``"sigmoid"``: scores ``sigmoid(logits)``,
@@ -82,9 +96,9 @@ from paddle_tpu.kernels import registry
 
 __all__ = ["routed_experts", "route"]
 
-GROUPED_FROM = 16      # ``experts / top_k`` from which (read: 7.2, 32), and
-GROUPED_UP_TO = 64     # tokens a call up to which (read: 24, 512; and with
-#                        16 held, where 64 already loses: docstring),
+GROUPED_FROM = 16      # ``experts / top_k`` from which (read: 7.2, 32, 40),
+GROUPED_UP_TO = 48     # and tokens a call up to which (won at 24, 32 and 48;
+#                        lost at 64 with 16 and with 40 held: docstring),
 #                        ``grouped`` is first
 
 

@@ -27,7 +27,10 @@ contract (token-identical output, enforced by parity tests):
 - **xla** — the JAX-native reference: gather each sequence's pages into a
   [B, Lmax] window, masked f32-softmax attention. Correct everywhere, but
   HBM traffic and FLOPs scale with the pool's capacity (`pages_per_slot`),
-  not the live lengths.
+  not the live lengths. (One exception: a GROUPED prefill chunk, which has
+  this arm alone, over a page row longer than `LONG_ROW` positions walks
+  the row `WALK_BLOCK` keys a turn and stops at the chunk's last position:
+  `_xla_prefill_walk`.)
 - **pallas** — the authored ragged paged-attention kernel
   (`kernels/pallas/paged_attention.py`): grid over sequences, a BLOCK of a
   sequence's pages a loop turn (256 tokens at the GPT-2 widths, read off
@@ -405,6 +408,10 @@ def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
     g = query_groups(nh, dh, k_pages.shape[-1])
     nkv = nh // g
     scale = 1.0 / (dh ** 0.5) if scale is None else scale
+    if g > 1 and k_scale is None \
+            and page_table.shape[0] * k_pages.shape[2] > LONG_ROW:
+        return _xla_prefill_walk(q, k_pages, v_pages, page_table, start,
+                                 layer, nkv, scale)
     row = page_table[None]
     kk = gather_kv(k_pages, row, layer, nkv).astype(jnp.float32)
     vv = gather_kv(v_pages, row, layer, nkv).astype(jnp.float32)
@@ -426,6 +433,60 @@ def _xla_prefill_attention(q, k_pages, v_pages, page_table, start, valid,
     sc = jnp.where(mask[None, None], sc, -1e30)
     pr = jax.nn.softmax(sc, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", pr, vv).astype(q.dtype)
+
+
+LONG_ROW = 8192      # positions of a slot's page row past which a grouped
+#                      chunk walks the row by blocks (read: 4,096 one-shot,
+#                      17,920 by blocks)
+WALK_BLOCK = 2048    # keys a turn of that walk takes
+
+
+def _xla_prefill_walk(q, k_pages, v_pages, page_table, start, layer, nkv,
+                      scale):
+    """`_xla_prefill_attention` for grouped queries over a LONG page row:
+    the same masked float32 softmax, taken `WALK_BLOCK` keys a turn with a
+    running maximum, sum and output, and only as many turns as reach the
+    chunk's last position. The one-shot form gathers the slot's whole row
+    and holds scores ``[kv heads, group, chunk, row]`` whatever the
+    context: at 64 query heads over 8, a chunk of 512 and a row of 17,920
+    that is 2.35 GB and 10.2 ms a chunk for the first chunk of a prompt as
+    for its last (my chip run, PR 50); by blocks a chunk pays for the keys
+    it can see. A row of at most `LONG_ROW` positions keeps the one-shot
+    form, whose single product wins there."""
+    _, c, nh, dh = q.shape
+    g = nh // nkv
+    ps = k_pages.shape[2]
+    bp = max(1, WALK_BLOCK // ps)                  # pages a turn
+    block = bp * ps
+    turns_max = -(-page_table.shape[0] // bp)
+    table = jnp.pad(page_table, (0, turns_max * bp - page_table.shape[0])
+                    ).reshape(turns_max, bp)       # padded with the trash page
+    qs = (q[0].astype(jnp.float32) * scale).reshape(c, nkv, g, dh)
+    pos = (start + jnp.arange(c)).astype(jnp.int32)
+    f32 = jnp.float32
+
+    def turn(i, carry):
+        m, total, acc = carry
+        pages = jax.lax.dynamic_index_in_dim(table, i, 0, keepdims=False)
+        k = k_pages[layer, pages].reshape(block, nkv, dh).astype(f32)
+        v = v_pages[layer, pages].reshape(block, nkv, dh).astype(f32)
+        sc = jnp.einsum("qkgd,lkd->kgql", qs, k)
+        seen = (i * block + jnp.arange(block, dtype=jnp.int32))[None, :] \
+            <= pos[:, None]                                    # [C, block]
+        sc = jnp.where(seen[None, None], sc, -1e30)
+        m_new = jnp.maximum(m, sc.max(axis=-1))
+        pr = jnp.exp(sc - m_new[..., None])
+        keep = jnp.exp(m - m_new)
+        return (m_new, total * keep + pr.sum(axis=-1),
+                acc * keep[..., None] + jnp.einsum("kgql,lkd->kgqd", pr, v))
+
+    # key 0 is in every query's sight, so the first turn sets a real maximum
+    init = (jnp.full((nkv, g, c), -1e30, f32), jnp.zeros((nkv, g, c), f32),
+            jnp.zeros((nkv, g, c, dh), f32))
+    turns = jnp.minimum((start + c - 1) // block + 1, turns_max)
+    _, total, acc = jax.lax.fori_loop(0, turns.astype(jnp.int32), turn, init)
+    out = acc / total[..., None]                               # [k, g, C, dh]
+    return jnp.moveaxis(out, 2, 0).reshape(q.shape).astype(q.dtype)
 
 
 def _prefill_impl_call(impl, q, k_pages, v_pages, page_table, start, valid,

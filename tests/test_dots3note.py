@@ -450,6 +450,12 @@ def test_the_registry_picks_the_arm_by_what_dense_would_waste():
     cands = registry.ops()["moe_experts"].candidates
     assert cands(dict(experts=256, top_k=8, tokens=24))[0] == "grouped"
     assert cands(dict(experts=256, top_k=8, tokens=512))[0] == "dense"
+    # the cut in tokens stands at the largest call at which grouped has won
+    # (48, with 40 held of 320; it lost at 64 there and with 16 of 256)
+    assert cands(dict(experts=320, top_k=8, tokens=48))[0] == "grouped"
+    assert cands(dict(experts=320, top_k=8, tokens=64))[0] == "dense"
+    assert cands(dict(experts=384, top_k=8, tokens=32))[0] == "grouped"
+    assert cands(dict(experts=256, top_k=8, tokens=128))[0] == "dense"
     assert cands(dict(experts=72, top_k=10, tokens=64))[0] == "dense"
     assert cands({})[0] == "dense"
     assert registry.dispatch(
