@@ -15,65 +15,73 @@ capacity-padded dispatch does not promise and a served model needs.
 
 An expert is gated: ``[u | v] = x W1_e`` (the FIRST half is activated),
 ``(silu(u) * v) W2_e``; with ``limit`` (a published ``swiglu_limit``) the
-halves are clamped first, ``u`` from above and ``v`` both ways. Weights arrive as the held experts' stacks, ``w1
-[hi - lo, d, 2 f]`` and ``w2 [hi - lo, f, d]``.
+halves are clamped first, ``u`` from above and ``v`` both ways. Weights arrive
+as the held experts' stacks, ``w1 [hi - lo, d, 2 f]`` and ``w2 [hi - lo, f,
+d]``.
 
-Two arms (registered as ``moe_experts``; counted per program build in
-``kernel.dispatch.moe_experts.<arm>``). ``dense``: every token through every held
-expert, the gate (zero where the expert was not chosen) folded in before the
-second product, whose contraction runs over experts and expert width at
-once. It reads each held expert once and computes ``held / top_k`` times the
-needed FLOPs: right where the step is bound by reading the experts anyway (a
-decode step of 64 tokens hits every one of 36 held experts). On the chip at
-Granite-4.0-H's sizes it also beat a sorted `jax.lax.ragged_dot` product at
-every token count tried, 64 to 1,024 (PERF.md section 6, PR 31), because the
-ragged product copies a layer's experts out of the stack first.
+Three arms (registered as ``moe_experts``; counted per program build in
+``kernel.dispatch.moe_experts.<arm>``). ``dense``: every token through every
+held expert, the gate (zero where the expert was not chosen) folded in before
+the second product, whose contraction runs over experts and expert width at
+once. It reads each held expert once and computes ``experts / top_k`` times
+the needed FLOPs: right where a call hits every held expert anyway and its
+FLOPs hide under the read, which on a v5e (197 TFLOP/s over 819 GB/s) is up
+to some 240 tokens a call. XLA fuses the slice of a stack of layers into it,
+so it is also the one arm that reads a layer's experts out of a stack without
+copying them first.
 
-``grouped``: the assignments that landed on a held expert, sorted by
-expert into groups of unequal size (an expert with no row is a group of
-none; no row is dropped, the buffer holds every assignment a call can
+``grouped``: the assignments that landed on a held expert, sorted by expert
+into groups of unequal size (`_sorted_rows`: an expert with no row is a group
+of none; no row is dropped, the buffer holds every assignment a call can
 make), and only those rows multiplied: `jax.lax.ragged_dot` twice, the gate
 between. A token that is not ``valid`` (a dead slot of a decode step, a
-chunk's padding) has no row, gets zero and reads no expert. It makes the
-needed FLOPs and reads the experts that were HIT; it
-wants a layer's experts as arrays of their own (a family hands them as a
-tuple of leaves, one a layer: a slice of a stack is copied first, which is
-what lost PR 31's trial). The registry takes it first where the dense arm
-would make ``experts / top_k`` >= `GROUPED_FROM` times the needed FLOPs (32
-at 256 experts and 8 a token) AND a call has at most `GROUPED_UP_TO` tokens:
-on the chip, a layer of 32 held experts of 256 took 1.42 ms grouped against
-2.15 dense at 24 tokens (a decode step hits a third of the experts), and
-5.64 against 4.59 at 512 (a chunk hits them all, and the sorted rows' gather
-and the ragged product's own overhead outweigh the FLOPs saved); Granite's
-36 of 72 at 10 a token read 1.51 against 1.06 at 64 tokens and 3.35 against
-2.13 at 512, so it stays dense (my chip run, PR 40; PERF.md section 6).
-Both thresholds lie BETWEEN measured points: ``experts / top_k`` was read
-at 7.2 and 32 and nowhere between, tokens a call at 24, 64 (Granite's ratio
-alone) and 512. A configuration that falls between them wants a reading of
-its own before it trusts the order here.
-One such reading (my chip run, PR 42): 16 held of 256 at 8 a token and a
-width of 2,048 over a hidden size of 7,168, a layer, ``grouped`` against
-``dense``: 1.15 / 1.95 ms at 24 tokens, 4.01 / 1.95 at 64, 2.99 / 1.97 at
-96, 4.65 / 1.97 at 128 (a decode step of that family: ``dense`` is taken,
-and is right), 5.33 / 4.24 at 512. ``dense`` reads its 16 experts once
-(1.41 GB, 1.72 ms at the memory's rate) whatever the tokens up to 128;
-``grouped`` sorts 8 rows a token and its ragged products walk every row
-of the buffer, so it loses from 64 tokens on: for THIS share the cut lies
-under 64, between 24 and 64, and no cell runs there.
-A second (my chip run, PR 50): 40 held of 320 at 8 a token (``experts /
-top_k`` 40) and a width of 1,280 over a hidden size of 4,096, a layer
-alone, ``grouped`` against ``dense``: 1.17 / 1.76 ms at 24 tokens, 1.60 /
-1.77 at 48 (a decode step of that family: ``grouped`` is taken, and is
-right by a tenth), 3.52 / 1.76 at 64, 2.73 / 1.78 at 96, 4.08 / 1.78 at
-128, 4.29 / 2.00 at 256, 4.60 / 3.80 at 512 (a chunk: ``dense`` is taken,
-and is right; it is bound by its FLOPs from some 240 tokens on, 0.64 TFLOP
-at 512), 5.46 / 7.32 at 1,024. In that family's cell, one run each, the
-rule's choice read 952-972 tokens/s at 47.1 ms a token, ``grouped`` in
-step and chunk 905 at 50.8, ``dense`` in both 940 at 48.2. So the cut in
-tokens lies between 48 and 64 for both shares read there, and
-`GROUPED_UP_TO` stands at 48, the largest call at which ``grouped`` has
-won (it stood at 64, where it has lost twice; no cell makes a call of 49
-to 64 tokens for a router that wasteful, so no cell's arm moved).
+chunk's padding) has no row, gets zero and reads no expert. It is the arm of
+a CPU, of a mesh and of widths that are no whole lane tiles, and the oracle of
+the third arm's tests; on one TPU it is first nowhere, because the chip's
+ragged product walks every row tile of the buffer, ran at half of what
+reading the hit experts takes in a decode step and lost to ``dense`` in every
+chunk read (PRs 31-50), and its custom calls carry no scope into a trace.
+
+``pallas`` (kernels/pallas/grouped_experts.py): the same plan, the two
+products as the repo's own Mosaic kernels. Only the (row tile, expert) pairs
+that share a row are visited, an unhit expert is not read, a hit one is read
+once, the activation and the gate are applied on the float32 result in VMEM:
+the roundings of ``grouped``, bit for bit on the chip at every size read. It
+wants a layer's experts as arrays of their own (a family hands them as leaves,
+one a layer; a slice of a stack would be copied first).
+
+The rule (`_candidates`, from the call's shapes alone): on one TPU with
+widths in whole lane tiles and a router of `WASTEFUL_FROM` experts a chosen
+one or more, ``pallas`` first where ``dense`` would be bound by its FLOPs
+(`FLOP_BOUND_FROM` tokens a call) or where a call is expected to hit at most
+`HIT_UP_TO` of the held experts, ``1 - (1 - top_k / experts) ** tokens``;
+``dense`` first in between and for a router that wastes little. The readings
+behind it, ms a layer alone, ``pallas`` / ``dense`` / ``grouped`` (my chip
+run, PR 51; held of experts at 8 a token, [hidden, width]):
+
+- 40 of 320, [4096, 1280]: 48 tokens (70% hit) 1.56 / 1.79 / 2.06; 512
+  2.24 / 3.72 / 4.69 (reading the 40 once takes 1.54);
+- 32 of 256, [5120, 1536]: 24 tokens (53%) 1.14 / 2.09 / 1.27; 512 2.62 /
+  4.49 / 5.39 (1.85);
+- 12 of 384, [7168, 2048]: 32 tokens (49%) 0.95 / 1.51 / 1.37; 512 2.04 /
+  3.48 / 4.22 (1.29);
+- 16 of 256, [7168, 2048]: 128 tokens (98%) 2.25 / 1.99 / 4.65, so ``dense``
+  keeps that step; 512 2.64 / 4.23 / 5.33 (1.72);
+- 36 of 72 at 10 a token, [4096, 768], the experts as leaves of their own:
+  64 tokens 1.15 / 0.99 / 1.45; 512 1.77 / 2.02 / 3.15. That family hands a
+  layer's experts as slices of a stack (0.68 GB copied a call before any arm
+  but ``dense`` starts), so `WASTEFUL_FROM` keeps it on ``dense`` at both
+  sizes until the kernel takes a layer index into the stack (ROADMAP.md
+  Speed 1); between 7.2 and 32 experts a chosen one nothing was read.
+
+The cut in the hit share lies between 0.70 (won) and 0.98 (lost), the cut in
+tokens where the FLOPs of ``dense`` pass its read. Between the cuts the two
+shares read there disagree: 40 of 320 read 1.55 / 1.80 (``pallas`` /
+``dense``) at 64 tokens and 2.02 / 2.38 at 256, where 16 of 256 read 2.25 /
+1.99 at 128; the one call a cell makes there is the second, so ``dense``
+stands, and a configuration that falls there wants a reading of its own
+before it trusts the order here. Past them ``pallas`` pays for a larger
+call: 40 of 320 read 2.61 / 7.32 at 1,024 tokens.
 
 A router scores with a softmax over the chosen logits (``scoring``
 ``"softmax"``) or with a sigmoid (``"sigmoid"``: scores ``sigmoid(logits)``,
@@ -96,19 +104,31 @@ from paddle_tpu.kernels import registry
 
 __all__ = ["routed_experts", "route"]
 
-GROUPED_FROM = 16      # ``experts / top_k`` from which (read: 7.2, 32, 40),
-GROUPED_UP_TO = 48     # and tokens a call up to which (won at 24, 32 and 48;
-#                        lost at 64 with 16 and with 40 held: docstring),
-#                        ``grouped`` is first
+WASTEFUL_FROM = 16      # ``experts / top_k`` from which ``dense`` yields
+#                         (read: 7.2 over a stack of layers, then 32 to 48)
+FLOP_BOUND_FROM = 240   # tokens a call from which ``dense`` is bound by its
+#                         FLOPs: a v5e's 197 TFLOP/s over its 819 GB/s
+HIT_UP_TO = 0.8         # expected share of the held experts a call hits up
+#                         to which reading the hit ones alone pays (0.70
+#                         won, 0.98 lost)
+_LANES = 128
 
 
 def _candidates(ctx):
-    wasteful = ctx.get("experts", 0) >= GROUPED_FROM * ctx.get("top_k", 1)
-    few = ctx.get("tokens", GROUPED_UP_TO + 1) <= GROUPED_UP_TO
-    return ["grouped", "dense"] if wasteful and few else ["dense", "grouped"]
+    experts, top_k = ctx.get("experts", 0), ctx.get("top_k", 1)
+    tokens = ctx.get("tokens", 0)
+    wasteful = experts >= WASTEFUL_FROM * top_k
+    few_hit = wasteful and \
+        1 - (1 - top_k / experts) ** tokens <= HIT_UP_TO
+    if not (registry.on_one_tpu() and all(
+            ctx.get(w, 1) % _LANES == 0 for w in ("hidden", "width"))):
+        return ["grouped", "dense"] if few_hit else ["dense", "grouped"]
+    if few_hit or (wasteful and tokens >= FLOP_BOUND_FROM):
+        return ["pallas", "dense", "grouped"]
+    return ["dense", "pallas", "grouped"]
 
 
-registry.register_op("moe_experts", impls=("dense", "grouped"),
+registry.register_op("moe_experts", impls=("dense", "grouped", "pallas"),
                      candidates=_candidates)
 
 _HI = jax.lax.Precision.HIGHEST
@@ -135,11 +155,11 @@ def route(x, w_router, top_k, scoring="softmax", bias=None, scale=1.0):
         top / jnp.sum(top, axis=-1, keepdims=True) * scale
 
 
-def _gated(h, gate, limit=None):
-    """[u | v] in f32 -> silu(u) * v, times the assignment's gate; with
-    ``limit`` the activated half is clamped from above and the linear half
-    both ways first."""
-    u, v = jnp.split(h, 2, axis=-1)
+def _gated(u, v, gate, limit=None):
+    """The halves ``[u | v]`` of a first product in f32 -> silu(u) * v,
+    times the assignment's gate; with ``limit`` the activated half is
+    clamped from above and the linear half both ways first. (Also the body
+    of the ``pallas`` arm's first kernel, on a block in VMEM.)"""
     if limit is not None:
         u, v = jnp.minimum(u, limit), jnp.clip(v, -limit, limit)
     return u * jax.nn.sigmoid(u) * v * gate
@@ -151,34 +171,62 @@ def _dense(x, w1, w2, idx, gates, lo, limit=None):
     gate = jnp.sum(jnp.where(chosen, gates[:, :, None], 0.0), axis=1)
     h = jnp.einsum("td,edf->tef", x, w1,
                    preferred_element_type=jnp.float32)         # [T, E, 2f]
-    act = _gated(h, gate[:, :, None], limit).astype(x.dtype)
+    act = _gated(*jnp.split(h, 2, axis=-1), gate[:, :, None],
+                 limit).astype(x.dtype)
     return jnp.einsum("tef,efd->td", act, w2,
                       preferred_element_type=jnp.float32)
 
 
-def _grouped(x, w1, w2, idx, gates, lo, valid=None, limit=None):
-    t, k = idx.shape
-    held = w1.shape[0]
+def _sorted_rows(idx, gates, lo, held, valid):
+    """The assignments that landed on a held expert, sorted by expert:
+    (order [T k] the assignments' places in turn, the rows of no group last;
+    sizes [held] int32 rows an expert; gate [T k] f32 in that order, zero
+    for a row in no group)."""
+    k = idx.shape[1]
     e = idx.reshape(-1) - lo
     on = (e >= 0) & (e < held)
     if valid is not None:         # a dead slot's row reads no expert
         on &= jnp.repeat(valid, k)
     key = jnp.where(on, e, held)                  # the others' rows last
-    order = jnp.argsort(key, stable=True)
+    order = jnp.argsort(key, stable=True).astype(jnp.int32)
     sizes = jnp.sum(key[:, None] == jnp.arange(held), axis=0,
                     dtype=jnp.int32)
-    gate = jnp.where(on, gates.reshape(-1), 0.0)[order]
-    h = jax.lax.ragged_dot(x[order // k], w1, sizes,
-                           preferred_element_type=jnp.float32)
-    act = _gated(h, gate[:, None], limit).astype(x.dtype)
-    y = jax.lax.ragged_dot(act, w2, sizes,
-                           preferred_element_type=jnp.float32)
-    # a row past the last group is no expert's: whatever the product left
-    # there is not added
-    y = jnp.where((gate != 0.0)[:, None], y, 0.0).astype(x.dtype)
+    return order, sizes, jnp.where(on, gates.reshape(-1), 0.0)[order]
+
+
+def _summed(y, gate, order, t, k):
+    """The sorted rows' results ``y`` back at their tokens, the ``top_k``
+    of a token added in float32. A row past the last group is no expert's:
+    whatever the product left there is not added."""
     back = jnp.zeros(t * k, jnp.int32).at[order].set(
         jnp.arange(t * k, dtype=jnp.int32))
-    return jnp.sum(y[back].reshape(t, k, -1), axis=1, dtype=jnp.float32)
+    y = jnp.where((gate != 0.0)[back, None], y[back], 0.0)
+    return jnp.sum(y.reshape(t, k, -1), axis=1, dtype=jnp.float32)
+
+
+def _grouped(x, w1, w2, idx, gates, lo, valid=None, limit=None):
+    t, k = idx.shape
+    order, sizes, gate = _sorted_rows(idx, gates, lo, w1.shape[0], valid)
+    h = jax.lax.ragged_dot(x[order // k], w1, sizes,
+                           preferred_element_type=jnp.float32)
+    act = _gated(*jnp.split(h, 2, axis=-1), gate[:, None],
+                 limit).astype(x.dtype)
+    y = jax.lax.ragged_dot(act, w2, sizes,
+                           preferred_element_type=jnp.float32)
+    return _summed(y.astype(x.dtype), gate, order, t, k)
+
+
+def _pallas(x, w1, w2, idx, gates, lo, valid=None, limit=None):
+    from paddle_tpu.kernels.pallas import _compat, grouped_experts as ge
+    t, k = idx.shape
+    order, sizes, gate = _sorted_rows(idx, gates, lo, w1.shape[0], valid)
+    tiles = ge.plan(t * k, x.shape[1], w2.shape[1], x.dtype.itemsize)
+    pad = -(t * k) % tiles.tm                      # whole row tiles
+    y = ge.grouped_experts(
+        x[jnp.pad(order, (0, pad)) // k], jnp.pad(gate, (0, pad)), w1, w2,
+        sizes, plan=tiles, limit=limit,
+        interpret=_compat.default_interpret())
+    return _summed(y, gate, order, t, k)
 
 
 def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
@@ -189,7 +237,7 @@ def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
     w_router : [d, E] over ALL experts; w1 : [hi - lo, d, 2 f]; w2 :
     [hi - lo, f, d] of the held experts ``held`` = (lo, hi); top_k : experts
     a token; valid : [T] bool, the tokens ``counts`` counts (None: all; what
-    the others get is unspecified: zero from ``grouped``);
+    the others get is unspecified: zero from ``grouped`` and ``pallas``);
     scoring, bias [E], scale : the router's gates (`route`); limit : the
     gated MLP's clamp (``swiglu_limit``: ``min(u, limit)``, ``clip(v, -limit,
     limit)``; None: none); impl : an arm
@@ -204,10 +252,13 @@ def routed_experts(x, w_router, w1, w2, *, top_k, held, counts=None,
                          f"{w_router.shape[1]}")
     arm = registry.dispatch("moe_experts", forced=impl, ctx=dict(
         experts=w_router.shape[1], top_k=top_k, held=hi - lo,
-        tokens=x.shape[0]))
+        tokens=x.shape[0], hidden=x.shape[1], width=w2.shape[1]))
     idx, gates = route(x, w_router, top_k, scoring, bias, scale)
-    y = _dense(x, w1, w2, idx, gates, lo, limit) if arm == "dense" \
-        else _grouped(x, w1, w2, idx, gates, lo, valid, limit)
+    if arm == "dense":
+        y = _dense(x, w1, w2, idx, gates, lo, limit)
+    else:
+        y = (_pallas if arm == "pallas" else _grouped)(
+            x, w1, w2, idx, gates, lo, valid, limit)
     y = y.astype(x.dtype)
     if counts is None:
         return y

@@ -35,6 +35,11 @@ Kernels:
   of consecutive page ids by one copy), each sequence to its own length,
   a block's scores kept in VMEM (the arm and its plan are chosen in
   `kernels/mla.py`).
+- :mod:`grouped_experts` — the routed experts' two products over rows
+  sorted by expert: grid over the (row tile, expert) pairs that share a
+  row, read off the group sizes by scalar prefetch; an unhit expert not
+  read, a hit one read once, the gated activation applied on the float32
+  result in VMEM (the arm is chosen in `kernels/moe.py`).
 - :mod:`fused_layernorm` — single-pass layernorm fwd + analytic bwd
   (≈ `fused_layernorm` kernels in `phi/kernels/fusion/`).
 

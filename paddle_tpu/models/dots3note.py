@@ -427,11 +427,12 @@ def _ffn(h, p, valid, counts, cfg, dense, hit_at):
         return h + _gated_mlp(b, p["w1"], p["w2"]), counts
     n = cfg.n_held + 1
     with jax.named_scope("moe"):
-        routed, tally = moe.routed_experts(
-            b, p["router"], p["w1"], p["w2"], top_k=cfg.experts_per_token,
-            held=cfg.experts_held, counts=counts[:n], valid=valid,
-            scoring="sigmoid", bias=p["bias"],
-            scale=cfg.routed_scaling_factor)
+        with jax.named_scope("moe_experts"):
+            routed, tally = moe.routed_experts(
+                b, p["router"], p["w1"], p["w2"], top_k=cfg.experts_per_token,
+                held=cfg.experts_held, counts=counts[:n], valid=valid,
+                scoring="sigmoid", bias=p["bias"],
+                scale=cfg.routed_scaling_factor)
         shared = _gated_mlp(b, p["shared.w1"], p["shared.w2"])
     hit = jnp.sum(tally[:n - 1] > counts[:n - 1], dtype=counts.dtype)
     return h + routed + shared, jnp.concatenate(

@@ -442,22 +442,26 @@ def test_grouped_arm_gives_a_dead_token_no_row():
 
 
 def test_the_registry_picks_the_arm_by_what_dense_would_waste():
-    """256 experts and 8 a token (32x the needed work under ``dense``) in
-    a decode step's few tokens: ``grouped`` first; the same in a chunk of
-    512 (every held expert hit), and 72 and 10, Granite's, at any size:
-    ``dense``; a forced arm wins either way."""
+    """Off a TPU (these tests; the rule on one TPU, where ``pallas`` takes
+    the sparse calls and the chunks: tests/test_moe_pallas.py): 256 experts
+    and 8 a token (32x the needed work under ``dense``) in a decode step's
+    few tokens, which leave half the held experts unhit: ``grouped``
+    first; the same in a chunk of 512 (every held expert hit), and 72 and
+    10, Granite's, at any size: ``dense``; a forced arm wins either way."""
     from paddle_tpu.kernels import registry
     cands = registry.ops()["moe_experts"].candidates
     assert cands(dict(experts=256, top_k=8, tokens=24))[0] == "grouped"
     assert cands(dict(experts=256, top_k=8, tokens=512))[0] == "dense"
-    # the cut in tokens stands at the largest call at which grouped has won
-    # (48, with 40 held of 320; it lost at 64 there and with 16 of 256)
+    # the cut is in the share of the held experts a call is expected to
+    # hit, 1 - (1 - top_k / experts) ** tokens, at 0.8: 70% at 48 tokens of
+    # 320 experts, 80.2% at 64
     assert cands(dict(experts=320, top_k=8, tokens=48))[0] == "grouped"
     assert cands(dict(experts=320, top_k=8, tokens=64))[0] == "dense"
     assert cands(dict(experts=384, top_k=8, tokens=32))[0] == "grouped"
     assert cands(dict(experts=256, top_k=8, tokens=128))[0] == "dense"
     assert cands(dict(experts=72, top_k=10, tokens=64))[0] == "dense"
     assert cands({})[0] == "dense"
+    assert "pallas" not in cands(dict(experts=256, top_k=8, tokens=24))
     assert registry.dispatch(
         "moe_experts", forced="dense",
         ctx=dict(experts=256, top_k=8, tokens=24)) == "dense"

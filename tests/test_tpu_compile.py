@@ -887,6 +887,16 @@ def test_granite_step_program_copies_no_state_or_expert_on_v5e(
             assert [f for f in families if re.search(want, f)], want
 
 
+def _experts_kernels(text):
+    """The Mosaic calls of an optimized HLO whose op name holds the scope
+    ``moe_experts``: the routed experts' two products a sparse layer where
+    the ``pallas`` arm of `kernels/moe.py` was taken."""
+    from harness import trace
+    return [ln for ln in text.splitlines() if "tpu_custom_call" in ln
+            and trace._scope((trace._OP_NAME.search(ln) or [None, ""])[1],
+                             frozenset(("moe_experts",)))]
+
+
 def _under_scope(text, scope):
     """``[(family, largest float32 result in elements, line)]`` of the
     materialised instructions of an optimized HLO whose name stack holds
@@ -1056,6 +1066,17 @@ def test_dots3_step_program_relays_no_pool_and_keeps_its_families_on_v5e(
             assert not any(found), (metric["patterns"], found)
             assert metric["scopes"] == ["mla"]
             continue
+        if name == "dots3_experts":
+            # both call sites take the repo's own grouped product (PR 51):
+            # none of the three families is left (the dense arm's float32
+            # [512, 32, 3072], the ragged products' [192, .]); the scope
+            # the metric asks for is on two Mosaic calls a sparse layer
+            assert not any(found), (metric["patterns"], found)
+            assert metric["scopes"] == ["moe_experts"]
+            assert "ragged-dot" not in text
+            assert len(_experts_kernels(text)) \
+                == 2 * (cfg.n_layers - cfg.first_dense)
+            continue
         # each kernel's patterns name a chunk's ops and a decode step's:
         # some of them are in each program, all of them in the two
         assert any(found), (name, metric["patterns"], found)
@@ -1159,8 +1180,15 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
     # the one full layer attends inside a kernel in both programs: a decode
     # step's paged absorbed walk (kernels/pallas/latent_decode.py) beside
     # the linear layers' updates, a chunk's per-head walk (latent_prefill)
+    # and a chunk's routed experts in two kernels a sparse layer (kernels/
+    # pallas/grouped_experts.py; a decode step of 128 tokens hits every
+    # held expert and keeps the dense arm)
+    sparse = len(cfg.layer_types) - cfg.first_dense
     assert kernels == len(cfg.full_layers) + (
-        len(cfg.linear_layers) if program == "decode_step" else 0)
+        len(cfg.linear_layers) if program == "decode_step" else 2 * sparse)
+    assert len(_experts_kernels(text)) \
+        == (0 if program == "decode_step" else 2 * sparse)
+    assert "ragged-dot" not in text
     under = _under_scope(text, "mla")
     calls = [ln for f, _, ln in under if f.startswith("custom-call")
              and "tpu_custom_call" in ln]
@@ -1209,10 +1237,17 @@ def test_giga_step_program_fits_and_keeps_its_families_on_v5e(
         found = [bool([f for f in families
                        if re.search(p.format(**shapes), f)])
                  for p in metric["patterns"]]
+        print(program, name, found)
+        if (program, name) == ("prefill_chunk_step", "giga_experts"):
+            # the chunk's family (the dense arm's [512, 16, 4096]) went
+            # with the arm: the scope the metric asks for is on the Mosaic
+            # calls counted above
+            assert not any(found), (metric["patterns"], found)
+            assert metric["scopes"] == ["moe_experts"]
+            continue
         # a kernel's patterns name both arms' ops, a chunk's and a decode
         # step's: some of them are in each program
         assert any(found), (name, metric["patterns"], found)
-        print(program, name, found)
     other = "deltanet_chunk" if program == "decode_step" \
         else "deltanet_update"
     # the decode update's own patterns match nothing a chunk makes (the

@@ -110,7 +110,8 @@ def test_solar_step_program_fits_and_names_its_kernels_on_v5e(
     n = sm.step_counts(cfg)
     ops = ("kda_update.pallas", "kda_update.xla", "kda_chunk.xla",
            "paged_attention.pallas", "prefill_attention.xla",
-           "deltanet_update.pallas", "deltanet_chunk.xla")
+           "deltanet_update.pallas", "deltanet_chunk.xla",
+           "moe_experts.pallas", "moe_experts.grouped", "moe_experts.dense")
     count = {o: metrics.counter(f"kernel.dispatch.{o}") for o in ops}
     built = {o: c.value for o, c in count.items()}
     if program == "decode_step":
@@ -131,6 +132,10 @@ def test_solar_step_program_fits_and_names_its_kernels_on_v5e(
     want.update({"kda_update.pallas": 3, "paged_attention.pallas": 1}
                 if program == "decode_step" else
                 {"kda_chunk.xla": 3, "prefill_attention.xla": 1})
+    # every layer routes, and in both programs through the repo's own
+    # grouped product (a step of 48 tokens hits 70% of the 40 held experts,
+    # a chunk of 512 would bind ``dense`` by its FLOPs)
+    want["moe_experts.pallas"] = cfg.num_layers
     assert grew == want
     # every scope the cell's metric files ask for, on the ops of the
     # program that makes it (`harness/trace.py`: the innermost wanted scope
@@ -153,6 +158,19 @@ def test_solar_step_program_fits_and_names_its_kernels_on_v5e(
     assert not any(under[s] for s in others)
     mosaic = {s: len([ln for ln in under[s] if "tpu_custom_call" in ln])
               for s in asked}
+    # two Mosaic calls a layer under ``moe_experts`` (kernels/pallas/
+    # grouped_experts.py), no ragged product left (the chip's compiler
+    # strips those of every scope), and no custom call with a result the
+    # share's ``unnamed`` patterns would add to the scope a second time
+    assert mosaic["moe_experts"] == 2 * cfg.num_layers
+    assert "ragged-dot" not in text
+    from harness import solar_bytes
+    shapes = solar_bytes.trace_shapes(cfgj)
+    families = {trace.family(ln.strip().removeprefix("ROOT "))
+                for ln in text.splitlines() if " = " in ln}
+    for p in harness_spec.layer_metric(
+            "solar_experts_roofline_share")["unnamed"]:
+        assert not [f for f in families if re.search(p.format(**shapes), f)]
     if program == "decode_step":
         assert (mosaic["kda_update"], mosaic["gqa_decode"]) == (3, 1)
         # the update rewrites the stack where it lies: no copy of a layer's
